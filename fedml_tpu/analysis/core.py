@@ -45,7 +45,7 @@ __all__ = [
 
 #: names whose call mints a jit compile site; the first positional
 #: argument is the traced callable (fedavg.py `ProgramSite(self._round,
-#: ...)`, elastic.py `CompiledRoundCache(fn, ...)`, compat.shard_map)
+#: ...)`, elastic.py `CompiledRoundCache(fn, ...)`, jax.shard_map)
 JIT_ENTRY_NAMES = frozenset(
     {"jit", "pjit", "ProgramSite", "shard_map", "CompiledRoundCache"}
 )

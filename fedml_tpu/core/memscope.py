@@ -336,7 +336,7 @@ def read_device_memory() -> tuple[str, list[dict]]:
         cap = (
             stats.get("bytes_limit")
             or stats.get("bytes_reservable_limit")
-            or perf.device_hbm_capacity(kind)
+            or perf.device_peaks(d)[2]
         )
         readings.append({
             "device": f"d{i}",

@@ -58,10 +58,10 @@ from fedml_tpu.core import telemetry
 # ---------------------------------------------------------------------------
 
 # (bf16 peak FLOP/s, HBM bandwidth B/s, HBM capacity bytes) per chip.
-# v5e (TPU v5 lite): 197 bf16 TFLOP/s, ~819 GB/s, 16 GB HBM. Fallbacks
-# for other chips; the point of MFU here is a stable, honest
-# denominator, and the capacity column is the headroom denominator the
-# memory monitor (core/memscope.py) alarms against.
+# v5e (TPU v5 lite): 197 bf16 TFLOP/s, ~819 GB/s, 16 GB HBM (Google
+# Cloud documentation, "TPU v5e"). The point of MFU here is a stable,
+# honest denominator, and the capacity column is the headroom
+# denominator the memory monitor (core/memscope.py) alarms against.
 PEAKS: dict[str, tuple[float, float, float]] = {
     "TPU v5 lite": (197e12, 819e9, 16e9),
     "TPU v4": (275e12, 1228e9, 32e9),
@@ -70,17 +70,21 @@ PEAKS: dict[str, tuple[float, float, float]] = {
 }
 
 
-def device_peak_flops(kind: str) -> float | None:
-    """bf16 MXU peak for a device kind (None for unknown kinds — CPU
-    hosts get no MFU gauge rather than a made-up denominator)."""
-    return PEAKS.get(kind, (None, None, None))[0]
-
-
-def device_hbm_capacity(kind: str) -> float | None:
-    """Per-chip HBM capacity in bytes (None for unknown kinds — the
-    memory monitor then prefers the device's own ``bytes_limit`` and
-    otherwise reports no headroom rather than a made-up one)."""
-    return PEAKS.get(kind, (None, None, None))[2]
+def device_peaks(device) -> tuple[float | None, float | None, float | None]:
+    """The :data:`PEAKS` row of a jax device. A device of another
+    platform (the CPU test mesh) has no row — it gets no MFU gauge and
+    no table headroom rather than a made-up denominator. A TPU whose
+    ``device_kind`` is missing from the table is an error, not a
+    silent ``None``."""
+    row = PEAKS.get(device.device_kind)
+    if row is not None:
+        return row
+    if device.platform == "tpu":
+        raise KeyError(
+            f"TPU device_kind {device.device_kind!r} is not in "
+            "fedml_tpu.core.perf.PEAKS: add its published peaks"
+        )
+    return (None, None, None)
 
 
 _COST_CACHE: dict = {}
@@ -146,10 +150,12 @@ def useful_round_cost(sim) -> float | None:
                 .compile()
                 .cost_analysis()
             )
-            if isinstance(ca, list):
-                ca = ca[0]
             step_flops = float(ca.get("flops") or 0) or None
         except Exception:
+            # a CPU backend may have no cost model; on the chip a
+            # missing MFU numerator is an error, not a None
+            if jax.devices()[0].platform == "tpu":
+                raise
             return None
         _COST_CACHE[cost_key] = step_flops
     counts = np.asarray(sim.arrays.counts)
@@ -604,9 +610,10 @@ def build_sim_perf(sim) -> tuple[RoundProfiler | None,
     """Perf wiring for a round-loop driver (``FedAvgSim.run`` and the
     experiment harness share this so the two loops cannot drift).
     Returns ``(None, None)`` unless ``cfg.fed.profile_rounds > 0`` —
-    the off path costs one attribute read. The analytic round cost is
-    resolved best-effort: sims outside the FedAvg family still get
-    wall-clock gauges and capture windows, just no MFU."""
+    the off path costs one attribute read. The analytic round cost
+    covers the FedAvg family; other sims still get wall-clock gauges
+    and capture windows, just no MFU. On a TPU a cost model that fails
+    is an error, not a missing gauge."""
     cfg = getattr(sim, "cfg", None)
     k = int(getattr(getattr(cfg, "fed", None), "profile_rounds", 0) or 0)
     if k <= 0:
@@ -618,16 +625,20 @@ def build_sim_perf(sim) -> tuple[RoundProfiler | None,
     if out_dir is None:
         out_dir = os.path.join(cfg.out_dir, cfg.run_name, "telemetry")
         os.makedirs(out_dir, exist_ok=True)
+    from fedml_tpu.algorithms.fedavg import FedAvgSim
+
     flops = None
-    try:
-        flops = useful_round_cost(sim)
-    except Exception:
-        flops = None
+    if isinstance(sim, FedAvgSim):
+        try:
+            flops = useful_round_cost(sim)
+        except Exception:
+            if jax.devices()[0].platform == "tpu":
+                raise
     # the sharded runtime spreads the round over its mesh: the honest
     # denominator is every chip it occupies, not one
     mesh = getattr(sim, "mesh", None)
     n_dev = int(mesh.devices.size) if mesh is not None else 1
-    peak = device_peak_flops(jax.devices()[0].device_kind)
+    peak = device_peaks(jax.devices()[0])[0]
     fuse = int(getattr(cfg.fed, "fuse_rounds", 1) or 1)
     profiler = RoundProfiler(k, out_dir, flops_per_round=flops,
                              fuse_rounds=fuse)

@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
@@ -186,7 +187,10 @@ def shard_client_banks(
 ) -> ShardedClientBanks:
     """Build :class:`ShardedClientBanks` from host-side federated data.
     ``max_n`` (per-client padded row length) is GLOBAL so every shard's
-    local update runs the same number of steps in lockstep."""
+    local update runs the same number of steps in lockstep. The leaves
+    are HOST arrays: the runtime places them over its mesh itself
+    (``jax.device_put`` with the clients-axis sharding), so the whole
+    set never lands on one device first."""
     n = data.num_clients
     assert n % n_shards == 0, (n, n_shards)
     K = n // n_shards
@@ -222,10 +226,10 @@ def shard_client_banks(
             mask[s, j, :m] = 1.0
             off += m
     return ShardedClientBanks(
-        x=jnp.asarray(xb, dtype),
-        y=jnp.asarray(yb),
-        idx=jnp.asarray(idx),
-        mask=jnp.asarray(mask),
+        x=xb.astype(np.dtype(dtype)),
+        y=yb.astype(jax.dtypes.canonicalize_dtype(yb.dtype)),
+        idx=idx,
+        mask=mask,
     )
 
 

@@ -117,7 +117,7 @@ def make_sequence_parallel_lm_step(
 
     from jax.sharding import PartitionSpec as P
 
-    from fedml_tpu.core.compat import shard_map
+    from jax import shard_map
 
     from fedml_tpu.ops.ring_attention import ring_attention
 

@@ -13,6 +13,7 @@ returns zero-copy numpy views into the frame.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import struct
 import subprocess
@@ -57,7 +58,12 @@ def _build() -> str | None:
             check=True, capture_output=True, timeout=120,
         )
         return _LIB
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as err:
+        # said once, loudly: the wire then runs the numpy paths
+        logging.getLogger(__name__).warning(
+            "native codec not built (%s): falling back to the "
+            "pure-numpy frame codec", err,
+        )
         return None
 
 

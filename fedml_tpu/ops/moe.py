@@ -57,7 +57,7 @@ def make_expert_parallel_moe(mesh, axis_name: str = "ep",
 
     Call with GLOBAL arrays; returns the sharded computation wrapped and
     ready (in/out specs applied)."""
-    from fedml_tpu.core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     p = mesh.shape[axis_name]

@@ -25,7 +25,7 @@ def make_pipeline(stage_fn, mesh, axis_name: str = "pp"):
 
     Returns y [M, mb, ...] (replicated; produced on the last stage and
     psum-broadcast)."""
-    from fedml_tpu.core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     p = mesh.shape[axis_name]

@@ -157,7 +157,7 @@ def make_sequence_parallel_attention(mesh, axis_name: str, causal: bool):
     takes/returns GLOBAL [B, T, H, D] arrays sharded on T."""
     from jax.sharding import PartitionSpec as P
 
-    from fedml_tpu.core.compat import shard_map
+    from jax import shard_map
 
     spec = P(None, axis_name, None, None)
     fn = functools.partial(

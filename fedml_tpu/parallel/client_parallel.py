@@ -34,9 +34,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-from fedml_tpu.core.compat import shard_map
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fedml_tpu.config import ExperimentConfig
 from fedml_tpu.core import bulk as BK
@@ -281,10 +280,19 @@ class ShardedFedAvg(FedAvgSim):
         self.arrays, self.batch_size = arrays_and_batch(
             data, cfg.data, device=False
         )
-        self.banks = shard_client_banks(
-            data,
-            self.n_client_shards,
-            pad_multiple=1 if cfg.data.full_batch else cfg.data.batch_size,
+        # placed ONCE with the layout the round's shard_map consumes
+        # (leading shard axis over ``clients``, replicated over
+        # ``data``): banks left on one device would be re-scattered
+        # from it on every round
+        self.banks = jax.device_put(
+            shard_client_banks(
+                data,
+                self.n_client_shards,
+                pad_multiple=(
+                    1 if cfg.data.full_batch else cfg.data.batch_size
+                ),
+            ),
+            NamedSharding(self.mesh, P(self.client_axis)),
         )
         assert self.banks.max_client_samples == self.arrays.max_client_samples
 

@@ -60,9 +60,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from fedml_tpu.core.compat import shard_map
 
 from fedml_tpu.core import compress as C
 from fedml_tpu.core import elastic as E

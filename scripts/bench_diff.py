@@ -13,11 +13,10 @@ Rules:
 
 - direction comes from the unit: ``rounds/sec`` / ``hit_rate`` /
   ``% test acc`` regress DOWN; ``seconds`` / ``ms/round`` regress UP;
-- records marked ``fallback`` (CPU measurements — the marked records
-  ``bench.py`` emits when the TPU backend is unavailable) are NEVER
+- records marked ``fallback`` (CPU measurements — ``bench.py`` marks
+  every record of an intentional ``JAX_PLATFORMS=cpu`` run) are NEVER
   compared against unmarked (TPU) baselines: the pair is reported as
-  skipped, which is exactly the honest outcome for a BENCH_r05-style
-  round;
+  skipped;
 - the default threshold (8%) sits above the observed window-to-window
   spread of the rate lines (``window_rates`` in each record bracket
   the best-of-3 estimator at a few percent);
@@ -26,7 +25,7 @@ Rules:
 
 Usage::
 
-    python scripts/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python scripts/bench_diff.py BENCH_r04.json runs/bench_latest.jsonl
     python scripts/bench_diff.py old.jsonl new.jsonl --threshold 0.05 --strict
 """
 

@@ -33,11 +33,11 @@ END = "<!-- BENCH_TABLE:END -->"
 
 def load_records(path: str = ARTIFACT) -> dict[str, dict]:
     """Last record per metric name wins (the artifact is append-only
-    across bench invocations). Records marked ``fallback`` — the
-    CPU-fallback measurements ``bench.py`` emits when the TPU backend
-    is unavailable — are SKIPPED: the doc perf table states TPU
-    numbers, and a fallback run must not silently overwrite them
-    (docs/PERFORMANCE.md states the fallback-record rule)."""
+    across bench invocations). Records marked ``fallback`` — what
+    ``bench.py`` measured in an intentional ``JAX_PLATFORMS=cpu`` run —
+    are SKIPPED: the doc perf table states TPU numbers, and a CPU run
+    must not silently overwrite them (docs/PERFORMANCE.md, "Bench
+    trustworthiness")."""
     recs: dict[str, dict] = {}
     with open(path) as f:
         for line in f:
@@ -136,15 +136,14 @@ def main() -> int:
                          "bench.py first")
     recs = load_records(args.artifact)
     if not recs:
-        # the artifact exists but holds no TPU-comparable records — a
-        # fallback-only run (TPU down; every record marked
+        # the artifact exists but holds no TPU-comparable records — an
+        # intentional JAX_PLATFORMS=cpu run (every record marked
         # fallback="cpu") on a fresh clone. The doc table KEEPS its
         # last TPU numbers: rendering/patching an empty table here
-        # would erase them, which is exactly what the fallback-record
-        # rule forbids — so every mode (write, --check, --stdout)
+        # would erase them — so every mode (write, --check, --stdout)
         # leaves the doc untouched.
         print("bench artifact has no TPU-comparable records "
-              "(cpu-fallback run); doc table left untouched")
+              "(CPU run); doc table left untouched")
         return 0
     table = render_table(recs)
     if args.stdout:

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 pytestmark = pytest.mark.slow
-from fedml_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from fedml_tpu.ops.flash_attention import flash_attention

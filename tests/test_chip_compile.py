@@ -1,0 +1,163 @@
+"""The programs of the main path compile for a DESCRIBED v5e.
+
+The TPU compiler is installed without a chip attached: it compiles for
+a topology that is described, not present, and refuses what the chip
+would refuse (tiling, fast-memory and HBM limits, partitioning). These
+tests keep the Pallas kernel, the ResNet-56 cohort local update at
+bench width and the four-chip ``ShardedFedAvg`` round compiling for
+``v5e:2x2`` at no chip time. Nothing runs: a compile that passes is not
+a chip run (``chip_smoke.py`` is).
+
+Only one process may load the TPU library, so the topology is described
+inside a module-scoped fixture of THIS file — never at import, never in
+``conftest.py`` — and every such compile lives here, in one file, which
+xdist's ``loadfile`` hands to one worker.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out of there
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _tree_bytes(tree) -> int:
+    return int(sum(
+        np.prod(x.shape) * np.dtype(x.dtype).itemsize
+        for x in jax.tree.leaves(tree)
+    ))
+
+
+def test_flash_attention_kernel_compiles_for_v5e(one_chip):
+    from fedml_tpu.ops.flash_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct((4, 2048, 8, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, causal=True)
+    ).lower(x, x, x).compile()
+    # the kernel itself is in the program, not an interpreted expansion
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_resnet56_cohort_update_compiles_for_v5e(one_chip):
+    """The headline local update: the whole 10-client cohort as one
+    widened ResNet-56 (``ops/cohort_conv``'s custom primitive), batch
+    32, bf16 — ``bench.headline_config``'s widths."""
+    import bench
+    from fedml_tpu.algorithms.base import build_cohort_local_update
+    from fedml_tpu.algorithms.fedavg import FedAvgSim
+    from fedml_tpu.data.loaders import load_dataset
+    from fedml_tpu.models import create_model
+
+    cfg = bench.headline_config()
+    assert cfg.train.compute_dtype == "bfloat16"
+    sim = FedAvgSim(create_model(cfg.model), load_dataset(cfg.data), cfg)
+    cohort = cfg.fed.clients_per_round
+    update = build_cohort_local_update(
+        sim.model, sim.task, cfg.train, sim.batch_size,
+        sim.arrays.max_client_samples, cohort,
+    )
+    a = sim.arrays
+    keys = jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), cohort)
+    )
+    rows = lambda t: jax.ShapeDtypeStruct(
+        (cohort,) + t.shape[1:], t.dtype, sharding=one_chip
+    )
+    compiled = jax.jit(update).lower(
+        _shapes(jax.eval_shape(sim.init).variables, one_chip),
+        rows(a.idx), rows(a.mask),
+        _shapes(a.x, one_chip), _shapes(a.y, one_chip),
+        _shapes(keys, one_chip),
+    ).compile()
+    assert " convolution(" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes)
+    assert 0 < need < V5E_HBM_BYTES, ma
+
+
+def test_sharded_round_compiles_for_four_v5e_chips(topo):
+    """``ShardedFedAvg``'s round over a 4-chip ``clients`` mesh: the
+    aggregation is a collective, and each chip is handed a quarter of
+    the sample banks, not all of them."""
+    import bench
+    from fedml_tpu.data.loaders import load_dataset
+    from fedml_tpu.models import create_model
+    from fedml_tpu.parallel import ShardedFedAvg, make_mesh
+
+    cfg = bench.headline_config()
+    cfg = dataclasses.replace(
+        cfg, fed=dataclasses.replace(cfg.fed, clients_per_round=8)
+    )
+    sim = ShardedFedAvg(
+        create_model(cfg.model), load_dataset(cfg.data), cfg,
+        make_mesh(client_axis=4, data_axis=1, devices=jax.devices()[:4]),
+    )
+    # the banks the constructor placed are spread over its mesh
+    assert len(sim.banks.x.sharding.device_set) == 4
+    # steer the round onto the described chips: same axes, same layout
+    sim.mesh = Mesh(
+        np.array(topo.devices[:4]).reshape(4, 1), sim.mesh.axis_names
+    )
+    rep = NamedSharding(sim.mesh, P())
+    by_client = NamedSharding(sim.mesh, P(sim.client_axis))
+    state = _shapes(jax.eval_shape(sim.init), rep)
+    banks = _shapes(sim.banks, by_client)
+    compiled = jax.jit(sim._sharded_round, donate_argnums=(0,)).lower(
+        state, banks
+    ).compile()
+    assert "all-reduce" in compiled.as_text()
+    # each chip's arguments are the replicated state (which comes back
+    # as the output, in the same padded device layout) and its quarter
+    # of the banks — not the whole set
+    ma = compiled.memory_analysis()
+    banks_per_chip = ma.argument_size_in_bytes - ma.output_size_in_bytes
+    quarter = _tree_bytes(banks) / 4
+    assert 0.9 * quarter < banks_per_chip < 1.2 * quarter, (
+        banks_per_chip, quarter
+    )

@@ -674,7 +674,7 @@ def test_sharded_reduce_is_bitwise(n_shards):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from fedml_tpu.core import robust
-    from fedml_tpu.core.compat import shard_map
+    from jax import shard_map
     from fedml_tpu.algorithms.fedavg import psum_reducer
 
     mesh = make_client_mesh(n_shards)

@@ -16,6 +16,7 @@ import socket
 import subprocess
 import sys
 import threading
+import time
 
 import jax
 import numpy as np
@@ -41,9 +42,8 @@ def _subproc_env():
     # the subprocess ranks must derive the SAME rng stream or the
     # cross-process equality pins compare different initializations
     env["JAX_THREEFRY_PARTITIONABLE"] = "1"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   os.environ.get("FEDML_TPU_TEST_CACHE",
-                                  "/tmp/fedml_tpu_test_xla_cache"))
+    # (the ranks place their compile cache themselves: run.main calls
+    # fedml_tpu.core.compile_cache.enable_compile_cache)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     return env
 
@@ -387,6 +387,14 @@ def test_pubsub_transport_over_broker_echo():
         bus_b = RemoteTopicBus("127.0.0.1", daemon.port)
         a = create_transport("pubsub", 0, bus=bus_a, size=2)
         b = create_transport("pubsub", 1, bus=bus_b, size=2)
+        # each transport's SUB rides its own connection and reader
+        # thread: rank 0's first PUB can overtake rank 1's SUB at the
+        # broker, which drops it (QoS 0) and leaves the echo waiting
+        # for ever — start only once the broker holds both
+        deadline = time.monotonic() + 10
+        while (sum(map(len, daemon._subs.values())) < 2
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         _echo_world(a, b)
         bus_a.close(); bus_b.close()
     finally:

@@ -1047,7 +1047,7 @@ class TestPreFixViolations:
         """Pre-fix ops/pipeline.py built `perm` as a list and closed
         over it in the shard_map'd `run`."""
         fs = lint(tmp_path, {"pipeline.py": """
-            from fedml_tpu.core.compat import shard_map
+            from jax import shard_map
 
             def make_pipeline(stage_fn, mesh, p):
                 perm = [(i, (i + 1) % p) for i in range(p)]

@@ -353,10 +353,13 @@ def test_peaks_table_has_capacity_column():
 
     for kind, row in perf.PEAKS.items():
         assert len(row) == 3 and row[2] > 0, (kind, row)
-    assert perf.device_hbm_capacity("TPU v5 lite") == 16e9
-    assert perf.device_hbm_capacity("unknown chip") is None
-    # the MFU accessor survived the widening
-    assert perf.device_peak_flops("TPU v5 lite") == 197e12
+    import types
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert perf.device_peaks(v5e) == (197e12, 819e9, 16e9)
+    # another platform has no row: no gauge, not a made-up denominator
+    cpu = types.SimpleNamespace(platform="cpu", device_kind="cpu")
+    assert perf.device_peaks(cpu) == (None, None, None)
 
 
 # ---------------------------------------------------------------------------

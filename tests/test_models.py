@@ -88,7 +88,7 @@ def test_sync_batchnorm_exact_across_shards():
     only pmean'd the stats after the fact)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from fedml_tpu.core.compat import shard_map
+    from jax import shard_map
     from fedml_tpu.models.vision import SyncBatchNorm
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("data",))

@@ -250,7 +250,9 @@ def test_defenses_under_explicit_client_sharding():
     sharded operand, lower, and match its single-device result."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((8,), ("clients",))
+    from fedml_tpu.parallel import make_client_mesh
+
+    mesh = make_client_mesh(8)
     rows = np.tile(np.arange(8, dtype=np.float32)[:, None], (1, 4))
     rows[3] = -50.0  # one attacker
     stacked = {"w": jnp.asarray(rows)}

@@ -481,16 +481,16 @@ print(repr(scorer.calculate_fid(x, fake)))
     import os
     from pathlib import Path
 
+    from fedml_tpu.core.compile_cache import CACHE_DIR
+
     repo = str(Path(__file__).resolve().parent.parent)
     env = dict(
         os.environ,
         PYTHONPATH=repo + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        # warm XLA cache: the two child processes would otherwise pay
-        # cold jits, busting the fast tier's budget
-        JAX_COMPILATION_CACHE_DIR=os.environ.get(
-            "FEDML_TPU_TEST_CACHE", "/tmp/fedml_tpu_test_xla_cache"
-        ),
     )
+    # warm XLA cache: the two child processes would otherwise pay
+    # cold jits, busting the fast tier's budget
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", CACHE_DIR)
     outs = []
     for _ in range(2):
         r = subprocess.run(
