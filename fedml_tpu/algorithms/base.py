@@ -336,20 +336,24 @@ def build_local_update(
                 offset = step * batch_size
                 if data_axis is not None:
                     offset = offset + jax.lax.axis_index(data_axis) * shard_bs
-                take = jax.lax.dynamic_slice_in_dim(perm, offset, shard_bs)
-                b_idx = idx_row[take]
-                w_b = mask_row[take]
-                x_b = jnp.take(x, b_idx, axis=0)
-                y_b = jnp.take(y, b_idx, axis=0)
+                with jax.named_scope("fedml.local.gather"):
+                    take = jax.lax.dynamic_slice_in_dim(
+                        perm, offset, shard_bs
+                    )
+                    b_idx = idx_row[take]
+                    w_b = mask_row[take]
+                    x_b = jnp.take(x, b_idx, axis=0)
+                    y_b = jnp.take(y, b_idx, axis=0)
                 skey = jax.random.fold_in(ekey, step)
                 params = variables["params"]
                 static_vars = {
                     k: v for k, v in variables.items() if k != "params"
                 }
-                (_, (new_vars, sums)), grads = grad_fn(
-                    params, static_vars, x_b, y_b, w_b, skey,
-                    global_params, frozen_params,
-                )
+                with jax.named_scope("fedml.local.grad"):
+                    (_, (new_vars, sums)), grads = grad_fn(
+                        params, static_vars, x_b, y_b, w_b, skey,
+                        global_params, frozen_params,
+                    )
                 if data_axis is not None:
                     grads = jax.lax.psum(grads, data_axis)
                     sums = jax.tree.map(
@@ -371,22 +375,24 @@ def build_local_update(
                         )
                         for k, v in new_vars.items()
                     }
-                updates, new_opt_state = opt.update(
-                    grads, opt_state, params
-                )
-                new_params = optax.apply_updates(params, updates)
-                # gate: a fully-padded batch must be a strict no-op. Uses
-                # the data-axis-psum'd weight total (sums were psum'd
-                # above) so every data shard takes the SAME branch — a
-                # shard whose slice happens to be all padding must still
-                # apply the collective update or shards silently diverge.
-                valid = sums["w_sum"] > 0
-                sel = lambda n, o: jax.tree.map(
-                    lambda a, b: jnp.where(valid, a, b), n, o
-                )
-                new_variables = {**new_vars, "params": new_params}
-                out_vars = sel(new_variables, variables)
-                out_opt = sel(new_opt_state, opt_state)
+                with jax.named_scope("fedml.local.update"):
+                    updates, new_opt_state = opt.update(
+                        grads, opt_state, params
+                    )
+                    new_params = optax.apply_updates(params, updates)
+                    # gate: a fully-padded batch must be a strict no-op.
+                    # Uses the data-axis-psum'd weight total (sums were
+                    # psum'd above) so every data shard takes the SAME
+                    # branch — a shard whose slice happens to be all
+                    # padding must still apply the collective update or
+                    # shards silently diverge.
+                    valid = sums["w_sum"] > 0
+                    sel = lambda n, o: jax.tree.map(
+                        lambda a, b: jnp.where(valid, a, b), n, o
+                    )
+                    new_variables = {**new_vars, "params": new_params}
+                    out_vars = sel(new_variables, variables)
+                    out_opt = sel(new_opt_state, opt_state)
                 msums = {k: msums[k] + sums[k] for k in msums}
                 return (out_vars, out_opt, msums), None
 
@@ -519,13 +525,14 @@ def build_cohort_local_update(
 
             def step_body(carry2, step):
                 variables, opt_state, msums = carry2
-                take = jax.lax.dynamic_slice_in_dim(
-                    perms, step * batch_size, batch_size, axis=1
-                )
-                b_idx = jnp.take_along_axis(idx_rows, take, axis=1)
-                w_b = jnp.take_along_axis(mask_rows, take, axis=1)
-                x_b = jnp.take(x, b_idx, axis=0)
-                y_b = jnp.take(y, b_idx, axis=0)
+                with jax.named_scope("fedml.local.gather"):
+                    take = jax.lax.dynamic_slice_in_dim(
+                        perms, step * batch_size, batch_size, axis=1
+                    )
+                    b_idx = jnp.take_along_axis(idx_rows, take, axis=1)
+                    w_b = jnp.take_along_axis(mask_rows, take, axis=1)
+                    x_b = jnp.take(x, b_idx, axis=0)
+                    y_b = jnp.take(y, b_idx, axis=0)
                 # ONE key for the whole cohort, derived from client 0's
                 # epoch key — safe only because cohort eligibility
                 # (FedModel.supports_cohort) excludes stochastic layers:
@@ -538,22 +545,28 @@ def build_cohort_local_update(
                 static_vars = {
                     k: v for k, v in variables.items() if k != "params"
                 }
-                (_, (new_vars, sums)), grads = grad_fn(
-                    params, static_vars, x_b, y_b, w_b, skey, global_params
-                )
-                updates, new_opt_state = opt.update(grads, opt_state, params)
-                new_params = optax.apply_updates(params, updates)
-                valid = sums["w_sum"] > 0  # [C]
-                sel = lambda n, o: jax.tree.map(
-                    lambda a, b: jnp.where(
-                        valid.reshape((C,) + (1,) * (a.ndim - 1)), a, b
-                    ),
-                    n,
-                    o,
-                )
-                new_variables = {**new_vars, "params": new_params}
-                out_vars = sel(new_variables, variables)
-                out_opt = sel(new_opt_state, opt_state)
+                with jax.named_scope("fedml.local.grad"):
+                    (_, (new_vars, sums)), grads = grad_fn(
+                        params, static_vars, x_b, y_b, w_b, skey,
+                        global_params,
+                    )
+                with jax.named_scope("fedml.local.update"):
+                    updates, new_opt_state = opt.update(
+                        grads, opt_state, params
+                    )
+                    new_params = optax.apply_updates(params, updates)
+                    valid = sums["w_sum"] > 0  # [C]
+                    sel = lambda n, o: jax.tree.map(
+                        lambda a, b: jnp.where(
+                            valid.reshape((C,) + (1,) * (a.ndim - 1)),
+                            a, b,
+                        ),
+                        n,
+                        o,
+                    )
+                    new_variables = {**new_vars, "params": new_params}
+                    out_vars = sel(new_variables, variables)
+                    out_opt = sel(new_opt_state, opt_state)
                 msums = {k: msums[k] + sums[k] for k in msums}
                 return (out_vars, out_opt, msums), None
 

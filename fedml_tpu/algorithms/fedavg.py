@@ -27,11 +27,13 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from fedml_tpu.config import ExperimentConfig, FedConfig, TrainConfig
 from fedml_tpu.core import adversary as A
 from fedml_tpu.core.anatomy import ANATOMY
+from fedml_tpu.core.tracing import span
 from fedml_tpu.core import bulk as BK
 from fedml_tpu.core import compress as C
 from fedml_tpu.core import elastic as E
@@ -743,36 +745,39 @@ class FedAvgSim:
         The sampled cohort rides the return value so consumers (the
         adversary injection gate) never re-derive the draw."""
         cfg = self.cfg.fed
-        rkey = R.round_key(self.root_key, state.round)
-        if n_active is not None:
-            cohort = self._sample_bucket(
-                jax.random.fold_in(rkey, 0), arrays.num_clients
-            )
-        else:
-            cohort = self.sampler(
-                jax.random.fold_in(rkey, 0),
-                arrays.num_clients,
-                cfg.clients_per_round,
-            )
-        ckeys = jax.vmap(lambda c: R.client_key(rkey, c))(cohort)
-        idx_rows = arrays.idx[cohort]
-        mask_rows = arrays.mask[cohort]
-
-        if self._cohort_update is not None:
-            stacked_vars, n_k, msums = _grouped_cohort_call(
-                self._cohort_update,
-                self._cohort_groups,
-                state.variables,
-                idx_rows,
-                mask_rows,
-                arrays.x,
-                arrays.y,
-                ckeys,
-            )
-        else:
-            stacked_vars, n_k, msums = jax.vmap(
-                self.local_update, in_axes=(None, 0, 0, None, None, 0)
-            )(state.variables, idx_rows, mask_rows, arrays.x, arrays.y, ckeys)
+        with jax.named_scope("fedml.sample"):
+            rkey = R.round_key(self.root_key, state.round)
+            if n_active is not None:
+                cohort = self._sample_bucket(
+                    jax.random.fold_in(rkey, 0), arrays.num_clients
+                )
+            else:
+                cohort = self.sampler(
+                    jax.random.fold_in(rkey, 0),
+                    arrays.num_clients,
+                    cfg.clients_per_round,
+                )
+            ckeys = jax.vmap(lambda c: R.client_key(rkey, c))(cohort)
+        with jax.named_scope("fedml.local"):
+            idx_rows = arrays.idx[cohort]
+            mask_rows = arrays.mask[cohort]
+            if self._cohort_update is not None:
+                stacked_vars, n_k, msums = _grouped_cohort_call(
+                    self._cohort_update,
+                    self._cohort_groups,
+                    state.variables,
+                    idx_rows,
+                    mask_rows,
+                    arrays.x,
+                    arrays.y,
+                    ckeys,
+                )
+            else:
+                stacked_vars, n_k, msums = jax.vmap(
+                    self.local_update,
+                    in_axes=(None, 0, 0, None, None, 0),
+                )(state.variables, idx_rows, mask_rows, arrays.x,
+                  arrays.y, ckeys)
         return stacked_vars, n_k, msums, rkey, cohort
 
     def _inject_adversaries(self, state, arrays, stacked_vars, cohort):
@@ -890,23 +895,28 @@ class FedAvgSim:
             state if self._peft is None
             else self._peft.view_state(state)
         )
-        if n_active is not None:
-            # elastic: full-grid draw, live prefix = the traced cohort
-            ids = self._sample_slot_ids(skey, arrays.num_clients)
-            live = E.active_mask(self._slots, n_active)
-        else:
-            # static: the SAME draw the stacked round makes (parity),
-            # tail slots padded with the out-of-range sentinel id (a
-            # pad slot must never alias a real client's bank row)
-            cohort = self.sampler(
-                skey, arrays.num_clients, cfg.clients_per_round
-            )
-            pad = self._slots - cohort.shape[0]
-            ids = SB.pad_ids(cohort, self._slots, arrays.num_clients)
-            live = (
-                E.active_mask(self._slots, cohort.shape[0])
-                if pad else None
-            )
+        with jax.named_scope("fedml.sample"):
+            if n_active is not None:
+                # elastic: full-grid draw, live prefix = the traced
+                # cohort
+                ids = self._sample_slot_ids(skey, arrays.num_clients)
+                live = E.active_mask(self._slots, n_active)
+            else:
+                # static: the SAME draw the stacked round makes
+                # (parity), tail slots padded with the out-of-range
+                # sentinel id (a pad slot must never alias a real
+                # client's bank row)
+                cohort = self.sampler(
+                    skey, arrays.num_clients, cfg.clients_per_round
+                )
+                pad = self._slots - cohort.shape[0]
+                ids = SB.pad_ids(
+                    cohort, self._slots, arrays.num_clients
+                )
+                live = (
+                    E.active_mask(self._slots, cohort.shape[0])
+                    if pad else None
+                )
         if adapter_bank is not None:
             return self._bulk_personal(
                 state, view, arrays, ids, live, rkey, adapter_bank
@@ -919,13 +929,28 @@ class FedAvgSim:
             non-finite screen. Returns ``(stacked_vars, n_k, msums,
             rejected, new_bank)`` — ``new_bank`` None unless ``bank``
             rode in and ``write_bank`` held."""
-            ckeys = jax.vmap(lambda c: R.client_key(rkey, c))(block_ids)
-            idx_rows = arrays.idx[block_ids]
-            mask_rows = arrays.mask[block_ids]
-            stacked_vars, n_k, msums = jax.vmap(
-                self.local_update, in_axes=(None, 0, 0, None, None, 0)
-            )(state.variables, idx_rows, mask_rows, arrays.x, arrays.y,
-              ckeys)
+            with jax.named_scope("fedml.sample"):
+                ckeys = jax.vmap(
+                    lambda c: R.client_key(rkey, c)
+                )(block_ids)
+            with jax.named_scope("fedml.local"):
+                idx_rows = arrays.idx[block_ids]
+                mask_rows = arrays.mask[block_ids]
+                stacked_vars, n_k, msums = jax.vmap(
+                    self.local_update,
+                    in_axes=(None, 0, 0, None, None, 0),
+                )(state.variables, idx_rows, mask_rows, arrays.x,
+                  arrays.y, ckeys)
+            with jax.named_scope("fedml.defense_agg"):
+                return heal_block(
+                    block_ids, block_live, bank, write_bank,
+                    stacked_vars, n_k, msums,
+                )
+
+        def heal_block(block_ids, block_live, bank, write_bank,
+                       stacked_vars, n_k, msums):
+            """`local_block`'s post-training half: everything between
+            a block's local updates and its fold."""
             if self.cfg.adversary.enabled():
                 stacked_vars = self._inject_adversaries(
                     view, arrays, stacked_vars, block_ids
@@ -970,10 +995,11 @@ class FedAvgSim:
             return stacked_vars, n_k, msums, rejected, new_bank
 
         def partials_of(sv, n_k, msums, rejected):
-            return fold_block_partials(
-                cfg, self.cfg.train, self.steps_per_epoch,
-                self.batch_size, view, sv, n_k, msums, rejected,
-            )
+            with jax.named_scope("fedml.defense_agg"):
+                return fold_block_partials(
+                    cfg, self.cfg.train, self.steps_per_epoch,
+                    self.batch_size, view, sv, n_k, msums, rejected,
+                )
 
         if self._stream_defense is None:
             if ef_bank is None:
@@ -1005,12 +1031,13 @@ class FedAvgSim:
                 partials_of,
             )
 
-        new_state = server_update_from_partials(
-            cfg, view, partials, rkey, agg_delta=agg_delta
-        )
-        if self._peft is not None:
-            new_state = self._peft.merge_state(new_state, state)
-        fin = finalize_sums(partials.msums)
+        with jax.named_scope("fedml.server_update"):
+            new_state = server_update_from_partials(
+                cfg, view, partials, rkey, agg_delta=agg_delta
+            )
+            if self._peft is not None:
+                new_state = self._peft.merge_state(new_state, state)
+            fin = finalize_sums(partials.msums)
         train_metrics = {
             "train_loss": fin["loss"],
             "train_acc": fin["acc"],
@@ -1360,51 +1387,53 @@ class FedAvgSim:
             else self._peft.view_state(state)
         )
 
-        if self.cfg.adversary.enabled():
-            stacked_vars = self._inject_adversaries(
-                view, arrays, stacked_vars, cohort
+        with jax.named_scope("fedml.defense_agg"):
+            if self.cfg.adversary.enabled():
+                stacked_vars = self._inject_adversaries(
+                    view, arrays, stacked_vars, cohort
+                )
+            live = (
+                E.active_mask(self._bucket, n_active)
+                if n_active is not None else None
             )
-        live = (
-            E.active_mask(self._bucket, n_active)
-            if n_active is not None else None
-        )
-        new_residual = None
-        if residual is not None:
-            # wire order mirrors the deploy path: the client compresses
-            # its (possibly adversarial) delta, THEN the server pads /
-            # screens what it decompressed
-            stacked_vars, new_residual = self._wire_roundtrip(
-                view, stacked_vars, residual, rkey, live
+            new_residual = None
+            if residual is not None:
+                # wire order mirrors the deploy path: the client
+                # compresses its (possibly adversarial) delta, THEN the
+                # server pads / screens what it decompressed
+                stacked_vars, new_residual = self._wire_roundtrip(
+                    view, stacked_vars, residual, rkey, live
+                )
+            if live is not None:
+                # elastic bucketing: the padded slots beyond the live
+                # cohort are healed to the global model (delta exactly
+                # 0) with zero weight BEFORE screening, so downstream
+                # they are indistinguishable from absent — and they
+                # must not pollute the round's train metrics either
+                stacked_vars, n_k, msums = E.mask_padded(
+                    stacked_vars, n_k, msums, view.variables, live
+                )
+            stacked_vars, n_k, rejected = self._screen_nonfinite(
+                view, stacked_vars, n_k
             )
-        if live is not None:
-            # elastic bucketing: the padded slots beyond the live
-            # cohort are healed to the global model (delta exactly 0)
-            # with zero weight BEFORE screening, so downstream they are
-            # indistinguishable from absent — and they must not pollute
-            # the round's train metrics either
-            stacked_vars, n_k, msums = E.mask_padded(
-                stacked_vars, n_k, msums, view.variables, live
-            )
-        stacked_vars, n_k, rejected = self._screen_nonfinite(
-            view, stacked_vars, n_k
-        )
 
-        new_state = server_update(
-            cfg,
-            self.cfg.train,
-            self.steps_per_epoch,
-            self.batch_size,
-            view,
-            stacked_vars,
-            n_k,
-            rkey,
-            local_reducer(),
-            valid=live,
-        )
-        if self._peft is not None:
-            new_state = self._peft.merge_state(new_state, state)
-        reduced = jax.tree.map(jnp.sum, msums)
-        fin = finalize_sums(reduced)
+        with jax.named_scope("fedml.server_update"):
+            new_state = server_update(
+                cfg,
+                self.cfg.train,
+                self.steps_per_epoch,
+                self.batch_size,
+                view,
+                stacked_vars,
+                n_k,
+                rkey,
+                local_reducer(),
+                valid=live,
+            )
+            if self._peft is not None:
+                new_state = self._peft.merge_state(new_state, state)
+            reduced = jax.tree.map(jnp.sum, msums)
+            fin = finalize_sums(reduced)
         train_metrics = {
             "train_loss": fin["loss"],
             "train_acc": fin["acc"],
@@ -1790,10 +1819,15 @@ class FedAvgSim:
         return state, m
 
     def evaluate_global(self, state: ServerState) -> dict:
-        m = self.evaluator(
-            state.variables, self.arrays.test_x, self.arrays.test_y
-        )
-        return {k: float(v) for k, v in m.items()}
+        x, y = self.arrays.test_x, self.arrays.test_y
+        # h2d_bytes is a COUNT read off the operands, not a timing: what
+        # this call re-sends from the host (0 here, the test set lives
+        # on the chip; all of it for ShardedFedAvg, whose arrays stay
+        # host numpy)
+        h2d = sum(a.nbytes for a in (x, y) if isinstance(a, np.ndarray))
+        with span("fedml.eval", phase="eval", h2d_bytes=h2d):
+            m = self.evaluator(state.variables, x, y)
+            return {k: float(v) for k, v in m.items()}
 
     def evaluate_train(self, state: ServerState) -> dict:
         m = self.evaluator(state.variables, self.arrays.x, self.arrays.y)
@@ -1820,67 +1854,60 @@ class FedAvgSim:
         try:
             if self._fuse > 1:
                 return self._run_fused(
-                    state, metrics_sink, profiler, monitor, _time
+                    state, metrics_sink, profiler, monitor
                 )
-            # the anatomy plane (core/anatomy.py) attributes phases at
-            # sync points this loop ALREADY has — the dispatch return
-            # and the one batched device_get below — so the off path
-            # stays one attribute check and the on path adds clock
-            # reads, never a new device sync
-            anat = ANATOMY.enabled
+            # ONE set of boundaries (core/tracing.span): each span is a
+            # profiler annotation, a ring event under --trace, and —
+            # where it names a phase — the anatomy plane's clock. They
+            # sit at sync points this loop ALREADY has (the dispatch
+            # return, the one batched device_get), so the off path is
+            # a flag check a span and nothing adds a device sync
             path = self._anatomy_path()
             for r in range(self.cfg.fed.num_rounds):
-                if anat:
-                    ANATOMY.begin_round(r, path=path)
                 t0 = _time.perf_counter()
                 if profiler is not None:
+                    # before the span opens: an annotation is kept
+                    # only if its session was on when it began
                     profiler.start_round(r)
-                state, train_m = self.run_round(state)
-                t_disp = _time.perf_counter() if anat else 0.0
-                # ONE batched D2H for the whole metric dict instead of
-                # a device sync per leaf
-                train_m = consume_round_counters(
-                    jax.device_get(dict(train_m))
-                )
-                if anat:
-                    # dispatch -> metrics-on-host: the compiled round's
-                    # device execution (the sims run the whole round as
-                    # one program, so `local` carries it; the dispatch
-                    # itself lands in host_gap)
-                    ANATOMY.phase(
-                        "local", _time.perf_counter() - t_disp
-                    )
-                record = {
-                    "round": r,
-                    **{k: float(v) for k, v in train_m.items()},
-                }
-                if profiler is not None:
-                    profiler.end_round(r)
-                if monitor is not None:
-                    monitor.note_round(_time.perf_counter() - t0)
-                if (r + 1) % self.cfg.fed.eval_every == 0 or (
-                    r == self.cfg.fed.num_rounds - 1
-                ):
-                    t_ev = _time.perf_counter() if anat else 0.0
-                    test_m = self.evaluate_global(state)
-                    if anat:
-                        ANATOMY.phase(
-                            "eval", _time.perf_counter() - t_ev
+                with span("fedml.round", round=r):
+                    ANATOMY.begin_round(r, path=path)
+                    # enqueue (and any retrace); lands in host_gap
+                    with span("fedml.dispatch"):
+                        state, train_m = self.run_round(state)
+                    # ONE batched D2H for the whole metric dict instead
+                    # of a device sync per leaf: the host blocked on the
+                    # compiled round's execution (the sims run the whole
+                    # round as one program, so `local` carries it)
+                    with span("fedml.fetch", phase="local"):
+                        train_m = consume_round_counters(
+                            jax.device_get(dict(train_m))
                         )
-                    record.update(
-                        {"test_acc": test_m["acc"],
-                         "test_loss": test_m["loss"]}
-                    )
-                if metrics_sink is not None:
-                    metrics_sink.log(record)
-                if anat:
+                    record = {
+                        "round": r,
+                        **{k: float(v) for k, v in train_m.items()},
+                    }
+                    if profiler is not None:
+                        profiler.end_round(r)
+                    if monitor is not None:
+                        monitor.note_round(_time.perf_counter() - t0)
+                    if (r + 1) % self.cfg.fed.eval_every == 0 or (
+                        r == self.cfg.fed.num_rounds - 1
+                    ):
+                        test_m = self.evaluate_global(state)
+                        record.update(
+                            {"test_acc": test_m["acc"],
+                             "test_loss": test_m["loss"]}
+                        )
+                    if metrics_sink is not None:
+                        with span("fedml.log"):
+                            metrics_sink.log(record)
                     ANATOMY.end_round()
         finally:
             if profiler is not None:
                 profiler.finish()
         return state
 
-    def _run_fused(self, state, metrics_sink, profiler, monitor, _time):
+    def _run_fused(self, state, metrics_sink, profiler, monitor):
         """Fused round loop (docs/PERFORMANCE.md "Round fusion"):
         advance in blocks of up to ``fuse_rounds`` rounds, keeping
         block k+1's dispatch in flight while the host converts block
@@ -1910,21 +1937,17 @@ class FedAvgSim:
 
         def log(rec):
             if metrics_sink is not None:
-                metrics_sink.log(rec)
+                with span("fedml.log", round=rec["round"]):
+                    metrics_sink.log(rec)
 
         def boundary_hook(r_last, last):
             if (r_last + 1) % cfg.eval_every == 0 or (
                 r_last == cfg.num_rounds - 1
             ):
-                anat = ANATOMY.enabled
-                t_ev = _time.perf_counter() if anat else 0.0
+                # the block's anatomy entry closed at the pipeline
+                # flush; fuse.drive runs this hook in amending mode, so
+                # the fedml.eval span's phase lands on that entry
                 test_m = self.evaluate_global(box[0])
-                if anat:
-                    # the block's anatomy entry closed at the pipeline
-                    # flush; the boundary eval amends it
-                    ANATOMY.amend_last(
-                        "eval", _time.perf_counter() - t_ev
-                    )
                 last.update({"test_acc": test_m["acc"],
                              "test_loss": test_m["loss"]})
             log(last)

@@ -54,6 +54,7 @@ the same way it resets the memory plane.
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import os
 import re
@@ -96,9 +97,10 @@ class RoundAnatomy:
 
     One instance per process (:data:`ANATOMY`). All methods no-op while
     ``enabled`` is False, so the disabled hot path is one attribute
-    check at each call site — the instrumented loops check
-    ``ANATOMY.enabled`` themselves before computing timestamps, keeping
-    the off path free of even a ``perf_counter()`` call.
+    check at each call site. The sims' loops hold no clock of their
+    own: their phases arrive from the span sites
+    (:func:`fedml_tpu.core.tracing.span` with ``phase=``), which read
+    the clock only while this plane (or the tracer ring) is on.
     """
 
     def __init__(self, ring_capacity: int = RING_CAPACITY):
@@ -108,6 +110,9 @@ class RoundAnatomy:
             maxlen=ring_capacity
         )
         self._open: dict[str, Any] | None = None
+        # inside amending(): a phase with no open round amends the
+        # last closed entry instead of being dropped
+        self._amend = False
         # deploy server: per-round result-arrival timestamps
         # (rank -> perf_counter seconds), the straggler-attribution and
         # critical-path inputs the close path already collects
@@ -149,10 +154,26 @@ class RoundAnatomy:
                 f"fixed (docs/OBSERVABILITY.md): {PHASES}"
             )
         with self._lock:
-            if self._open is None:
+            if self._open is not None:
+                p = self._open["phases"]
+                p[name] = p.get(name, 0.0) + max(0.0, float(seconds))
                 return
-            p = self._open["phases"]
-            p[name] = p.get(name, 0.0) + max(0.0, float(seconds))
+            late = self._amend
+        if late:
+            self.amend_last(name, seconds)
+
+    @contextlib.contextmanager
+    def amending(self):
+        """Phases reported inside land on the LAST closed ring entry
+        (:meth:`amend_last`) — the fused drivers close each block's
+        entry at the pipeline flush and only then run the boundary
+        eval/checkpoint, whose spans (core/tracing.py ``phase=``)
+        report here with no round open."""
+        self._amend = True
+        try:
+            yield
+        finally:
+            self._amend = False
 
     def note_arrival(self, rank: int, ts: float | None = None) -> None:
         """Deploy server: timestamp a client result's arrival (one host
@@ -209,9 +230,8 @@ class RoundAnatomy:
 
     def amend_last(self, name: str, seconds: float) -> None:
         """Attribute ``seconds`` to ``name`` on the LAST closed ring
-        entry — the fused drivers close each block's entry at the
-        pipeline flush and only then run the boundary eval/checkpoint,
-        so those phases amend the block they belong to. The entry's
+        entry (what :meth:`phase` does inside :meth:`amending`), so
+        boundary phases amend the block they belong to. The entry's
         wall grows by the same amount: attribution stays conserved
         (phases still sum to wall_s) and ``host_gap`` is untouched."""
         if not self.enabled:
@@ -309,6 +329,7 @@ class RoundAnatomy:
             self.enabled = False
             self._ring.clear()
             self._open = None
+            self._amend = False
             self._arrivals = {}
             self._rounds = 0
 

@@ -30,11 +30,13 @@ the two drivers cannot drift.
 
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Any, Callable, Iterable, Iterator
 
 import numpy as np
+
+from fedml_tpu.core.anatomy import ANATOMY
+from fedml_tpu.core.tracing import span
 
 
 def plan_blocks(
@@ -85,17 +87,20 @@ class BlockPipeline:
     checkpoint / profiler boundaries, end of run).
 
     Flushed blocks come back as ``(start, length, rows, wall_s,
-    compiled, get_wait_s)``: ``rows`` is one host dict per round (sliced
+    compiled)``: ``rows`` is one host dict per round (sliced
     out of the ``[K, ...]`` stacked leaves — one batched transfer for
     the whole block), ``wall_s`` spans dispatch -> metrics-on-host, i.e.
     the block's execution in the steady state (the next block was
     already enqueued when the flush started waiting), ``compiled``
     echoes the flag the dispatcher pushed (True when this dispatch
     traced a fresh block program — its wall is compile-dominated and
-    must stay out of the per-round SLO surface), and ``get_wait_s`` is
-    the seconds the ``device_get`` blocked — the anatomy plane's
-    ``local`` attribution (core/anatomy.py), timed at a sync the
-    pipeline already pays."""
+    must stay out of the per-round SLO surface). The ``device_get`` is
+    the ``fedml.fetch`` span (core/tracing.py): a sync the pipeline
+    already pays, and the anatomy plane's ``local`` attribution — the
+    block's anatomy entry (core/anatomy.py) opens and closes here, one
+    per fused block; dispatch + host row conversion land in
+    ``host_gap``, and the driver's boundary hook amends eval/checkpoint
+    onto the entry afterwards."""
 
     def __init__(self) -> None:
         self._pending: tuple[int, int, Any, float, bool] | None = None
@@ -103,29 +108,30 @@ class BlockPipeline:
     def push(
         self, start: int, length: int, device_metrics: Any, t0: float,
         compiled: bool = False,
-    ) -> tuple[int, int, list[dict], float, bool, float] | None:
+    ) -> tuple[int, int, list[dict], float, bool] | None:
         prev = self.flush()
         self._pending = (start, length, device_metrics, t0, compiled)
         return prev
 
     def flush(
         self,
-    ) -> tuple[int, int, list[dict], float, bool, float] | None:
+    ) -> tuple[int, int, list[dict], float, bool] | None:
         if self._pending is None:
             return None
         import jax
 
         start, n, dm, t0, compiled = self._pending
         self._pending = None
-        t_get = time.perf_counter()
-        host = jax.device_get(dm)  # one batched D2H for the block
+        ANATOMY.begin_round(start, path="fused", rounds=n)
+        with span("fedml.fetch", phase="local", start=start, rounds=n):
+            host = jax.device_get(dm)  # one batched D2H for the block
         wall = time.perf_counter() - t0
-        get_wait = time.perf_counter() - t_get
+        ANATOMY.end_round(wall_s=wall)
         rows = [
             {k: np.asarray(v)[i] for k, v in host.items()}
             for i in range(n)
         ]
-        return start, n, rows, wall, compiled, get_wait
+        return start, n, rows, wall, compiled
 
 
 def drive(
@@ -137,7 +143,6 @@ def drive(
     make_records: Callable[[int, list[dict]], list[dict]],
     log: Callable[[dict], None],
     boundary_hook: Callable[[int, dict], None],
-    span: Callable[[int, int], Any] | None = None,
 ) -> None:
     """The fused round loop, shared by ``FedAvgSim._run_fused`` and the
     harness ``Experiment._fused_loop`` so the two drivers cannot drift.
@@ -150,9 +155,13 @@ def drive(
     - ``log(record)`` emits a finished record;
     - ``boundary_hook(r_last, last_record)`` runs at every boundary
       block with the held last record — the driver evaluates /
-      checkpoints there and must log ``last_record`` itself;
-    - ``span(start, length)`` optionally wraps each dispatch in a
-      context manager (tracer spans).
+      checkpoints there and must log ``last_record`` itself.
+
+    Each iteration is one ``fedml.block`` span (``start``, ``rounds``)
+    holding ``fedml.dispatch``, the pipeline's ``fedml.fetch`` and, at a
+    boundary, the hook's ``fedml.eval`` / ``fedml.log``
+    (core/tracing.py) — the fused twin of the per-round loop's
+    ``fedml.round``.
 
     Pipelining: block k+1's dispatch goes out before block k's metrics
     are fetched, so the host-side conversion overlaps device execution;
@@ -163,24 +172,13 @@ def drive(
     excluded from the per-round SLO surface like the warmup round
     (otherwise the remainder lengths an eval/checkpoint cadence forces
     would put an XLA compile into the p99)."""
-    from fedml_tpu.core.anatomy import ANATOMY
-
     pipeline = BlockPipeline()
     seen_lengths: set[int] = set()
 
     def emit(flushed, hold_last=False):
-        start, blen, rows, wall, compiled, get_wait = flushed
+        start, blen, rows, wall, compiled = flushed
         if monitor is not None:
             monitor.note_block(wall, blen, compiled=compiled)
-        if ANATOMY.enabled:
-            # one anatomy entry per fused block: `local` is the
-            # device_get wait the flush already paid (remaining device
-            # execution in the steady state); dispatch + host row
-            # conversion land in host_gap. The driver's boundary hook
-            # amends eval/checkpoint onto this entry afterwards.
-            ANATOMY.begin_round(start, path="fused", rounds=blen)
-            ANATOMY.phase("local", get_wait)
-            ANATOMY.end_round(wall_s=wall)
         records = make_records(start, rows)
         last = records.pop() if hold_last else None
         for rec in records:
@@ -188,30 +186,32 @@ def drive(
         return last
 
     for bstart, blen, boundary in blocks:
-        capturing = profiler is not None and profiler.wants_capture
-        if capturing:
-            # a capture window must contain exactly this block's
-            # device work: drain the pipeline first
-            prev = pipeline.flush()
+        with span("fedml.block", start=bstart, rounds=blen):
+            capturing = profiler is not None and profiler.wants_capture
+            if capturing:
+                # a capture window must contain exactly this block's
+                # device work: drain the pipeline first
+                prev = pipeline.flush()
+                if prev:
+                    emit(prev)
+                profiler.start_round(bstart)
+            compiled = blen not in seen_lengths
+            seen_lengths.add(blen)
+            t0 = time.perf_counter()
+            with span("fedml.dispatch"):
+                dm = run_block(blen)
+            prev = pipeline.push(bstart, blen, dm, t0, compiled)
             if prev:
                 emit(prev)
-            profiler.start_round(bstart)
-        compiled = blen not in seen_lengths
-        seen_lengths.add(blen)
-        t0 = time.perf_counter()
-        cm = (span(bstart, blen) if span is not None
-              else contextlib.nullcontext())
-        with cm:
-            dm = run_block(blen)
-        prev = pipeline.push(bstart, blen, dm, t0, compiled)
-        if prev:
-            emit(prev)
-        if boundary or capturing:
-            last = emit(pipeline.flush(), hold_last=boundary)
-            if capturing:
-                profiler.end_round(bstart, rounds=blen)
-            if boundary:
-                boundary_hook(bstart + blen - 1, last)
+            if boundary or capturing:
+                last = emit(pipeline.flush(), hold_last=boundary)
+                if capturing:
+                    profiler.end_round(bstart, rounds=blen)
+                if boundary:
+                    # the block's anatomy entry is closed: phases the
+                    # hook's spans report (eval) amend it
+                    with ANATOMY.amending():
+                        boundary_hook(bstart + blen - 1, last)
     final = pipeline.flush()
     if final:
         emit(final)

@@ -49,11 +49,13 @@ status source like every actor's.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 from typing import Any, Callable
 
 from fedml_tpu.core import telemetry
+from fedml_tpu.core.tracing import span
 
 #: per-program table cap: program slugs are bounded by design (elastic
 #: buckets are powers of two, block lengths a small set), but a
@@ -186,6 +188,119 @@ def program_table() -> dict[str, dict]:
 
 
 # ---------------------------------------------------------------------------
+# scope map: which fedml.* scope each instruction of a program ran under
+# ---------------------------------------------------------------------------
+
+# A profiler trace names a device op by its OPTIMIZED HLO instruction
+# (``%add_select_fusion.9 = ...``) and, through ``jax.profiler.
+# ProfileData``, carries no ``op_name`` — so the ``jax.named_scope``
+# phases of the round program (``fedml.sample``, ``fedml.local.grad``,
+# ...; docs/OBSERVABILITY.md "Spans and scopes") cannot be read off the
+# event. The optimized module's text has the same instruction names
+# WITH ``metadata={op_name=".../fedml.local.update/..."}``; every
+# :class:`ProgramSite` compile keeps that text here (a str — nothing
+# that holds the executable or its device memory), whatever the metrics
+# plane says, and :func:`scope_map` parses it when first asked.
+# slug -> {"family", "key", "module", "text" | "map", "keep_s"}
+_HLO: dict[str, dict[str, Any]] = {}
+
+_SCOPE_RE = re.compile(r"fedml\.[a-z_]+(?:\.[a-z_]+)*")
+_INSTR_RE = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_COMP_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_OPNAME_RE = re.compile(r'op_name="([^"]*)"')
+_CALLS_RE = re.compile(r"\bfusion\(.*calls=%?([\w.\-]+)")
+
+
+def keep_program_hlo(family: str, key, compiled) -> float:
+    """Keep one compiled program's optimized HLO text for
+    :func:`scope_map`. Returns the seconds it took (also kept as
+    ``keep_s`` and observed as ``mem.scope_text_s``); a backend that
+    cannot produce the text keeps nothing — this must never fail a
+    compile."""
+    t0 = time.perf_counter()
+    try:
+        text = compiled.as_text()
+    except Exception:
+        return 0.0
+    head = text[:256].split(None, 2)
+    module = head[1].rstrip(",") if len(head) > 1 else ""
+    keep_s = time.perf_counter() - t0
+    slug = program_slug(family, key)
+    with _LOCK:
+        if slug in _HLO or len(_HLO) < MAX_PROGRAMS:
+            _HLO[slug] = {"family": family, "key": key, "module": module,
+                          "text": text, "keep_s": keep_s}
+    telemetry.METRICS.observe("mem.scope_text_s", keep_s)
+    return keep_s
+
+
+def parse_scopes(text: str) -> dict[str, str | None]:
+    """``{instruction: scope}`` of one optimized HLO module's text: the
+    innermost ``fedml.*`` scope in the instruction's ``op_name`` (the
+    last on the name stack — backward ops keep theirs inside
+    ``transpose(jvp(...))``), else None. A fusion whose own metadata
+    names no scope takes the scope most of its fused instructions
+    share. Instruction names are unique within a module, fused
+    computations' included."""
+    own: dict[str, str | None] = {}
+    calls: dict[str, str] = {}
+    inside: dict[str, list[str]] = {}
+    comp = None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            m = _COMP_RE.match(line)
+            comp = m.group(1) if m else None
+            continue
+        m = _INSTR_RE.match(line)
+        if m is None:
+            continue
+        name = m.group(1)
+        scope = None
+        op = _OPNAME_RE.search(line)
+        if op is not None:
+            found = _SCOPE_RE.findall(op.group(1))
+            if found:
+                scope = found[-1]
+        own[name] = scope
+        if scope is not None and comp is not None:
+            inside.setdefault(comp, []).append(scope)
+        elif scope is None and " fusion(" in line:
+            c = _CALLS_RE.search(line)
+            if c is not None:
+                calls[name] = c.group(1)
+    for name, called in calls.items():
+        scopes = inside.get(called)
+        if scopes:
+            own[name] = max(set(scopes), key=scopes.count)
+    return own
+
+
+def scope_programs() -> list[tuple[str, Any, str]]:
+    """``[(family, key, HLO module name)]`` of the programs whose scope
+    map can be asked for (a trace's ``XLA Modules`` events are named
+    ``<module>(<id>)``)."""
+    with _LOCK:
+        return [(r["family"], r["key"], r["module"])
+                for r in _HLO.values()]
+
+
+def scope_map(family: str, key) -> dict[str, str | None] | None:
+    """``{instruction: scope or None}`` for one compiled program — what
+    the benchmark's reader (``benchmarks/lib/program_spans.py``) and
+    :meth:`fedml_tpu.core.perf.RoundProfiler.finish` join a trace's
+    device ops against. Parsed on the first call (the text is then
+    dropped for the map); None for a program this process never
+    compiled through a :class:`ProgramSite`."""
+    with _LOCK:
+        rec = _HLO.get(program_slug(family, key))
+        if rec is None:
+            return None
+        if "map" not in rec:
+            rec["map"] = parse_scopes(rec.pop("text"))
+        return rec["map"]
+
+
+# ---------------------------------------------------------------------------
 # runtime donation audit
 # ---------------------------------------------------------------------------
 
@@ -273,12 +388,14 @@ class ProgramSite:
         with self._lock:
             exe = self._exes.get(key)
         if exe is None:
-            t0 = time.perf_counter()
-            exe = self._jit.lower(*args).compile()
-            wall = time.perf_counter() - t0
-            with self._lock:
-                self._exes[key] = exe
-            note_program(self.family, key, exe, compile_s=wall)
+            with span("fedml.compile", family=self.family, key=str(key)):
+                t0 = time.perf_counter()
+                exe = self._jit.lower(*args).compile()
+                wall = time.perf_counter() - t0
+                with self._lock:
+                    self._exes[key] = exe
+                note_program(self.family, key, exe, compile_s=wall)
+                keep_program_hlo(self.family, key, exe)
         audit = bool(self._donate) and key not in self._audited
         donated = (
             [leaf
@@ -479,8 +596,8 @@ MONITOR = DeviceMemoryMonitor()
 def reset() -> None:
     """Return the module to its pristine state (test isolation; called
     by :func:`fedml_tpu.core.telemetry.shutdown`)."""
-    global _PROGRAMS
     with _LOCK:
         _PROGRAMS.clear()
+        _HLO.clear()
     MONITOR.reset()
     MONITOR.headroom_warn = 0.9

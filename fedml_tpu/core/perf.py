@@ -17,9 +17,11 @@ observability"):
   ``FedConfig.profile_rounds``), each parsed into a per-round
   **device-time breakdown**: compute vs collective vs host-blocked vs
   idle. Captures land under ``<telemetry_dir>/jax_profile/round<k>/``
-  (one window per round, so breakdowns are genuinely per-round and
-  ``--trace_jax`` TraceAnnotations fold into the same capture), the
-  parsed breakdowns into ``perf_rank<r>.json``;
+  (one window per round, so breakdowns are genuinely per-round; each
+  capture holds the loop's ``fedml.*`` spans and the round program's
+  ``fedml.*`` scopes, core/tracing.py), the parsed breakdowns — with
+  the device time split by scope (:func:`phase_split`) — into
+  ``perf_rank<r>.json``;
 - :class:`PerfMonitor` — a live ``perf.mfu`` gauge computed from the
   same cost model over a smoothed round rate, plus the
   **dispatch-bound detector**: ``mfu < mfu_floor`` becomes a
@@ -350,6 +352,52 @@ def device_time_breakdown(
     }
 
 
+def phase_split(events: list[dict[str, Any]]) -> dict[str, float] | None:
+    """Device-op seconds of a capture by ``fedml.*`` scope
+    (docs/OBSERVABILITY.md "Spans and scopes"): every device op is
+    joined by its HLO instruction name against
+    :func:`fedml_tpu.core.memscope.scope_map` of the program it ran in
+    (``hlo_module`` where the event names it, else whichever kept
+    program knows the instruction). Ops of a mapped program under no
+    scope read ``unscoped``; ops of programs with no map (the
+    evaluator, jitted helpers) read ``other_programs``. Durations are
+    summed (ops on one device line do not overlap; control-flow
+    wrappers are left out). None when no program has a map."""
+    from fedml_tpu.core import memscope
+
+    maps: dict[str, dict] = {}
+    for family, key, module in memscope.scope_programs():
+        m = memscope.scope_map(family, key)
+        if m:
+            maps.setdefault(module, {}).update(m)
+    if not maps:
+        return None
+    device_planes = {
+        e["pid"] for e in events if e["process"].startswith("/device:")
+    }
+    out: dict[str, float] = {}
+    for e in events:
+        if e["dur"] <= 0 or not (
+            e["pid"] in device_planes if device_planes
+            else "hlo_op" in e["args"]
+        ):
+            continue
+        op = str(e["args"].get("hlo_op") or e["name"]).lstrip("%")
+        if op.split(".")[0] in ("while", "conditional", "call"):
+            continue
+        module = e["args"].get("hlo_module")
+        if module is not None:
+            smap = maps.get(module)
+        else:
+            smap = next((m for m in maps.values() if op in m), None)
+        if smap is None:
+            scope = "other_programs"
+        else:
+            scope = smap.get(op) or "unscoped"
+        out[scope] = out.get(scope, 0.0) + e["dur"] / 1e6
+    return out
+
+
 # ---------------------------------------------------------------------------
 # runtime layer: capture windows + live gauges
 # ---------------------------------------------------------------------------
@@ -361,8 +409,8 @@ class RoundProfiler:
     Each profiled round gets its OWN capture session under
     ``<out_dir>/jax_profile/round<k>/`` — per-round windows make the
     breakdown genuinely per-round without segmenting one long capture,
-    and keep ``--trace_jax``'s TraceAnnotations inside the matching
-    round's file. A ``capture.json`` manifest (epoch start + wall
+    and keep the loop's ``fedml.*`` span annotations inside the
+    matching round's file. A ``capture.json`` manifest (epoch start + wall
     window) rides next to each capture so ``scripts/merge_trace.py``
     can rebase the session-relative device timestamps onto the host
     span timeline. Parsed breakdowns feed ``perf.profile.*`` gauges and
@@ -446,8 +494,11 @@ class RoundProfiler:
                 json.dump(manifest, f)
         except OSError:
             pass
-        bd = device_time_breakdown(load_trace_events(d),
-                                   window_s=window_s)
+        events = load_trace_events(d)
+        bd = device_time_breakdown(events, window_s=window_s)
+        phases = phase_split(events)
+        if phases is not None:
+            bd["phases_s"] = phases
         bd["round"] = round_idx
         bd["rounds_in_window"] = int(rounds)
         self.breakdowns.append(bd)
@@ -483,6 +534,15 @@ class RoundProfiler:
             for k in ("compute_frac", "collective_frac", "host_frac",
                       "idle_frac", "window_s")
         }
+        # the phase split, where the round program's scope map exists
+        # (memscope.scope_map): mean seconds a profiled window by scope
+        split = [b["phases_s"] for b in self.breakdowns
+                 if "phases_s" in b]
+        if split:
+            mean["phases_s"] = {
+                k: float(np.mean([p.get(k, 0.0) for p in split]))
+                for k in sorted({k for p in split for k in p})
+            }
         tmp = path + ".tmp"
         try:
             with open(tmp, "w") as f:

@@ -53,7 +53,7 @@ import time
 import uuid
 from typing import Any
 
-from fedml_tpu.core.tracing import Tracer
+from fedml_tpu.core.tracing import Tracer, span
 
 
 def percentiles_from_histogram(
@@ -427,14 +427,9 @@ def current_trace() -> str | None:
     return getattr(_tls, "trace", None)
 
 
-def maybe_span(name: str, **attrs):
-    """A tracer span when tracing is on, a null context otherwise."""
-    import contextlib
-
-    tr = TRACER
-    if tr is None:
-        return contextlib.nullcontext()
-    return tr.span(name, **attrs)
+#: The one span primitive (core/tracing.py): always a profiler
+#: annotation, plus a ring event while :data:`TRACER` is configured.
+maybe_span = span
 
 
 def flight_dump(reason: str, **fields) -> str | None:
@@ -483,7 +478,6 @@ def configure(
     telemetry_dir: str | None = None,
     rank: int = 0,
     trace: bool = True,
-    jax_profiler: bool = False,
     flight_capacity: int = 1024,
     metrics_interval: float | None = None,
     metrics_port: int | None = None,
@@ -494,9 +488,9 @@ def configure(
     """Enable telemetry for THIS process (idempotent).
 
     - metrics counting switches on unconditionally;
-    - ``trace=True`` creates the process tracer (optionally wrapping
-      spans in ``jax.profiler.TraceAnnotation`` so device work lines up
-      with host spans in a jax profile);
+    - ``trace=True`` creates the process tracer: spans
+      (:func:`fedml_tpu.core.tracing.span`) then append to its ring as
+      well as annotating any active ``jax.profiler`` session;
     - a ``telemetry_dir`` additionally arms the flight recorder, the
       crash hooks (sys/threading excepthook -> flight dump), and the
       exit flush that writes ``trace_rank<r>.json`` +
@@ -524,7 +518,7 @@ def configure(
     RECORDER.rank = rank
     if trace:
         if TRACER is None:
-            TRACER = Tracer(use_jax_profiler=jax_profiler, rank=rank)
+            TRACER = Tracer(rank=rank)
         else:
             TRACER.rank = rank
     if telemetry_dir:

@@ -4,8 +4,15 @@ Reference: wall-clock timers around aggregation
 (``FedAVGAggregator.py:60,86-87``) and grep-able "--Benchmark" lines via
 ``log_communication_tick/tock`` + ``log_round_start/end``
 (``fedml_core/distributed/communication/utils.py:4-18``). Here the same
-API feeds a structured in-memory trace (exportable to JSON) and optionally
-``jax.profiler`` ranges so device timelines line up with host spans.
+API feeds a structured in-memory trace (exportable to JSON).
+
+:func:`span` is the ONE span primitive (docs/OBSERVABILITY.md "Spans and
+scopes"): it always enters a ``jax.profiler.TraceAnnotation``, so the
+switch for "tracing on" is a profiler session being active (the
+benchmark's ``--trace 1``, ``--profile_rounds``, ``--profile_on_breach``)
+and host spans land in the capture on the device's clock; with a
+configured tracer it also appends to the ring, and with the anatomy plane
+on a ``phase=`` site feeds its duration to ``ANATOMY.phase``.
 
 Every event carries a wall-clock ``ts`` (epoch seconds at start), the
 emitting ``rank`` and thread id — the coordinates
@@ -19,13 +26,107 @@ in as ordinary attrs from the telemetry layer
 from __future__ import annotations
 
 import collections
-import contextlib
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Any
+
+from jax.profiler import TraceAnnotation
+
+_tls = threading.local()
+
+
+def _open_spans() -> list:
+    """This thread's stack of open spans, ``(name, round)`` each."""
+    try:
+        return _tls.stack
+    except AttributeError:
+        _tls.stack = []
+        return _tls.stack
+
+
+class span:
+    """``with span(name, **attrs):`` — one boundary, three sinks.
+
+    - ALWAYS a ``jax.profiler.TraceAnnotation(name, **attrs)``: a flag
+      check while no profiler session is active, an event with its
+      attrs as stats (parent by nesting on the thread) while one is;
+    - with ``telemetry.TRACER`` configured (or an explicit ``_tracer``)
+      a ring event as well, carrying ``parent`` — the enclosing span's
+      name on this thread — so self time can be computed from the ring;
+    - with ``ANATOMY.enabled`` and ``phase=``, the duration goes to
+      ``ANATOMY.phase(phase, seconds)``.
+
+    A span without a ``round`` attr inherits the enclosing span's, so
+    the spans of one round share that identifier. The clock is read
+    only when the ring or the anatomy plane wants the duration;
+    ``seconds`` holds it afterwards (0.0 otherwise). The body's value
+    and exceptions pass through untouched; a raising body still leaves
+    its ring event, tagged ``error``."""
+
+    __slots__ = ("name", "attrs", "phase", "seconds", "_ann", "_tracer",
+                 "_anat", "_t0", "_ts", "_parent")
+
+    def __init__(self, name: str, phase: str | None = None,
+                 _tracer: "Tracer | None" = None, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.phase = phase
+        self.seconds = 0.0
+        self._tracer = _tracer
+
+    def __enter__(self) -> "span":
+        attrs = self.attrs
+        stack = _open_spans()
+        self._parent = None
+        if stack:
+            self._parent, rnd = stack[-1]
+            if rnd is not None and "round" not in attrs:
+                attrs["round"] = rnd
+        self._ann = TraceAnnotation(self.name, **attrs)
+        tr = self._tracer
+        anat = None
+        # telemetry imports this module and anatomy imports telemetry:
+        # both are looked up once loaded, never imported from here
+        if tr is None:
+            tel = sys.modules.get("fedml_tpu.core.telemetry")
+            if tel is not None:
+                tr = self._tracer = tel.TRACER
+        if self.phase is not None:
+            mod = sys.modules.get("fedml_tpu.core.anatomy")
+            if mod is not None and mod.ANATOMY.enabled:
+                anat = mod.ANATOMY
+        self._anat = anat
+        stack.append((self.name, attrs.get("round")))
+        if tr is not None or anat is not None:
+            self._ts = time.time()
+            self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
+        _open_spans().pop()
+        tr, anat = self._tracer, self._anat
+        if tr is not None or anat is not None:
+            self.seconds = dt = time.perf_counter() - self._t0
+            if tr is not None:
+                ev = tr._base("span", self._ts, dt, {
+                    "name": self.name, "parent": self._parent,
+                    **self.attrs,
+                })
+                if exc is not None:
+                    # the span record must survive a raising body: a
+                    # failing round still leaves its timing (tagged
+                    # with the error) instead of silently dropping it
+                    ev["error"] = repr(exc)
+                tr._emit(ev)
+            if anat is not None:
+                anat.phase(self.phase, dt)
+        return False
 
 
 class Tracer:
@@ -37,14 +138,13 @@ class Tracer:
     counts evictions and is recorded in :meth:`dump`.
     """
 
-    def __init__(self, use_jax_profiler: bool = False,
-                 rank: int | None = None, max_events: int = 200_000):
+    def __init__(self, rank: int | None = None,
+                 max_events: int = 200_000):
         self.events: collections.deque[dict[str, Any]] = collections.deque(
             maxlen=max_events
         )
         self.dropped = 0
         self._open: dict[str, tuple[float, float]] = {}
-        self._jax = use_jax_profiler
         self.rank = rank
         self._lock = threading.Lock()
 
@@ -98,33 +198,10 @@ class Tracer:
             ))
 
     # -- generic spans -----------------------------------------------------
-    @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        ctx = (
-            __import__("jax").profiler.TraceAnnotation(name)
-            if self._jax
-            else contextlib.nullcontext()
-        )
-        t0 = time.perf_counter()
-        ts = time.time()
-        err: BaseException | None = None
-        try:
-            with ctx:
-                yield
-        except BaseException as e:
-            # the span record must survive a raising body: a failing
-            # round still leaves its timing (tagged with the error)
-            # instead of silently dropping the event
-            err = e
-            raise
-        finally:
-            ev = self._base(
-                "span", ts, time.perf_counter() - t0,
-                {"name": name, **attrs},
-            )
-            if err is not None:
-                ev["error"] = repr(err)
-            self._emit(ev)
+        """:func:`span` with THIS tracer as the ring (whatever
+        ``telemetry.TRACER`` holds)."""
+        return span(name, _tracer=self, **attrs)
 
     def event(self, name: str, **attrs):
         """Instant event (zero duration) — message sends/delivers, fault

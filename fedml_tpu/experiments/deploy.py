@@ -149,7 +149,6 @@ class DeployConfig:
     # None + trace=False keeps the telemetry plane fully disabled
     telemetry_dir: str | None = None
     trace: bool = False  # span tracing without (or in addition to) a dir
-    trace_jax: bool = False  # wrap spans in jax.profiler.TraceAnnotation
     # periodic metrics time-series flush: seconds between snapshot rows
     # appended to metrics_rank<r>.jsonl (None = off; the round-latency
     # SLO surface of a long-lived server — histograms carry p50/p95/p99
@@ -1272,7 +1271,7 @@ class Supervisor:
 
 def run_role(cfg: ExperimentConfig, dep: DeployConfig) -> dict:
     """Run THIS process's rank to completion; returns the rank summary."""
-    if (dep.telemetry_dir or dep.trace or dep.trace_jax
+    if (dep.telemetry_dir or dep.trace
             or dep.metrics_interval or dep.metrics_port is not None
             or cfg.fed.slos or cfg.fed.anatomy
             or cfg.fed.profile_on_breach):
@@ -1281,7 +1280,6 @@ def run_role(cfg: ExperimentConfig, dep: DeployConfig) -> dict:
             telemetry_dir=dep.telemetry_dir
             or telemetry.default_dir(cfg.out_dir, cfg.run_name),
             rank=dep.rank,
-            jax_profiler=dep.trace_jax,
             metrics_interval=dep.metrics_interval,
             metrics_port=dep.metrics_port,
             metrics_host=dep.metrics_host,

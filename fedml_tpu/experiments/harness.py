@@ -374,7 +374,7 @@ class Experiment:
                     # covers the whole trajectory (round-level spans
                     # come from the generic loop below otherwise)
                     with telemetry.maybe_span(
-                        "sim_run", sim=type(sim).__name__
+                        "fedml.run", sim=type(sim).__name__
                     ):
                         sim.run(metrics_sink=sink)
                     return
@@ -463,45 +463,49 @@ class Experiment:
                 )
             if profiler is not None:
                 profiler.start_round(r)
-            with telemetry.maybe_span("sim_round", round=r):
-                if state is None:  # host-driven sims (HeteroFedGDKD)
-                    m = sim.run_round()
-                else:
-                    out = (
-                        sim.run_round(state, r)
-                        if _wants_round(sim) else sim.run_round(state)
+            with telemetry.maybe_span("fedml.round", round=r):
+                with telemetry.maybe_span("fedml.dispatch"):
+                    if state is None:  # host-driven sims (HeteroFedGDKD)
+                        m = sim.run_round()
+                    else:
+                        out = (
+                            sim.run_round(state, r)
+                            if _wants_round(sim)
+                            else sim.run_round(state)
+                        )
+                        state, m = out
+                record = {"round": r}
+                if start_round:
+                    # this incarnation resumed mid-run: its rows win
+                    # over any pre-crash row for the same round
+                    record["resumed"] = True
+                if isinstance(m, dict):
+                    from fedml_tpu.algorithms.fedavg import (
+                        consume_round_counters,
                     )
-                    state, m = out
-            record = {"round": r}
-            if start_round:
-                # this incarnation resumed mid-run: its rows win over
-                # any pre-crash row for the same round
-                record["resumed"] = True
-            if isinstance(m, dict):
-                from fedml_tpu.algorithms.fedavg import (
-                    consume_round_counters,
-                )
 
-                m = consume_round_counters(_batched_get(dict(m)))
-                record.update({k: _f(v) for k, v in m.items()
-                               if _scalar(v)})
-            # the scalar conversion above forced the round's metrics to
-            # host, so the capture window and wall time cover the
-            # device execution, not just the dispatch
-            if profiler is not None:
-                profiler.end_round(r)
-            if monitor is not None:
-                monitor.note_round(_time.perf_counter() - t0)
-            if (r + 1) % cfg.fed.eval_every == 0 or (
-                r == cfg.fed.num_rounds - 1
-            ):
-                record.update(Experiment._eval_record(sim, state))
-            sink.log(record)
-            if ckpt is not None and (
-                (r + 1) % cfg.checkpoint_every == 0
-                or r == cfg.fed.num_rounds - 1
-            ):
-                Experiment._save_state(ckpt, sim, r, state)
+                    with telemetry.maybe_span("fedml.fetch"):
+                        m = consume_round_counters(_batched_get(dict(m)))
+                    record.update({k: _f(v) for k, v in m.items()
+                                   if _scalar(v)})
+                # the scalar conversion above forced the round's
+                # metrics to host, so the capture window and wall time
+                # cover the device execution, not just the dispatch
+                if profiler is not None:
+                    profiler.end_round(r)
+                if monitor is not None:
+                    monitor.note_round(_time.perf_counter() - t0)
+                if (r + 1) % cfg.fed.eval_every == 0 or (
+                    r == cfg.fed.num_rounds - 1
+                ):
+                    record.update(Experiment._eval_record(sim, state))
+                with telemetry.maybe_span("fedml.log"):
+                    sink.log(record)
+                if ckpt is not None and (
+                    (r + 1) % cfg.checkpoint_every == 0
+                    or r == cfg.fed.num_rounds - 1
+                ):
+                    Experiment._save_state(ckpt, sim, r, state)
 
     @staticmethod
     def _save_state(ckpt, sim, r, state):
@@ -594,6 +598,10 @@ class Experiment:
                 records.append(rec)
             return records
 
+        def log(rec):
+            with telemetry.maybe_span("fedml.log", round=rec["round"]):
+                sink.log(rec)
+
         def boundary_hook(r_last, last):
             if telemetry.METRICS.enabled:
                 from fedml_tpu.core import export as _export
@@ -606,7 +614,7 @@ class Experiment:
                 r_last == total - 1
             ):
                 last.update(Experiment._eval_record(sim, box[0]))
-            sink.log(last)
+            log(last)
             if ckpt is not None and (
                 (r_last + 1) % cfg.checkpoint_every == 0
                 or r_last == total - 1
@@ -620,10 +628,8 @@ class Experiment:
             profiler=profiler,
             monitor=monitor,
             make_records=make_records,
-            log=sink.log,
+            log=log,
             boundary_hook=boundary_hook,
-            span=lambda start, rounds: telemetry.maybe_span(
-                "sim_block", start=start, rounds=rounds),
         )
 
 

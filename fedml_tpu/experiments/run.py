@@ -285,11 +285,6 @@ def parse_args(argv=None) -> tuple[ExperimentConfig, argparse.Namespace]:
                    help="enable span tracing + metrics without naming a "
                         "directory (dumps to <out_dir>/<run>/telemetry; "
                         "implied by --telemetry_dir)")
-    p.add_argument("--trace_jax", action="store_true",
-                   help="additionally wrap tracer spans in "
-                        "jax.profiler.TraceAnnotation so device "
-                        "timelines line up with host spans in a jax "
-                        "profile")
     # -- round fusion (core/fuse.py; docs/PERFORMANCE.md "Round
     # fusion") --------------------------------------------------------------
     p.add_argument("--fuse_rounds", type=int, default=None,
@@ -330,9 +325,9 @@ def parse_args(argv=None) -> tuple[ExperimentConfig, argparse.Namespace]:
                         "collective/host/idle) under "
                         "<telemetry_dir>/jax_profile/, plus live "
                         "perf.* gauges (round rate, MFU, dispatch-"
-                        "bound detector) for the whole run; composes "
-                        "with --trace_jax (span annotations land "
-                        "inside the captures). Implies telemetry.")
+                        "bound detector) for the whole run; the "
+                        "captures hold the fedml.* host spans and "
+                        "device scopes. Implies telemetry.")
     p.add_argument("--metrics_interval", type=float, default=None,
                    help="seconds between periodic metrics snapshots "
                         "appended to metrics_rank<r>.jsonl in the "
@@ -937,7 +932,6 @@ def _deploy_config(a) -> "DeployConfig":
         world_size=a.world_size,
         telemetry_dir=a.telemetry_dir,
         trace=a.trace,
-        trace_jax=a.trace_jax,
         metrics_interval=a.metrics_interval,
         metrics_port=a.metrics_port,
         metrics_host=a.metrics_host,
@@ -1194,7 +1188,7 @@ def main(argv=None) -> int:
             f"{sorted(_ADVERSARY_SIMS)}); results here are DENSE",
             file=sys.stderr,
         )
-    if (a.telemetry_dir or a.trace or a.trace_jax
+    if (a.telemetry_dir or a.trace
             or cfg.fed.profile_rounds or a.metrics_interval
             or a.metrics_port is not None or cfg.fed.slos
             or cfg.fed.anatomy or cfg.fed.profile_on_breach):
@@ -1204,7 +1198,6 @@ def main(argv=None) -> int:
             telemetry_dir=a.telemetry_dir
             or telemetry.default_dir(cfg.out_dir, cfg.run_name),
             rank=0,
-            jax_profiler=a.trace_jax,
             metrics_interval=a.metrics_interval,
             metrics_port=a.metrics_port,
             metrics_host=a.metrics_host,

@@ -343,22 +343,28 @@ class ShardedFedAvg(FedAvgSim):
             # ids); keys use GLOBAL client ids so the host mirror matches.
             # Under elastic bucketing the shard samples its full BUCKET
             # and a traced per-shard live count masks the padded slots.
-            local = R.sample_stratum(ckey, shard, K, Kb)
-            ckeys = jax.vmap(
-                lambda c: R.client_key(rkey, shard * K + c)
-            )(local)
-            if self._shard_cohort_update is not None:
-                # cohort-grouped fast path per shard: this shard's slice
-                # of the cohort runs as ONE widened network (see
-                # fedml_tpu.models.cohort) — purely intra-shard compute,
-                # so it composes with the client-axis psum unchanged
-                stacked_vars, n_k, msums = self._shard_cohort_update(
-                    state.variables, idx[local], mask[local], x, y, ckeys
-                )
-            else:
-                stacked_vars, n_k, msums = jax.vmap(
-                    self.local_update, in_axes=(None, 0, 0, None, None, 0)
-                )(state.variables, idx[local], mask[local], x, y, ckeys)
+            with jax.named_scope("fedml.sample"):
+                local = R.sample_stratum(ckey, shard, K, Kb)
+                ckeys = jax.vmap(
+                    lambda c: R.client_key(rkey, shard * K + c)
+                )(local)
+            with jax.named_scope("fedml.local"):
+                if self._shard_cohort_update is not None:
+                    # cohort-grouped fast path per shard: this shard's
+                    # slice of the cohort runs as ONE widened network
+                    # (see fedml_tpu.models.cohort) — purely intra-shard
+                    # compute, so it composes with the client-axis psum
+                    # unchanged
+                    stacked_vars, n_k, msums = self._shard_cohort_update(
+                        state.variables, idx[local], mask[local], x, y,
+                        ckeys,
+                    )
+                else:
+                    stacked_vars, n_k, msums = jax.vmap(
+                        self.local_update,
+                        in_axes=(None, 0, 0, None, None, 0),
+                    )(state.variables, idx[local], mask[local], x, y,
+                      ckeys)
 
             # PEFT view: the psum'd aggregation below only ever sees
             # the O(adapter) pruned subtree — the frozen base is a
@@ -370,31 +376,34 @@ class ShardedFedAvg(FedAvgSim):
             )
             live = None
             if n_act is not None:
-                live = E.active_mask(
-                    Kb, n_act // self.n_client_shards
-                )
-                stacked_vars, n_k, msums = E.mask_padded(
-                    stacked_vars, n_k, msums, view.variables, live
-                )
+                with jax.named_scope("fedml.defense_agg"):
+                    live = E.active_mask(
+                        Kb, n_act // self.n_client_shards
+                    )
+                    stacked_vars, n_k, msums = E.mask_padded(
+                        stacked_vars, n_k, msums, view.variables, live
+                    )
 
-            new_state = server_update(
-                cfg,
-                self.cfg.train,
-                self.steps_per_epoch,
-                self.batch_size,
-                view,
-                stacked_vars,
-                n_k,
-                rkey,
-                red,
-                valid=live,
-            )
-            if self._peft is not None:
-                new_state = self._peft.merge_state(new_state, state)
-            reduced = jax.tree.map(
-                lambda v: jax.lax.psum(jnp.sum(v), self.client_axis), msums
-            )
-            fin = finalize_sums(reduced)
+            with jax.named_scope("fedml.server_update"):
+                new_state = server_update(
+                    cfg,
+                    self.cfg.train,
+                    self.steps_per_epoch,
+                    self.batch_size,
+                    view,
+                    stacked_vars,
+                    n_k,
+                    rkey,
+                    red,
+                    valid=live,
+                )
+                if self._peft is not None:
+                    new_state = self._peft.merge_state(new_state, state)
+                reduced = jax.tree.map(
+                    lambda v: jax.lax.psum(jnp.sum(v), self.client_axis),
+                    msums,
+                )
+                fin = finalize_sums(reduced)
             metrics = {"train_loss": fin["loss"], "train_acc": fin["acc"]}
             return new_state, metrics
 
@@ -440,7 +449,8 @@ class ShardedFedAvg(FedAvgSim):
         draw = (
             min(S, K) if self._elastic else self.cohort_per_shard
         )
-        local = R.sample_stratum(ckey, shard, K, draw)
+        with jax.named_scope("fedml.sample"):
+            local = R.sample_stratum(ckey, shard, K, draw)
         pad = S - draw
         if pad:
             # the LOCAL sentinel (= K, this shard's row count): the
@@ -464,39 +474,44 @@ class ShardedFedAvg(FedAvgSim):
             )
 
         def fold_block(block_ids, block_live):
-            ckeys = jax.vmap(
-                lambda c: R.client_key(rkey, shard * K + c)
-            )(block_ids)
-            stacked_vars, n_k, msums = jax.vmap(
-                self.local_update, in_axes=(None, 0, 0, None, None, 0)
-            )(state.variables, idx[block_ids], mask[block_ids], x, y,
-              ckeys)
-            if block_live is not None:
-                stacked_vars, n_k, msums = E.mask_padded(
-                    stacked_vars, n_k, msums, view.variables,
-                    block_live,
+            with jax.named_scope("fedml.sample"):
+                ckeys = jax.vmap(
+                    lambda c: R.client_key(rkey, shard * K + c)
+                )(block_ids)
+            with jax.named_scope("fedml.local"):
+                stacked_vars, n_k, msums = jax.vmap(
+                    self.local_update,
+                    in_axes=(None, 0, 0, None, None, 0),
+                )(state.variables, idx[block_ids], mask[block_ids], x,
+                  y, ckeys)
+            with jax.named_scope("fedml.defense_agg"):
+                if block_live is not None:
+                    stacked_vars, n_k, msums = E.mask_padded(
+                        stacked_vars, n_k, msums, view.variables,
+                        block_live,
+                    )
+                # the sharded stacked path carries no non-finite screen
+                # (adversary configs are rejected at construction) —
+                # the bulk twin mirrors it: rejected stays 0
+                return fold_block_partials(
+                    cfg, self.cfg.train, self.steps_per_epoch,
+                    self.batch_size, view, stacked_vars, n_k, msums,
+                    jnp.zeros((), jnp.float32),
                 )
-            # the sharded stacked path carries no non-finite screen
-            # (adversary configs are rejected at construction) — the
-            # bulk twin mirrors it: rejected stays 0
-            return fold_block_partials(
-                cfg, self.cfg.train, self.steps_per_epoch,
-                self.batch_size, view, stacked_vars, n_k, msums,
-                jnp.zeros((), jnp.float32),
-            )
 
         partials = BK.stream_blocks(
             fold_block, local, live, self._block_size
         )
-        partials = jax.tree.map(
-            lambda v: jax.lax.psum(v, self.client_axis), partials
-        )
-        new_state = server_update_from_partials(
-            cfg, view, partials, rkey
-        )
-        if self._peft is not None:
-            new_state = self._peft.merge_state(new_state, state)
-        fin = finalize_sums(partials.msums)
+        with jax.named_scope("fedml.server_update"):
+            partials = jax.tree.map(
+                lambda v: jax.lax.psum(v, self.client_axis), partials
+            )
+            new_state = server_update_from_partials(
+                cfg, view, partials, rkey
+            )
+            if self._peft is not None:
+                new_state = self._peft.merge_state(new_state, state)
+            fin = finalize_sums(partials.msums)
         return new_state, {
             "train_loss": fin["loss"], "train_acc": fin["acc"],
         }

@@ -24,8 +24,8 @@ span durations, instant events render as markers.
 jax-profiler captures (``--profile_rounds``, core/perf.py) live in
 their own files by design — ``<telemetry_dir>/jax_profile/round<k>/``,
 one session per profiled round — so they can never clobber the host
-span dumps, and ``--trace_jax`` annotations land INSIDE the capture
-they belong to. ``--jax-profile`` optionally folds those captures into
+span dumps, and the ``fedml.*`` span annotations (core/tracing.py)
+land INSIDE the capture they belong to. ``--jax-profile`` optionally folds those captures into
 the merged timeline: each profiled round becomes its own Perfetto
 process (``jax profile round <k>``) holding the XLA op events, rebased
 onto the host timeline via the epoch anchor in each capture's

@@ -149,6 +149,36 @@ def test_phase_conservation_sharded(anatomy_env):
     _assert_conserved(ANATOMY.tracez()["entries"], "sharded", 2)
 
 
+def test_entries_keep_their_keys_and_the_loops_hold_no_clock_pair(
+        anatomy_env):
+    """The phases are fed by the span sites (core/tracing.span,
+    ``phase=``): the ring entry's schema is what it was, and the round
+    loops hold no hand-written clock pair for anatomy any more."""
+    import inspect
+
+    from fedml_tpu.core import fuse
+
+    _sim(_cfg(rounds=2, eval_every=1)).run()
+    _sim(_cfg(rounds=2, fuse_rounds=2)).run()
+    entries = ANATOMY.tracez()["entries"]
+    assert [e["path"] for e in entries] == ["stacked", "stacked", "fused"]
+    for e in entries:
+        assert set(e) == {"round", "path", "rounds", "wall_s", "phases",
+                          "dominant", "ts"}
+        assert {"local", "eval", "host_gap"} == set(e["phases"])
+        assert abs(sum(e["phases"].values()) - e["wall_s"]) <= CONSERVE_TOL
+    for fn in (FedAvgSim.run, FedAvgSim._run_fused, fuse.drive,
+               fuse.BlockPipeline.flush):
+        src = inspect.getsource(fn)
+        assert "ANATOMY.phase(" not in src and "amend_last(" not in src
+        for gone in ("t_disp", "t_ev", "t_get", "get_wait"):
+            assert gone not in src, (fn.__qualname__, gone)
+    # one clock read is left in each loop: the perf monitor's round wall
+    assert inspect.getsource(FedAvgSim.run).count("perf_counter()") == 2
+    assert inspect.getsource(FedAvgSim._run_fused).count(
+        "perf_counter") == 0
+
+
 def test_amend_last_conserves(anatomy_env):
     ANATOMY.begin_round(0, path="fused", rounds=2)
     ANATOMY.phase("local", 0.8)

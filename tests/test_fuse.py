@@ -534,12 +534,11 @@ def test_block_pipeline_one_deep():
     dm1 = {"a": jnp.arange(2.0)}
     assert pl.push(0, 2, dm1, 0.0, compiled=True) is None
     prev = pl.push(2, 2, {"a": jnp.arange(2.0) + 2}, 0.0)
-    start, n, rows, wall, compiled, get_wait = prev
+    start, n, rows, wall, compiled = prev
     assert (start, n, compiled) == (0, 2, True)
     assert [float(r["a"]) for r in rows] == [0.0, 1.0]
     assert wall > 0
-    assert 0 <= get_wait <= wall
-    start, n, rows, _, compiled, _ = pl.flush()
+    start, n, rows, _, compiled = pl.flush()
     assert (start, n, compiled) == (2, 2, False)
     assert [float(r["a"]) for r in rows] == [2.0, 3.0]
     assert pl.flush() is None

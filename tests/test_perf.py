@@ -338,6 +338,51 @@ def test_sim_run_with_profile_rounds_writes_breakdown_and_gauges(
         telemetry.shutdown()
 
 
+def test_profile_rounds_breakdown_gains_the_phase_split(tmp_path):
+    """The operator's own capture reads the round program's scopes:
+    each profiled window's device time split by ``fedml.*`` scope
+    through ``memscope.scope_map`` (docs/OBSERVABILITY.md "Spans and
+    scopes"), the same join the benchmark's reader makes."""
+    from fedml_tpu.core import memscope
+
+    memscope.reset()
+    telemetry.configure(telemetry_dir=str(tmp_path / "t"), rank=0)
+    try:
+        sim = _tiny_sim(cpr=2, profile_rounds=2, num_rounds=3)
+        sim.run()
+        data = json.load(open(tmp_path / "t" / "perf_rank0.json"))
+        assert len(data["rounds"]) == 2
+        for row in data["rounds"]:
+            phases = row["phases_s"]
+            assert {"fedml.local.grad", "fedml.local.update",
+                    "fedml.server_update"} <= set(phases)
+            assert all(v >= 0 for v in phases.values())
+            # every op of the round program is counted once, somewhere
+            assert sum(phases.values()) > 0
+        mean = data["mean"]["phases_s"]
+        assert mean["fedml.local.grad"] == pytest.approx(sum(
+            r["phases_s"]["fedml.local.grad"] for r in data["rounds"]
+        ) / 2)
+        # a synthetic capture: the join is by module, then instruction
+        fam, key, module = memscope.scope_programs()[0]
+        smap = memscope.scope_map(fam, key)
+        grad = next(n for n, s in smap.items() if s == "fedml.local.grad")
+        none = next(n for n, s in smap.items() if s is None)
+        ev = lambda name, mod, dur: {
+            "name": name, "pid": 1, "tid": 1, "ts": 0.0, "dur": dur,
+            "process": "/host:CPU",
+            "args": {"hlo_op": name, "hlo_module": mod}}
+        split = perf.phase_split([
+            ev(grad, module, 2e6), ev(none, module, 1e6),
+            ev("while.3", module, 9e6), ev("fusion.1", "jit_evaluate", 4e6),
+        ])
+        assert split == {"fedml.local.grad": 2.0, "unscoped": 1.0,
+                         "other_programs": 4.0}
+    finally:
+        telemetry.shutdown()
+    assert perf.phase_split([]) is None  # no program kept, no split
+
+
 # ---------------------------------------------------------------------------
 # 5. percentile estimation + its surfaces
 # ---------------------------------------------------------------------------

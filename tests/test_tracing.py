@@ -50,3 +50,326 @@ def test_transformer_fedmodel_in_fedavg():
     state = sim.init()
     state, m = sim.run_round(state)
     assert jnp.isfinite(m["train_loss"])
+
+
+# ---------------------------------------------------------------------------
+# the one span primitive (core/tracing.span) and the fedml.* scopes
+# (docs/OBSERVABILITY.md "Spans and scopes")
+# ---------------------------------------------------------------------------
+
+import glob  # noqa: E402
+import weakref  # noqa: E402
+
+import numpy as np  # noqa: E402
+
+from fedml_tpu.algorithms.fedavg import FedAvgSim  # noqa: E402
+from fedml_tpu.config import (  # noqa: E402
+    DataConfig,
+    ExperimentConfig,
+    FedConfig,
+    ModelConfig,
+    TrainConfig,
+)
+from fedml_tpu.core import anatomy, memscope, telemetry, tracing  # noqa: E402
+from fedml_tpu.core.anatomy import ANATOMY  # noqa: E402
+from fedml_tpu.core.tracing import span  # noqa: E402
+from fedml_tpu.data.loaders import load_dataset  # noqa: E402
+from fedml_tpu.models import create_model  # noqa: E402
+from fedml_tpu.parallel import ShardedFedAvg, make_mesh  # noqa: E402
+
+SCOPES = ("fedml.sample", "fedml.local.gather", "fedml.local.grad",
+          "fedml.local.update", "fedml.server_update")
+
+
+def _cfg(kind="lr", rounds=3, clients=8, cohort=4, **fed_kw):
+    fed_kw.setdefault("eval_every", 2)
+    if kind == "lr":  # the vmapped local update
+        data = DataConfig(dataset="fake_mnist", num_clients=clients,
+                          batch_size=32, seed=0)
+        model = ModelConfig(name="lr", num_classes=10,
+                            input_shape=(28, 28, 1))
+    else:  # a cohort-grouped net (build_cohort_local_update)
+        data = DataConfig(dataset="fake_cifar10", num_clients=clients,
+                          batch_size=16, seed=0, dataset_r=0.1)
+        model = ModelConfig(
+            name="cnn_custom", num_classes=10, input_shape=(32, 32, 3),
+            extra=(("convs", (8,)), ("denses", (16,))))
+    return ExperimentConfig(
+        data=data, model=model, train=TrainConfig(lr=0.05, epochs=1),
+        fed=FedConfig(num_rounds=rounds, clients_per_round=cohort,
+                      **fed_kw),
+        seed=0,
+    )
+
+
+def _sim(kind="lr", sharded=False, **kw):
+    if sharded:
+        kw.setdefault("clients", 16)
+        kw.setdefault("cohort", 8)
+    cfg = _cfg(kind, **kw)
+    model, data = create_model(cfg.model), load_dataset(cfg.data)
+    if not sharded:
+        return FedAvgSim(model, data, cfg)
+    return ShardedFedAvg(model, data, cfg, make_mesh(
+        client_axis=4, data_axis=1, devices=jax.devices()[:4]))
+
+
+def _captured_spans(trace_dir):
+    """``[(name, start_ns, end_ns, stats)]`` of the ``fedml.*`` host
+    events of a finished ``jax.profiler`` capture, by start."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("fedml."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda ev: ev[1])
+
+
+def _run_captured(sim, trace_dir):
+    from fedml_tpu.metrics import MetricsSink
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        return sim.run(metrics_sink=MetricsSink())
+    finally:
+        jax.profiler.stop_trace()
+
+
+def test_span_off_is_transparent():
+    """No profiler session, no tracer, no anatomy: nothing is recorded,
+    no clock is read, and the body's value and exception pass."""
+    assert telemetry.TRACER is None and not ANATOMY.enabled
+
+    def body():
+        with span("fedml.x", round=3, phase="eval") as sp:
+            assert tracing._open_spans()[-1] == ("fedml.x", 3)
+            return sp, 41 + 1
+
+    sp, value = body()
+    assert value == 42 and sp.seconds == 0.0
+    with pytest.raises(KeyError):
+        with span("fedml.y"):
+            raise KeyError("boom")
+    assert tracing._open_spans() == []
+    assert ANATOMY.tracez()["entries"] == []
+
+
+def test_span_ring_records_parent_round_and_error(tmp_path):
+    telemetry.configure(telemetry_dir=str(tmp_path / "t"), rank=0)
+    try:
+        with span("fedml.round", round=7):
+            with span("fedml.dispatch"):
+                pass
+            with pytest.raises(ValueError):
+                with span("fedml.eval", h2d_bytes=12):
+                    raise ValueError("bad")
+        evs = {e["name"]: e for e in telemetry.TRACER.events
+               if e["kind"] == "span"}
+        assert evs["fedml.round"]["parent"] is None
+        assert evs["fedml.dispatch"]["parent"] == "fedml.round"
+        assert evs["fedml.eval"]["parent"] == "fedml.round"
+        # one identifier for the spans of one round, inherited
+        assert {e["round"] for e in evs.values()} == {7}
+        assert evs["fedml.eval"]["h2d_bytes"] == 12
+        assert "ValueError" in evs["fedml.eval"]["error"]
+        # self time is computable from the ring alone
+        kids = sum(e["seconds"] for e in evs.values()
+                   if e["parent"] == "fedml.round")
+        assert 0 <= evs["fedml.round"]["seconds"] - kids
+        # Tracer.span is the same primitive on an explicit ring
+        own = Tracer()
+        with own.span("a"):
+            with own.span("b", round=1):
+                pass
+        assert [(e["name"], e["parent"]) for e in own.events] == [
+            ("b", "a"), ("a", None)]
+    finally:
+        telemetry.shutdown()
+
+
+def test_span_phase_feeds_anatomy_and_amends(tmp_path):
+    telemetry.configure(telemetry_dir=str(tmp_path / "t"), rank=0)
+    anatomy.configure(anatomy=True)
+    try:
+        ANATOMY.begin_round(0)
+        with span("fedml.fetch", phase="local") as sp:
+            pass
+        with span("fedml.log"):  # no phase: host_gap
+            pass
+        entry = ANATOMY.end_round()
+        assert entry["phases"]["local"] == sp.seconds > 0
+        assert set(entry["phases"]) == {"local", "host_gap"}
+        # with the round closed a phase is dropped ...
+        with span("fedml.eval", phase="eval"):
+            pass
+        assert "eval" not in ANATOMY.tracez()["entries"][-1]["phases"]
+        # ... unless the driver says it belongs to the closed entry
+        with ANATOMY.amending():
+            with span("fedml.eval", phase="eval") as ev:
+                pass
+        last = ANATOMY.tracez()["entries"][-1]
+        assert last["phases"]["eval"] == ev.seconds
+        assert abs(sum(last["phases"].values()) - last["wall_s"]) < 1e-9
+        with pytest.raises(ValueError):
+            with span("fedml.z", phase="not_a_phase"):
+                pass
+    finally:
+        anatomy.reset()
+        telemetry.shutdown()
+
+
+def test_capture_holds_nested_round_spans(tmp_path):
+    """Under a profiler session the host plane holds, for every round,
+    ``fedml.round > fedml.dispatch, fedml.fetch, fedml.eval, fedml.log``
+    nested in that order, with ``round`` stats; FedAvgSim's test set
+    lives on the device, so an evaluation re-sends nothing."""
+    sim = _sim(rounds=3)
+    sim.run()  # compile outside the capture
+    _run_captured(sim, tmp_path)
+    spans = _captured_spans(tmp_path)
+    rounds = [s for s in spans if s[0] == "fedml.round"]
+    assert [s[3]["round"] for s in rounds] == [0, 1, 2]
+    for name, lo, hi, stats in rounds:
+        kids = [s for s in spans if s[0] != "fedml.round"
+                and lo <= s[1] and s[2] <= hi]
+        want = ["fedml.dispatch", "fedml.fetch", "fedml.log"]
+        if stats["round"] in (1, 2):  # eval_every=2, and the last round
+            want.insert(2, "fedml.eval")
+        assert [k[0] for k in kids] == want
+        assert all(k[3]["round"] == stats["round"] for k in kids)
+        # children do not overlap
+        assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+    evals = [s for s in spans if s[0] == "fedml.eval"]
+    assert [e[3]["h2d_bytes"] for e in evals] == [0, 0]
+    assert not any(s[0] == "fedml.compile" for s in spans)
+
+
+def test_capture_sharded_eval_counts_h2d_bytes(tmp_path):
+    """ShardedFedAvg keeps its arrays on the host: every evaluation
+    re-sends the whole test set, and the span says how much."""
+    sim = _sim(sharded=True, rounds=2)
+    a = sim.arrays
+    assert isinstance(a.test_x, np.ndarray)
+    _run_captured(sim, tmp_path)
+    spans = _captured_spans(tmp_path)
+    evals = [s for s in spans if s[0] == "fedml.eval"]
+    assert [e[3]["h2d_bytes"] for e in evals] == [
+        a.test_x.nbytes + a.test_y.nbytes]
+    # the first round compiled inside the capture, under its own span
+    (comp,) = [s for s in spans if s[0] == "fedml.compile"]
+    assert comp[3]["family"] == "sharded_round"
+    disp = [s for s in spans if s[0] == "fedml.dispatch"][0]
+    assert disp[1] <= comp[1] and comp[2] <= disp[2]
+
+
+@pytest.mark.parametrize("kind,sharded", [
+    ("lr", False), ("cnn", False), ("cnn", True), ("lr", True),
+], ids=["vmapped", "cohort", "sharded-cohort", "sharded-vmapped"])
+def test_compiled_round_holds_every_scope(kind, sharded):
+    """Both local-update builders and both round bodies carry the
+    scopes into the OPTIMIZED module's metadata."""
+    sim = _sim(kind, sharded=sharded)
+    state = sim.init()
+    if sharded:
+        assert (sim._shard_cohort_update is not None) == (kind == "cnn")
+        compiled = jax.jit(sim._sharded_round).lower(
+            state, sim.banks).compile()
+    else:
+        assert (sim._cohort_update is not None) == (kind == "cnn")
+        compiled = jax.jit(sim._round).lower(state, sim.arrays).compile()
+    text = compiled.as_text()
+    for scope in SCOPES:
+        assert f"/{scope}/" in text, scope
+    assert "fedml.local/" in text
+    # backward ops keep theirs inside the transposed name stack
+    assert any("fedml.local.grad" in ln and "transpose(jvp(" in ln
+               for ln in text.splitlines())
+
+
+def test_scope_map_covers_module_and_frees_executable():
+    memscope.reset()
+    sim = _sim("cnn", rounds=1)
+    state, _ = sim.run_round(sim.init())
+    ((family, key, module),) = memscope.scope_programs()
+    assert (family, module) == ("sim_round", "jit__round")
+    exe = sim._round_fn._exes[key]
+    text = exe.as_text()
+    smap = memscope.scope_map(family, key)
+    assert smap is memscope.scope_map(family, key)  # parsed once
+    # every instruction of the module is in the map, under a scope of
+    # the vocabulary or under None
+    names = {ln.split(" = ")[0].split()[-1].lstrip("%")
+             for ln in text.splitlines()
+             if ln.startswith(" ") and " = " in ln}
+    assert names and names <= set(smap)
+    vocab = set(SCOPES) | {"fedml.local", "fedml.defense_agg"}
+    assert {s for s in smap.values() if s is not None} <= vocab
+    assert set(SCOPES) <= set(smap.values())
+    # a fusion reads the scope of its own metadata, or its body's
+    fusions = [n for n in smap if "fusion" in n and smap[n]]
+    assert fusions
+    assert memscope.scope_map("sim_round", "no-such-key") is None
+    # the map holds text, never the executable: it dies with the sim
+    ref = weakref.ref(exe)
+    del exe, sim, state
+    import gc
+
+    gc.collect()
+    assert ref() is None
+    assert memscope.scope_map(family, key) is smap
+    memscope.reset()
+    assert memscope.scope_programs() == []
+
+
+@pytest.mark.parametrize("which", ["FedAvgSim", "ShardedFedAvg", "fused"])
+def test_results_bit_identical_under_profiler(which, tmp_path):
+    """Spans and scopes only annotate: the trajectory under an active
+    profiler session equals the one without."""
+    def build():
+        if which == "ShardedFedAvg":
+            return _sim(sharded=True, rounds=2)
+        if which == "fused":
+            return _sim(rounds=4, fuse_rounds=2)
+        return _sim(rounds=2)
+
+    plain = build().run()
+    traced = _run_captured(build(), tmp_path)
+    for a, b in zip(jax.tree.leaves(plain.variables),
+                    jax.tree.leaves(traced.variables)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    names = {s[0] for s in _captured_spans(tmp_path)}
+    if which == "fused":
+        assert {"fedml.block", "fedml.dispatch", "fedml.fetch",
+                "fedml.eval"} <= names
+    else:
+        assert {"fedml.round", "fedml.dispatch", "fedml.fetch",
+                "fedml.eval"} <= names
+
+
+def test_annotating_is_not_a_knob():
+    """The switch for spans in a capture is the profiler session: no
+    flag, field or argument selects it."""
+    import inspect
+
+    from fedml_tpu.experiments import deploy, run
+
+    assert "jax_profiler" not in inspect.signature(
+        telemetry.configure).parameters
+    assert "use_jax_profiler" not in inspect.signature(
+        Tracer.__init__).parameters
+    import dataclasses
+
+    gone = "trace" + "_jax"  # spelled apart: a grep for it finds nothing
+    fields = {f.name for f in dataclasses.fields(deploy.DeployConfig)}
+    assert "trace" in fields and gone not in fields
+    assert '"--trace"' in inspect.getsource(run)
+    assert gone not in inspect.getsource(run)
+    assert telemetry.maybe_span is span
