@@ -472,6 +472,12 @@ def build_cohort_local_update(
     AND is where-gated per client (params, optimizer state, and
     batch_stats all carry the leading [C] axis outside the network), so
     padded steps remain strict no-ops, matching the vmapped path.
+
+    Callers: ``FedAvgSim`` (``_locals``) and ``ShardedFedAvg`` (each
+    shard's slice of the cohort), both through
+    ``fedavg.grouped_cohort_call``: ``cohort`` is the width of ONE
+    size-sorted group (``stack_utils.resolve_cohort_groups``), and the
+    call runs once a group, each to its own step count.
     """
     assert max_n % batch_size == 0, (max_n, batch_size)
     steps_per_epoch = max_n // batch_size

@@ -33,7 +33,7 @@ import optax
 from fedml_tpu.config import ExperimentConfig, FedConfig, TrainConfig
 from fedml_tpu.core import adversary as A
 from fedml_tpu.core.anatomy import ANATOMY
-from fedml_tpu.core.tracing import span
+from fedml_tpu.core.tracing import log_span, span
 from fedml_tpu.core import bulk as BK
 from fedml_tpu.core import compress as C
 from fedml_tpu.core import elastic as E
@@ -371,10 +371,15 @@ from fedml_tpu.algorithms.stack_utils import (  # noqa: E402
 )
 
 
-def _grouped_cohort_call(
-    cohort_update, groups: int, variables, idx_rows, mask_rows, x, y, ckeys
+def grouped_cohort_call(
+    cohort_update, groups: int, variables, idx_rows, mask_rows, x, y, ckeys,
+    traced_once: bool = False,
 ):
-    """Run the fused cohort update in ``groups`` size-sorted sub-groups.
+    """Run the fused cohort update in ``groups`` size-sorted sub-groups
+    (``FedAvgSim._locals`` over the sampled cohort;
+    ``ShardedFedAvg._sharded_round`` over a shard's slice of it, with
+    the groups under ``lax.map``: ``traced_once``, see
+    ``stack_utils.size_grouped_lanes``).
 
     Clients are sorted by sample count (descending) so each sub-group's
     dynamic trip count is set by ITS largest member, not the cohort's;
@@ -391,6 +396,7 @@ def _grouped_cohort_call(
     return _size_grouped_lanes(
         lambda i, m, k: cohort_update(variables, i, m, x, y, k),
         (idx_rows, mask_rows, ckeys), mask_rows, groups,
+        traced_once=traced_once,
     )
 
 
@@ -762,7 +768,7 @@ class FedAvgSim:
             idx_rows = arrays.idx[cohort]
             mask_rows = arrays.mask[cohort]
             if self._cohort_update is not None:
-                stacked_vars, n_k, msums = _grouped_cohort_call(
+                stacked_vars, n_k, msums = grouped_cohort_call(
                     self._cohort_update,
                     self._cohort_groups,
                     state.variables,
@@ -1899,7 +1905,7 @@ class FedAvgSim:
                              "test_loss": test_m["loss"]}
                         )
                     if metrics_sink is not None:
-                        with span("fedml.log"):
+                        with log_span(record):
                             metrics_sink.log(record)
                     ANATOMY.end_round()
         finally:
@@ -1937,7 +1943,7 @@ class FedAvgSim:
 
         def log(rec):
             if metrics_sink is not None:
-                with span("fedml.log", round=rec["round"]):
+                with log_span(rec):
                     metrics_sink.log(rec)
 
         def boundary_hook(r_last, last):

@@ -75,8 +75,13 @@ def resolve_cohort_groups(
 
 
 def size_grouped_lanes(vcall, lane_args: tuple, mask_rows, requested: int,
-                       auto_group_size: int = 2):
-    """Run a vmapped per-client update in size-sorted sub-groups.
+                       auto_group_size: int = 2, traced_once: bool = False):
+    """Run a per-client update in size-sorted sub-groups.
+
+    Callers: the fused cohort update of both runtimes
+    (``fedavg.grouped_cohort_call`` — ``FedAvgSim._locals``, and
+    ``ShardedFedAvg``'s shard body with ``traced_once``) and the vmapped
+    GAN rounds (``sgan``, ``gan_family``).
 
     ``requested`` is the raw ``TrainConfig.cohort_groups`` value; the
     actual group count is resolved HERE against the true lane count
@@ -85,10 +90,20 @@ def size_grouped_lanes(vcall, lane_args: tuple, mask_rows, requested: int,
     the data's natural client count cannot drop or duplicate lanes.
 
     Sorting clients by n_k means each sub-group's step-loop cost is set
-    by ITS largest member, not the cohort's (for vmapped updates with a
-    per-lane dynamic trip count, vmap's batched while runs each call to
-    the max over its lanes). Scheduling only: each lane's trajectory
-    depends on (globals, its rows, its key) alone.
+    by ITS largest member, not the cohort's (the fused cohort update and
+    vmap's batched while both run a call to the max over its lanes).
+    Scheduling only: each lane's trajectory depends on (globals, its
+    rows, its key) alone.
+
+    ``traced_once``: the sorted lanes are reshaped to ``[groups,
+    lanes / groups, ...]`` and the groups run under ``lax.map``, so
+    ``vcall`` is traced and lowered once however many groups there are;
+    each group's inner loop keeps its own dynamic trip count. Otherwise
+    a Python loop traces ``vcall`` once a group (ResNet-56, two groups:
+    27 s of tracing against 16 s) and the round runs 1 % shorter (367
+    against 370 ms, the same mix; PERF.md section 6, PR 25) — the
+    single-device callers keep it until a benchmark cell weighs their
+    set-up against their rounds.
 
     ``lane_args`` are pytrees with leading lane axis; every output of
     ``vcall`` must be lane-stacked. Results come back in input order.
@@ -100,6 +115,16 @@ def size_grouped_lanes(vcall, lane_args: tuple, mask_rows, requested: int,
     assert c % groups == 0, (c, groups)
     sub = c // groups
     order = jnp.argsort(-jnp.sum(mask_rows, axis=1))
+    if traced_once:
+        grouped = jax.tree.map(
+            lambda a: a[order].reshape((groups, sub) + a.shape[1:]),
+            lane_args,
+        )
+        outs = jax.lax.map(lambda args: vcall(*args), grouped)
+        inv = jnp.argsort(order)
+        return jax.tree.map(
+            lambda a: a.reshape((c,) + a.shape[2:])[inv], outs
+        )
     inv = jnp.argsort(order)
     sorted_args = jax.tree.map(lambda a: a[order], lane_args)
     outs = []
@@ -109,3 +134,18 @@ def size_grouped_lanes(vcall, lane_args: tuple, mask_rows, requested: int,
         )))
     cat = jax.tree.map(lambda *ls: jnp.concatenate(ls, 0), *outs)
     return jax.tree.map(lambda a: a[inv], cat)
+
+
+def lockstep_slot_steps(mask_rows, groups: int, batch_size: int,
+                        epochs: int = 1):
+    """Slot-steps a lockstep cohort update executes on ``mask_rows``
+    (``[lanes, max_n]``) run as ``groups`` size-sorted groups: every
+    group steps to the count of its largest member,
+    ``ceil(max n_k / batch)`` an epoch, at its full width — the trip
+    counts ``build_cohort_local_update`` takes on the groups
+    :func:`size_grouped_lanes` forms. Over the live client steps
+    (``sum ceil(n_k / batch)``) it is the schedule's occupancy."""
+    width = mask_rows.shape[0] // groups
+    by_size = jnp.sort(jnp.sum(mask_rows, axis=1))[::-1]
+    largest = by_size.reshape(groups, width)[:, 0]
+    return epochs * width * jnp.sum(jnp.ceil(largest / batch_size))

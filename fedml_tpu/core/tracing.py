@@ -129,6 +129,17 @@ class span:
         return False
 
 
+def log_span(record: dict) -> span:
+    """The ``fedml.log`` span of one round record (every round loop's
+    ``sink.log`` site). A counter the round program reported rides it
+    as an attr, so a profiler capture holds it beside the device's
+    timeline: ``slot_steps`` (the sharded cohort round)."""
+    attrs = {"round": record["round"]}
+    if "slot_steps" in record:
+        attrs["slot_steps"] = int(record["slot_steps"])
+    return span("fedml.log", **attrs)
+
+
 class Tracer:
     """Span collector with the reference's tick/tock vocabulary.
 

@@ -16,6 +16,7 @@ from typing import Any, Callable
 
 from fedml_tpu.config import ExperimentConfig
 from fedml_tpu.core import telemetry
+from fedml_tpu.core.tracing import log_span
 from fedml_tpu.data.loaders import load_dataset
 from fedml_tpu.metrics.sink import MetricsSink
 from fedml_tpu.models import create_model
@@ -499,7 +500,7 @@ class Experiment:
                     r == cfg.fed.num_rounds - 1
                 ):
                     record.update(Experiment._eval_record(sim, state))
-                with telemetry.maybe_span("fedml.log"):
+                with log_span(record):
                     sink.log(record)
                 if ckpt is not None and (
                     (r + 1) % cfg.checkpoint_every == 0
@@ -599,7 +600,7 @@ class Experiment:
             return records
 
         def log(rec):
-            with telemetry.maybe_span("fedml.log", round=rec["round"]):
+            with log_span(rec):
                 sink.log(rec)
 
         def boundary_hook(r_last, last):

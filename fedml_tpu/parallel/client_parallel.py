@@ -55,9 +55,14 @@ from fedml_tpu.algorithms.fedavg import (
     FedAvgSim,
     ServerState,
     fold_block_partials,
+    grouped_cohort_call,
     psum_reducer,
     server_update,
     server_update_from_partials,
+)
+from fedml_tpu.algorithms.stack_utils import (
+    lockstep_slot_steps,
+    resolve_cohort_groups,
 )
 from fedml_tpu.models.base import FedModel
 
@@ -168,7 +173,14 @@ class ShardedFedAvg(FedAvgSim):
                 partition=self._peft.part if self._peft else None,
             )
         # per-shard cohort-grouped update (data axis 1 only: the cohort
-        # network has no per-batch psum seam for intra-client DDP)
+        # network has no per-batch psum seam for intra-client DDP). A
+        # shard's cohort runs in size-sorted groups by the rule FedAvgSim
+        # uses (stack_utils.resolve_cohort_groups: groups of 5 unless
+        # train.cohort_groups says otherwise), so the network is built
+        # at the GROUP's width
+        self._shard_groups = resolve_cohort_groups(
+            cfg.train.cohort_groups, self.cohort_per_shard
+        )
         self._shard_cohort_update = (
             build_cohort_local_update(
                 model,
@@ -176,7 +188,7 @@ class ShardedFedAvg(FedAvgSim):
                 cfg.train,
                 self.batch_size,
                 self.arrays.max_client_samples,
-                self.cohort_per_shard,
+                self.cohort_per_shard // self._shard_groups,
             )
             if self.n_data_shards == 1
             and cfg.train.cohort_fused
@@ -348,23 +360,30 @@ class ShardedFedAvg(FedAvgSim):
                 ckeys = jax.vmap(
                     lambda c: R.client_key(rkey, shard * K + c)
                 )(local)
+            slot_steps = None
             with jax.named_scope("fedml.local"):
+                idx_rows, mask_rows = idx[local], mask[local]
                 if self._shard_cohort_update is not None:
                     # cohort-grouped fast path per shard: this shard's
-                    # slice of the cohort runs as ONE widened network
-                    # (see fedml_tpu.models.cohort) — purely intra-shard
-                    # compute, so it composes with the client-axis psum
-                    # unchanged
-                    stacked_vars, n_k, msums = self._shard_cohort_update(
-                        state.variables, idx[local], mask[local], x, y,
-                        ckeys,
+                    # slice of the cohort runs as widened networks (see
+                    # fedml_tpu.models.cohort), one size-sorted group at
+                    # a time, each to its own step count, the network
+                    # traced once — purely intra-shard compute, so it
+                    # composes with the client-axis psum unchanged
+                    stacked_vars, n_k, msums = grouped_cohort_call(
+                        self._shard_cohort_update, self._shard_groups,
+                        state.variables, idx_rows, mask_rows, x, y, ckeys,
+                        traced_once=True,
+                    )
+                    slot_steps = lockstep_slot_steps(
+                        mask_rows, self._shard_groups, self.batch_size,
+                        self.cfg.train.epochs,
                     )
                 else:
                     stacked_vars, n_k, msums = jax.vmap(
                         self.local_update,
                         in_axes=(None, 0, 0, None, None, 0),
-                    )(state.variables, idx[local], mask[local], x, y,
-                      ckeys)
+                    )(state.variables, idx_rows, mask_rows, x, y, ckeys)
 
             # PEFT view: the psum'd aggregation below only ever sees
             # the O(adapter) pruned subtree — the frozen base is a
@@ -404,7 +423,16 @@ class ShardedFedAvg(FedAvgSim):
                     msums,
                 )
                 fin = finalize_sums(reduced)
-            metrics = {"train_loss": fin["loss"], "train_acc": fin["acc"]}
+                metrics = {
+                    "train_loss": fin["loss"], "train_acc": fin["acc"],
+                }
+                if slot_steps is not None:
+                    # what the lockstep schedule executed this round,
+                    # over all shards; the live client steps over it is
+                    # the schedule's occupancy (docs/OBSERVABILITY.md)
+                    metrics["slot_steps"] = jax.lax.psum(
+                        slot_steps, self.client_axis
+                    )
             return new_state, metrics
 
         in_specs = (rep, cspec, cspec, cspec, cspec)

@@ -234,3 +234,152 @@ def test_sharded_cohort_one_client_per_shard():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5
         )
+
+
+# -- size-sorted groups inside a shard (TrainConfig.cohort_groups) ---------
+
+
+def _grouped_job(cohort_groups):
+    """2 shards x 8 sampled of 16 clients a shard, heterogeneous sizes:
+    wide enough for the grouping rule to split a shard's cohort."""
+    cfg = cfg_for(
+        MeshConfig(client_axis_size=2, data_axis_size=1),
+        model=ModelConfig(
+            name="cnn_fedavg", num_classes=10, input_shape=(16, 16, 3)
+        ),
+        data=DataConfig(
+            dataset="fake_cifar10", num_clients=32, batch_size=16, seed=5,
+            partition_method="hetero", partition_alpha=0.5, dataset_r=0.2,
+        ),
+        train=TrainConfig(lr=0.1, epochs=1, cohort_groups=cohort_groups),
+        fed=FedConfig(num_rounds=1, clients_per_round=16, eval_every=1),
+    )
+    data = load_dataset(cfg.data)
+    data.x_train = data.x_train[:, ::2, ::2, :]
+    data.x_test = data.x_test[:, ::2, ::2, :]
+    return cfg, data, create_model(cfg.model)
+
+
+def _sharded(cohort_groups):
+    cfg, data, model = _grouped_job(cohort_groups)
+    sim = ShardedFedAvg(
+        model, data, cfg, make_mesh(client_axis=2, data_axis=1)
+    )
+    assert sim._shard_cohort_update is not None
+    return sim
+
+
+@pytest.fixture(scope="module")
+def lockstep_round():
+    """The same job with every shard in ONE lockstep group, on the mesh
+    and on its single-device stratified mirror."""
+    sim = _sharded(1)
+    assert sim._shard_groups == 1
+    s_mesh, m_mesh = sim.run_round(sim.init())
+    cfg, data, model = _grouped_job(1)
+    single = FedAvgSim(model, data, cfg, sampler=stratified(2))
+    s_single, _ = single.run_round(single.init())
+    return jax.device_get((s_mesh.variables, s_single.variables, m_mesh))
+
+
+def _shard_steps(sim, groups):
+    """Host mirror of round 0: per shard, the step counts of its sampled
+    clients, largest first, as ``[groups, width]``."""
+    key = jax.random.fold_in(R.round_key(sim.root_key, 0), 0)
+    cohort = np.asarray(R.sample_clients_stratified(
+        key, sim.cfg.data.num_clients, sim.cfg.fed.clients_per_round,
+        sim.n_client_shards,
+    ))
+    sizes = np.asarray(sim.arrays.mask).sum(axis=1)[cohort]
+    steps = np.ceil(sizes / sim.batch_size).astype(int)
+    return [
+        np.sort(s)[::-1].reshape(groups, -1)
+        for s in steps.reshape(sim.n_client_shards, -1)
+    ]
+
+
+@pytest.mark.parametrize("cohort_groups,groups", [(0, 2), (1, 1), (2, 2),
+                                                  (4, 4)])
+def test_sharded_cohort_groups_are_scheduling_only(
+    lockstep_round, cohort_groups, groups
+):
+    """A shard's cohort trained in size-sorted groups (the rule
+    FedAvgSim uses, honoured on the mesh) equals the lockstep shard and
+    the single-device mirror: the same clients take the same steps, only
+    padded no-op steps are skipped. ``slot_steps`` counts what the
+    schedule executed: per group, its largest member's steps x its
+    width, over all shards."""
+    v_lockstep, v_single, _ = lockstep_round
+    sim = _sharded(cohort_groups)
+    assert sim._shard_groups == groups
+    per_shard = _shard_steps(sim, groups)
+    if groups == 2:
+        # the fixture must exercise the mechanism: groups of one shard
+        # that stop at different step counts
+        assert any(g[0, 0] != g[1, 0] for g in per_shard), per_shard
+    state, m = sim.run_round(sim.init())
+    for ref, atol, rtol in ((v_lockstep, 2e-6, 2e-5),
+                            (v_single, 5e-4, 1e-3)):
+        for a, b in zip(jax.tree.leaves(ref),
+                        jax.tree.leaves(state.variables)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), atol=atol, rtol=rtol
+            )
+    want = sum(int(g[:, 0].sum()) * g.shape[1] for g in per_shard)
+    assert float(m["slot_steps"]) == want
+    live = sum(int(g.sum()) for g in per_shard)
+    assert live <= want
+    if groups > 1:
+        assert want < float(lockstep_round[2]["slot_steps"])
+
+
+def _lower_round(sim):
+    return jax.jit(lambda s, b: sim._sharded_round(s, b)).lower(
+        sim.init(), sim.banks
+    )
+
+
+def test_sharded_grouped_round_traces_cohort_body_once(monkeypatch):
+    """The groups run under ``lax.map``: however many groups a shard's
+    cohort splits into, the cohort network is traced as often as for one
+    lockstep group (a Python loop over groups traced it once a group)."""
+    from fedml_tpu.models.base import FedModel
+
+    calls = []
+    inner = FedModel.apply_cohort_train
+
+    def counting(self, *a, **k):
+        calls.append(1)
+        return inner(self, *a, **k)
+
+    monkeypatch.setattr(FedModel, "apply_cohort_train", counting)
+    counts = {}
+    for cohort_groups in (1, 4):
+        del calls[:]
+        _lower_round(_sharded(cohort_groups))
+        counts[cohort_groups] = len(calls)
+    assert counts[1] >= 1
+    assert counts[4] == counts[1], counts
+
+
+def test_sharded_single_group_round_is_the_ungrouped_program(monkeypatch):
+    """With one group a shard (``cohort_groups=1``, or a shard cohort the
+    rule does not split) the round lowers to the program built without
+    the grouping branch: the cohort update called straight."""
+    from fedml_tpu.parallel import client_parallel as CP
+
+    grouped = _lower_round(_sharded(1)).as_text()
+    monkeypatch.setattr(
+        CP, "grouped_cohort_call",
+        lambda update, groups, *operands, **_: update(*operands),
+    )
+    assert _lower_round(_sharded(1)).as_text() == grouped
+    # a shard cohort under 8 is never split by the automatic rule
+    cfg, data, model = _grouped_job(0)
+    cfg = cfg_for(cfg.mesh, model=cfg.model, data=cfg.data,
+                  train=cfg.train,
+                  fed=FedConfig(num_rounds=1, clients_per_round=12,
+                                eval_every=1))
+    narrow = ShardedFedAvg(model, data, cfg,
+                           make_mesh(client_axis=2, data_axis=1))
+    assert narrow.cohort_per_shard == 6 and narrow._shard_groups == 1

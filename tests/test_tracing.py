@@ -133,12 +133,12 @@ def _captured_spans(trace_dir):
     return sorted(out, key=lambda ev: ev[1])
 
 
-def _run_captured(sim, trace_dir):
+def _run_captured(sim, trace_dir, sink=None):
     from fedml_tpu.metrics import MetricsSink
 
     jax.profiler.start_trace(str(trace_dir))
     try:
-        return sim.run(metrics_sink=MetricsSink())
+        return sim.run(metrics_sink=sink or MetricsSink())
     finally:
         jax.profiler.stop_trace()
 
@@ -268,6 +268,28 @@ def test_capture_sharded_eval_counts_h2d_bytes(tmp_path):
     assert comp[3]["family"] == "sharded_round"
     disp = [s for s in spans if s[0] == "fedml.dispatch"][0]
     assert disp[1] <= comp[1] and comp[2] <= disp[2]
+
+
+@pytest.mark.parametrize("kind", ["cnn", "lr"],
+                         ids=["sharded-cohort", "sharded-vmapped"])
+def test_capture_log_span_carries_slot_steps(kind, tmp_path):
+    """The sharded cohort round counts the slot-steps its lockstep
+    schedule executed; the count reaches the round record and rides the
+    round's ``fedml.log`` span. The vmapped round reports none."""
+    from fedml_tpu.metrics import MetricsSink
+
+    sim = _sim(kind, sharded=True, rounds=2)
+    sink = MetricsSink()
+    _run_captured(sim, tmp_path, sink)
+    logs = [s for s in _captured_spans(tmp_path) if s[0] == "fedml.log"]
+    assert [s[3]["round"] for s in logs] == [0, 1]
+    if kind == "lr":
+        assert not any("slot_steps" in r for r in sink.history)
+        assert not any("slot_steps" in s[3] for s in logs)
+        return
+    counts = [r["slot_steps"] for r in sink.history]
+    assert all(c >= sim.cfg.fed.clients_per_round for c in counts)
+    assert [s[3]["slot_steps"] for s in logs] == counts
 
 
 @pytest.mark.parametrize("kind,sharded", [
