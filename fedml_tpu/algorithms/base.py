@@ -16,6 +16,7 @@ small client's trajectory exactly matches serial training on its real data.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable
 
 import jax
@@ -292,11 +293,15 @@ def build_local_update(
             x_b = _to_compute(x_b)
         else:
             variables = {**static_vars, "params": params}
-        logits, new_vars = model.apply_train(variables, x_b, rng)
+        logits, new_vars, counted = model.apply_train_counted(
+            variables, x_b, rng
+        )
         if mixed:
             logits = logits.astype(jnp.float32)
             new_vars = _to_f32(new_vars)
-        sums = task.metric_sums(logits, y_b, w_b)
+        # what the model counted this step (nothing, for most models)
+        # rides the metric sums
+        sums = {**task.metric_sums(logits, y_b, w_b), **counted}
         w_total = sums["w_sum"]
         if data_axis is not None:
             w_total = jax.lax.psum(w_total, data_axis)
@@ -393,6 +398,13 @@ def build_local_update(
                     new_variables = {**new_vars, "params": new_params}
                     out_vars = sel(new_variables, variables)
                     out_opt = sel(new_opt_state, opt_state)
+                if model.counters:
+                    # a fully-padded step counts nothing
+                    sums = {
+                        k: jnp.where(valid, v, 0.0)
+                        if k in model.counters else v
+                        for k, v in sums.items()
+                    }
                 msums = {k: msums[k] + sums[k] for k in msums}
                 return (out_vars, out_opt, msums), None
 
@@ -405,7 +417,9 @@ def build_local_update(
             return (variables, opt_state, msums), None
 
         opt_state = opt.init(start_params)
-        msums0 = zero_sums()
+        msums0 = {
+            **zero_sums(), **{c: jnp.asarray(0.0) for c in model.counters}
+        }
         ekeys = jax.vmap(lambda e: jax.random.fold_in(rng, e))(
             jnp.arange(cfg.epochs)
         )
@@ -631,23 +645,33 @@ def build_cohort_local_update(
 # ---------------------------------------------------------------------------
 
 
+#: the most float32 logits one evaluation batch may hold
+EVAL_LOGIT_BYTES = 256 * 2 ** 20
+
+
 def build_evaluator(model: FedModel, task: Task, eval_batch: int = 256):
-    """Jitted global-test evaluation: pad to a multiple of ``eval_batch``,
-    scan batches, reduce metric sums (reference
-    ``_local_test_on_all_clients`` / ``test_on_server_for_all_clients``,
+    """Jitted global-test evaluation: pad to a multiple of the batch —
+    ``eval_batch`` samples, or as many fewer as keep a batch's float32
+    logits under :data:`EVAL_LOGIT_BYTES` (a language model's are
+    ``T x vocab`` a sample: 103 MB at 2,048 x 12,544) — scan batches,
+    reduce metric sums (reference ``_local_test_on_all_clients`` /
+    ``test_on_server_for_all_clients``,
     ``FedAVGAggregator.py:110-164``)."""
 
     def evaluate(variables, x, y):
+        one = jax.eval_shape(model.apply_eval, variables, x[:1])
+        batch = max(1, min(
+            eval_batch, EVAL_LOGIT_BYTES // (4 * math.prod(one.shape))))
         n = x.shape[0]
-        pad = (-n) % eval_batch
+        pad = (-n) % batch
         xp = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
         yp = jnp.concatenate([y, jnp.zeros((pad,) + y.shape[1:], y.dtype)])
         w = jnp.concatenate([jnp.ones((n,)), jnp.zeros((pad,))])
-        nb = (n + pad) // eval_batch
+        nb = (n + pad) // batch
 
         def body(sums, i):
             sl = lambda a: jax.lax.dynamic_slice_in_dim(
-                a, i * eval_batch, eval_batch
+                a, i * batch, batch
             )
             logits = model.apply_eval(variables, sl(xp))
             s = task.metric_sums(logits, sl(yp), sl(w))

@@ -84,6 +84,21 @@ def consume_round_counters(train_metrics: dict) -> dict:
     return train_metrics
 
 
+def round_counters(model: FedModel, msums: dict) -> dict:
+    """What the model counted in one round (``FedModel.counters``, summed
+    by the local update over a client's steps) as round metrics: every
+    counter's total over the cohort and, for ``client_counters``, the
+    sampled clients' own values as ``<name>_by_client``. ``msums``
+    leaves are ``[C]``; a ``<name>_by_client`` leaf already there (the
+    bulk round scatters it block by block) is passed on."""
+    out = {name: jnp.sum(msums[name]) for name in model.counters}
+    for name in model.client_counters:
+        out[name + "_by_client"] = msums.get(
+            name + "_by_client", msums[name]
+        )
+    return out
+
+
 class ServerState(NamedTuple):
     variables: Pytree  # full model variables (params [+ batch_stats])
     opt_state: Any  # server optimizer state
@@ -647,7 +662,12 @@ class FedAvgSim:
         return ServerState(
             variables=variables,
             opt_state=opt.init(opt_params),
-            momentum=T.tree_zeros_like(opt_params),
+            # the FedNova buffer exists only where gmf asks for one: at
+            # gmf 0 it would be a model-sized tree nothing reads
+            momentum=(
+                T.tree_zeros_like(opt_params) if self.cfg.fed.gmf > 0
+                else ()
+            ),
             round=jnp.asarray(0, jnp.int32),
         )
 
@@ -942,11 +962,23 @@ class FedAvgSim:
             with jax.named_scope("fedml.local"):
                 idx_rows = arrays.idx[block_ids]
                 mask_rows = arrays.mask[block_ids]
-                stacked_vars, n_k, msums = jax.vmap(
-                    self.local_update,
-                    in_axes=(None, 0, 0, None, None, 0),
-                )(state.variables, idx_rows, mask_rows, arrays.x,
-                  arrays.y, ckeys)
+                if self._block_size == 1:
+                    # a block of ONE client is that client's update, not
+                    # a map of width 1 over it: kernels keep their own
+                    # lowering and nothing is batched
+                    stacked_vars, n_k, msums = jax.tree.map(
+                        lambda a: a[None],
+                        self.local_update(
+                            state.variables, idx_rows[0], mask_rows[0],
+                            arrays.x, arrays.y, ckeys[0],
+                        ),
+                    )
+                else:
+                    stacked_vars, n_k, msums = jax.vmap(
+                        self.local_update,
+                        in_axes=(None, 0, 0, None, None, 0),
+                    )(state.variables, idx_rows, mask_rows, arrays.x,
+                      arrays.y, ckeys)
             with jax.named_scope("fedml.defense_agg"):
                 return heal_block(
                     block_ids, block_live, bank, write_bank,
@@ -1007,16 +1039,28 @@ class FedAvgSim:
                     self.batch_size, view, sv, n_k, msums, rejected,
                 )
 
+        by_client = self.model.client_counters
         if self._stream_defense is None:
             if ef_bank is None:
-                def fold_block(block_ids, block_live):
+                def fold_block(block_ids, block_live, block_pos=None):
                     sv, n_k, msums, rej, _ = local_block(
                         block_ids, block_live, None
                     )
-                    return partials_of(sv, n_k, msums, rej)
+                    p = partials_of(sv, n_k, msums, rej)
+                    if block_pos is None:
+                        return p
+                    # a sampled client's own count, at its slot: the
+                    # blocks' vectors add up to the cohort's
+                    return p._replace(msums={**p.msums, **{
+                        name + "_by_client": jnp.zeros(
+                            ids.shape, jnp.float32
+                        ).at[block_pos].set(msums[name])
+                        for name in by_client
+                    }})
 
                 partials = BK.stream_blocks(
-                    fold_block, ids, live, self._block_size
+                    fold_block, ids, live, self._block_size,
+                    positions=bool(by_client),
                 )
                 new_ef = None
             else:
@@ -1049,6 +1093,10 @@ class FedAvgSim:
             "train_acc": fin["acc"],
             "nonfinite_rejected": partials.rejected,
         }
+        if self.model.counters:
+            train_metrics.update(
+                round_counters(self.model, partials.msums)
+            )
         if new_ef is not None:
             return new_state, train_metrics, new_ef
         return new_state, train_metrics
@@ -1448,6 +1496,8 @@ class FedAvgSim:
             # robust.nonfinite_rejected counter)
             "nonfinite_rejected": rejected,
         }
+        if self.model.counters:
+            train_metrics.update(round_counters(self.model, msums))
         if new_residual is not None:
             train_metrics["compress_residual_norm"] = T.tree_l2_norm(
                 new_residual
@@ -1890,7 +1940,9 @@ class FedAvgSim:
                         )
                     record = {
                         "round": r,
-                        **{k: float(v) for k, v in train_m.items()},
+                        **{k: float(v) if np.ndim(v) == 0
+                           else [float(u) for u in v]
+                           for k, v in train_m.items()},
                     }
                     if profiler is not None:
                         profiler.end_round(r)

@@ -211,6 +211,18 @@ _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _CALLS_RE = re.compile(r"\bfusion\(.*calls=%?([\w.\-]+)")
 
 
+# The TPU compiler rewrites ``jax.lax.ragged_dot`` into Mosaic calls
+# whose ``op_name`` ends in the rewrite's own name (``ragged-dot-none``,
+# ``ragged-dot-metadata``) after a PREFIX of the name stack that stops
+# short of the model's scopes (``.../fedml.local/closed_call/
+# ragged-dot-none`` on the chip's round). The program has one caller
+# of it, ``ops/moe.py:moe_layer``, under this scope.
+_RENAMED = {
+    "ragged-dot-none": "fedml.model.moe.experts",
+    "ragged-dot-metadata": "fedml.model.moe.experts",
+}
+
+
 def keep_program_hlo(family: str, key, compiled) -> float:
     """Keep one compiled program's optimized HLO text for
     :func:`scope_map`. Returns the seconds it took (also kept as
@@ -234,33 +246,44 @@ def keep_program_hlo(family: str, key, compiled) -> float:
     return keep_s
 
 
+def _scope_of(op_name: str) -> str | None:
+    renamed = _RENAMED.get(op_name.rsplit("/", 1)[-1])
+    if renamed is not None:
+        return renamed
+    found = _SCOPE_RE.findall(op_name)
+    return found[-1] if found else None
+
+
 def parse_scopes(text: str) -> dict[str, str | None]:
     """``{instruction: scope}`` of one optimized HLO module's text: the
     innermost ``fedml.*`` scope in the instruction's ``op_name`` (the
     last on the name stack — backward ops keep theirs inside
     ``transpose(jvp(...))``), else None. A fusion whose own metadata
     names no scope takes the scope most of its fused instructions
-    share. Instruction names are unique within a module, fused
+    share. An instruction may run over several lines (a Mosaic call's
+    kernel metadata holds newlines): the ``op_name`` that follows it
+    is its own. Instruction names are unique within a module, fused
     computations' included."""
     own: dict[str, str | None] = {}
     calls: dict[str, str] = {}
     inside: dict[str, list[str]] = {}
     comp = None
+    unnamed = None  # the last instruction whose line held no op_name
     for line in text.splitlines():
-        if not line.startswith(" "):
-            m = _COMP_RE.match(line)
-            comp = m.group(1) if m else None
-            continue
         m = _INSTR_RE.match(line)
         if m is None:
+            op = _OPNAME_RE.search(line)
+            if op is not None and unnamed is not None:
+                own[unnamed] = _scope_of(op.group(1))
+                unnamed = None
+            elif not line.startswith(" "):
+                m = _COMP_RE.match(line)
+                comp = m.group(1) if m else None
             continue
         name = m.group(1)
-        scope = None
         op = _OPNAME_RE.search(line)
-        if op is not None:
-            found = _SCOPE_RE.findall(op.group(1))
-            if found:
-                scope = found[-1]
+        scope = _scope_of(op.group(1)) if op is not None else None
+        unnamed = name if op is None else None
         own[name] = scope
         if scope is not None and comp is not None:
             inside.setdefault(comp, []).append(scope)

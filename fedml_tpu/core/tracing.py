@@ -129,14 +129,27 @@ class span:
         return False
 
 
+#: round-record keys that are counts of the model's own
+#: (``FedModel.counters``), carried on ``fedml.log`` as ``slot_steps`` is
+COUNTER_PREFIXES = ("moe_rows_",)
+
+
 def log_span(record: dict) -> span:
     """The ``fedml.log`` span of one round record (every round loop's
     ``sink.log`` site). A counter the round program reported rides it
     as an attr, so a profiler capture holds it beside the device's
-    timeline: ``slot_steps`` (the sharded cohort round)."""
+    timeline: ``slot_steps`` (the sharded cohort round) and the model's
+    own counters (``FedModel.counters``: the decoder's ``moe_rows_*``;
+    a per-client list rides as its text, ``[a, b]``)."""
     attrs = {"round": record["round"]}
-    if "slot_steps" in record:
-        attrs["slot_steps"] = int(record["slot_steps"])
+    for name, value in record.items():
+        if name == "slot_steps" or name.startswith(COUNTER_PREFIXES):
+            # a list rides as text that begins with no digit: the
+            # profiler reads "31798,32360" back as the number 31798
+            attrs[name] = (
+                str([int(v) for v in value])
+                if isinstance(value, (list, tuple)) else int(value)
+            )
     return span("fedml.log", **attrs)
 
 

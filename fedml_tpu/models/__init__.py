@@ -169,6 +169,18 @@ def create_model(cfg: ModelConfig) -> FedModel:
             cfg.input_shape,
             input_dtype=jnp.int32,
         )
+    if name == "decoder":
+        from fedml_tpu.models.decoder import decoder_from_extra
+        from fedml_tpu.ops.moe import MOE_COUNTERS
+
+        module = decoder_from_extra(extra, nc)
+        sparse = "sparse" in dict(module.cfg)["mlp_layer_types"]
+        return FedModel(
+            module, cfg.input_shape, input_dtype=jnp.int32,
+            counters=MOE_COUNTERS if sparse else (),
+            client_counters=MOE_COUNTERS[:1] if sparse else (),
+            jit_init=True,
+        )
     if name in ("deeplab", "deeplab_lite"):  # fedseg (FedSegAPI.py:19)
         from fedml_tpu.models.segmentation import DeepLabLite
 

@@ -34,10 +34,27 @@ class FedModel:
     has_dropout: bool = False
     # inputs may be int tokens (NLP) rather than floats
     input_dtype: Any = jnp.float32
+    # scalars the module sows into its ``counters`` collection in train
+    # mode (the decoder's expert-load counts): the local update sums
+    # them over a client's steps beside the metric sums, and the round
+    # reports the cohort's totals — and, for ``client_counters``, the
+    # value of every sampled client (``<name>_by_client``)
+    counters: tuple[str, ...] = ()
+    client_counters: tuple[str, ...] = ()
+    # make the weights in ONE compiled program (the forward pass an
+    # eager init would run op by op is dead code there): for models of
+    # gigabytes
+    jit_init: bool = False
 
     def init(self, rng: jax.Array) -> Variables:
         dummy = jnp.zeros((1,) + tuple(self.input_shape), self.input_dtype)
-        return self.module.init({"params": rng}, dummy, train=False)
+        init = lambda r: self.module.init({"params": r}, dummy, train=False)
+        variables = (jax.jit(init) if self.jit_init else init)(rng)
+        if self.counters:  # init makes every collection; this one is no state
+            variables = {
+                k: v for k, v in variables.items() if k != "counters"
+            }
+        return variables
 
     def apply_train(
         self, variables: Variables, x: jax.Array, rng: jax.Array
@@ -51,6 +68,27 @@ class FedModel:
             return logits, {**variables, **mutated}
         logits = self.module.apply(variables, x, train=True, rngs=rngs)
         return logits, variables
+
+    def apply_train_counted(
+        self, variables: Variables, x: jax.Array, rng: jax.Array
+    ) -> tuple[jax.Array, Variables, dict]:
+        """:meth:`apply_train` plus what the module counted in this
+        call: ``{name: scalar}`` for every name of ``counters``."""
+        if not self.counters:
+            logits, new_vars = self.apply_train(variables, x, rng)
+            return logits, new_vars, {}
+        rngs = {"dropout": rng} if self.has_dropout else None
+        mutable = ["counters"] + (
+            ["batch_stats"] if self.has_batch_stats else []
+        )
+        logits, mutated = self.module.apply(
+            variables, x, train=True, rngs=rngs, mutable=mutable
+        )
+        counted = mutated.pop("counters")
+        return (
+            logits, {**variables, **mutated},
+            {name: counted[name] for name in self.counters},
+        )
 
     def apply_eval(self, variables: Variables, x: jax.Array) -> jax.Array:
         return self.module.apply(variables, x, train=False)

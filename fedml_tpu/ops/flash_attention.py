@@ -10,14 +10,19 @@ The kernel is compiled for the TPU; off the TPU a caller asks for the
 pallas interpreter itself (``interpret=True``, as the CPU tests do) or
 uses :func:`fedml_tpu.ops.ring_attention.full_attention`.
 
-Measured honestly on v5e (B=4, H=8, D=64, bf16, causal): XLA's fused
-attention (``full_attention``) is 6-11x FASTER than this kernel at
-T=2048-8192 — the XLA TPU attention fusion is excellent and this
-hand-tiled kernel does not beat it. ``TransformerLM`` therefore defaults
-to ``full_attention``; use this kernel when the [T, T] score matrix must
-never materialize in HBM at sequence lengths where XLA's fusion would
-spill (or shard the sequence with
-:func:`fedml_tpu.ops.ring_attention.ring_attention` instead).
+This kernel has no backward pass, no window and no grouped-query heads,
+and no model calls it: ``chip_smoke.py`` keeps it as the bring-up check
+that a Pallas kernel of this repo compiles and runs on the chip. The
+attention the decoder stack trains through is
+:func:`fedml_tpu.ops.attention.causal_attention` (JAX's bundled splash
+attention on the TPU). What the chip read for that one (PR 27, TPU v5
+lite, 2 sequences of 2,048, 8 key-value heads of 128, bfloat16, forward
++ backward, wall clock over 10 calls): 64 query heads with a 512-window
+5.48 ms in blocks of 512 (7.69 at 256, 7.42 at 1,024) against 22.6 ms
+for the masked product XLA fuses (:func:`fedml_tpu.ops.attention.
+masked_attention`); 48 heads causal 5.57 ms (5.37 at 1,024) against
+17.5 ms. An earlier note here (2026-07) had XLA's fused attention 6-11x
+faster than THIS kernel at T 2048-8192; it was not re-measured.
 """
 
 from __future__ import annotations

@@ -15,6 +15,8 @@ carries a fixed ``capacity`` of token slots; overflow tokens are dropped
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -138,3 +140,154 @@ def make_expert_parallel_moe(mesh, axis_name: str = "ep",
         out_specs=spec_x,
         check_vma=False,
     )
+
+
+# ---------------------------------------------------------------------------
+# the chip's share of a sparse-expert layer (top-k, dropless)
+# ---------------------------------------------------------------------------
+
+#: what :func:`moe_layer` counts, in this order
+MOE_COUNTERS = ("moe_rows_held", "moe_rows_routed", "moe_rows_max_expert")
+
+
+def gated_ffn(x, w1, w3, w2):
+    """``(silu(x W1) * (x W3)) W2``, the gated feed-forward every expert
+    (and the dense layer, and the shared expert) is."""
+    return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(h, order, inverse, n_held, k):
+    """Row ``r`` of the result is token ``order[r] // k``'s row of ``h``.
+    Both directions are gathers: the cotangent is un-sorted through
+    ``inverse`` and a token's ``k`` copies summed. Only the first
+    ``n_held`` rows are anyone's input, so only their cotangent counts:
+    the grouped products leave the rest of theirs unwritten."""
+    return h[order // k]
+
+
+def _dispatch_fwd(h, order, inverse, n_held, k):
+    return h[order // k], (inverse, n_held, h.shape[0])
+
+
+def _dispatch_bwd(k, res, g):
+    inverse, n_held, n = res
+    g = jnp.where((jnp.arange(g.shape[0]) < n_held)[:, None], g, 0)
+    return g[inverse].reshape(n, k, -1).sum(1), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unsort(y, order, inverse):
+    """``y[inverse]`` with the gather ``g[order]`` as its transpose (a
+    permutation's, where autodiff would scatter)."""
+    return y[inverse]
+
+
+_unsort.defvjp(lambda y, order, inverse: (y[inverse], order),
+               lambda order, g: (g[order], None, None))
+
+
+def _mapped(fn):
+    """``fn`` with a ``vmap`` rule that first gives every operand the
+    mapped axis: ``ragged_dot`` maps only when all three of its operands
+    are mapped along axis 0, and under the cohort's ``vmap`` the weights
+    of a client's first step are not (they are the global ones)."""
+    wrapped = jax.custom_batching.custom_vmap(fn)
+
+    @wrapped.def_vmap
+    def rule(axis_size, in_batched, *args):
+        args = [a if mapped else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, mapped in zip(args, in_batched)]
+        out = jax.vmap(fn)(*args)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return wrapped
+
+
+_ragged = _mapped(lambda x, w, sizes: jax.lax.ragged_dot(x, w, sizes))
+_ragged_transposed = _mapped(lambda x, w, sizes, g: jax.vjp(
+    lambda x, w: jax.lax.ragged_dot(x, w, sizes), x, w)[1](g))
+
+
+@jax.custom_vjp
+def grouped_product(x, w, sizes):
+    """Rows ``[M, K]`` sorted by group, one ``[K, N]`` matrix a group
+    (``w`` ``[G, K, N]``), ``sizes`` ``[G]`` rows a group -> ``[M, N]``;
+    rows past ``sum(sizes)`` are not to be read, of the result and of
+    the rows' cotangent alike (the TPU kernel leaves them unwritten:
+    the caller masks both ends). ``jax.lax.ragged_dot``
+    (on the TPU a Mosaic kernel that visits the row tiles present) and
+    its own two transposes, made to run under ``vmap``."""
+    return _ragged(x, w, sizes)
+
+
+grouped_product.defvjp(
+    lambda x, w, sizes: (_ragged(x, w, sizes), (x, w, sizes)),
+    lambda res, g: (*_ragged_transposed(*res, g), None))
+
+
+def route_top_k(scores, top_k: int, scale: float):
+    """Sigmoid scores ``[N, E]`` (float32) -> the ``top_k`` largest a
+    token, their weights renormalised over those ``top_k`` and times
+    ``scale``: ``(expert ids [N, k], weights [N, k])``."""
+    p = jax.nn.sigmoid(scores)
+    top_p, top_e = jax.lax.top_k(p, top_k)
+    return top_e, scale * top_p / jnp.sum(top_p, -1, keepdims=True)
+
+
+def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float):
+    """This chip's part of one sparse-expert layer, dropless.
+
+    ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts, ``w1`` /
+    ``w3`` ``[count, D, F]`` and ``w2`` ``[count, F, D]`` of the experts
+    held here — ids ``[first, first + count)``, ``held = (first,
+    count)`` — and ``shared`` (``w1, w3, w2`` of the shared expert) or
+    no such key. ``h``: ``[N, D]`` tokens.
+
+    Every token is routed over all ``E`` experts; the assignments whose
+    expert is held are ordered by expert and go through grouped matrix
+    products (:func:`grouped_product`: on the TPU a kernel whose work
+    follows the rows present, never ``E x N``), are weighted —
+    weights normalised over all ``top_k``, held or not — and summed back
+    into their tokens; the shared expert is added. The row buffer holds
+    the worst case (``N x top_k`` rows), so no assignment is ever
+    dropped. What the absent experts would add is left out: on one chip
+    there is no exchange and nothing stands in for one.
+
+    -> ``(y [N, D], counters float32 [3])`` in :data:`MOE_COUNTERS`'
+    order: assignments that landed on held experts, assignments made
+    (``N x top_k``), rows of the fullest held expert."""
+    first, count = held
+    n, _ = h.shape
+    with jax.named_scope("fedml.model.moe.route"):
+        scores = jnp.dot(h, params["router"],
+                         preferred_element_type=jnp.float32)
+        top_e, top_w = route_top_k(scores, top_k, scale)
+        local = top_e.reshape(-1) - first
+        # an assignment to an absent expert sorts past every held group
+        group = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(group, stable=True)
+        inverse = jnp.argsort(order)
+        sizes = jnp.bincount(group, length=count + 1)[:count].astype(
+            jnp.int32)
+        n_held = jnp.sum(sizes)
+        rows = _dispatch(h, order, inverse, n_held, top_k)
+    with jax.named_scope("fedml.model.moe.experts"):
+        up = jax.nn.silu(grouped_product(rows, params["w1"], sizes)) * (
+            grouped_product(rows, params["w3"], sizes))
+        out = grouped_product(up, params["w2"], sizes)
+    with jax.named_scope("fedml.model.moe.route"):
+        # rows past the held groups belong to absent experts
+        out = jnp.where((jnp.arange(n * top_k) < n_held)[:, None], out, 0)
+        out = _unsort(out, order, inverse).reshape(n, top_k, -1)
+        y = jnp.einsum("nkd,nk->nd", out, top_w.astype(out.dtype))
+    if "shared" in params:
+        with jax.named_scope("fedml.model.mlp"):
+            y = y + gated_ffn(h, *params["shared"])
+    counters = jnp.stack([
+        n_held, jnp.asarray(n * top_k, jnp.int32), jnp.max(sizes),
+    ]).astype(jnp.float32)
+    return y.astype(h.dtype), counters

@@ -83,6 +83,66 @@ def test_flash_attention_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)])
+def test_decoder_attention_compiles_for_v5e(one_chip, monkeypatch, heads,
+                                            window):
+    """The decoder stack's attention at Laguna-XS.2's widths (48 full /
+    64 sliding query heads over 8 key-value heads of 128, 2 sequences of
+    2,048, window 512), forward and backward: the blockwise kernel is in
+    the program, three calls of it (forward, dq, dkv)."""
+    from fedml_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    q = jax.ShapeDtypeStruct((2, 2048, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 2048, 8, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return A.causal_attention(q, k, v, window=window).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # no [T, T] score tensor: the scratch is far under one head's scores
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2048 * (
+        2048 * heads * 2)
+
+
+def test_grouped_expert_products_compile_for_v5e(one_chip):
+    """One sparse layer's share at Laguna-XS.2's widths (4,096 tokens,
+    8 of 256 experts a token, 32 held of width 512), forward and
+    backward: the grouped products are the compiler's ragged-dot
+    kernels, not a dense expansion over the experts, and the scope map
+    still names them."""
+    from fedml_tpu.core.memscope import parse_scopes
+    from fedml_tpu.ops.moe import moe_layer
+
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    params = {"router": sds((2048, 256)), "w1": sds((32, 2048, 512)),
+              "w3": sds((32, 2048, 512)), "w2": sds((32, 512, 2048)),
+              "shared": (sds((2048, 512)), sds((2048, 512)),
+                         sds((512, 2048)))}
+
+    def loss(params, h):
+        y, counters = moe_layer(params, h, (0, 32), 8, 2.5)
+        return y.astype(jnp.float32).sum(), counters
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        params, sds((4096, 2048))).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot-none") >= 9  # 3 products x (1 + 2)
+    # the worst-case row buffers ([32768, 2048] bfloat16 = 134 MB each),
+    # not 32 experts x 32,768 rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    scopes = parse_scopes(text)
+    assert {v for k, v in scopes.items()
+            if k.startswith("ragged-dot-none")} == {
+                "fedml.model.moe.experts"}
+
+
 def test_resnet56_cohort_update_compiles_for_v5e(one_chip):
     """The headline local update: the whole 10-client cohort as one
     widened ResNet-56 (``ops/cohort_conv``'s custom primitive), batch

@@ -330,7 +330,13 @@ def test_frozen_base_and_server_state_shape():
     n_tr_leaves = len(jax.tree.leaves(
         sim._peft.part.trainable(state.variables["params"])
     ))
-    assert len(jax.tree.leaves(state.momentum)) == n_tr_leaves
+    # gmf 0: no momentum buffer at all; with one, it has the trainable
+    # subtree's shape (as the server optimizer's state has)
+    assert state.momentum == ()
+    import dataclasses
+    gmf = dataclasses.replace(
+        sim.cfg, fed=dataclasses.replace(sim.cfg.fed, gmf=0.5))
+    assert len(jax.tree.leaves(_sim(gmf).init().momentum)) == n_tr_leaves
     state, ms = _run(sim, 3)
     _bitwise(_frozen_of(sim, state), frozen0, "frozen base")
     # the trainable subtree DID move
