@@ -22,6 +22,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from fedml_tpu.config import TrainConfig
 from fedml_tpu.core import tree as T
@@ -649,16 +651,25 @@ def build_cohort_local_update(
 EVAL_LOGIT_BYTES = 256 * 2 ** 20
 
 
-def build_evaluator(model: FedModel, task: Task, eval_batch: int = 256):
+def build_evaluator(model: FedModel, task: Task, eval_batch: int = 256,
+                    mesh=None):
     """Jitted global-test evaluation: pad to a multiple of the batch —
     ``eval_batch`` samples, or as many fewer as keep a batch's float32
     logits under :data:`EVAL_LOGIT_BYTES` (a language model's are
     ``T x vocab`` a sample: 103 MB at 2,048 x 12,544) — scan batches,
     reduce metric sums (reference ``_local_test_on_all_clients`` /
     ``test_on_server_for_all_clients``,
-    ``FedAVGAggregator.py:110-164``)."""
+    ``FedAVGAggregator.py:110-164``).
 
-    def evaluate(variables, x, y):
+    With a ``mesh`` the evaluator is ``evaluate(variables, x, y, w)``
+    over rows split across EVERY device of the mesh (leading axis
+    ``P(mesh.axis_names)``, ``w`` 1 for a real row and 0 for the rows
+    that pad the set to a multiple of the device count): each device
+    runs the same body over its own rows under ``shard_map`` with the
+    variables replicated, and the metric sums are ``psum``med. Without
+    one it is ``evaluate(variables, x, y)`` on whatever holds ``x``."""
+
+    def shard_sums(variables, x, y, w=None):
         one = jax.eval_shape(model.apply_eval, variables, x[:1])
         batch = max(1, min(
             eval_batch, EVAL_LOGIT_BYTES // (4 * math.prod(one.shape))))
@@ -666,7 +677,8 @@ def build_evaluator(model: FedModel, task: Task, eval_batch: int = 256):
         pad = (-n) % batch
         xp = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
         yp = jnp.concatenate([y, jnp.zeros((pad,) + y.shape[1:], y.dtype)])
-        w = jnp.concatenate([jnp.ones((n,)), jnp.zeros((pad,))])
+        w = jnp.concatenate(
+            [jnp.ones((n,)) if w is None else w, jnp.zeros((pad,))])
         nb = (n + pad) // batch
 
         def body(sums, i):
@@ -678,6 +690,24 @@ def build_evaluator(model: FedModel, task: Task, eval_batch: int = 256):
             return {k: sums[k] + s[k] for k in sums}, None
 
         sums, _ = jax.lax.scan(body, zero_sums(), jnp.arange(nb))
+        return sums
+
+    if mesh is None:
+        def evaluate(variables, x, y):
+            sums = shard_sums(variables, x, y)
+            return {**finalize_sums(sums), "count": sums["count"]}
+
+        return jax.jit(evaluate)
+
+    axes = mesh.axis_names
+    rows = P(axes)
+
+    def evaluate(variables, x, y, w):
+        sums = shard_map(
+            lambda *operands: jax.lax.psum(shard_sums(*operands), axes),
+            mesh=mesh, in_specs=(P(), rows, rows, rows), out_specs=P(),
+            check_vma=False,
+        )(variables, x, y, w)
         return {**finalize_sums(sums), "count": sums["count"]}
 
     return jax.jit(evaluate)

@@ -634,9 +634,12 @@ class FedAvgSim:
         )
 
     def _prepare_data(self, data: FederatedData, cfg: ExperimentConfig):
-        """Resolve device data + batch size. The mesh-sharded subclass
-        overrides this to keep the global arrays host-side (its training
-        data lives in per-shard banks instead)."""
+        """Resolve device data + batch size: the whole population and
+        the test set on the default device, where the round and
+        ``evaluate_global`` read them. The mesh-sharded subclass
+        overrides this: its global arrays stay host-side, its training
+        data lives in per-shard banks and its test set split over the
+        mesh."""
         self.arrays, self.batch_size = arrays_and_batch(data, cfg.data)
 
     # -- initialization ----------------------------------------------------
@@ -1874,15 +1877,22 @@ class FedAvgSim:
         )
         return state, m
 
+    def _global_eval(self):
+        """The evaluator of the global test set and its operands after
+        the variables, where and in the layout that evaluator consumes
+        them: here the test set as ``_prepare_data`` placed it on the
+        chip; ``ShardedFedAvg`` answers with its mesh's."""
+        return self.evaluator, (self.arrays.test_x, self.arrays.test_y)
+
     def evaluate_global(self, state: ServerState) -> dict:
-        x, y = self.arrays.test_x, self.arrays.test_y
+        evaluator, operands = self._global_eval()
         # h2d_bytes is a COUNT read off the operands, not a timing: what
-        # this call re-sends from the host (0 here, the test set lives
-        # on the chip; all of it for ShardedFedAvg, whose arrays stay
-        # host numpy)
-        h2d = sum(a.nbytes for a in (x, y) if isinstance(a, np.ndarray))
+        # this call re-sends from the host. 0 for both simulators (the
+        # test set lives on the chip, or split over the mesh); anything
+        # else means an operand was left as host numpy
+        h2d = sum(a.nbytes for a in operands if isinstance(a, np.ndarray))
         with span("fedml.eval", phase="eval", h2d_bytes=h2d):
-            m = self.evaluator(state.variables, x, y)
+            m = evaluator(state.variables, *operands)
             return {k: float(v) for k, v in m.items()}
 
     def evaluate_train(self, state: ServerState) -> dict:

@@ -129,7 +129,8 @@ class FederatedData:
     ) -> FederatedArrays:
         """``device=False`` keeps all leaves as host numpy arrays — used by
         the mesh-sharded runtime, whose training data lives in per-shard
-        banks instead (jit transfers host leaves on use, e.g. at eval)."""
+        banks and whose test set is placed over the mesh instead (jit
+        transfers a host leaf on use: ``evaluate_train`` there)."""
         if dtype is None:
             dtype = _infer_input_dtype(self.x_train)
         idx, mask, counts = _pad_index_map(

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -47,6 +48,7 @@ from fedml_tpu.core import statebank as SB
 from fedml_tpu.data.federated import FederatedData, shard_client_banks
 from fedml_tpu.algorithms.base import (
     build_cohort_local_update,
+    build_evaluator,
     build_local_update,
     cohort_update_supported,
     finalize_sums,
@@ -161,6 +163,8 @@ class ShardedFedAvg(FedAvgSim):
         # NOTE: super().__init__ may have LoRA-injected the model
         # (fedml_tpu.peft) — rebuilds below must use the injected one
         model = self.model
+        # each device evaluates the test rows it holds (_prepare_data)
+        self._mesh_evaluator = build_evaluator(model, self.task, mesh=mesh)
         if self.n_data_shards > 1:
             self.local_update = build_local_update(
                 model,
@@ -285,8 +289,10 @@ class ShardedFedAvg(FedAvgSim):
 
     def _prepare_data(self, data, cfg):
         """Training data lives ONLY in the per-shard banks (per-device HBM
-        ~1/n_shards of the global set); the global FederatedArrays stays as
-        host numpy and is transferred only when evaluation runs."""
+        ~1/n_shards of the global set) and the test set ONLY as
+        ``self._test_rows``, its rows split over every device of the
+        mesh; the global FederatedArrays stays host numpy
+        (``evaluate_train`` still sends its training set from there)."""
         from fedml_tpu.data.federated import arrays_and_batch
 
         self.arrays, self.batch_size = arrays_and_batch(
@@ -307,6 +313,22 @@ class ShardedFedAvg(FedAvgSim):
             NamedSharding(self.mesh, P(self.client_axis)),
         )
         assert self.banks.max_client_samples == self.arrays.max_client_samples
+        # the test set likewise, in the layout the mesh evaluator
+        # consumes: (x, y, w) padded to a multiple of the device count,
+        # w 1 for a real row and 0 for padding, rows over all devices
+        x, y = self.arrays.test_x, self.arrays.test_y
+        pad = (-len(x)) % self.mesh.devices.size
+        rows = lambda a: np.concatenate(
+            [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
+        self._test_rows = jax.device_put(
+            (rows(x), rows(y), rows(np.ones(len(x), np.float32))),
+            NamedSharding(self.mesh, P(self.mesh.axis_names)),
+        )
+
+    def _global_eval(self):
+        """The mesh's evaluator and the rows ``_prepare_data`` placed
+        for it: every device evaluates its own."""
+        return self._mesh_evaluator, self._test_rows
 
     def _sharded_round(self, state: ServerState, banks, n_active=None,
                        residual=None, bank=None):

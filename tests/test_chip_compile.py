@@ -181,10 +181,11 @@ def test_resnet56_cohort_update_compiles_for_v5e(one_chip):
     assert 0 < need < V5E_HBM_BYTES, ma
 
 
-def test_sharded_round_compiles_for_four_v5e_chips(topo):
-    """``ShardedFedAvg``'s round over a 4-chip ``clients`` mesh: the
-    aggregation is a collective, and each chip is handed a quarter of
-    the sample banks, not all of them."""
+@pytest.fixture(scope="module")
+def mesh_sim(topo):
+    """The headline job as ``ShardedFedAvg`` built on four of this
+    process's devices, its mesh then steered onto the described chips:
+    same axes, same layout."""
     import bench
     from fedml_tpu.data.loaders import load_dataset
     from fedml_tpu.models import create_model
@@ -198,12 +199,20 @@ def test_sharded_round_compiles_for_four_v5e_chips(topo):
         create_model(cfg.model), load_dataset(cfg.data), cfg,
         make_mesh(client_axis=4, data_axis=1, devices=jax.devices()[:4]),
     )
-    # the banks the constructor placed are spread over its mesh
+    # what the constructor placed is spread over its mesh
     assert len(sim.banks.x.sharding.device_set) == 4
-    # steer the round onto the described chips: same axes, same layout
+    assert len(sim._test_rows[0].sharding.device_set) == 4
     sim.mesh = Mesh(
         np.array(topo.devices[:4]).reshape(4, 1), sim.mesh.axis_names
     )
+    return sim
+
+
+def test_sharded_round_compiles_for_four_v5e_chips(mesh_sim):
+    """``ShardedFedAvg``'s round over a 4-chip ``clients`` mesh: the
+    aggregation is a collective, and each chip is handed a quarter of
+    the sample banks, not all of them."""
+    sim = mesh_sim
     rep = NamedSharding(sim.mesh, P())
     by_client = NamedSharding(sim.mesh, P(sim.client_axis))
     state = _shapes(jax.eval_shape(sim.init), rep)
@@ -221,3 +230,25 @@ def test_sharded_round_compiles_for_four_v5e_chips(topo):
     assert 0.9 * quarter < banks_per_chip < 1.2 * quarter, (
         banks_per_chip, quarter
     )
+
+
+def test_mesh_evaluator_compiles_for_four_v5e_chips(mesh_sim):
+    """The mesh's evaluation of the global test set: each chip is handed
+    a quarter of the rows and the replicated variables, and the metric
+    sums cross the chips in a collective."""
+    from fedml_tpu.algorithms.base import build_evaluator
+
+    sim = mesh_sim
+    rep = NamedSharding(sim.mesh, P())
+    by_row = NamedSharding(sim.mesh, P(sim.mesh.axis_names))
+    variables = _shapes(jax.eval_shape(sim.init).variables, rep)
+    rows = _shapes(sim._test_rows, by_row)
+    compiled = build_evaluator(sim.model, sim.task, mesh=sim.mesh).lower(
+        variables, *rows
+    ).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    n, *image = rows[0].shape
+    per_chip = ",".join(map(str, [n // 4, *image]))
+    whole = ",".join(map(str, [n, *image]))
+    assert f"f32[{per_chip}]" in text and f"f32[{whole}]" not in text

@@ -4,9 +4,12 @@ The key invariant: a shard_map-parallel round computes the SAME aggregate as
 the single-device vmapped round (the reference's distributed FedAvg is, by
 construction, numerically equal to its standalone sim; here we prove it)."""
 
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from fedml_tpu.config import (
     DataConfig,
@@ -18,6 +21,7 @@ from fedml_tpu.config import (
 )
 from fedml_tpu.algorithms.fedavg import FedAvgSim
 from fedml_tpu.core import random as R
+from fedml_tpu.data.federated import build_federated_data
 from fedml_tpu.data.loaders import load_dataset
 from fedml_tpu.models import create_model
 from fedml_tpu.parallel import ShardedFedAvg, make_mesh
@@ -383,3 +387,73 @@ def test_sharded_single_group_round_is_the_ungrouped_program(monkeypatch):
     narrow = ShardedFedAvg(model, data, cfg,
                            make_mesh(client_axis=2, data_axis=1))
     assert narrow.cohort_per_shard == 6 and narrow._shard_groups == 1
+
+
+# -- evaluation on the mesh ---------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def eval_population():
+    cfg = cfg_for(MeshConfig(client_axis_size=4, data_axis_size=1))
+    return cfg, load_dataset(cfg.data), create_model(cfg.model)
+
+
+def _with_test_rows(data, n):
+    """``data`` with its test set cut to the first ``n`` rows."""
+    return build_federated_data(
+        data.x_train, data.y_train, data.x_test[:n], data.y_test[:n],
+        data.num_classes, data.num_clients,
+    )
+
+
+@pytest.mark.parametrize("n_test", [37, 256, 1000])
+@pytest.mark.parametrize("shape", [(4, 1), (2, 2)], ids=["4x1", "2x2"])
+def test_mesh_evaluation_matches_single_device(
+        eval_population, shape, n_test):
+    """Each device evaluates its own rows of the test set and the sums
+    are psummed: the same ``acc`` and ``count`` as FedAvgSim's evaluator
+    on the same variables, and the same ``loss`` up to summation order —
+    whether or not the set divides by devices x batch (the lr model's
+    batch is 256: 37 rows pad a device's 10 to one batch, 1,000 leave
+    every device 250 of 256), before and after a round."""
+    cfg, data, model = eval_population
+    clients, data_axis = shape
+    cfg = dataclasses.replace(cfg, mesh=MeshConfig(
+        client_axis_size=clients, data_axis_size=data_axis))
+    data = _with_test_rows(data, n_test)
+    single = FedAvgSim(model, data, cfg)
+    sharded = ShardedFedAvg(
+        model, data, cfg,
+        make_mesh(client_axis=clients, data_axis=data_axis))
+    state = sharded.init()
+    for _ in range(2):
+        got = sharded.evaluate_global(state)
+        want = single.evaluator(
+            state.variables, single.arrays.test_x, single.arrays.test_y)
+        assert got["count"] == float(want["count"]) == n_test
+        assert got["acc"] == float(want["acc"])
+        assert got["loss"] == pytest.approx(float(want["loss"]), abs=1e-6)
+        state, _ = sharded.run_round(state)
+
+
+def test_mesh_evaluation_sends_nothing_from_the_host(eval_population):
+    """The test set is placed once, rows over every device of the mesh
+    with a weight per row, and an evaluation moves nothing from the
+    host: it runs with host-to-device transfers disallowed."""
+    cfg, data, model = eval_population
+    mesh = make_mesh(client_axis=4, data_axis=1)
+    sharded = ShardedFedAvg(model, _with_test_rows(data, 37), cfg, mesh)
+    evaluator, operands = sharded._global_eval()
+    assert evaluator is not sharded.evaluator
+    rows = NamedSharding(mesh, P(("clients", "data")))
+    for a in operands:
+        assert isinstance(a, jax.Array) and a.shape[0] == 40
+        assert a.sharding.is_equivalent_to(rows, a.ndim)
+    np.testing.assert_array_equal(
+        np.asarray(operands[2]), np.r_[np.ones(37), np.zeros(3)])
+    # the global arrays stay host numpy, as _prepare_data promises
+    assert isinstance(sharded.arrays.test_x, np.ndarray)
+    state, _ = sharded.run_round(sharded.init())
+    with jax.transfer_guard_host_to_device("disallow"):
+        m = sharded.evaluate_global(state)
+    assert m["count"] == 37
