@@ -466,7 +466,7 @@ def _compiled_round(sim, cache: bool = False):
         compiled = jax.jit(sim._round, donate_argnums=(0,)).lower(
             state, sim.arrays
         ).compile()
-        run_round = lambda st: compiled(st, sim.arrays)
+        run_round = lambda st: compiled(st, sim.arrays)[:2]
         if cache:
             sim._bench_cached_round = run_round
     state, _ = run_round(state)  # warmup (execute once)
@@ -622,7 +622,7 @@ def _compiled_block(sim, fuse: int):
         .lower(state, sim.arrays, None, None, fuse)
         .compile()
     )
-    run_block = lambda st: compiled(st, sim.arrays, None, None)
+    run_block = lambda st: compiled(st, sim.arrays, None, None)[:2]
     state, _ = run_block(state)  # warmup (execute once)
     jax.block_until_ready(jax.tree.leaves(state))
     return run_block, state
@@ -2045,7 +2045,7 @@ def bank_bench_records(cohorts=(1000, 10_000, 100_000), block=32):
                 "defense": "median",
                 "compress": "int8",
                 "bank_resident_mb": round(
-                    sim._ef_bank.resident_bytes() / 1e6, 3
+                    sim._carry.resident_bytes() / 1e6, 3
                 ),
                 "temp_mb": round(prog["temp_bytes"] / 1e6, 3),
                 "argument_mb": round(

@@ -32,6 +32,7 @@ from fedml_tpu.core import export as EXPORT
 from fedml_tpu.core import memscope as MEMSCOPE
 from fedml_tpu.core import robust, telemetry
 from fedml_tpu.core import tree as T
+from fedml_tpu.core.tracing import span
 from fedml_tpu.core.membership import MembershipLedger
 from fedml_tpu.core.reputation import QuarantinePolicy, ReputationTracker
 from fedml_tpu.core.manager import ClientManager, ServerManager
@@ -1583,7 +1584,7 @@ class FedAvgClientActor(ClientManager):
         # the np.asarray conversion blocks on the async dispatch, so the
         # span covers the real device work, not just the enqueue
         t_loc = time.monotonic()
-        with telemetry.maybe_span(
+        with span(
             "local_update", rank=self.rank, round=round_idx,
             client=client_idx,
         ):

@@ -32,8 +32,7 @@ import optax
 
 from fedml_tpu.config import ExperimentConfig, FedConfig, TrainConfig
 from fedml_tpu.core import adversary as A
-from fedml_tpu.core.anatomy import ANATOMY
-from fedml_tpu.core.tracing import log_span, span
+from fedml_tpu.core.tracing import span
 from fedml_tpu.core import bulk as BK
 from fedml_tpu.core import compress as C
 from fedml_tpu.core import elastic as E
@@ -56,32 +55,6 @@ from fedml_tpu.algorithms.base import (
 from fedml_tpu.models.base import FedModel
 
 Pytree = Any
-
-
-def consume_round_counters(train_metrics: dict) -> dict:
-    """Pop device-computed counter values out of a round's metric dict
-    and feed them to the process metrics registry (the round loops —
-    :meth:`FedAvgSim.run` and the harness — call this where they
-    already force the metrics to host, so the bench's sync-free
-    ``run_round`` loop pays nothing)."""
-    rej = train_metrics.pop("nonfinite_rejected", None)
-    if rej is not None:
-        r = float(rej)
-        if r:
-            telemetry.METRICS.inc("robust.nonfinite_rejected", r)
-            telemetry.RECORDER.record("nonfinite_rejected", count=r,
-                                      path="sim")
-    res = train_metrics.pop("compress_residual_norm", None)
-    if res is not None:
-        # the error-feedback carry (docs/OBSERVABILITY.md): bounded ==
-        # compression error is telescoping carry, not accumulating bias
-        telemetry.METRICS.gauge("compress.residual_norm", float(res))
-    # round-boundary device-memory sample (core/memscope.py): every
-    # sim round loop funnels through here exactly once per round with
-    # the metrics already forced to host — the natural boundary for
-    # the live mem.* gauges. One attribute check when telemetry is off.
-    M.MONITOR.sample()
-    return train_metrics
 
 
 def round_counters(model: FedModel, msums: dict) -> dict:
@@ -445,10 +418,6 @@ class FedAvgSim:
         # untouched and every path stays byte-identical.
         model, self._peft = PF.build_peft(model, cfg)
         self.model = model
-        # personalization bank: a client-id-keyed ClientStateBank
-        # (core/statebank.py), created lazily on the first round;
-        # `_adapter_bank` exposes its raw rows for callers
-        self._bank_adapter = None
         self.task = make_task(data.task)
         self._prepare_data(data, cfg)
         # token-model sanity: an embed table smaller than the data's
@@ -565,23 +534,22 @@ class FedAvgSim:
         # [bucket, ...] operand. Off by default: the dense round is
         # byte-identical (no extra operand, no residual allocation).
         self._cspec = C.CompressionSpec.from_fed(cfg.fed, seed=cfg.seed)
-        self._ef_residual = None  # lazy zero carry, [bucket, ...]
-        # bulk mode keeps the EF carry in a client-id-keyed
-        # ClientStateBank instead of the slot-keyed [bucket, ...] carry
-        # (the residual follows the CLIENT across rounds; core/
-        # statebank.py) — also created lazily, checkpointed alongside
-        # the adapter bank (bank_state/restore_banks)
-        self._ef_bank = None
-        if self._peft is not None and self._peft.personalized:
-            # the private adapter bank rides as a donated operand
-            # (arg 4 of _round) exactly like the EF residual would —
-            # compress+personalize is rejected, so the two never
-            # coexist
-            donate = (0, 4)
-        elif self._cspec.enabled():
-            donate = (0, 3)
-        else:
-            donate = (0,)
+        # -- the ONE per-client carry a configuration threads between
+        # rounds, as the round body's last operand and last result
+        # (`_round`): "adapter", the personalization ClientStateBank
+        # (core/statebank.py); "ef_residual", the error-feedback carry —
+        # slot-keyed [bucket, ...] rows on the stacked round, a
+        # client-id-keyed ClientStateBank in bulk mode (the residual
+        # follows the CLIENT across rounds); or None. Never two:
+        # compress+personalize is rejected. Created lazily on the first
+        # round (`_ensure_carry`); the banks are checkpointed
+        # (bank_state/restore_banks)
+        self._carry_kind = (
+            "adapter" if self._peft is not None and self._peft.personalized
+            else "ef_residual" if self._cspec.enabled()
+            else None
+        )
+        self._carry = None
         # the round program is an instrumented AOT site
         # (core/memscope.py): compiles are explicit .lower().compile()
         # calls — byte-identical lowering to a first jit call — so
@@ -594,13 +562,15 @@ class FedAvgSim:
         # mem.program.* accounting and the donation audit name the
         # block program distinctly from the stacked one.
         family = "sim_bulk" if self._bulk.enabled() else "sim_round"
-        self._round_fn = M.ProgramSite(self._round, family=family,
-                                       donate_argnums=donate)
+        self._round_fn = M.ProgramSite(
+            self._round, family=family,
+            donate_argnums=self._donate_argnums(),
+        )
         # -- fused multi-round execution (core/fuse.py, docs/
         # PERFORMANCE.md "Round fusion"): with fuse_rounds K > 1 ONE
         # compiled program runs K complete rounds as a lax.scan over
-        # the round body — ServerState (and the error-feedback
-        # residual) ride as donated scan carries, per-round train
+        # the round body — ServerState (and the per-client carry)
+        # ride as donated scan carries, per-round train
         # metrics stack into [K, ...] outputs the driver consumes once
         # per block. Cohort sampling folds in the CARRIED round
         # counter, so the sampled cohorts are bitwise-identical to the
@@ -622,7 +592,8 @@ class FedAvgSim:
                     "sim_bulk_block" if self._bulk.enabled()
                     else "sim_block"
                 ),
-                static_argnums=(5,), donate_argnums=donate,
+                static_argnums=(4,),
+                donate_argnums=self._donate_argnums(),
             )
             if self._fuse > 1 else None
         )
@@ -632,6 +603,13 @@ class FedAvgSim:
         M.MONITOR.headroom_warn = float(
             getattr(cfg.fed, "mem_headroom_warn", 0.9) or 0.9
         )
+
+    def _donate_argnums(self) -> tuple:
+        """What a round program (or fused block) donates of ``(state,
+        operand, n_active, carry)``: the state always, the carry slot
+        when the configuration threads one. Donated operands are audited
+        is_deleted after the first execution (core/memscope.py)."""
+        return (0,) if self._carry_kind is None else (0, 3)
 
     def _prepare_data(self, data: FederatedData, cfg: ExperimentConfig):
         """Resolve device data + batch size: the whole population and
@@ -889,7 +867,7 @@ class FedAvgSim:
         return stacked_vars, new_residual
 
     def _bulk_round(self, state: ServerState, arrays: FederatedArrays,
-                    n_active=None, ef_bank=None, adapter_bank=None):
+                    n_active=None, carry=None):
         """The block-streamed round body (core/bulk.py,
         docs/PERFORMANCE.md "Bulk-client execution"): sample the
         cohort, chunk it into ``block_size`` slots, run each block
@@ -902,12 +880,12 @@ class FedAvgSim:
         :func:`server_update_from_partials`, which shares
         :func:`server_update`'s exact post-reduce tail.
 
-        ``ef_bank`` (the compression error-feedback
-        :class:`~fedml_tpu.core.statebank.ClientStateBank`) and
-        ``adapter_bank`` (the PEFT personalization bank) ride the scan
-        carry and come back updated; compress+personalize stays
-        rejected, so at most one is non-None. A streamed defense
-        (:mod:`fedml_tpu.core.streamdef`) turns the body into TWO
+        ``carry`` is the configuration's one
+        :class:`~fedml_tpu.core.statebank.ClientStateBank` (the
+        compression error-feedback bank or the PEFT personalization
+        bank, `_carry_kind`) or None; it rides the scan carry and comes
+        back updated, third of ``(state, metrics, carry)``. A streamed
+        defense (:mod:`fedml_tpu.core.streamdef`) turns the body into TWO
         passes over the same blocks: pass 1 folds partials + the
         defense sketch (EF rows read-only), the selection/quantile
         decision is made from the sketch, pass 2 folds the decided
@@ -946,10 +924,11 @@ class FedAvgSim:
                     E.active_mask(self._slots, cohort.shape[0])
                     if pad else None
                 )
-        if adapter_bank is not None:
+        if self._carry_kind == "adapter":
             return self._bulk_personal(
-                state, view, arrays, ids, live, rkey, adapter_bank
+                state, view, arrays, ids, live, rkey, carry
             )
+        ef_bank = carry
 
         def local_block(block_ids, block_live, bank, write_bank=True):
             """The stacked round's pre-aggregation prefix, one block at
@@ -1100,9 +1079,7 @@ class FedAvgSim:
             train_metrics.update(
                 round_counters(self.model, partials.msums)
             )
-        if new_ef is not None:
-            return new_state, train_metrics, new_ef
-        return new_state, train_metrics
+        return new_state, train_metrics, new_ef
 
     def _defended_fold(self, view, ids, live, rkey, ef_bank,
                        local_block, partials_of):
@@ -1316,7 +1293,7 @@ class FedAvgSim:
         return new_state, train_metrics, bank
 
     def _personal_round(self, state: ServerState,
-                        arrays: FederatedArrays, bank, n_active=None):
+                        arrays: FederatedArrays, n_active, bank):
         """Personalized PEFT round (fedml_tpu.peft.personal,
         docs/PERFORMANCE.md "Parameter-efficient federated
         fine-tuning"): each sampled client trains with ITS OWN private
@@ -1414,22 +1391,23 @@ class FedAvgSim:
         return new_state, train_metrics, new_bank
 
     def _round(self, state: ServerState, arrays: FederatedArrays,
-               n_active=None, residual=None, bank=None):
+               n_active=None, carry=None):
+        """The round body. Every body — this one, the two it hands over
+        to, the mesh's ``_sharded_round`` — has this ONE signature and
+        returns ``(state, metrics, carry)``: ``n_active`` is the
+        elastic live count or None, ``carry`` the configuration's one
+        per-client carry (`_carry_kind`) or None. None is an empty
+        pytree: a program without the feature has no operand, donation
+        or result for it."""
         if self._bulk.enabled():
-            # in bulk mode the residual slot carries the EF
-            # ClientStateBank and the bank slot the adapter bank —
-            # never both (compress+personalize stays rejected); the
-            # python-level dispatch keeps the stacked trace below
+            # the python-level dispatch keeps the stacked trace below
             # byte-identical when bulk is off
-            return self._bulk_round(
-                state, arrays, n_active, ef_bank=residual,
-                adapter_bank=bank,
-            )
-        if bank is not None:
+            return self._bulk_round(state, arrays, n_active, carry)
+        if self._carry_kind == "adapter":
             # personalized PEFT: private adapter bank in, bank out
-            # (fedml_tpu.peft.personal; compress+personalize is
-            # rejected at construction, so residual is None)
-            return self._personal_round(state, arrays, bank, n_active)
+            # (fedml_tpu.peft.personal)
+            return self._personal_round(state, arrays, n_active, carry)
+        residual = carry
         cfg = self.cfg.fed
         stacked_vars, n_k, msums, rkey, cohort = self._locals(
             state, arrays, n_active
@@ -1505,14 +1483,14 @@ class FedAvgSim:
             train_metrics["compress_residual_norm"] = T.tree_l2_norm(
                 new_residual
             )
-            return new_state, train_metrics, new_residual
-        return new_state, train_metrics
+        return new_state, train_metrics, new_residual
 
     def _fused_block(self, state: ServerState, operand, n_active=None,
-                     residual=None, bank=None, length: int = 1):
+                     carry=None, length: int = 1):
         """``length`` complete rounds as ONE program: a ``lax.scan``
-        over the round body with (state[, EF residual / adapter bank])
-        as the carry. Each iteration derives its round key from the
+        over the round body with ``(state, carry)`` as the scan carry
+        (``carry`` the EF residual / bank / adapter bank, or None).
+        Each iteration derives its round key from the
         CARRIED ``state.round`` (``_locals`` folds it in), so sampling,
         adversary injection, and the compression quantizer draws are
         bitwise-identical to ``length`` separate ``_round`` calls —
@@ -1520,42 +1498,82 @@ class FedAvgSim:
         (the PR-5/PR-7 band, pinned in tests/test_fuse.py). The
         elastic live count is a scan-invariant traced operand: churn
         mid-block is impossible by construction — ``set_cohort_size``
-        lands at the next block boundary. Metric leaves stack to
-        ``[length, ...]``."""
-        if residual is not None:
-            def body(carry, _):
-                s, res = carry
-                s, m, res = self._round_impl(s, operand, n_active, res)
-                return (s, res), m
+        lands at the next block boundary. In bulk mode the scans nest:
+        this one wraps the round body's block scan. Metric leaves stack
+        to ``[length, ...]``."""
+        def body(sc, _):
+            s, m, c = self._round_impl(sc[0], operand, n_active, sc[1])
+            return (s, c), m
 
-            (state, residual), ms = jax.lax.scan(
-                body, (state, residual), None, length=length
-            )
-            return state, ms, residual
-        if bank is not None:
-            def body(carry, _):
-                s, bk = carry
-                s, m, bk = self._round_impl(
-                    s, operand, n_active, None, bk
-                )
-                return (s, bk), m
-
-            (state, bank), ms = jax.lax.scan(
-                body, (state, bank), None, length=length
-            )
-            return state, ms, bank
-
-        def body(carry, _):
-            s, m = self._round_impl(carry, operand, n_active)
-            return s, m
-
-        state, ms = jax.lax.scan(body, state, None, length=length)
-        return state, ms
+        (state, carry), ms = jax.lax.scan(
+            body, (state, carry), None, length=length
+        )
+        return state, ms, carry
 
     def _round_operand(self):
-        """Device operand the round body trains from (the sharded
-        runtime overrides this with its per-shard banks)."""
+        """Device operand the round body trains from, read at every
+        dispatch (the sharded runtime overrides this with its per-shard
+        banks)."""
         return self.arrays
+
+    def _program_key(self):
+        """Executable identity of the round program: the compiled
+        bucket, or the bulk engine's block grid (the sharded runtime
+        answers with its per-shard ones). A fused block's key carries
+        its length besides."""
+        if self._bulk.enabled():
+            return (self._n_blocks, self._block_size)
+        return self._bucket
+
+    def _dispatch(self, site, state: ServerState, length=None):
+        """The host side of a round, and of a fused block of ``length``
+        rounds: make sure the carry exists, ONE call through the
+        program site, store the carry, note the bank I/O. No branch per
+        feature combination — a feature a configuration lacks is a None
+        operand."""
+        rounds = 1 if length is None else length
+        self._ensure_carry(state)
+        if self._bulk.enabled():
+            # a fused block counts its K rounds so bulk.rounds stays
+            # per-round like every fused metric
+            BK.note_round(
+                self._block_size, self._n_blocks,
+                self._slots - self._n_active, rounds=rounds,
+            )
+            if self._stream_defense is not None:
+                self._note_stream_defense(state)
+        # the live count rides as a TRACED operand: any cohort size in
+        # the bucket reuses the one compiled program; the ProgramSite
+        # is the executable store mirror_jit_cache accounts
+        n = (
+            jnp.asarray(self._n_active, jnp.int32)
+            if self._elastic else None
+        )
+        key = self._program_key()
+        args = (state, self._round_operand(), n, self._carry)
+        if length is not None:
+            key = (key if isinstance(key, tuple) else (key,)) + (length,)
+            args += (length,)
+
+        def call():
+            return site(key, *args)
+
+        state, m, self._carry = (
+            E.mirror_jit_cache(site, call) if self._elastic else call()
+        )
+        if isinstance(self._carry, SB.ClientStateBank):
+            # per round each block gathers and scatters its rows once;
+            # a streamed defense's first pass reads them once more
+            blocks = rounds * (
+                self._n_blocks if self._bulk.enabled() else 1
+            )
+            SB.note_round_io(
+                blocks * (2 if self._stream_defense else 1), blocks
+            )
+        return state, m
+
+    def run_round(self, state: ServerState):
+        return self._dispatch(self._round_fn, state)
 
     def run_block(self, state: ServerState, length: int):
         """Run ``length`` complete rounds as one compiled block
@@ -1570,87 +1588,7 @@ class FedAvgSim:
                 "run_block requires FedConfig(fuse_rounds > 1) — the "
                 "fused block program is built at construction"
             )
-        bulk = self._bulk.enabled()
-        compressed = self._cspec.enabled()
-        personalized = (
-            self._peft is not None and self._peft.personalized
-        )
-        if personalized:
-            self._ensure_adapter_bank(state)
-        if compressed:
-            if bulk:
-                self._ensure_ef_bank(state)
-            elif self._ef_residual is None:
-                self._ef_residual = C.zero_residual(
-                    self._wire_template(state.variables), self._bucket
-                )
-                telemetry.METRICS.gauge(
-                    "compress.ratio",
-                    C.wire_ratio(self._cspec,
-                                 self._wire_template(state.variables)),
-                )
-        operand = self._round_operand()
-        n = (
-            jnp.asarray(self._n_active, jnp.int32)
-            if self._elastic else None
-        )
-        if bulk:
-            # nested scans: the outer fused-round scan wraps the inner
-            # block scan (the bulk round IS _round_impl's body here);
-            # the fused block counts its K rounds so bulk.rounds stays
-            # per-round like every fused metric
-            self._note_bulk_dispatch(rounds=length)
-            if self._stream_defense is not None:
-                self._note_stream_defense(state)
-            key = self._program_key() + (length,)
-        else:
-            key = (self._bucket, length)
-        res = None
-        if compressed:
-            res = self._ef_bank if bulk else self._ef_residual
-
-        def call():
-            return self._block_fn(
-                key, state, operand, n, res,
-                self._bank_adapter if personalized else None, length,
-            )
-
-        out = (
-            E.mirror_jit_cache(self._block_fn, call)
-            if self._elastic else call()
-        )
-        if compressed:
-            state, m, new_res = out
-            if bulk:
-                self._ef_bank = new_res
-                SB.note_round_io(
-                    length * self._n_blocks
-                    * (2 if self._stream_defense else 1),
-                    length * self._n_blocks,
-                )
-            else:
-                self._ef_residual = new_res
-            return state, m
-        if personalized:
-            state, m, self._bank_adapter = out
-            SB.note_round_io(
-                length * (self._n_blocks if bulk else 1),
-                length * (self._n_blocks if bulk else 1),
-            )
-            return state, m
-        return out
-
-    def _program_key(self) -> tuple:
-        """Executable identity of the bulk round program: the compiled
-        block grid. (Only meaningful with the bulk engine on; the
-        stacked paths key by bucket as they always have.)"""
-        return (self._n_blocks, self._block_size)
-
-    def _note_bulk_dispatch(self, rounds: int = 1) -> None:
-        BK.note_round(
-            self._block_size, self._n_blocks,
-            self._slots - self._n_active, rounds=rounds,
-        )
+        return self._dispatch(self._block_fn, state, length)
 
     def _note_stream_defense(self, state: ServerState) -> None:
         """``defense.sketch_*`` gauges at bulk dispatch
@@ -1666,69 +1604,52 @@ class FedAvgSim:
         )
         SD.note_defense(self._stream_defense, flat_dim, self._slots)
 
-    # -- client-state banks (core/statebank.py) ----------------------------
-    @property
-    def _adapter_bank(self):
-        """Raw ``[num_clients, ...]`` adapter rows (None before the
-        first personalized round) — the established surface
-        :func:`fedml_tpu.peft.personal.personal_variables` and the
-        personalization tests consume; internally the rows live in a
-        :class:`~fedml_tpu.core.statebank.ClientStateBank`."""
-        b = self._bank_adapter
-        return None if b is None else b.rows
-
-    @_adapter_bank.setter
-    def _adapter_bank(self, rows):
-        self._bank_adapter = (
-            None if rows is None
-            else SB.ClientStateBank("adapter", rows)
-        )
-
-    def _ensure_adapter_bank(self, state: ServerState) -> None:
-        """Create the personalization bank LAZILY on the first round
-        (from the CURRENT state's init-valued adapters) so that the
-        repo's re-call-init()-for-a-snapshot idiom can never reset a
-        trained bank mid-run; its lifetime is the simulator's."""
-        if self._bank_adapter is not None:
+    # -- the per-client carry (core/statebank.py) --------------------------
+    def _ensure_carry(self, state: ServerState) -> None:
+        """Create the configuration's carry LAZILY on the first round,
+        from the CURRENT state — the personalization bank's rows are its
+        init-valued adapters, so the repo's
+        re-call-init()-for-a-snapshot idiom can never reset a trained
+        bank mid-run; the error-feedback carry is zero, one row per
+        CLIENT in bulk mode and per bucket slot on the stacked round
+        (round 0 transmits the uncorrected delta either way). Its
+        lifetime is the simulator's."""
+        if self._carry is not None or self._carry_kind is None:
             return
-        rows = PP.init_bank(
-            self._peft, state.variables["params"],
-            self.arrays.num_clients,
-        )
-        self._bank_adapter = SB.ClientStateBank("adapter", rows)
-        telemetry.METRICS.gauge(
-            "peft.personal_bank_mb", PP.bank_bytes(rows) / 1e6
-        )
-        SB.note_bank(self._bank_adapter)
-
-    def _ensure_ef_bank(self, state: ServerState) -> None:
-        """Create the bulk-mode error-feedback bank lazily: one zero
-        row per CLIENT of the wire template (round 0 transmits the
-        uncorrected delta, exactly like the stacked zero carry)."""
-        if self._ef_bank is not None:
-            return
-        self._ef_bank = SB.ClientStateBank.zeros(
-            "ef_residual", self._wire_template(state.variables),
-            self.arrays.num_clients,
-        )
-        telemetry.METRICS.gauge(
-            "compress.ratio",
-            C.wire_ratio(self._cspec,
-                         self._wire_template(state.variables)),
-        )
-        SB.note_bank(self._ef_bank)
+        if self._carry_kind == "adapter":
+            rows = PP.init_bank(
+                self._peft, state.variables["params"],
+                self.arrays.num_clients,
+            )
+            self._carry = SB.ClientStateBank("adapter", rows)
+            telemetry.METRICS.gauge(
+                "peft.personal_bank_mb", PP.bank_bytes(rows) / 1e6
+            )
+        else:
+            template = self._wire_template(state.variables)
+            self._carry = (
+                SB.ClientStateBank.zeros(
+                    "ef_residual", template, self.arrays.num_clients
+                )
+                if self._bulk.enabled()
+                else C.zero_residual(template, self._bucket)
+            )
+            telemetry.METRICS.gauge(
+                "compress.ratio", C.wire_ratio(self._cspec, template)
+            )
+        if isinstance(self._carry, SB.ClientStateBank):
+            SB.note_bank(self._carry)
 
     def bank_state(self) -> dict:
         """Client-state banks for the checkpoint composite
         (docs/FAULT_TOLERANCE.md "Client-state banks"): ``{name:
         savable rows}``, empty when no bank has been created yet (a
-        fresh run has nothing to save — and nothing to restore)."""
-        out = {}
-        if self._bank_adapter is not None:
-            out[self._bank_adapter.name] = self._bank_adapter.savable()
-        if self._ef_bank is not None:
-            out[self._ef_bank.name] = self._ef_bank.savable()
-        return out
+        fresh run has nothing to save — and nothing to restore) or the
+        carry is not a bank."""
+        c = self._carry
+        if not isinstance(c, SB.ClientStateBank):
+            return {}
+        return {c.name: c.savable()}
 
     def restore_banks(self, state: ServerState, blob) -> None:
         """Adopt checkpointed bank rows (the restore half of
@@ -1736,19 +1657,13 @@ class FedAvgSim:
         from a run without this bank — leaves the lazy fresh-bank init
         in place instead of crashing: the run resumes with round-0
         rows, which is exactly what a pre-bank checkpoint encoded."""
-        if not blob:
+        kind = self._carry_kind
+        if not blob or kind not in blob:
             return
-        if ("adapter" in blob and self._peft is not None
-                and self._peft.personalized):
-            self._ensure_adapter_bank(state)
-            self._bank_adapter = SB.ClientStateBank.from_savable(
-                "adapter", self._bank_adapter.rows, blob["adapter"]
-            )
-        if ("ef_residual" in blob and self._bulk.enabled()
-                and self._cspec.enabled()):
-            self._ensure_ef_bank(state)
-            self._ef_bank = SB.ClientStateBank.from_savable(
-                "ef_residual", self._ef_bank.rows, blob["ef_residual"]
+        self._ensure_carry(state)
+        if isinstance(self._carry, SB.ClientStateBank):
+            self._carry = SB.ClientStateBank.from_savable(
+                kind, self._carry.rows, blob[kind]
             )
 
     def _wire_template(self, variables):
@@ -1770,112 +1685,6 @@ class FedAvgSim:
         if self._peft is not None and self._peft.personalized:
             return "personal"
         return "stacked"
-
-    # -- public API --------------------------------------------------------
-    def run_round(self, state: ServerState):
-        if self._bulk.enabled():
-            self._note_bulk_dispatch()
-            if self._stream_defense is not None:
-                self._note_stream_defense(state)
-            key = self._program_key()
-            n = (
-                jnp.asarray(self._n_active, jnp.int32)
-                if self._elastic else None
-            )
-            if self._peft is not None and self._peft.personalized:
-                self._ensure_adapter_bank(state)
-
-                def call():
-                    return self._round_fn(
-                        key, state, self.arrays, n, None,
-                        self._bank_adapter,
-                    )
-
-                state, m, self._bank_adapter = (
-                    E.mirror_jit_cache(self._round_fn, call)
-                    if self._elastic else call()
-                )
-                SB.note_round_io(self._n_blocks, self._n_blocks)
-                return state, m
-            if self._cspec.enabled():
-                self._ensure_ef_bank(state)
-
-                def call():
-                    return self._round_fn(
-                        key, state, self.arrays, n, self._ef_bank
-                    )
-
-                state, m, self._ef_bank = (
-                    E.mirror_jit_cache(self._round_fn, call)
-                    if self._elastic else call()
-                )
-                SB.note_round_io(
-                    self._n_blocks
-                    * (2 if self._stream_defense else 1),
-                    self._n_blocks,
-                )
-                return state, m
-            if not self._elastic:
-                return self._round_fn(key, state, self.arrays)
-            return E.mirror_jit_cache(
-                self._round_fn,
-                lambda: self._round_fn(key, state, self.arrays, n),
-            )
-        if self._peft is not None and self._peft.personalized:
-            # the bank is a donated operand and comes back updated —
-            # the same thread-through discipline as the EF residual
-            self._ensure_adapter_bank(state)
-            n = (
-                jnp.asarray(self._n_active, jnp.int32)
-                if self._elastic else None
-            )
-
-            def call():
-                return self._round_fn(
-                    self._bucket, state, self.arrays, n, None,
-                    self._bank_adapter,
-                )
-
-            state, m, self._bank_adapter = (
-                E.mirror_jit_cache(self._round_fn, call)
-                if self._elastic else call()
-            )
-            SB.note_round_io(1, 1)
-            return state, m
-        compressed = self._cspec.enabled()
-        if compressed and self._ef_residual is None:
-            self._ef_residual = C.zero_residual(
-                self._wire_template(state.variables), self._bucket
-            )
-            telemetry.METRICS.gauge(
-                "compress.ratio",
-                C.wire_ratio(self._cspec,
-                             self._wire_template(state.variables)),
-            )
-        key = self._bucket
-        if not self._elastic:
-            if not compressed:
-                return self._round_fn(key, state, self.arrays)
-            state, m, self._ef_residual = self._round_fn(
-                key, state, self.arrays, None, self._ef_residual
-            )
-            return state, m
-        # the live count rides as a TRACED operand: any cohort size in
-        # [1, bucket] reuses the one compiled program; the ProgramSite
-        # is the executable store here
-        n = jnp.asarray(self._n_active, jnp.int32)
-        if not compressed:
-            return E.mirror_jit_cache(
-                self._round_fn,
-                lambda: self._round_fn(key, state, self.arrays, n),
-            )
-        state, m, self._ef_residual = E.mirror_jit_cache(
-            self._round_fn,
-            lambda: self._round_fn(
-                key, state, self.arrays, n, self._ef_residual
-            ),
-        )
-        return state, m
 
     def _global_eval(self):
         """The evaluator of the global test set and its operands after
@@ -1901,133 +1710,19 @@ class FedAvgSim:
 
     def run(self, metrics_sink=None) -> ServerState:
         """Round loop (reference ``fedavg_api.train``,
-        ``standalone/fedavg/fedavg_api.py:40-81``). With
-        ``cfg.fed.profile_rounds > 0`` the perf-observability layer
-        (core/perf.py) rides along: jax-profiler capture windows around
-        the first K rounds (device-time breakdown) and live ``perf.*``
-        gauges — round rate, MFU from the shared analytic cost model,
-        and the dispatch-bound detector — for every round. The round
-        wall time is taken AFTER the metric host conversion forces the
-        device, so it measures execution, not dispatch. With
-        ``cfg.fed.fuse_rounds > 1`` the loop advances in fused blocks
-        with pipelined host consumption (:meth:`_run_fused`)."""
-        import time as _time
-
-        from fedml_tpu.core import perf as P
-
-        state = self.init()
-        profiler, monitor = P.build_sim_perf(self)
-        try:
-            if self._fuse > 1:
-                return self._run_fused(
-                    state, metrics_sink, profiler, monitor
-                )
-            # ONE set of boundaries (core/tracing.span): each span is a
-            # profiler annotation, a ring event under --trace, and —
-            # where it names a phase — the anatomy plane's clock. They
-            # sit at sync points this loop ALREADY has (the dispatch
-            # return, the one batched device_get), so the off path is
-            # a flag check a span and nothing adds a device sync
-            path = self._anatomy_path()
-            for r in range(self.cfg.fed.num_rounds):
-                t0 = _time.perf_counter()
-                if profiler is not None:
-                    # before the span opens: an annotation is kept
-                    # only if its session was on when it began
-                    profiler.start_round(r)
-                with span("fedml.round", round=r):
-                    ANATOMY.begin_round(r, path=path)
-                    # enqueue (and any retrace); lands in host_gap
-                    with span("fedml.dispatch"):
-                        state, train_m = self.run_round(state)
-                    # ONE batched D2H for the whole metric dict instead
-                    # of a device sync per leaf: the host blocked on the
-                    # compiled round's execution (the sims run the whole
-                    # round as one program, so `local` carries it)
-                    with span("fedml.fetch", phase="local"):
-                        train_m = consume_round_counters(
-                            jax.device_get(dict(train_m))
-                        )
-                    record = {
-                        "round": r,
-                        **{k: float(v) if np.ndim(v) == 0
-                           else [float(u) for u in v]
-                           for k, v in train_m.items()},
-                    }
-                    if profiler is not None:
-                        profiler.end_round(r)
-                    if monitor is not None:
-                        monitor.note_round(_time.perf_counter() - t0)
-                    if (r + 1) % self.cfg.fed.eval_every == 0 or (
-                        r == self.cfg.fed.num_rounds - 1
-                    ):
-                        test_m = self.evaluate_global(state)
-                        record.update(
-                            {"test_acc": test_m["acc"],
-                             "test_loss": test_m["loss"]}
-                        )
-                    if metrics_sink is not None:
-                        with log_span(record):
-                            metrics_sink.log(record)
-                    ANATOMY.end_round()
-        finally:
-            if profiler is not None:
-                profiler.finish()
-        return state
-
-    def _run_fused(self, state, metrics_sink, profiler, monitor):
-        """Fused round loop (docs/PERFORMANCE.md "Round fusion"):
-        advance in blocks of up to ``fuse_rounds`` rounds, keeping
-        block k+1's dispatch in flight while the host converts block
-        k's stacked metrics (one batched transfer per block), and
-        syncing only at eval boundaries and profiler-capture windows.
-        The loop itself is ``core.fuse.drive`` (shared with the
-        harness's fused loop); boundary placement
-        (``core.fuse.plan_blocks``) guarantees eval runs on exactly
-        the same round's state as the unfused loop, even when
-        ``eval_every % fuse_rounds != 0``."""
+        ``standalone/fedavg/fedavg_api.py:40-81``): ``core.fuse.run_loop``
+        from a fresh ``init()``, in fused blocks with pipelined host
+        consumption when ``cfg.fed.fuse_rounds > 1``. The three calls
+        are looked up on the instance at every round."""
         from fedml_tpu.core import fuse as F
 
-        cfg = self.cfg.fed
-        box = [state]
-
-        def run_block(length):
-            box[0], dm = self.run_block(box[0], length)
-            return dm
-
-        def make_records(start, rows):
-            return [
-                {"round": start + i,
-                 **{k: float(v) for k, v in
-                    consume_round_counters(row).items()}}
-                for i, row in enumerate(rows)
-            ]
-
-        def log(rec):
-            if metrics_sink is not None:
-                with log_span(rec):
-                    metrics_sink.log(rec)
-
-        def boundary_hook(r_last, last):
-            if (r_last + 1) % cfg.eval_every == 0 or (
-                r_last == cfg.num_rounds - 1
-            ):
-                # the block's anatomy entry closed at the pipeline
-                # flush; fuse.drive runs this hook in amending mode, so
-                # the fedml.eval span's phase lands on that entry
-                test_m = self.evaluate_global(box[0])
-                last.update({"test_acc": test_m["acc"],
-                             "test_loss": test_m["loss"]})
-            log(last)
-
-        F.drive(
-            run_block,
-            F.plan_blocks(0, cfg.num_rounds, self._fuse,
-                          cfg.eval_every),
-            profiler=profiler,
-            monitor=monitor,
-            make_records=make_records,
-            log=log,
-            boundary_hook=boundary_hook,
+        return F.run_loop(
+            self, self.init(), metrics_sink,
+            step=lambda state, r: self.run_round(state),
+            run_block=lambda state, n: self.run_block(state, n),
+            evaluate=lambda state: self.evaluate_global(state),
+            path=self._anatomy_path(),
+            total=self.cfg.fed.num_rounds,
+            eval_every=self.cfg.fed.eval_every,
+            fuse=self._fuse,
         )
-        return box[0]

@@ -38,7 +38,7 @@ The deploy server actor wires its own ``perf.agg_wall_s`` /
 ``perf.host_wait_s`` accounting (the server-side time accounting the
 Smart-NIC FL serving work optimizes against, arxiv 2307.06561) in
 ``algorithms/distributed_fedavg.py``; the sims wire this module through
-``FedAvgSim.run`` and the experiment harness.
+the one round loop, ``core/fuse.run_loop``.
 """
 
 from __future__ import annotations
@@ -667,8 +667,8 @@ class PerfMonitor:
 
 def build_sim_perf(sim) -> tuple[RoundProfiler | None,
                                  PerfMonitor | None]:
-    """Perf wiring for a round-loop driver (``FedAvgSim.run`` and the
-    experiment harness share this so the two loops cannot drift).
+    """Perf wiring of the round loop (``core/fuse.run_loop``, which
+    ``FedAvgSim.run`` and the experiment harness both call).
     Returns ``(None, None)`` unless ``cfg.fed.profile_rounds > 0`` —
     the off path costs one attribute read. The analytic round cost
     covers the FedAvg family; other sims still get wall-clock gauges

@@ -53,7 +53,7 @@ import time
 import uuid
 from typing import Any
 
-from fedml_tpu.core.tracing import Tracer, span
+from fedml_tpu.core.tracing import Tracer
 
 
 def percentiles_from_histogram(
@@ -425,11 +425,6 @@ def set_current_trace(trace_id: str | None) -> None:
 
 def current_trace() -> str | None:
     return getattr(_tls, "trace", None)
-
-
-#: The one span primitive (core/tracing.py): always a profiler
-#: annotation, plus a ring event while :data:`TRACER` is configured.
-maybe_span = span
 
 
 def flight_dump(reason: str, **fields) -> str | None:

@@ -15,8 +15,9 @@ import os
 from typing import Any, Callable
 
 from fedml_tpu.config import ExperimentConfig
+from fedml_tpu.core import fuse as F
 from fedml_tpu.core import telemetry
-from fedml_tpu.core.tracing import log_span
+from fedml_tpu.core.tracing import span
 from fedml_tpu.data.loaders import load_dataset
 from fedml_tpu.metrics.sink import MetricsSink
 from fedml_tpu.models import create_model
@@ -374,9 +375,7 @@ class Experiment:
                     # run-shaped sims drive their own loop: one span
                     # covers the whole trajectory (round-level spans
                     # come from the generic loop below otherwise)
-                    with telemetry.maybe_span(
-                        "fedml.run", sim=type(sim).__name__
-                    ):
+                    with span("fedml.run", sim=type(sim).__name__):
                         sim.run(metrics_sink=sink)
                     return
                 except TypeError:
@@ -413,34 +412,18 @@ class Experiment:
 
     @staticmethod
     def _round_loop(sim, cfg, sink, state, start_round, ckpt):
-        import time as _time
-
-        from fedml_tpu.core import perf as P
-
-        # perf observability (core/perf.py): same wiring as
-        # FedAvgSim.run for sims the generic loop drives
-        # (checkpointable runs, run_round-protocol sims). Inert unless
-        # cfg.fed.profile_rounds > 0.
-        profiler, monitor = P.build_sim_perf(sim)
-        try:
-            Experiment._instrumented_loop(
-                sim, cfg, sink, state, start_round, ckpt, profiler,
-                monitor, _time,
-            )
-        finally:
-            if profiler is not None:
-                profiler.finish()
-
-    @staticmethod
-    def _instrumented_loop(sim, cfg, sink, state, start_round, ckpt,
-                           profiler, monitor, _time):
+        """The generic protocol, as hooks of the ONE round loop
+        (``core.fuse.run_loop`` — the loop ``FedAvgSim.run`` calls, so
+        a checkpointed run is timed and traced like any other): a round
+        is ``run_round()`` for host-driven sims without state
+        (HeteroFedGDKD), ``run_round(state, r)`` or ``run_round(state)``;
+        the evaluator is the first of the known protocol names; a
+        checkpoint is the after-round hook and a resume the start state
+        and round. ``fuse_rounds`` > 1 drives ``run_block`` sims in
+        blocks that end on the eval / checkpoint rounds."""
         fuse = int(getattr(cfg.fed, "fuse_rounds", 1) or 1)
-        if fuse > 1:
-            if hasattr(sim, "run_block") and state is not None:
-                return Experiment._fused_loop(
-                    sim, cfg, sink, state, start_round, ckpt, profiler,
-                    monitor, _time,
-                )
+        fusable = hasattr(sim, "run_block") and state is not None
+        if fuse > 1 and not fusable:
             import warnings
 
             warnings.warn(
@@ -450,8 +433,16 @@ class Experiment:
                 "running per-round",
                 stacklevel=2,
             )
-        for r in range(start_round, cfg.fed.num_rounds):
-            t0 = _time.perf_counter()
+        total = cfg.fed.num_rounds
+        wants_round = _wants_round(sim)
+
+        def step(state, r):
+            if state is None:
+                return None, sim.run_round()
+            return (sim.run_round(state, r) if wants_round
+                    else sim.run_round(state))
+
+        def after_round(r, state):
             if telemetry.METRICS.enabled:
                 # /statusz "run" block (core/export.py): the sim loop
                 # has no actor to register, so the live round rides
@@ -459,54 +450,28 @@ class Experiment:
                 from fedml_tpu.core import export as _export
 
                 _export.set_run_state(
-                    round=r, num_rounds=cfg.fed.num_rounds,
-                    run_name=cfg.run_name,
+                    round=r, num_rounds=total, run_name=cfg.run_name,
                 )
-            if profiler is not None:
-                profiler.start_round(r)
-            with telemetry.maybe_span("fedml.round", round=r):
-                with telemetry.maybe_span("fedml.dispatch"):
-                    if state is None:  # host-driven sims (HeteroFedGDKD)
-                        m = sim.run_round()
-                    else:
-                        out = (
-                            sim.run_round(state, r)
-                            if _wants_round(sim)
-                            else sim.run_round(state)
-                        )
-                        state, m = out
-                record = {"round": r}
-                if start_round:
-                    # this incarnation resumed mid-run: its rows win
-                    # over any pre-crash row for the same round
-                    record["resumed"] = True
-                if isinstance(m, dict):
-                    from fedml_tpu.algorithms.fedavg import (
-                        consume_round_counters,
-                    )
+            if ckpt is not None and (
+                (r + 1) % cfg.checkpoint_every == 0 or r == total - 1
+            ):
+                Experiment._save_state(ckpt, sim, r, state)
 
-                    with telemetry.maybe_span("fedml.fetch"):
-                        m = consume_round_counters(_batched_get(dict(m)))
-                    record.update({k: _f(v) for k, v in m.items()
-                                   if _scalar(v)})
-                # the scalar conversion above forced the round's
-                # metrics to host, so the capture window and wall time
-                # cover the device execution, not just the dispatch
-                if profiler is not None:
-                    profiler.end_round(r)
-                if monitor is not None:
-                    monitor.note_round(_time.perf_counter() - t0)
-                if (r + 1) % cfg.fed.eval_every == 0 or (
-                    r == cfg.fed.num_rounds - 1
-                ):
-                    record.update(Experiment._eval_record(sim, state))
-                with log_span(record):
-                    sink.log(record)
-                if ckpt is not None and (
-                    (r + 1) % cfg.checkpoint_every == 0
-                    or r == cfg.fed.num_rounds - 1
-                ):
-                    Experiment._save_state(ckpt, sim, r, state)
+        F.run_loop(
+            sim, state, sink,
+            step=step,
+            run_block=sim.run_block if fusable else None,
+            evaluate=lambda state: Experiment._evaluate(sim, state),
+            path=getattr(sim, "_anatomy_path", lambda: "stacked")(),
+            start=start_round,
+            total=total,
+            eval_every=cfg.fed.eval_every,
+            fuse=fuse,
+            checkpoint_every=(
+                cfg.checkpoint_every if ckpt is not None else 0
+            ),
+            after_round=after_round,
+        )
 
     @staticmethod
     def _save_state(ckpt, sim, r, state):
@@ -548,90 +513,15 @@ class Experiment:
         return restored, nxt
 
     @staticmethod
-    def _eval_record(sim, state) -> dict:
-        """Run the sim's evaluator (first of the known protocol names)
-        and normalize bare test-split {acc, loss} to the test_* names
-        the summary consumers (battery table, wandb groupings) key
-        on."""
+    def _evaluate(sim, state) -> dict:
+        """Run the sim's evaluator (first of the known protocol names);
+        ``core.fuse.eval_record`` names its fields for the record."""
         for ev_name in ("evaluate_global", "evaluate_clients",
                         "evaluate_consensus", "evaluate"):
             if hasattr(sim, ev_name):
-                ev = getattr(sim, ev_name)(state) if state is not \
-                    None else getattr(sim, ev_name)()
-                rename = {"acc": "test_acc", "loss": "test_loss"}
-                return {rename.get(k, k): _f(v)
-                        for k, v in ev.items() if _scalar(v)}
+                ev = getattr(sim, ev_name)
+                return ev(state) if state is not None else ev()
         return {}
-
-    @staticmethod
-    def _fused_loop(sim, cfg, sink, state, start_round, ckpt, profiler,
-                    monitor, _time):
-        """Block-driven round loop for run_block sims (docs/
-        PERFORMANCE.md "Round fusion"): dispatch blocks of up to
-        ``fuse_rounds`` rounds, convert the PREVIOUS block's stacked
-        metrics while the current one runs on device (one batched
-        transfer per block), and sync only at eval / checkpoint /
-        profiler-capture boundaries. The loop itself is
-        ``core.fuse.drive`` (shared with ``FedAvgSim._run_fused``);
-        ``core.fuse.plan_blocks`` places boundaries so evaluation and
-        checkpoints see exactly the same round's state as the
-        per-round loop."""
-        from fedml_tpu.core import fuse as F
-        from fedml_tpu.algorithms.fedavg import consume_round_counters
-
-        ckpt_every = cfg.checkpoint_every if ckpt is not None else 0
-        total = cfg.fed.num_rounds
-        box = [state]
-
-        def run_block(length):
-            box[0], dm = sim.run_block(box[0], length)
-            return dm
-
-        def make_records(start, rows):
-            records = []
-            for i, row in enumerate(rows):
-                row = consume_round_counters(row)
-                rec = {"round": start + i}
-                if start_round:
-                    rec["resumed"] = True
-                rec.update({k: _f(v) for k, v in row.items()
-                            if _scalar(v)})
-                records.append(rec)
-            return records
-
-        def log(rec):
-            with log_span(rec):
-                sink.log(rec)
-
-        def boundary_hook(r_last, last):
-            if telemetry.METRICS.enabled:
-                from fedml_tpu.core import export as _export
-
-                _export.set_run_state(
-                    round=r_last, num_rounds=total,
-                    run_name=cfg.run_name,
-                )
-            if (r_last + 1) % cfg.fed.eval_every == 0 or (
-                r_last == total - 1
-            ):
-                last.update(Experiment._eval_record(sim, box[0]))
-            log(last)
-            if ckpt is not None and (
-                (r_last + 1) % cfg.checkpoint_every == 0
-                or r_last == total - 1
-            ):
-                Experiment._save_state(ckpt, sim, r_last, box[0])
-
-        F.drive(
-            run_block,
-            F.plan_blocks(start_round, total, int(cfg.fed.fuse_rounds),
-                          cfg.fed.eval_every, ckpt_every),
-            profiler=profiler,
-            monitor=monitor,
-            make_records=make_records,
-            log=log,
-            boundary_hook=boundary_hook,
-        )
 
 
 def _wants_round(sim) -> bool:
@@ -657,27 +547,6 @@ def _run_accepts_sink(sim) -> bool:
     return "metrics_sink" in params or any(
         p.kind == inspect.Parameter.VAR_KEYWORD for p in params.values()
     )
-
-
-def _batched_get(m: dict) -> dict:
-    """Fetch every device-array leaf of a round's metric dict in ONE
-    batched ``jax.device_get`` (async copies first, then one block)
-    instead of a device sync per ``float(leaf)``; non-array values
-    (host-driven sims mix types) pass through untouched."""
-    import jax
-
-    arrs = {k: v for k, v in m.items() if isinstance(v, jax.Array)}
-    if arrs:
-        m = {**m, **jax.device_get(arrs)}
-    return m
-
-
-def _scalar(v) -> bool:
-    return isinstance(v, (int, float)) or getattr(v, "ndim", None) == 0
-
-
-def _f(v):
-    return float(v)
 
 
 def _ensure(path: str) -> str:

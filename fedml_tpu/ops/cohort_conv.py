@@ -122,73 +122,8 @@ def _out_spatial(i, pad, k, s, d, ld=1):
 # ---------------------------------------------------------------------------
 
 
-# Minimum per-group width for supergroup packing — DISABLED by default
-# (10**9). Measured on v5e: packing 64-wide groups to 128 lanes wins
-# ~1.5x per-op in isolation (XLA dense-expands narrow groups, so the
-# isolated grouped conv runs at ~15% useful MFU vs ~45% packed), but
-# INSIDE the full fat-model gradient the same rewrite is a net 1.3x
-# REGRESSION (57ms -> 75ms round): the kernel-construction ops defeat
-# XLA's conv/BN fusion choices around every conv. Kept (with exact
-# numerics, tested) for experimentation via FEDML_TPU_PACK_MIN_CIG=64;
-# see docs/PERFORMANCE.md for the measurement story.
-import os as _os
-
-_PACK_MIN_CIG = int(_os.environ.get("FEDML_TPU_PACK_MIN_CIG", str(10**9)))
-
-
-def _pack_factor(cig: int, groups: int) -> int:
-    """How many adjacent groups to pack block-diagonally into one
-    supergroup so per-group input width reaches the MXU's 128 lanes.
-
-    XLA lowers grouped convolutions with narrow groups by DENSE EXPANSION
-    (measured on v5e: a 10-group 64-ch/group conv costs the same as the
-    full 640-ch dense conv — 10x the useful FLOPs), but lowers >=128-wide
-    groups natively at ~45% MFU fwd+bwd. Packing ``p`` adjacent groups
-    into one group with a block-diagonal kernel trades ``p``x FLOPs
-    (p << groups) for the native lowering. Returns the smallest PROPER
-    divisor of ``groups`` whose packed width reaches 128 lanes
-    (p == groups would just re-create the dense expansion); 1 (stock
-    path) when none does, when groups are already wide, or for
-    depthwise-class convs (cig < 16 — XLA's dedicated depthwise lowering
-    beats a ~128x FLOP inflation on bandwidth-bound ops)."""
-    if cig >= 128 or groups == 1 or cig < max(_PACK_MIN_CIG, 16):
-        # cig < 16 is a hard floor regardless of the env knob: depthwise-
-        # class convs have XLA's dedicated lowering, and a >=128-lane
-        # block-diagonal form would inflate their FLOPs ~16-128x.
-        return 1
-    for p in range(2, groups):
-        if groups % p == 0 and cig * p >= 128:
-            return p
-    return 1
-
-
-def _pack_blockdiag(w, fgc: int, pack: int):
-    """Rewrite a grouped-conv kernel ``[kh, kw, cig, co]`` (out channels
-    group-major over ``fgc`` groups) as the equivalent supergrouped kernel
-    ``[kh, kw, pack*cig, co]`` for ``fgc // pack`` groups: each supergroup
-    packs ``pack`` adjacent groups block-diagonally, off-diagonal blocks
-    exact zeros (values unchanged — x + 0 is exact)."""
-    kh, kw, cig, co = w.shape
-    cog = co // fgc
-    # Row-block p_i of the supergroup kernel holds w's columns whose
-    # group lands at pack-position p_i, zeros elsewhere. Built as pack
-    # mask-multiplies + one concat (elementwise, fusion-friendly; its
-    # linear transpose — the dw path — is mask-multiplies of the split
-    # gradient, equally cheap). No scatters or high-rank transposes:
-    # those lowered badly inside large programs on TPU.
-    group_pos = (jnp.arange(co) // cog) % pack
-    blocks = [
-        w * (group_pos == p_i).astype(w.dtype) for p_i in range(pack)
-    ]
-    return jnp.concatenate(blocks, axis=2)
-
-
 def _lax_fwd(x, w, *, strides, padding, fgc, rhs_dilation,
              lhs_dilation=(1, 1), **_):
-    pack = _pack_factor(w.shape[2], fgc)
-    if pack > 1:
-        w = _pack_blockdiag(w, fgc, pack)
-        fgc = fgc // pack
     return lax.conv_general_dilated(
         x,
         w,
@@ -222,9 +157,7 @@ def _cohort_fwd(x_b, w_b, *, strides, padding, fgc, rhs_dilation,
                 lhs_dilation=(1, 1), **_):
     """Batched-over-(x, w) conv as ONE grouped conv: clients become channel
     groups. Bit-identical to ``vmap(conv)`` — group c of the grouped conv
-    sees exactly client c's channels and kernel. Narrow groups are then
-    supergroup-packed by :func:`_lax_fwd` exactly like the unbatched
-    (cohort-grouped-model) path."""
+    sees exactly client c's channels and kernel."""
     C, B, H, W, ci = x_b.shape
     _, kh, kw, cig, co = w_b.shape
     xg = x_b.transpose(1, 2, 3, 0, 4).reshape(B, H, W, C * ci)

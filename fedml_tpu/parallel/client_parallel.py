@@ -44,7 +44,6 @@ from fedml_tpu.core import elastic as E
 from fedml_tpu.core import memscope as M
 from fedml_tpu.core import random as R
 from fedml_tpu.core import robust
-from fedml_tpu.core import statebank as SB
 from fedml_tpu.data.federated import FederatedData, shard_client_banks
 from fedml_tpu.algorithms.base import (
     build_cohort_local_update,
@@ -228,17 +227,16 @@ class ShardedFedAvg(FedAvgSim):
         # (core/memscope.py): compile wall + memory_analysis recorded
         # per program, the donated state audited on first execution.
         # Personalized PEFT donates the adapter ClientStateBank too
-        # (operand 4, the single-device layout) — it shards over the
+        # (the carry, the single-device layout) — it shards over the
         # client axis inside the round, each shard owning its own
         # K-row slice.
-        personalized = self._peft is not None and self._peft.personalized
         self._round_fn = M.ProgramSite(
             self._sharded_round,
             family=(
                 "sharded_bulk" if self._bulk.enabled()
                 else "sharded_round"
             ),
-            donate_argnums=(0, 4) if personalized else (0,),
+            donate_argnums=self._donate_argnums(),
         )
         # round fusion (docs/PERFORMANCE.md "Round fusion"): the
         # inherited _fused_block scans over whatever _round_impl names
@@ -331,18 +329,17 @@ class ShardedFedAvg(FedAvgSim):
         return self._mesh_evaluator, self._test_rows
 
     def _sharded_round(self, state: ServerState, banks, n_active=None,
-                       residual=None, bank=None):
-        """One mesh round. The trailing ``(residual, bank)`` operands
-        mirror :meth:`FedAvgSim._round`'s layout (the inherited fused
-        block calls through it): compression is rejected at
-        construction so ``residual`` is always None; ``bank`` is the
-        personalized-PEFT adapter :class:`~fedml_tpu.core.statebank.
-        ClientStateBank`, sharded over the client axis — inside the
+                       bank=None):
+        """One mesh round, with :meth:`FedAvgSim._round`'s signature
+        and ``(state, metrics, carry)`` result (the inherited dispatch
+        and fused block call through it). Compression is rejected at
+        construction, so the carry is the personalized-PEFT adapter
+        :class:`~fedml_tpu.core.statebank.ClientStateBank` or None;
+        it is sharded over the client axis — inside the
         shard each body sees its own ``[K, ...]`` slice (local ids,
         local sentinel ``K``) and returns the updated slice, which
         shard_map stitches back to the full ``[num_clients, ...]``
         bank."""
-        del residual  # compress is rejected at construction
         cfg = self.cfg.fed
         rkey = R.round_key(self.root_key, state.round)
         ckey = jax.random.fold_in(rkey, 0)
@@ -353,15 +350,12 @@ class ShardedFedAvg(FedAvgSim):
         rep = P()
         red = psum_reducer(self.client_axis)
 
-        def shard_fn(state, x, y, idx, mask, *rest):
+        def shard_fn(state, x, y, idx, mask, bank_l, n_act):
             # leading shard axis arrives with extent 1 inside the shard
             x, y = x[0], y[0]
             idx, mask = idx[0], mask[0]
-            rest = list(rest)
             # the bank slice's leading axis is the CLIENT axis itself
             # (num_clients -> K per shard): no extent-1 unwrap
-            bank_l = rest.pop(0) if bank is not None else None
-            n_act = rest[0] if rest else None
             shard = jax.lax.axis_index(self.client_axis)
             if self._bulk.enabled():
                 return self._bulk_shard_body(
@@ -455,30 +449,21 @@ class ShardedFedAvg(FedAvgSim):
                     metrics["slot_steps"] = jax.lax.psum(
                         slot_steps, self.client_axis
                     )
-            return new_state, metrics
+            return new_state, metrics, None
 
-        in_specs = (rep, cspec, cspec, cspec, cspec)
-        operands = (state, banks.x, banks.y, banks.idx, banks.mask)
-        if bank is not None:
-            # the adapter bank shards like the sample banks: P on the
-            # leading (client) axis of every row leaf — shard s owns
-            # rows [s*K, (s+1)*K) of the global bank
-            in_specs += (cspec,)
-            operands += (bank,)
-        if n_active is not None:
-            # the live count is a REPLICATED operand (not a closure):
-            # closed-over tracers under shard_map are version-fragile
-            in_specs += (rep,)
-            operands += (n_active,)
-        out_specs = (rep, rep, cspec) if bank is not None else (rep, rep)
-        out = shard_map(
+        # the adapter bank shards like the sample banks: P on the
+        # leading (client) axis of every row leaf — shard s owns rows
+        # [s*K, (s+1)*K) of the global bank. The live count is a
+        # REPLICATED operand (not a closure): closed-over tracers under
+        # shard_map are version-fragile. Either may be None, an empty
+        # pytree: its spec then names nothing
+        return shard_map(
             shard_fn,
             mesh=self.mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
+            in_specs=(rep, cspec, cspec, cspec, cspec, cspec, rep),
+            out_specs=(rep, rep, cspec),
             check_vma=False,
-        )(*operands)
-        return out
+        )(state, banks.x, banks.y, banks.idx, banks.mask, bank, n_active)
 
     def _bulk_shard_body(self, state, x, y, idx, mask, shard, rkey,
                          ckey, K, n_act, bank=None):
@@ -564,7 +549,7 @@ class ShardedFedAvg(FedAvgSim):
             fin = finalize_sums(partials.msums)
         return new_state, {
             "train_loss": fin["loss"], "train_acc": fin["acc"],
-        }
+        }, None
 
     def _local_personal_update(self, state, x, y, idx, mask,
                                shard, rkey, K, ids, priv):
@@ -710,48 +695,10 @@ class ShardedFedAvg(FedAvgSim):
             "nonfinite_rejected": partials.rejected,
         }, bank
 
-    def _program_key(self) -> tuple:
-        return (self._shard_blocks, self._block_size)
+    def _program_key(self):
+        if self._bulk.enabled():
+            return (self._shard_blocks, self._block_size)
+        return self.bucket_per_shard
 
     def _round_operand(self):
         return self.banks
-
-    def run_round(self, state):
-        personalized = (
-            self._peft is not None and self._peft.personalized
-        )
-        if self._bulk.enabled():
-            self._note_bulk_dispatch()
-            key = self._program_key()
-        else:
-            key = self.bucket_per_shard
-        n = (
-            jnp.asarray(self._n_active, jnp.int32)
-            if self._elastic else None
-        )
-        if personalized:
-            # the adapter bank is a donated operand and comes back
-            # updated (the single-device thread-through discipline);
-            # per round each shard gathers+scatters its own slice once
-            # per block
-            self._ensure_adapter_bank(state)
-
-            def call():
-                return self._round_fn(
-                    key, state, self.banks, n, None,
-                    self._bank_adapter,
-                )
-
-            state, m, self._bank_adapter = (
-                E.mirror_jit_cache(self._round_fn, call)
-                if self._elastic else call()
-            )
-            io = self._n_blocks if self._bulk.enabled() else 1
-            SB.note_round_io(io, io)
-            return state, m
-        if not self._elastic:
-            return self._round_fn(key, state, self.banks)
-        return E.mirror_jit_cache(
-            self._round_fn,
-            lambda: self._round_fn(key, state, self.banks, n),
-        )

@@ -167,7 +167,7 @@ def main() -> int:
     acc1 = sim.evaluate_global(state)["acc"]
     assert last < first, f"loss did not fall: {first} -> {last}"
     assert acc1 > acc0 + 0.15, f"no convergence: {acc0} -> {acc1}"
-    assert sim._ef_bank is not None and sim._stream_defense == "median"
+    assert sim._carry is not None and sim._stream_defense == "median"
 
     # -- 2. flat bytes across the cohort sweep, banks riding -------------
     foot = {}

@@ -167,16 +167,18 @@ def test_entries_keep_their_keys_and_the_loops_hold_no_clock_pair(
                           "dominant", "ts"}
         assert {"local", "eval", "host_gap"} == set(e["phases"])
         assert abs(sum(e["phases"].values()) - e["wall_s"]) <= CONSERVE_TOL
-    for fn in (FedAvgSim.run, FedAvgSim._run_fused, fuse.drive,
+    for fn in (FedAvgSim.run, fuse.drive_rounds, fuse.drive,
                fuse.BlockPipeline.flush):
         src = inspect.getsource(fn)
         assert "ANATOMY.phase(" not in src and "amend_last(" not in src
         for gone in ("t_disp", "t_ev", "t_get", "get_wait"):
             assert gone not in src, (fn.__qualname__, gone)
-    # one clock read is left in each loop: the perf monitor's round wall
-    assert inspect.getsource(FedAvgSim.run).count("perf_counter()") == 2
-    assert inspect.getsource(FedAvgSim._run_fused).count(
-        "perf_counter") == 0
+    # one clock read is left in each loop: the perf monitor's round
+    # wall; the simulator itself holds no loop and so no clock
+    assert inspect.getsource(fuse.drive_rounds).count(
+        "perf_counter()") == 2
+    assert inspect.getsource(fuse.run_loop).count("perf_counter") == 0
+    assert inspect.getsource(FedAvgSim.run).count("perf_counter") == 0
 
 
 def test_amend_last_conserves(anatomy_env):

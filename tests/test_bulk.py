@@ -317,8 +317,8 @@ def test_bulk_compress_composes():
     contract and the telescoping pin live in tests/test_statebank.py.)"""
     sim = _sim(_cfg(client_block_size=4, compress="int8"))
     _, ms = _run(sim, 3)
-    assert sim._ef_bank is not None
-    assert sim._ef_bank.num_rows == 8  # one row per CLIENT, not slot
+    assert sim._carry is not None
+    assert sim._carry.num_rows == 8  # one row per CLIENT, not slot
     assert ms[-1]["train_loss"] < ms[0]["train_loss"]
     # both codecs construct
     _sim(_cfg(client_block_size=4, compress="topk_int8"))

@@ -309,7 +309,7 @@ def test_sim_compress_off_byte_identical():
     for la, lb in zip(jax.tree.leaves(sa.variables),
                       jax.tree.leaves(sb.variables)):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-    assert a._ef_residual is None and b._ef_residual is None
+    assert a._carry is None and b._carry is None
 
 
 def test_sim_compressed_round_runs_and_reports_residual():
@@ -320,7 +320,7 @@ def test_sim_compressed_round_runs_and_reports_residual():
     assert "compress_residual_norm" in m
     assert np.isfinite(float(m["train_loss"]))
     # the carry is live and model-shaped at the bucket extent
-    assert jax.tree.leaves(sim._ef_residual)[0].shape[0] == 4
+    assert jax.tree.leaves(sim._carry)[0].shape[0] == 4
 
 
 def test_sim_elastic_compressed_churn():

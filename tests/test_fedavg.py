@@ -281,20 +281,3 @@ def test_resolve_cohort_groups_policy():
     assert _resolve_cohort_groups(7, 10) == 5
     assert _resolve_cohort_groups(4, 9) == 3
     assert _resolve_cohort_groups(1, 8) == 1
-
-
-def test_pack_factor_policy():
-    from fedml_tpu.ops import cohort_conv as cc
-
-    old = cc._PACK_MIN_CIG
-    try:
-        cc._PACK_MIN_CIG = 64  # enable for the test
-        assert cc._pack_factor(64, 10) == 2   # 128 lanes
-        assert cc._pack_factor(128, 10) == 1  # already wide
-        assert cc._pack_factor(8, 64) == 1    # depthwise floor
-        assert cc._pack_factor(64, 1) == 1    # single group
-        assert cc._pack_factor(64, 2) == 1    # p==groups would be dense
-        cc._PACK_MIN_CIG = 10**9  # the shipped default: never packs
-        assert cc._pack_factor(64, 10) == 1
-    finally:
-        cc._PACK_MIN_CIG = old

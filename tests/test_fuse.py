@@ -223,12 +223,96 @@ def test_block_donates_ef_residual():
     sim = _sim(_cfg(fuse_rounds=2, compress="int8"))
     state = sim.init()
     state, _ = sim.run_block(state, 2)  # materializes the residual
-    old_res_leaf = jax.tree.leaves(sim._ef_residual)[0]
+    old_res_leaf = jax.tree.leaves(sim._carry)[0]
     state, _ = sim.run_block(state, 2)
     jax.block_until_ready(jax.tree.leaves(state))
     assert old_res_leaf.is_deleted(), (
         "the EF residual is a donated scan carry"
     )
+
+
+# ---------------------------------------------------------------------------
+# 4b. one call contract: (state, operand, n_active, carry) -> (state,
+#     metrics, carry), whatever the configuration
+# ---------------------------------------------------------------------------
+
+_FEATURES = {
+    # feature -> (fed kwargs, the carry the configuration names)
+    "plain": ({}, None),
+    "elastic": ({"elastic_buckets": True}, None),
+    "compressed": ({"compress": "int8"}, "ef_residual"),
+    "personalized": (None, "adapter"),  # tests.test_statebank._peft_cfg
+}
+
+
+@pytest.mark.parametrize("bulk", [False, True], ids=["stacked", "bulk"])
+@pytest.mark.parametrize("feature", list(_FEATURES))
+def test_run_round_one_contract(feature, bulk):
+    """Every accepted combination of {plain, elastic, compressed,
+    personalized} x {stacked, bulk} goes through the ONE dispatch:
+    ``run_round`` returns ``(state, metrics)``, the carry kept between
+    rounds is the one the configuration names (None otherwise), and a
+    feature the configuration lacks is NO operand, donation or result
+    of the compiled program — the plain program has the parent's
+    operands (state + data) and donates the state alone."""
+    from fedml_tpu.core import memscope
+    from fedml_tpu.core import statebank as SB
+    from tests.test_statebank import _peft_cfg, _peft_sim
+
+    fed_kw, kind = _FEATURES[feature]
+    block = {"client_block_size": 2} if bulk else {}
+    if feature == "personalized":
+        sim = _peft_sim(_peft_cfg(cohort=4, **block))
+    else:
+        sim = _sim(_cfg(**fed_kw, **block))
+    assert sim._carry_kind == kind and sim._carry is None
+    telemetry.METRICS.enabled = True
+    telemetry.METRICS.reset()
+    memscope.reset()
+    try:
+        state = sim.init()
+        n_state = len(jax.tree.leaves(state))
+        n_data = len(jax.tree.leaves(sim._round_operand()))
+        out = sim.run_round(state)
+        assert len(out) == 2
+        state, m = out
+        assert np.isfinite(float(m["train_loss"]))
+        carry = sim._carry
+        if kind is None:
+            assert carry is None
+        elif kind == "adapter" or bulk:
+            # client-id-keyed rows, named as the checkpoint names them
+            assert isinstance(carry, SB.ClientStateBank)
+            assert carry.name == kind
+            assert carry.num_rows == sim.arrays.num_clients
+            assert set(sim.bank_state()) == {kind}
+        else:
+            # the stacked round's slot-keyed residual: [bucket, ...]
+            assert {leaf.shape[0] for leaf in jax.tree.leaves(carry)} \
+                == {sim._bucket}
+            assert sim.bank_state() == {}
+        n_carry = len(jax.tree.leaves(carry))
+        (exe,) = sim._round_fn._exes.values()
+        args = jax.tree.leaves(
+            exe.args_info, is_leaf=lambda a: hasattr(a, "donated")
+        )
+        assert len(args) == (
+            n_state + n_data + int(sim._elastic) + n_carry
+        )
+        assert sum(a.donated for a in args) == n_state + n_carry
+        assert sim._round_fn._donate == ((0, 3) if kind else (0,))
+        # the carry is threaded: the next round consumes (donates) it
+        state, m = sim.run_round(state)
+        jax.block_until_ready(jax.tree.leaves(state))
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(carry))
+        assert type(sim._carry) is type(carry)
+        c = telemetry.METRICS.snapshot()["counters"]
+        assert c["mem.donation_audits"] == 1
+        assert c.get("mem.donation_misses", 0) == 0
+    finally:
+        telemetry.METRICS.enabled = False
+        telemetry.METRICS.reset()
+        memscope.reset()
 
 
 # ---------------------------------------------------------------------------
@@ -296,8 +380,8 @@ def test_fuse_compress_parity_and_residual_carry(method):
         assert "compress_residual_norm" in b
     for a, b in zip(_leaves(s_u), _leaves(s_f)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
-    for a, b in zip(_leaves(sim_u._ef_residual),
-                    _leaves(sim_f._ef_residual)):
+    for a, b in zip(_leaves(sim_u._carry),
+                    _leaves(sim_f._carry)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
@@ -436,6 +520,75 @@ def test_harness_fused_loop_checkpoint_boundary(tmp_path):
     assert os.path.isdir(ckpt_dir) and os.listdir(ckpt_dir)
 
 
+def _decoder_sim(block):
+    from tests import test_decoder as D
+
+    return D._sim(D.TD.tiny_config(), block)
+
+
+def _sharded_sim(_block):
+    from fedml_tpu.parallel import ShardedFedAvg, make_mesh
+
+    cfg = dataclasses.replace(
+        _cfg(num_clients=16, rounds=3, cohort=8, eval_every=2),
+        mesh=MeshConfig(client_axis_size=4, data_axis_size=1),
+    )
+    return ShardedFedAvg(
+        create_model(cfg.model), load_dataset(cfg.data), cfg,
+        make_mesh(client_axis=4, data_axis=1),
+    )
+
+
+@pytest.mark.parametrize("build,block,path", [
+    (_decoder_sim, 0, "stacked"),   # FedAvgSim
+    (_decoder_sim, 1, "bulk"),      # bulk FedAvgSim
+    (_sharded_sim, 0, "sharded"),   # ShardedFedAvg on the CPU's devices
+], ids=["FedAvgSim", "bulk", "ShardedFedAvg"])
+def test_checkpointed_harness_logs_what_run_logs(tmp_path, build, block,
+                                                 path):
+    """There is ONE per-round loop: a run through ``Experiment`` with
+    ``checkpoint_every`` set and ``sim.run(metrics_sink=...)`` log the
+    same records round for round — list-valued counters included — and
+    both open an anatomy round a round; the checkpoint is the loop's
+    after-round hook."""
+    import os
+
+    from fedml_tpu.core import anatomy
+    from fedml_tpu.experiments.harness import Experiment
+
+    class Sink(_Sink):
+        path = None
+
+    telemetry.configure(telemetry_dir=str(tmp_path / "telemetry"), rank=0)
+    anatomy.configure(anatomy=True)
+    try:
+        ran = Sink()
+        sim = build(block)
+        sim.run(metrics_sink=ran)
+        rounds = sim.cfg.fed.num_rounds
+        by_run = anatomy.ANATOMY.tracez()["entries"]
+        anatomy.reset()
+        anatomy.configure(anatomy=True)
+        harnessed = Sink()
+        sim = build(block)
+        cfg = dataclasses.replace(
+            sim.cfg, checkpoint_every=2, out_dir=str(tmp_path))
+        Experiment._run_sim(sim, cfg, harnessed)
+        by_harness = anatomy.ANATOMY.tracez()["entries"]
+    finally:
+        anatomy.reset()
+        telemetry.shutdown()
+    assert len(ran.rows) == rounds and harnessed.rows == ran.rows
+    assert any("test_acc" in r for r in ran.rows)
+    if build is _decoder_sim:
+        assert all(len(r["moe_rows_held_by_client"]) == 2
+                   for r in harnessed.rows)
+    for entries in (by_run, by_harness):
+        assert [(e["round"], e["path"]) for e in entries] == [
+            (r, path) for r in range(rounds)]
+    assert os.listdir(os.path.join(tmp_path, "ckpt"))
+
+
 def test_harness_warns_and_falls_back_without_run_block(tmp_path):
     """fuse_rounds > 1 on a sim without the block protocol warns and
     runs per-round instead of crashing."""
@@ -520,7 +673,9 @@ def test_run_fused_with_profiler_captures_blocks(tmp_path):
         g = telemetry.METRICS.snapshot()["gauges"]
         assert "perf.rounds_per_s" in g
     finally:
-        telemetry.configure(telemetry_dir=None, rank=0)
+        # back to the all-disabled state: a configure() would leave a
+        # tracer on for whatever this worker runs next
+        telemetry.shutdown()
 
 
 # ---------------------------------------------------------------------------
@@ -560,20 +715,18 @@ def test_drive_flags_first_dispatch_of_each_length_as_compiled():
     mon = Monitor()
     dispatched = []
 
-    def run_block(n):
+    def run_block(state, n):
         dispatched.append(n)
-        return {"x": jnp.zeros((n,))}
+        return state, {"x": jnp.zeros((n,))}
 
     logged = []
     F.drive(
-        run_block,
+        run_block, None,
         F.plan_blocks(0, 10, 4, eval_every=5),  # lengths 4,1,4,1
         monitor=mon,
-        make_records=lambda start, rows: [
-            {"round": start + i} for i in range(len(rows))
-        ],
+        eval_due=lambda r: False,
+        evaluate=lambda state: {},
         log=logged.append,
-        boundary_hook=lambda r_last, last: logged.append(last),
     )
     assert dispatched == [4, 1, 4, 1]
     assert [r["round"] for r in logged] == list(range(10))

@@ -424,7 +424,7 @@ def test_codec_composition_residual_is_adapter_sized():
     assert all(np.isfinite(m["train_loss"]) for m in ms)
     # the EF residual carries ONLY the aggregated subtree, per slot
     agg = sim._peft.agg_part.trainable(state.variables["params"])
-    res_leaves = jax.tree.leaves(sim._ef_residual)
+    res_leaves = jax.tree.leaves(sim._carry)
     agg_leaves = jax.tree.leaves(agg)
     assert len(res_leaves) == len(agg_leaves)
     for r, a in zip(res_leaves, agg_leaves):
@@ -524,7 +524,7 @@ def test_personalize_no_leak_and_bank_semantics():
     plan = sim._peft
     # the bank is created LAZILY on the first round (so a later
     # init()-for-a-snapshot call can never reset a trained bank)
-    assert sim._adapter_bank is None
+    assert sim._carry is None
     params0 = jax.device_get(state.variables["params"])
     server_adapters0 = plan.private.trainable(params0)
     # the pre-round-0 baseline: every row at the init adapter values
@@ -533,7 +533,7 @@ def test_personalize_no_leak_and_bank_semantics():
     for r in range(3):
         prev_bank = bank
         state, m = sim.run_round(state)
-        bank = jax.device_get(sim._adapter_bank)
+        bank = jax.device_get(sim._carry.rows)
         # recompute the round's cohort from the same seeded draw
         rkey = R.round_key(sim.root_key, jnp.asarray(r, jnp.int32))
         cohort = set(np.asarray(jax.device_get(sim.sampler(
@@ -575,7 +575,7 @@ def test_personalize_no_leak_and_bank_semantics():
                               np.asarray(headN["kernel"]))
     # per-client personalized model differs from the global model
     pv = PP.personal_variables(
-        plan, state.variables, sim._adapter_bank, a
+        plan, state.variables, sim._carry.rows, a
     )
     gm = sim.evaluate_global(state)
     assert set(gm) >= {"acc", "loss"}
@@ -616,7 +616,7 @@ def test_personalize_composition_accepted(fed_kw):
     state = sim.init()
     state, m = sim.run_round(state)
     assert np.isfinite(float(m["train_loss"]))
-    assert sim._adapter_bank is not None
+    assert sim._carry is not None
 
 
 def test_personalize_bank_survives_init_snapshot():
@@ -626,9 +626,9 @@ def test_personalize_bank_survives_init_snapshot():
                     peft_personalize=True))
     state = sim.init()
     state, _ = sim.run_round(state)
-    trained = jax.device_get(sim._adapter_bank)
+    trained = jax.device_get(sim._carry.rows)
     sim.init()  # snapshot idiom — must be side-effect-free here
-    _bitwise(jax.device_get(sim._adapter_bank), trained,
+    _bitwise(jax.device_get(sim._carry.rows), trained,
              "bank after init() snapshot")
 
 
@@ -698,7 +698,7 @@ def test_personalize_sharded_accepted():
         ),
         server_adapters0, "sharded server-side adapters",
     )
-    assert sim._bank_adapter is not None
+    assert sim._carry is not None
 
 
 def test_peft_rejects_non_transformer_sim():

@@ -211,8 +211,8 @@ def test_ef_bank_rows_follow_client_identity():
                     client_block_size=2, compress="int8"))
     state = sim.init()
     state, _ = sim.run_round(state)
-    assert sim._ef_bank is not None
-    rows = jax.device_get(sim._ef_bank.rows)
+    assert sim._carry is not None
+    rows = jax.device_get(sim._carry.rows)
     # recompute round 0's cohort from the same seeded draw
     rkey = R.round_key(sim.root_key, jnp.asarray(0, jnp.int32))
     cohort = set(np.asarray(jax.device_get(
@@ -248,9 +248,9 @@ def test_checkpoint_composite_restores_banks_bitwise(tmp_path):
         assert nxt == 2
         _bitwise(jax.device_get(state2.variables),
                  jax.device_get(state.variables), "server plane")
-        assert sim2._bank_adapter is not None
-        _bitwise(jax.device_get(sim2._bank_adapter.rows),
-                 jax.device_get(sim._bank_adapter.rows),
+        assert sim2._carry is not None
+        _bitwise(jax.device_get(sim2._carry.rows),
+                 jax.device_get(sim._carry.rows),
                  "adapter bank rows")
     finally:
         ckpt.close()
@@ -270,9 +270,9 @@ def test_checkpoint_composite_restores_ef_bank(tmp_path):
         state2 = sim2.init()
         state2, nxt = Experiment._restore_state(ckpt, sim2, state2)
         assert nxt == 1
-        assert sim2._ef_bank is not None
-        _bitwise(jax.device_get(sim2._ef_bank.rows),
-                 jax.device_get(sim._ef_bank.rows), "EF bank rows")
+        assert sim2._carry is not None
+        _bitwise(jax.device_get(sim2._carry.rows),
+                 jax.device_get(sim._carry.rows), "EF bank rows")
     finally:
         ckpt.close()
 
@@ -303,8 +303,8 @@ def test_resume_continues_bit_identically(tmp_path):
         ckpt.close()
     _bitwise(jax.device_get(state_c.variables),
              jax.device_get(state_a.variables), "resumed server state")
-    _bitwise(jax.device_get(sim_c._bank_adapter.rows),
-             jax.device_get(sim_a._bank_adapter.rows),
+    _bitwise(jax.device_get(sim_c._carry.rows),
+             jax.device_get(sim_a._carry.rows),
              "resumed adapter bank")
 
 
@@ -324,7 +324,7 @@ def test_legacy_bare_checkpoint_restores_with_fresh_banks(tmp_path):
         assert nxt == 1
         _bitwise(jax.device_get(state2.variables),
                  jax.device_get(state.variables), "legacy server plane")
-        assert sim2._bank_adapter is None  # fresh lazy init pending
+        assert sim2._carry is None  # fresh lazy init pending
         state2, m = sim2.run_round(state2)
         assert np.isfinite(float(m["train_loss"]))
     finally:
@@ -362,7 +362,7 @@ def test_personalize_composition_no_leak(fed_kw):
         server_adapters0, "server-side adapters",
     )
     # pin 2: at least one sampled client's row trained away from init
-    bank = jax.device_get(sim._bank_adapter.rows)
+    bank = jax.device_get(sim._carry.rows)
     init = jax.device_get(
         SB.ClientStateBank.broadcast(
             "i", sim._peft.private.trainable(params0), 8
