@@ -147,47 +147,20 @@ def make_expert_parallel_moe(mesh, axis_name: str = "ep",
 # ---------------------------------------------------------------------------
 
 #: what :func:`moe_layer` counts, in this order
-MOE_COUNTERS = ("moe_rows_held", "moe_rows_routed", "moe_rows_max_expert")
+MOE_COUNTERS = ("moe_rows_held", "moe_rows_routed", "moe_rows_max_expert",
+                "moe_rows_compact")
+
+#: the row buffer holds this many times the held experts' uniform share
+#: of the assignments, rounded up to the grouped product's row tile
+ROW_SHARE, ROW_TILE = 2, 128
+
+ROUTE, EXPERTS = "fedml.model.moe.route", "fedml.model.moe.experts"
 
 
 def gated_ffn(x, w1, w3, w2):
     """``(silu(x W1) * (x W3)) W2``, the gated feed-forward every expert
     (and the dense layer, and the shared expert) is."""
     return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(h, order, inverse, n_held, k):
-    """Row ``r`` of the result is token ``order[r] // k``'s row of ``h``.
-    Both directions are gathers: the cotangent is un-sorted through
-    ``inverse`` and a token's ``k`` copies summed. Only the first
-    ``n_held`` rows are anyone's input, so only their cotangent counts:
-    the grouped products leave the rest of theirs unwritten."""
-    return h[order // k]
-
-
-def _dispatch_fwd(h, order, inverse, n_held, k):
-    return h[order // k], (inverse, n_held, h.shape[0])
-
-
-def _dispatch_bwd(k, res, g):
-    inverse, n_held, n = res
-    g = jnp.where((jnp.arange(g.shape[0]) < n_held)[:, None], g, 0)
-    return g[inverse].reshape(n, k, -1).sum(1), None, None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _unsort(y, order, inverse):
-    """``y[inverse]`` with the gather ``g[order]`` as its transpose (a
-    permutation's, where autodiff would scatter)."""
-    return y[inverse]
-
-
-_unsort.defvjp(lambda y, order, inverse: (y[inverse], order),
-               lambda order, g: (g[order], None, None))
 
 
 def _mapped(fn):
@@ -229,6 +202,12 @@ grouped_product.defvjp(
     lambda res, g: (*_ragged_transposed(*res, g), None))
 
 
+def _transposed(x, w, sizes, g):
+    """:func:`grouped_product`'s cotangents of ``x`` and ``w`` (its
+    forward product is unused and removed by the compiler)."""
+    return jax.vjp(lambda x, w: grouped_product(x, w, sizes), x, w)[1](g)
+
+
 def route_top_k(scores, top_k: int, scale: float):
     """Sigmoid scores ``[N, E]`` (float32) -> the ``top_k`` largest a
     token, their weights renormalised over those ``top_k`` and times
@@ -236,6 +215,170 @@ def route_top_k(scores, top_k: int, scale: float):
     p = jax.nn.sigmoid(scores)
     top_p, top_e = jax.lax.top_k(p, top_k)
     return top_e, scale * top_p / jnp.sum(top_p, -1, keepdims=True)
+
+
+def row_buffer(n: int, top_k: int, count: int, experts: int) -> int:
+    """Rows of the bounded buffer for ``n`` tokens routed ``top_k`` ways
+    over ``experts`` of which ``count`` are held: :data:`ROW_SHARE`
+    times what uniform routing sends here, in whole row tiles, and never
+    more than the worst case ``n * top_k``."""
+    share = -(-ROW_SHARE * n * top_k * count // experts)
+    return min(n * top_k, -(-share // ROW_TILE) * ROW_TILE)
+
+
+def _one_path_a_batch(fits_fn, other_fn):
+    """``(fits, *arrays) -> lax.cond(fits, fits_fn, other_fn, *arrays)``
+    that stays ONE branch under ``vmap``: a mapped predicate turns
+    ``cond`` into a select that runs both sides, so the batch takes
+    ``fits_fn`` when EVERY instance fits and ``other_fn`` otherwise."""
+    @jax.custom_batching.custom_vmap
+    def branch(fits, *arrays):
+        return jax.lax.cond(fits, fits_fn, other_fn, *arrays)
+
+    @branch.def_vmap
+    def rule(axis_size, in_batched, fits, *arrays):
+        axes = jax.tree.map(lambda mapped: 0 if mapped else None,
+                            tuple(in_batched[1:]))
+        out = _one_path_a_batch(
+            jax.vmap(fits_fn, axes, axis_size=axis_size),
+            jax.vmap(other_fn, axes, axis_size=axis_size),
+        )(jnp.all(fits), *arrays)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return branch
+
+
+def _read_back(x, inverse, n_held):
+    """``x`` ``[r, ...]``, zero from row ``n_held`` on, as each of the
+    ``N x top_k`` assignments reads it: its own row if its expert is
+    held (the first ``n_held`` of the order), else zero. Over the
+    worst-case buffer every assignment has a row; a shorter one gets a
+    zero row after it for the rest to read, so that no mask pass follows
+    the gather."""
+    if x.shape[0] == inverse.shape[0]:
+        return x[inverse]
+    x = jnp.pad(x, ((0, 1),) + ((0, 0),) * (x.ndim - 1))
+    return x[jnp.where(inverse < n_held, inverse, x.shape[0] - 1)]
+
+
+def _held_rows_forward(r, h, w1, w3, w2, top_w, order, inverse, sizes,
+                       n_held):
+    """The held experts' sum over a buffer of ``r`` rows (at least
+    ``n_held``): row ``i`` is token ``order[i] // k``'s. -> ``(y [N, D],
+    (rows, a, b, out))``, what the backward pass reads again, all of
+    ``r`` rows. Rows from ``n_held`` on are no held expert's: the
+    grouped products leave them unwritten, so ``out`` is masked."""
+    n, k = top_w.shape
+    with jax.named_scope(ROUTE):
+        rows = h[order[:r] // k]
+    with jax.named_scope(EXPERTS):
+        a = grouped_product(rows, w1, sizes)
+        b = grouped_product(rows, w3, sizes)
+        out = grouped_product(jax.nn.silu(a) * b, w2, sizes)
+    with jax.named_scope(ROUTE):
+        out = jnp.where((jnp.arange(r) < n_held)[:, None], out, 0)
+        y = jnp.einsum(
+            "nkd,nk->nd", _read_back(out, inverse, n_held).reshape(n, k, -1),
+            top_w.astype(out.dtype))
+    return y, (rows, a, b, out)
+
+
+def _held_rows_backward(r, kept, w1, w3, w2, top_w, order, inverse, sizes,
+                        n_held, g):
+    """:func:`_held_rows_forward`'s cotangents of ``h``, ``w1``, ``w3``,
+    ``w2`` and ``top_w`` for ``g`` ``[N, D]``, from what it kept. The
+    rows' cotangent is unwritten from ``n_held`` on as well, and masked
+    before it reaches the tokens."""
+    rows, a, b, out = kept
+    n, k = top_w.shape
+    with jax.named_scope(ROUTE):
+        g_rows = g[order[:r] // k]
+        weight = top_w.reshape(-1)[order[:r]].astype(out.dtype)
+        d_out = g_rows * weight[:, None]
+        d_weight = jnp.einsum("rd,rd->r", out, g_rows,
+                              preferred_element_type=jnp.float32)
+        d_top_w = _read_back(d_weight, inverse, n_held).reshape(
+            n, k).astype(top_w.dtype)
+    with jax.named_scope(EXPERTS):
+        up, gate = jax.vjp(lambda a, b: jax.nn.silu(a) * b, a, b)
+        d_up, d_w2 = _transposed(up, w2, sizes, d_out)
+        d_a, d_b = gate(d_up)
+        d_rows1, d_w1 = _transposed(rows, w1, sizes, d_a)
+        d_rows3, d_w3 = _transposed(rows, w3, sizes, d_b)
+    with jax.named_scope(ROUTE):
+        d_rows = jnp.where((jnp.arange(r) < n_held)[:, None],
+                           d_rows1 + d_rows3, 0)
+        d_h = _read_back(d_rows, inverse, n_held).reshape(n, k, -1).sum(1)
+    return d_h, d_w1, d_w3, d_w2, d_top_w
+
+
+def _held_experts_run(c, keep, *args):
+    """-> ``(y, taken, kept)``: over ``c`` rows where the held
+    assignments fit them (``taken`` = ``N x top_k``, ``kept`` = what
+    the backward pass reads again if ``keep``), else over all ``N x
+    top_k`` (``taken`` 0, ``kept`` zeros of the ``c``-row shapes: the
+    backward pass runs that side's forward pass again)."""
+    top_w, n_held = args[4], args[-1]
+    nk = top_w.size
+
+    def over(r):
+        def run(*args):
+            y, kept = _held_rows_forward(r, *args)
+            if not keep:
+                kept = ()
+            elif r != c:
+                kept = jax.tree.map(
+                    lambda x: jnp.zeros((c,) + x.shape[1:], x.dtype), kept)
+            return y, jnp.float32(nk if r < nk else 0), kept
+        return run
+
+    if c == nk:  # no smaller buffer: one path, decided while tracing
+        return over(nk)(*args)
+    return _one_path_a_batch(over(c), over(nk))(n_held <= c, *args)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(c, h, w1, w3, w2, top_w, order, inverse, sizes, n_held):
+    """What the held experts add to the tokens: ``(y [N, D], N x top_k
+    if the rows went through the bounded buffer of ``c`` rows, else
+    0)``.
+
+    The branch sits in the rules, not under them: differentiated by
+    JAX, a ``cond`` returns the residuals of BOTH sides, zero-filled
+    for the side not taken — the worst-case-sized arrays the bounded
+    buffer exists to avoid."""
+    return _held_experts_run(
+        c, False, h, w1, w3, w2, top_w, order, inverse, sizes, n_held)[:2]
+
+
+def _held_experts_fwd(c, *args):
+    y, taken, kept = _held_experts_run(c, True, *args)
+    return (y, taken), (kept, taken, args)
+
+
+def _held_experts_bwd(c, res, cotangents):
+    kept, taken, args = res
+    nk = args[4].size  # top_w
+
+    def bounded(kept, g, h, *rest):
+        return _held_rows_backward(c, kept, *rest, g)
+
+    def worst(kept, g, h, *rest):
+        _, kept = _held_rows_forward(nk, h, *rest)
+        return _held_rows_backward(nk, kept, *rest, g)
+
+    if c == nk:
+        grads = bounded(kept, cotangents[0], *args)
+    else:
+        # (the barrier keeps the compiler from moving what reads the
+        # gradients — a cast to float32 twice their size — into the
+        # branches, whose results then wait for the update at that size)
+        grads = jax.lax.optimization_barrier(_one_path_a_batch(
+            bounded, worst)(taken > 0, kept, cotangents[0], *args))
+    return (*grads, None, None, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float):
@@ -253,16 +396,21 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float):
     follows the rows present, never ``E x N``), are weighted —
     weights normalised over all ``top_k``, held or not — and summed back
     into their tokens; the shared expert is added. The row buffer holds
-    the worst case (``N x top_k`` rows), so no assignment is ever
-    dropped. What the absent experts would add is left out: on one chip
-    there is no exchange and nothing stands in for one.
+    what the held experts can be expected to receive
+    (:func:`row_buffer`: twice their uniform share of the ``N x top_k``
+    assignments); a call that receives more goes through a buffer of
+    all ``N x top_k`` rows instead, so no assignment is ever dropped,
+    and a mapped batch goes one way together. What the absent experts
+    would add is left out: on one chip there is no exchange and nothing
+    stands in for one.
 
-    -> ``(y [N, D], counters float32 [3])`` in :data:`MOE_COUNTERS`'
+    -> ``(y [N, D], counters float32 [4])`` in :data:`MOE_COUNTERS`'
     order: assignments that landed on held experts, assignments made
-    (``N x top_k``), rows of the fullest held expert."""
+    (``N x top_k``), rows of the fullest held expert, assignments made
+    in a call that went through the bounded buffer."""
     first, count = held
     n, _ = h.shape
-    with jax.named_scope("fedml.model.moe.route"):
+    with jax.named_scope(ROUTE):
         scores = jnp.dot(h, params["router"],
                          preferred_element_type=jnp.float32)
         top_e, top_w = route_top_k(scores, top_k, scale)
@@ -274,20 +422,13 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float):
         sizes = jnp.bincount(group, length=count + 1)[:count].astype(
             jnp.int32)
         n_held = jnp.sum(sizes)
-        rows = _dispatch(h, order, inverse, n_held, top_k)
-    with jax.named_scope("fedml.model.moe.experts"):
-        up = jax.nn.silu(grouped_product(rows, params["w1"], sizes)) * (
-            grouped_product(rows, params["w3"], sizes))
-        out = grouped_product(up, params["w2"], sizes)
-    with jax.named_scope("fedml.model.moe.route"):
-        # rows past the held groups belong to absent experts
-        out = jnp.where((jnp.arange(n * top_k) < n_held)[:, None], out, 0)
-        out = _unsort(out, order, inverse).reshape(n, top_k, -1)
-        y = jnp.einsum("nkd,nk->nd", out, top_w.astype(out.dtype))
+    y, bounded = _held_experts(
+        row_buffer(n, top_k, count, scores.shape[1]), h, params["w1"],
+        params["w3"], params["w2"], top_w, order, inverse, sizes, n_held)
     if "shared" in params:
         with jax.named_scope("fedml.model.mlp"):
             y = y + gated_ffn(h, *params["shared"])
     counters = jnp.stack([
-        n_held, jnp.asarray(n * top_k, jnp.int32), jnp.max(sizes),
-    ]).astype(jnp.float32)
+        n_held.astype(jnp.float32), jnp.float32(n * top_k),
+        jnp.max(sizes).astype(jnp.float32), bounded])
     return y.astype(h.dtype), counters

@@ -134,9 +134,11 @@ def test_grouped_expert_products_compile_for_v5e(one_chip):
         params, sds((4096, 2048))).compile()
     text = compiled.as_text()
     assert text.count("ragged-dot-none") >= 9  # 3 products x (1 + 2)
-    # the worst-case row buffers ([32768, 2048] bfloat16 = 134 MB each),
-    # not 32 experts x 32,768 rows
+    # the bounded row buffers ([8192, 2048] bfloat16) and, on the side
+    # of the branch a call over them takes, the worst-case ones
+    # ([32768, 2048] = 134 MB each): never 32 experts x 32,768 rows
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    assert text.count(" conditional(") == 2  # forward rule, backward rule
     scopes = parse_scopes(text)
     assert {v for k, v in scopes.items()
             if k.startswith("ragged-dot-none")} == {

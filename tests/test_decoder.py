@@ -109,15 +109,16 @@ def _layer_params(key, d=64, experts=16, f=32, first=0, count=16,
     return p, every
 
 
-def _uncut_layer(p, every, h, top_k, scale):
+def _uncut_layer(p, every, h, top_k, scale, first=0):
     """The whole layer written out: every expert on every token, a
-    mask for the chosen ones (no share, no sort)."""
+    mask for the chosen ones (no share, no sort). ``every`` holds the
+    experts from id ``first`` on: a share of them written out."""
     prob = jax.nn.sigmoid(h @ p["router"])
     top_p, top_e = jax.lax.top_k(prob, top_k)
     w = scale * top_p / top_p.sum(-1, keepdims=True)
     y = jnp.zeros_like(h)
     for e in range(every["w1"].shape[0]):
-        share = jnp.where(top_e == e, w, 0.0).sum(-1)
+        share = jnp.where(top_e == first + e, w, 0.0).sum(-1)
         y += share[:, None] * MOE.gated_ffn(
             h, every["w1"][e], every["w3"][e], every["w2"][e])
     return y + MOE.gated_ffn(h, *p["shared"])
@@ -199,6 +200,208 @@ def test_dropless_under_imbalance():
         y, _uncut_layer(full, every, h, 4, 2.5), rtol=2e-5, atol=2e-5)
     assert [float(c) for c in counters[:2]] == [160.0, 160.0]
     assert float(counters[2]) == 40.0  # every token on every held expert
+    # 160 rows where the bounded buffer holds 128: the worst-case path
+    assert MOE.row_buffer(40, 4, 4, 16) == 128
+    assert float(counters[3]) == 0.0
+
+
+# held (4, 4) of 16 experts, 64 tokens top-4: 256 assignments, a bounded
+# buffer of 128 rows
+STEERED = {"well_under": (8, 8), "exactly": (31, 4), "one_over": (31, 5)}
+
+
+def _steered(key, on_all_held, on_one_held, tokens=64):
+    """A share of a layer (held (4, 4) of 16, top-4) and tokens whose
+    routing is set by three marker features: ``on_all_held`` tokens pick
+    experts 4-7 (four held rows each), ``on_one_held`` pick 4, 8, 9, 10
+    (one held row), the rest 8-11 (none). -> (params, h, held rows)."""
+    p, _ = _layer_params(key, first=4, count=4)
+    picks = jnp.zeros((3, 16)).at[0, 4:8].set(9.0).at[
+        1, jnp.array([4, 8, 9, 10])].set(9.0).at[2, 8:12].set(9.0)
+    p["router"] = (0.1 * p["router"]).at[:3].set(picks)
+    kind = jnp.where(jnp.arange(tokens) < on_all_held, 0, jnp.where(
+        jnp.arange(tokens) < on_all_held + on_one_held, 1, 2))
+    h = jax.random.normal(jax.random.fold_in(key, 1), (tokens, 64))
+    h = h.at[:, :3].set(jax.nn.one_hot(kind, 3))
+    return p, h, 4 * on_all_held + on_one_held
+
+
+def _written_out(p, h):
+    return _uncut_layer(p, {k: p[k] for k in ("w1", "w3", "w2")}, h, 4,
+                        2.5, first=4)
+
+
+def _value_and_gradients(layer, p, h, weigh):
+    """-> (y, counters or None, gradients of sum(y * weigh) by every
+    parameter and by ``h``)."""
+    def loss(p, h):
+        out = layer(p, h)
+        y, counters = out if isinstance(out, tuple) else (out, None)
+        return jnp.sum(y * weigh), (y, counters)
+
+    (_, (y, counters)), grads = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(p, h)
+    return y, counters, grads
+
+
+def _assert_trees_close(got, want, rtol=2e-4):
+    flat = dict(jax.tree_util.tree_leaves_with_path(want))
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        scale = float(jnp.max(jnp.abs(flat[path]))) + 1e-12
+        assert float(jnp.max(jnp.abs(g - flat[path]))) <= rtol * scale, (
+            jax.tree_util.keystr(path))
+
+
+def _share(p, h):
+    return MOE.moe_layer(p, h, (4, 4), 4, 2.5)
+
+
+@pytest.mark.parametrize("case", list(STEERED))
+def test_bounded_buffer_against_the_written_out_layer(case):
+    """(d') held rows well under the bounded buffer's 128, exactly 128
+    and 129: values, the gradient of the tokens and of every parameter
+    against the written-out share; the fourth counter says which buffer
+    the call went through."""
+    key = jax.random.key(13)
+    p, h, held_rows = _steered(key, *STEERED[case])
+    assert MOE.row_buffer(64, 4, 4, 16) == 128
+    weigh = jax.random.normal(jax.random.fold_in(key, 2), h.shape)
+    y, counters, grads = _value_and_gradients(_share, p, h, weigh)
+    want, _, want_grads = _value_and_gradients(_written_out, p, h, weigh)
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
+    _assert_trees_close(grads, want_grads)
+    assert float(jnp.max(jnp.abs(grads[0]["router"]))) > 0
+    assert [float(c) for c in counters] == [
+        held_rows, 256.0, sum(STEERED[case]),  # expert 4 is the fullest
+        0.0 if case == "one_over" else 256.0]
+
+
+@pytest.mark.parametrize("cases", [("one_over", "well_under"),
+                                   ("exactly", "well_under")])
+def test_a_mapped_batch_goes_one_way_together(cases):
+    """Under ``vmap`` one instance over the bounded buffer sends the
+    whole batch through the worst-case one (a mapped ``cond`` would run
+    both sides for everyone): results equal the unmapped calls instance
+    by instance, and every instance's counter names the batch's path."""
+    key = jax.random.key(17)
+    made = [_steered(jax.random.fold_in(key, i), *STEERED[c])
+            for i, c in enumerate(cases)]
+    p = jax.tree.map(lambda *x: jnp.stack(x), *[m[0] for m in made])
+    h = jnp.stack([m[1] for m in made])
+    weigh = jax.random.normal(jax.random.fold_in(key, 9), h.shape[1:])
+    one = lambda p, h: _value_and_gradients(_share, p, h, weigh)
+    y, counters, grads = jax.jit(jax.vmap(one))(p, h)
+    every_fits = "one_over" not in cases
+    for i, (p_i, h_i, held_rows) in enumerate(made):
+        y_i, counters_i, grads_i = one(p_i, h_i)
+        np.testing.assert_allclose(y[i], y_i, rtol=2e-5, atol=2e-5)
+        _assert_trees_close(jax.tree.map(lambda g: g[i], grads), grads_i)
+        assert float(counters[i][0]) == float(counters_i[0]) == held_rows
+        assert float(counters[i][3]) == (256.0 if every_fits else 0.0)
+    # shared parameters, mapped tokens: the cohort's first step
+    y, counters, _ = jax.vmap(one, in_axes=(None, 0))(
+        made[0][0], jnp.stack([made[0][1], made[0][1]]))
+    np.testing.assert_allclose(y[1], one(*made[0][:2])[0], rtol=2e-5,
+                               atol=2e-5)
+
+
+def _eqns(jaxpr, bounded_side_only=True):
+    """Every equation of ``jaxpr`` and of the programs it calls; of a
+    ``cond``, only the side taken when the predicate holds (the rows
+    fit the bounded buffer) unless told otherwise."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for name, value in eqn.params.items():
+            values = value if isinstance(value, (tuple, list)) else (value,)
+            if (eqn.primitive.name == "cond" and name == "branches"
+                    and bounded_side_only):
+                values = values[1:]
+            for v in values:
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, bounded_side_only)
+
+
+@pytest.mark.parametrize("mapped", [False, True], ids=["unmapped", "vmap"])
+def test_no_worst_case_sized_array_on_the_bounded_path(mapped):
+    """Forward, recomputation and backward of a layer share under
+    ``remat``: outside the ``cond`` sides that run when the rows do not
+    fit, no array has ``N x top_k`` rows by a model or expert width (a
+    differentiated ``cond`` would return both sides' residuals) — but
+    for the result of the gather in which every assignment reads its
+    row, or the zero row, of the 128 + 1."""
+    p, h, _ = _steered(jax.random.key(19), *STEERED["well_under"])
+    weigh = jnp.ones_like(h)
+    layer = jax.checkpoint(_share)
+    fn = lambda p, h: _value_and_gradients(layer, p, h, weigh)[2]
+    if mapped:
+        fn, h = jax.vmap(fn, in_axes=(None, 0)), jnp.stack([h, h])
+    jaxpr = jax.make_jaxpr(fn)(p, h).jaxpr
+    lead = (2,) if mapped else ()
+
+    def wide(eqns):
+        found = {}
+        for eqn in eqns:
+            for v in eqn.outvars:
+                s = v.aval.shape[len(lead):]
+                if len(s) >= 2 and s[-1] in (64, 32) and (
+                        s[-2] == 256 or s[-3:-1] == (64, 4)):
+                    found.setdefault(s, []).append(eqn)
+        return found
+
+    found = wide(_eqns(jaxpr))
+    assert set(found) == {(256, 64), (64, 4, 64)}
+    assert all(e.primitive.name == "gather" and e.invars[0].aval.shape
+               == lead + (129, 64) for e in found[(256, 64)])
+    assert all(e.primitive.name == "reshape" for e in found[(64, 4, 64)])
+    # the other side holds them, as it must: it is looked at
+    assert (256, 32) in wide(_eqns(jaxpr, bounded_side_only=False))
+
+
+def _unwritten(fill):
+    """:func:`MOE.grouped_product` as the chip runs it: rows past
+    ``sum(sizes)`` hold ``fill``, in the result and in the rows'
+    cotangent (the CPU's expansion writes zeros there)."""
+    real = MOE.grouped_product
+    past = lambda x, sizes: (jnp.arange(x.shape[-2]) >= jnp.sum(sizes))[
+        :, None]
+
+    @jax.custom_vjp
+    def double(x, w, sizes):
+        return jnp.where(past(x, sizes), fill, real(x, w, sizes))
+
+    def fwd(x, w, sizes):
+        return double(x, w, sizes), (x, w, sizes)
+
+    def bwd(res, g):
+        x, w, sizes = res
+        d_x, d_w = jax.vjp(lambda x, w: real(x, w, sizes), x, w)[1](g)
+        return jnp.where(past(x, sizes), fill, d_x), d_w, None
+
+    double.defvjp(fwd, bwd)
+    return double
+
+
+@pytest.mark.parametrize("case", ["well_under", "one_over"])
+def test_rows_the_chip_leaves_unwritten_reach_nothing(case, monkeypatch):
+    """The chip leaves the rows past the held groups unwritten in a
+    grouped product's result and in the rows' cotangent (PR 27's first
+    chip run read NaN losses no CPU test had seen). With NaN there, on
+    the bounded path (rows from the held count to 128) and on the
+    worst-case one, the output and every gradient are finite and equal
+    what they are without."""
+    key = jax.random.key(23)
+    p, h, _ = _steered(key, *STEERED[case])
+    weigh = jax.random.normal(jax.random.fold_in(key, 2), h.shape)
+    layer = jax.checkpoint(_share)
+    want = _value_and_gradients(layer, p, h, weigh)
+    monkeypatch.setattr(MOE, "grouped_product", _unwritten(jnp.nan))
+    y, counters, grads = _value_and_gradients(layer, p, h, weigh)
+    assert all(bool(jnp.isfinite(x).all())
+               for x in jax.tree.leaves((y, grads)))
+    np.testing.assert_allclose(y, want[0], rtol=1e-6, atol=1e-6)
+    _assert_trees_close(grads, want[2], rtol=1e-5)
+    assert float(counters[3]) == (0.0 if case == "one_over" else 256.0)
 
 
 def _sim(config, block):
@@ -245,8 +448,12 @@ def test_round_at_block_one_equals_the_stacked_round(tiny):
     for stacked, bulk in zip(*sinks):
         assert stacked["moe_rows_routed"] == 2 * 2 * 2 * TD.SEQ * 4 * 4
         for name in ("moe_rows_held", "moe_rows_routed",
-                     "moe_rows_max_expert", "moe_rows_held_by_client"):
+                     "moe_rows_max_expert", "moe_rows_compact",
+                     "moe_rows_held_by_client"):
             assert stacked[name] == bulk[name], name
+        # 64 tokens top-4 a step, 4 of 16 experts held: a bounded buffer
+        # of 128 rows, which fresh routers (64 rows expected) stay under
+        assert 0 < stacked["moe_rows_compact"] <= stacked["moe_rows_routed"]
         assert len(bulk["moe_rows_held_by_client"]) == 2
         assert sum(bulk["moe_rows_held_by_client"]) == bulk["moe_rows_held"]
         np.testing.assert_allclose(
@@ -258,8 +465,9 @@ def test_log_span_carries_the_counters():
     from fedml_tpu.core.tracing import log_span
 
     attrs = log_span({"round": 3, "train_loss": 1.0, "moe_rows_held": 9.0,
+                      "moe_rows_compact": 64.0,
                       "moe_rows_held_by_client": [4.0, 5.0]}).attrs
-    assert attrs == {"round": 3, "moe_rows_held": 9,
+    assert attrs == {"round": 3, "moe_rows_held": 9, "moe_rows_compact": 64,
                      "moe_rows_held_by_client": "[4, 5]"}
 
 
