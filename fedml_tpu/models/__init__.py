@@ -170,14 +170,17 @@ def create_model(cfg: ModelConfig) -> FedModel:
             input_dtype=jnp.int32,
         )
     if name == "decoder":
-        from fedml_tpu.models.decoder import decoder_from_extra
+        from fedml_tpu.models.decoder import (
+            attention_counters, decoder_from_extra,
+        )
         from fedml_tpu.ops.moe import MOE_COUNTERS
 
         module = decoder_from_extra(extra, nc)
         sparse = "sparse" in dict(module.cfg)["mlp_layer_types"]
+        attended = attention_counters(dict(module.cfg)["layer_types"])
         return FedModel(
             module, cfg.input_shape, input_dtype=jnp.int32,
-            counters=MOE_COUNTERS if sparse else (),
+            counters=(MOE_COUNTERS if sparse else ()) + attended,
             client_counters=MOE_COUNTERS[:1] if sparse else (),
             jit_init=True,
         )

@@ -7,27 +7,40 @@ Per layer ``l`` (pre-norm residual blocks, RMSNorm, no bias anywhere):
 
 - attention: ``heads_per_layer[l]`` query heads over
   ``num_key_value_heads`` key-value heads of ``head_dim``;
-  ``layer_types[l]`` is ``full_attention`` or ``sliding_attention``
-  (causal, the latter also within ``sliding_window`` keys), each kind
-  with its own ``rope`` record (``rope_theta``,
-  ``partial_rotary_factor``, ``rope_type`` ``default`` | ``yarn`` with
-  ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
-  ``beta_slow``, ``attention_factor``; rotate-half pairing); with
-  ``gating`` a per-head sigmoid gate of the normed input scales each
-  head's output before the output projection;
+  ``layer_types[l]`` is one of three kinds, all causal:
+  ``full_attention``; ``sliding_attention`` (within ``sliding_window``
+  keys); ``sparse_attention`` (query ``t`` reads the ``min(t + 1,
+  topk)`` keys a learned index ranks highest — ``sparse_attention:
+  {index_heads, index_head_dim, topk}``: ``index_heads`` index queries
+  and one head weight each a token, ONE index key head, all of the
+  normed layer input, rotated like the layer's own queries;
+  :func:`fedml_tpu.ops.attention.index_scores`, ``select_top_k``,
+  ``selected_attention``; the set is discrete, so the index
+  projections get no gradient from the task's loss). Each kind has its
+  own ``rope`` record (``rope_theta``, ``partial_rotary_factor``,
+  ``rope_type`` ``default`` | ``yarn`` with ``factor``,
+  ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+  ``attention_factor``; rotate-half pairing); with ``qk_norm`` every
+  query and key head is RMS-normed (one learned scale over
+  ``head_dim``) before it is rotated; with ``gating`` a per-head
+  sigmoid gate of the normed input scales each head's output before
+  the output projection;
 - feed-forward: ``mlp_layer_types[l]`` is ``dense`` (a gated
   feed-forward of ``intermediate_size``) or ``sparse``
   (:func:`fedml_tpu.ops.moe.moe_layer`: a router over ``num_experts``,
-  ``num_experts_per_tok`` a token, sigmoid scores renormalised over the
-  chosen and times ``routed_scaling_factor``, the experts
-  ``experts_held = [first, count]`` of width ``moe_intermediate_size``
-  held here, and a shared expert of
-  ``shared_expert_intermediate_size``).
+  ``num_experts_per_tok`` a token, probabilities by ``router_scoring``
+  — ``sigmoid``, the default, or ``softmax`` over all experts —
+  renormalised over the chosen and times ``routed_scaling_factor``,
+  the experts ``experts_held = [first, count]`` of width
+  ``moe_intermediate_size`` held here, and a shared expert of
+  ``shared_expert_intermediate_size``, none at 0).
 
 Untied embedding and head over ``vocab_size`` rows. Each layer is
 recomputed in the backward pass (``nn.remat``: only a layer's input is
-kept). The sparse layers' counters (:data:`fedml_tpu.ops.moe.
-MOE_COUNTERS`) are summed over layers and sown into the ``counters``
+kept). What the layers count (:func:`counter_names`: the sparse layers'
+:data:`fedml_tpu.ops.moe.MOE_COUNTERS` and, in a stack with
+sparse-attention layers, :data:`fedml_tpu.ops.attention.
+ATTN_COUNTERS`) is summed over layers and sown into the ``counters``
 collection, which :meth:`fedml_tpu.models.base.FedModel.
 apply_train_counted` hands to the local update.
 
@@ -47,11 +60,26 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedml_tpu.models.transformer import AttnFn, DenseFactory, _dense
-from fedml_tpu.ops.attention import causal_attention
-from fedml_tpu.ops.moe import MOE_COUNTERS, moe_layer
+from fedml_tpu.ops.attention import (
+    ATTN_COUNTERS, causal_attention, index_scores, select_top_k,
+)
+from fedml_tpu.ops.moe import MOE_COUNTERS, SCORINGS, moe_layer
 
-SLIDING = "sliding_attention"  # any other layer type is full attention
+# any other layer type is full attention
+SLIDING, SELECTED = "sliding_attention", "sparse_attention"
 DENSE, SPARSE = "dense", "sparse"
+SIGMOID = "sigmoid"  # the router's scoring where the configuration names none
+
+
+def attention_counters(layer_types) -> tuple[str, ...]:
+    """What a stack of these attention kinds counts beside its sparse
+    layers' :data:`MOE_COUNTERS` (every layer of it, of any kind)."""
+    return ATTN_COUNTERS if SELECTED in layer_types else ()
+
+
+def counter_names(layer_types) -> tuple[str, ...]:
+    """What a stack of these attention kinds counts a training step."""
+    return MOE_COUNTERS + attention_counters(layer_types)
 
 
 def rope_inverse_frequencies(rope: dict, head_dim: int):
@@ -118,6 +146,12 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(x.dtype)
 
 
+def _counted(routed, attended):
+    """One layer's counts in :func:`counter_names`' order."""
+    return (jnp.concatenate([routed, jnp.stack(attended)]) if attended
+            else routed)
+
+
 class DecoderLayer(nn.Module):
     cfg: Any  # the frozen configuration (a tuple of items)
     index: int
@@ -138,11 +172,21 @@ class DecoderLayer(nn.Module):
             q = dense(heads * hd, "q_proj")(h).reshape(b, t, heads, hd)
             k = dense(kv * hd, "k_proj")(h).reshape(b, t, kv, hd)
             v = dense(kv * hd, "v_proj")(h).reshape(b, t, kv, hd)
-            cos, sin = rope_tables(dict(dict(c["rope"])[kind]), hd, t)
+            if c["qk_norm"]:
+                q = RMSNorm(c["rms_norm_eps"], name="q_norm")(q)
+                k = RMSNorm(c["rms_norm_eps"], name="k_norm")(k)
+            rope = dict(dict(c["rope"])[kind])
+            cos, sin = rope_tables(rope, hd, t)
+            # a stack with sparse-attention layers counts in every layer
+            how, attended = {}, (jnp.float32(0),) * len(
+                attention_counters(c["layer_types"]))
+            if kind == SELECTED:
+                how["selection"], attended = self.select(h, rope)
             a = self.attn_fn(
                 apply_rope(q, cos, sin), apply_rope(k, cos, sin), v,
                 causal=True,
-                window=c["sliding_window"] if kind == SLIDING else None)
+                window=c["sliding_window"] if kind == SLIDING else None,
+                **how)
             if c["gating"]:
                 a = a * jax.nn.sigmoid(dense(heads, "g_proj")(h))[..., None]
             x = x + dense(d, "o_proj")(a.reshape(b, t, heads * hd))
@@ -153,7 +197,8 @@ class DecoderLayer(nn.Module):
                     dense(c["intermediate_size"], "gate_proj")(h)
                 ) * dense(c["intermediate_size"], "up_proj")(h)
                 x = x + dense(d, "down_proj")(up)
-            return x, jnp.zeros((len(MOE_COUNTERS),), jnp.float32)
+            return x, _counted(
+                jnp.zeros((len(MOE_COUNTERS),), jnp.float32), attended)
         with jax.named_scope("fedml.model.moe"):
             h = RMSNorm(c["rms_norm_eps"], name="mlp_norm")(x)
             first, count = c["experts_held"]
@@ -179,8 +224,32 @@ class DecoderLayer(nn.Module):
             params = jax.tree.map(lambda p: p.astype(x.dtype), params)
             y, counters = moe_layer(
                 params, h.reshape(b * t, d), (first, count),
-                c["num_experts_per_tok"], c["routed_scaling_factor"])
-            return x + y.reshape(b, t, d), counters
+                c["num_experts_per_tok"], c["routed_scaling_factor"],
+                scoring=c["router_scoring"])
+            return x + y.reshape(b, t, d), _counted(counters, attended)
+
+    @nn.nowrap
+    def select(self, h, rope: dict):
+        """The keys each query of a sparse-attention layer reads, from
+        the normed layer input ``h`` -> (``[B, T, T]`` bool,
+        :data:`ATTN_COUNTERS`' two counts of this call). The index's
+        output is a set: no gradient reaches its projections."""
+        sa = dict(dict(self.cfg)["sparse_attention"])
+        b, t, _ = h.shape
+        j, e = sa["index_heads"], sa["index_head_dim"]
+        dense = lambda f, name: _dense(self.dense_cls, f, False, name)
+        with jax.named_scope("fedml.model.attn.index"):
+            qi = dense(j * e, "index_q_proj")(h).reshape(b, t, j, e)
+            ki = dense(e, "index_k_proj")(h).reshape(b, t, 1, e)
+            w = dense(j, "index_w_proj")(h)
+            cos, sin = rope_tables({**rope, "partial_rotary_factor": 1.0},
+                                   e, t)
+            scores = index_scores(apply_rope(qi, cos, sin),
+                                  apply_rope(ki, cos, sin)[:, :, 0], w)
+        with jax.named_scope("fedml.model.attn.select"):
+            selection = select_top_k(scores, sa["topk"])
+            selected = jnp.sum(selection, dtype=jnp.float32)
+        return selection, (selected, jnp.float32(b * t * (t + 1) // 2))
 
 
 class DecoderLM(nn.Module):
@@ -198,12 +267,13 @@ class DecoderLM(nn.Module):
             x = nn.Embed(self.vocab_size, c["hidden_size"],
                          name="embed")(tokens)
         layer = nn.remat(DecoderLayer)
-        counters = jnp.zeros((len(MOE_COUNTERS),), jnp.float32)
+        names = counter_names(c["layer_types"])
+        counters = jnp.zeros((len(names),), jnp.float32)
         for l in range(len(c["layer_types"])):
             x, counted = layer(self.cfg, l, self.attn_fn, self.dense_cls,
                                name=f"layer_{l}")(x)
             counters = counters + counted
-        for name, value in zip(MOE_COUNTERS, counters):
+        for name, value in zip(names, counters):
             self.sow("counters", name, value,
                      reduce_fn=lambda a, b: a + b,
                      init_fn=lambda: jnp.zeros((), jnp.float32))
@@ -232,7 +302,8 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
     if missing:
         raise ValueError(f"decoder: model extra lacks {', '.join(missing)}")
     c = {
-        "sliding_window": None, "gating": False, "rms_norm_eps": 1e-6,
+        "sliding_window": None, "gating": False, "qk_norm": False,
+        "rms_norm_eps": 1e-6, "router_scoring": SIGMOID,
         "moe_intermediate_size": 0, "shared_expert_intermediate_size": 0,
         "num_experts": 0, "num_experts_per_tok": 0,
         "routed_scaling_factor": 1.0, "experts_held": (0, 0),
@@ -251,4 +322,18 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         raise ValueError(
             f"decoder: experts_held {c['experts_held']} does not lie in "
             f"the router's {c['num_experts']} experts")
+    if c["router_scoring"] not in SCORINGS:
+        raise ValueError(
+            f"decoder: unknown router_scoring {c['router_scoring']!r}; "
+            f"known: {sorted(SCORINGS)}")
+    if SELECTED in c["layer_types"]:
+        sa = c.get("sparse_attention") or {}
+        lacks = [k for k in ("index_heads", "index_head_dim", "topk")
+                 if not isinstance(sa.get(k), int)]
+        if lacks:
+            raise ValueError(
+                f"decoder: sparse_attention lacks {', '.join(lacks)}")
+        if min(sa["index_heads"], sa["index_head_dim"], sa["topk"]) < 1:
+            raise ValueError(
+                f"decoder: sparse_attention sizes must be at least 1: {sa}")
     return DecoderLM(_freeze(c), int(extra.get("vocab_size", num_classes)))

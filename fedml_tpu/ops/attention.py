@@ -1,5 +1,6 @@
-"""Causal attention with an optional sliding window and grouped-query
-heads: the attention the configured decoder stack
+"""Causal attention with an optional sliding window, or over a set of
+keys chosen a query by a learned index, and grouped-query heads: the
+attention the configured decoder stack
 (:mod:`fedml_tpu.models.decoder`) calls.
 
 ``q`` is ``[B, T, H, D]``; ``k`` and ``v`` are ``[B, T, Hkv, D]`` with
@@ -16,6 +17,27 @@ multi-query form), mapped over the batch and the key-value heads. Off
 the TPU the same function is the masked product written out
 (:func:`masked_attention`): what the CPU tests run and what the kernel
 is tested against.
+
+Learned sparse attention (a mask that is DATA) is three functions. With
+``t`` a query position and ``s <= t`` a key position:
+
+- :func:`index_scores`: ``I[t, s] = (J E)^-1/2 sum_j w[t, j]
+  relu(qI[t, j] . kI[s])`` from ``J`` index heads of ``E`` dimensions
+  over ONE index key head; float32, a block at a time (on the TPU a
+  Pallas kernel over the causal tiles), so that ``[J, T, T]`` never
+  exists;
+- :func:`select_top_k`: ``S[t]``, the ``min(t + 1, k)`` positions ``s <=
+  t`` of largest ``I[t, s]``, ties to the lower ``s`` — exact (the
+  ``k``-th largest score of a row is found bit by bit on the scores'
+  order-preserving integer image, no sort and no approximation; on the
+  TPU a Pallas kernel that keeps a block of rows in fast memory through
+  its passes) and one set a query, shared by every head;
+- :func:`selected_attention`: softmax attention over ``S[t]`` alone. On
+  the TPU the same splash kernel over the selection as a DYNAMIC mask
+  (every block's mask bits are an operand; a block in which no query
+  keeps a key is never visited), forward and backward; elsewhere the
+  masked product. The selection is discrete: no gradient reaches the
+  index through it.
 """
 
 from __future__ import annotations
@@ -44,18 +66,37 @@ def attention_mask(t: int, window: int | None) -> np.ndarray:
     return seen
 
 
-def masked_attention(q, k, v, window: int | None = None) -> jax.Array:
-    """The arithmetic itself: scores ``q k^T / sqrt(D)``, the mask, a
-    float32 softmax, the mix. Holds ``[B, H, T, T]`` scores."""
+def masked_attention(q, k, v, window: int | None = None,
+                     selection=None) -> jax.Array:
+    """The arithmetic itself: scores ``q k^T / sqrt(D)``, the mask (the
+    causal or window one, or ``selection`` ``[B, T, T]`` bool: the keys
+    each query reads), a float32 softmax, the mix. Holds ``[B, H, T,
+    T]`` scores."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
     qg = q.reshape(b, t, hkv, h // hkv, d)
     s = jnp.einsum("bqgnd,bkgd->bgnqk", qg, k,
                    preferred_element_type=jnp.float32) / np.sqrt(d)
-    s = jnp.where(attention_mask(t, window), s, -jnp.inf)
+    seen = (attention_mask(t, window) if selection is None
+            else selection[:, None, None])
+    s = jnp.where(seen, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     a = jnp.einsum("bgnqk,bkgd->bqgnd", p.astype(v.dtype), v)
     return a.reshape(b, t, h, d).astype(q.dtype)
+
+
+@functools.lru_cache(maxsize=16)
+def _block_sizes(t: int):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    blk = min(BLOCK, t)
+    return sk.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        block_q_dq=blk, block_kv_dq=blk,
+    )
 
 
 @functools.lru_cache(maxsize=16)
@@ -70,12 +111,7 @@ def _splash_kernel(t: int, group: int, window: int | None,
 
     one = (sm.CausalMask((t, t)) if window is None
            else sm.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
-    blk = min(BLOCK, t)
-    sizes = sk.BlockSizes(
-        block_q=blk, block_kv=blk, block_kv_compute=blk,
-        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
-        block_q_dq=blk, block_kv_dq=blk,
-    )
+    sizes = _block_sizes(t)
     # the kernel object keeps its mask tables as arrays: made concrete
     # here, so that one traced program's tracers never reach the next
     with jax.ensure_compile_time_eval():
@@ -101,14 +137,265 @@ def splash_attention(q, k, v, window: int | None = None,
 
 
 def causal_attention(q, k, v, causal: bool = True,
-                     window: int | None = None) -> jax.Array:
-    """``[B, T, H, D]`` causal (optionally windowed) grouped-query
-    attention behind the ``AttnFn`` contract of
-    :mod:`fedml_tpu.models.transformer`; the kernel on the TPU, the
-    masked product elsewhere."""
+                     window: int | None = None, selection=None) -> jax.Array:
+    """``[B, T, H, D]`` causal grouped-query attention — within
+    ``window`` keys, or over ``selection`` (``[B, T, T]`` bool from
+    :func:`select_top_k`: :func:`selected_attention`) — behind the
+    ``AttnFn`` contract of :mod:`fedml_tpu.models.transformer`; the
+    kernel on the TPU, the masked product elsewhere."""
     if not causal:
         raise ValueError("causal_attention is causal; use full_attention")
+    if selection is not None:
+        return selected_attention(q, k, v, selection)
     with jax.named_scope("fedml.model.attn.kernel"):
         if _on_tpu():
             return splash_attention(q, k, v, window)
         return masked_attention(q, k, v, window)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention: index, selection, attention over the selection
+# ---------------------------------------------------------------------------
+
+#: what a stack with sparse-attention layers counts, in this order: keys
+#: selected (sum over layers and queries of ``|S[t]|``) and keys a dense
+#: causal layer would read (sum of ``t + 1``)
+ATTN_COUNTERS = ("attn_keys_selected", "attn_keys_causal")
+
+_INT_MIN = np.iinfo(np.int32).min
+
+
+def index_scores(qi, ki, w) -> jax.Array:
+    """``qi`` ``[B, T, J, E]`` index queries, ``ki`` ``[B, T, E]`` the
+    one index key head, ``w`` ``[B, T, J]`` head weights -> ``I`` ``[B,
+    T, T]`` float32 (module docstring). Products take their inputs as
+    they come (the step's compute dtype) and accumulate in float32. On
+    the TPU a kernel that scores the blocks a causal query can read and
+    leaves ``I[t, s]`` for ``s`` in a later block than ``t`` UNWRITTEN
+    (:func:`select_top_k` never reads ``s > t``); elsewhere every pair,
+    a block of :data:`BLOCK` queries at a time. The scores exist to be
+    ranked: they are cut off from every gradient."""
+    qi, ki, w = jax.lax.stop_gradient((qi, ki, w))
+    if _whole_blocks(qi.shape[1], BLOCK, "index_scores") and _on_tpu():
+        return index_scores_kernel(qi, ki, w)
+    return index_scores_blocks(qi, ki, w)
+
+
+def _whole_blocks(t: int, edge: int, what: str) -> bool:
+    """Whether ``t`` tokens are whole blocks of ``edge``, which is what
+    ``what``'s kernel tiles. A sequence of less than one block is small
+    enough for the written-out form; a longer one that is not whole
+    blocks is refused, since the written-out form would be several
+    times slower on the chip without a word."""
+    if t > edge and t % edge:
+        raise ValueError(
+            f"{what}: {t} tokens are not whole blocks of {edge}; pad the "
+            "sequence")
+    return t % edge == 0
+
+
+def index_scores_blocks(qi, ki, w) -> jax.Array:
+    """:func:`index_scores` written out."""
+    b, t, j, e = qi.shape
+    blk = min(BLOCK, t)
+
+    def block(args):
+        q_blk, w_blk = args  # [B, blk, J, E], [B, blk, J]
+        s = jnp.einsum("bqje,bke->bqjk", q_blk, ki,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("bqjk,bqj->bqk", jax.nn.relu(s),
+                          w_blk.astype(jnp.float32))
+
+    split = lambda x: jnp.moveaxis(
+        x.reshape(b, t // blk, blk, *x.shape[2:]), 1, 0)
+    scores = jax.lax.map(block, (split(qi), split(w)))  # [T/blk, B, blk, T]
+    return jnp.moveaxis(scores, 0, 1).reshape(b, t, t) * (j * e) ** -0.5
+
+
+def index_scores_kernel(qi, ki, w, interpret: bool = False) -> jax.Array:
+    """:func:`index_scores`' TPU kernel: one ``[block, block]`` tile of
+    scores a grid step — ``J`` products ``[block, E] x [E, block]``,
+    each through a relu and its head's weight into a float32
+    accumulator — over the tiles at or under the diagonal; a tile above
+    it is neither computed nor written."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, j, e = qi.shape
+    blk = min(BLOCK, t)
+    n = t // blk
+
+    def kernel(q_ref, k_ref, w_ref, o_ref):
+        @pl.when(pl.program_id(2) <= pl.program_id(1))
+        def _():
+            keys, weights = k_ref[...], w_ref[...]
+            acc = jnp.zeros((blk, blk), jnp.float32)
+            for head in range(j):
+                dots = jax.lax.dot_general(
+                    q_ref[head], keys, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                acc += weights[:, head:head + 1] * jnp.maximum(dots, 0.0)
+            o_ref[...] = acc
+
+    under = lambda qb, kb: jnp.minimum(qb, kb)  # a tile above: the diagonal's
+    return pl.pallas_call(
+        kernel,
+        grid=(b, n, n),
+        in_specs=[
+            pl.BlockSpec((None, j, blk, e), lambda i, qb, kb: (i, 0, qb, 0)),
+            pl.BlockSpec((None, blk, e),
+                         lambda i, qb, kb: (i, under(qb, kb), 0)),
+            pl.BlockSpec((None, blk, j), lambda i, qb, kb: (i, qb, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, blk, blk),
+                               lambda i, qb, kb: (i, qb, under(qb, kb))),
+        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # float32 inputs (the evaluator's) take 19.4 MB of it
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret, name="sparse_index_scores",
+    )(qi.transpose(0, 2, 1, 3), ki,
+      w.astype(jnp.float32) * (j * e) ** -0.5)
+
+
+def _ordered(scores):
+    """float32 -> int32 with the same order (``-0.0`` as ``0.0``)."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    bits = jnp.where(scores == 0, 0, bits)
+    return jnp.where(bits < 0, bits ^ np.int32(0x7FFFFFFF), bits)
+
+
+#: rows of scores one step of the selection kernel ranks
+SELECT_ROWS = 128
+
+
+def select_top_k(scores, k: int) -> jax.Array:
+    """``scores`` ``[B, T, T]`` -> ``[B, T, T]`` bool: for query ``t``
+    the ``min(t + 1, k)`` keys ``s <= t`` of largest score, ties to the
+    lower ``s``; ``scores[t, s]`` for ``s > t`` is never read. Exact: a
+    row's ``k``-th largest score is built bit by bit on the scores'
+    order-preserving integer image (32 counting passes over the row),
+    then the position of the last of the equal scores it may keep the
+    same way. On the TPU a kernel that holds :data:`SELECT_ROWS` rows in
+    fast memory through all their passes; elsewhere the same passes over
+    the whole array."""
+    if k < 1:
+        raise ValueError(f"select_top_k: k = {k}")
+    b, t, _ = scores.shape
+    if k >= t:  # every causal key is kept: nothing to rank
+        return jnp.broadcast_to(attention_mask(t, None), (b, t, t))
+    if _on_tpu() and _whole_blocks(t, SELECT_ROWS, "select_top_k"):
+        return select_top_k_kernel(scores, k)
+    return select_top_k_passes(scores, k)
+
+
+def _kept(key, rows, cols, k: int):
+    """The keys kept of ``key`` (``[..., R, T]`` rows of
+    order-preserving int32 scores, ``_INT_MIN`` where ``s > t``);
+    ``rows`` ``[R, 1]`` and ``cols`` their positions."""
+    count = lambda m: jnp.sum(m.astype(jnp.int32), -1, keepdims=True)
+    keep = jnp.minimum(rows + 1, k)
+    zero = jnp.zeros(key.shape[:-1] + (1,), jnp.int32)
+
+    def raise_bit(i, kth):  # bits 30 .. 0, after the sign
+        above = kth | jnp.left_shift(np.int32(1), 30 - i)
+        return jnp.where(count(key >= above) >= keep, above, kth)
+
+    kth = jax.lax.fori_loop(0, 31, raise_bit, jnp.where(
+        count(key >= zero) >= keep, zero, _INT_MIN))
+    over, level = key > kth, key == kth
+    owed = keep - count(over)  # of the keys level with the k-th
+    bits = max(1, int(key.shape[-1] - 1).bit_length())
+
+    def raise_position(i, last):
+        later = last | jnp.left_shift(np.int32(1), bits - 1 - i)
+        return jnp.where(count(level & (cols < later)) < owed, later, last)
+
+    last = jax.lax.fori_loop(0, bits, raise_position, zero)
+    return over | (level & (cols <= last))
+
+
+def select_top_k_passes(scores, k: int) -> jax.Array:
+    """:func:`select_top_k` written out."""
+    b, t, _ = scores.shape
+    rows, cols = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    key = jnp.where(cols <= rows, _ordered(scores), _INT_MIN)
+    return _kept(key, rows, cols, k)
+
+
+def select_top_k_kernel(scores, k: int, interpret: bool = False) -> jax.Array:
+    """:func:`select_top_k`'s TPU kernel: :data:`SELECT_ROWS` rows of
+    scores a grid step, ranked where they lie; a block of rows that all
+    keep every causal key (``t < k``) is not ranked."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, _ = scores.shape
+    r = SELECT_ROWS
+
+    def kernel(s_ref, o_ref):
+        first = pl.program_id(1) * r
+        rows = first + jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (r, t), 1)
+        causal = cols <= rows
+
+        @pl.when(first + r <= k)
+        def _():
+            o_ref[...] = causal.astype(jnp.int8)
+
+        @pl.when(first + r > k)
+        def _():
+            key = jnp.where(causal, _ordered(s_ref[...]), _INT_MIN)
+            o_ref[...] = _kept(key, rows, cols, k).astype(jnp.int8)
+
+    rows_of = pl.BlockSpec((None, r, t), lambda i, block: (i, block, 0))
+    return pl.pallas_call(
+        kernel, grid=(b, t // r), in_specs=[rows_of], out_specs=rows_of,
+        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=100 * 2 ** 20),
+        interpret=interpret, name="sparse_select_top_k",
+    )(scores.astype(jnp.float32)) != 0
+
+
+def selected_splash(q, k, v, selection, interpret: bool = False) -> jax.Array:
+    """:func:`selected_attention`'s TPU kernel: splash attention with
+    ``selection`` as a dynamic mask, one kernel a query head (the mask's
+    blocks are read a head; keys and values too), a sequence at a
+    time."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    qg = (q * (d ** -0.5)).astype(q.dtype).reshape(b, t, hkv, h // hkv, 1, d)
+    qg = qg.transpose(0, 2, 3, 4, 1, 5)  # [B, Hkv, group, 1, T, D]
+    kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    out = []
+    for i in range(b):
+        kernel = sk.make_splash_mqa_single_device(
+            selection[i][None], block_sizes=_block_sizes(t),
+            interpret=interpret)
+        heads = jax.vmap(jax.vmap(kernel, in_axes=(0, None, None)))
+        out.append(heads(qg[i], kg[i], vg[i]))  # [Hkv, group, 1, T, D]
+    a = jnp.stack(out)[:, :, :, 0]
+    return a.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
+
+
+def selected_attention(q, k, v, selection) -> jax.Array:
+    """``[B, T, H, D]`` grouped-query attention in which query ``t``
+    reads the keys ``selection[b, t]`` marks (``[B, T, T]`` bool, a
+    subset of ``s <= t`` that is never empty; :func:`select_top_k` keeps
+    ``t`` itself only if it ranks) and no other: per head,
+    softmax over the marked keys of ``q . k / sqrt(D)``, then the mix of
+    their values. Where every causal key is marked this is dense causal
+    attention, to the bit of the written-out form. No gradient flows to
+    ``selection``."""
+    selection = jax.lax.stop_gradient(selection)
+    with jax.named_scope("fedml.model.attn.kernel"):
+        if _on_tpu():
+            return selected_splash(q, k, v, selection)
+        return masked_attention(q, k, v, selection=selection)

@@ -208,11 +208,18 @@ def _transposed(x, w, sizes, g):
     return jax.vjp(lambda x, w: grouped_product(x, w, sizes), x, w)[1](g)
 
 
-def route_top_k(scores, top_k: int, scale: float):
-    """Sigmoid scores ``[N, E]`` (float32) -> the ``top_k`` largest a
-    token, their weights renormalised over those ``top_k`` and times
+#: how a router turns its logits into the probabilities it ranks
+SCORINGS = {"sigmoid": jax.nn.sigmoid,
+            "softmax": lambda scores: jax.nn.softmax(scores, -1)}
+
+
+def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid"):
+    """Router logits ``[N, E]`` (float32) over ALL experts -> the
+    ``top_k`` a token of largest probability — ``scoring`` ``sigmoid``
+    (each expert's own) or ``softmax`` (over all ``E``, in float32) —
+    their weights renormalised over those ``top_k`` and times
     ``scale``: ``(expert ids [N, k], weights [N, k])``."""
-    p = jax.nn.sigmoid(scores)
+    p = SCORINGS[scoring](scores)
     top_p, top_e = jax.lax.top_k(p, top_k)
     return top_e, scale * top_p / jnp.sum(top_p, -1, keepdims=True)
 
@@ -381,7 +388,8 @@ def _held_experts_bwd(c, res, cotangents):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float):
+def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
+              scoring: str = "sigmoid"):
     """This chip's part of one sparse-expert layer, dropless.
 
     ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts, ``w1`` /
@@ -390,7 +398,8 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float):
     count)`` — and ``shared`` (``w1, w3, w2`` of the shared expert) or
     no such key. ``h``: ``[N, D]`` tokens.
 
-    Every token is routed over all ``E`` experts; the assignments whose
+    Every token is routed over all ``E`` experts (:func:`route_top_k`,
+    ``scoring`` its kind of probability); the assignments whose
     expert is held are ordered by expert and go through grouped matrix
     products (:func:`grouped_product`: on the TPU a kernel whose work
     follows the rows present, never ``E x N``), are weighted —
@@ -413,7 +422,7 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float):
     with jax.named_scope(ROUTE):
         scores = jnp.dot(h, params["router"],
                          preferred_element_type=jnp.float32)
-        top_e, top_w = route_top_k(scores, top_k, scale)
+        top_e, top_w = route_top_k(scores, top_k, scale, scoring)
         local = top_e.reshape(-1) - first
         # an assignment to an absent expert sorts past every held group
         group = jnp.where((local >= 0) & (local < count), local, count)

@@ -110,6 +110,36 @@ def test_decoder_attention_compiles_for_v5e(one_chip, monkeypatch, heads,
         2048 * heads * 2)
 
 
+def test_sparse_attention_compiles_for_v5e(one_chip, monkeypatch):
+    """Learned sparse attention at Keye-VL-2.0-30B-A3B's widths (one
+    sequence of 8,192; an index of 16 heads of 64 over one key head that
+    keeps 2,048 keys a query; 32 query heads over 4 key-value heads of
+    128): the index and selection kernels, and attention over the
+    selection forward and backward — five Mosaic kernels in the program
+    and no ``[heads, T, T]`` tensor in its scratch."""
+    from fedml_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    t = 8192
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+
+    def loss(q, k, v, qi, ki, w):
+        selection = A.select_top_k(A.index_scores(qi, ki, w), 2048)
+        return A.causal_attention(q, k, v, selection=selection).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds(1, t, 32, 128), sds(1, t, 4, 128), sds(1, t, 4, 128),
+        sds(1, t, 16, 64), sds(1, t, 64), sds(1, t, 16)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5
+    assert "sparse_index_scores" in text and "sparse_select_top_k" in text
+    # the scores (268 MB), the selection and its three blocked copies
+    # (67 MB each): far under one key-value head's [8, T, T] scores
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def test_grouped_expert_products_compile_for_v5e(one_chip):
     """One sparse layer's share at Laguna-XS.2's widths (4,096 tokens,
     8 of 256 experts a token, 32 held of width 512), forward and

@@ -1,8 +1,9 @@
 """The configured decoder stack (``create_model`` name ``decoder``)
-against the plain reference of ``benchmarks/configs/laguna-xs2-share8``
-at tiny widths, and the pieces it is made of: windowed grouped-query
-attention, the chip's share of a sparse-expert layer, the counters a
-round carries."""
+against the plain references of ``benchmarks/configs/laguna-xs2-share8``
+and ``keye-vl2-a3b-share8`` at tiny widths, and the pieces it is made
+of: windowed grouped-query attention, attention over the keys a learned
+index selects, the chip's share of a sparse-expert layer under either
+router scoring, the counters a round carries."""
 
 import os
 import sys
@@ -19,6 +20,7 @@ for _p in (os.path.join(ROOT, "benchmarks"),
         sys.path.insert(0, _p)
 
 import tiny_decoder as TD  # noqa: E402
+import tiny_keye as TK  # noqa: E402
 
 from fedml_tpu.config import (  # noqa: E402
     DataConfig, ExperimentConfig, FedConfig, ModelConfig, TrainConfig,
@@ -109,11 +111,12 @@ def _layer_params(key, d=64, experts=16, f=32, first=0, count=16,
     return p, every
 
 
-def _uncut_layer(p, every, h, top_k, scale, first=0):
+def _uncut_layer(p, every, h, top_k, scale, first=0, scoring="sigmoid"):
     """The whole layer written out: every expert on every token, a
     mask for the chosen ones (no share, no sort). ``every`` holds the
     experts from id ``first`` on: a share of them written out."""
-    prob = jax.nn.sigmoid(h @ p["router"])
+    prob = (jax.nn.sigmoid(h @ p["router"]) if scoring == "sigmoid"
+            else jax.nn.softmax(h @ p["router"], -1))
     top_p, top_e = jax.lax.top_k(prob, top_k)
     w = scale * top_p / top_p.sum(-1, keepdims=True)
     y = jnp.zeros_like(h)
@@ -121,24 +124,27 @@ def _uncut_layer(p, every, h, top_k, scale, first=0):
         share = jnp.where(top_e == first + e, w, 0.0).sum(-1)
         y += share[:, None] * MOE.gated_ffn(
             h, every["w1"][e], every["w3"][e], every["w2"][e])
-    return y + MOE.gated_ffn(h, *p["shared"])
+    return y + MOE.gated_ffn(h, *p["shared"]) if "shared" in p else y
 
 
-def test_the_eight_shares_add_up_to_the_uncut_layer():
-    """(b) one sparse layer, 16 experts top-4: the outputs of all 8
-    shares (held = (0, 2), (2, 2), ...), the shared expert counted
-    once, sum to the uncut layer's output; their held rows sum to every
-    assignment made."""
+@pytest.mark.parametrize("scoring, scale, shared", [
+    ("sigmoid", 2.5, True), ("softmax", 1.0, False)])
+def test_the_eight_shares_add_up_to_the_uncut_layer(scoring, scale, shared):
+    """(b) one sparse layer, 16 experts top-4, under either router
+    scoring: the outputs of all 8 shares (held = (0, 2), (2, 2), ...),
+    the shared expert — where the layer has one — counted once, sum to
+    the uncut layer's output; their held rows sum to every assignment
+    made."""
     key = jax.random.key(7)
     h = jax.random.normal(jax.random.fold_in(key, 1), (48, 64))
-    full, every = _layer_params(key)
-    whole = _uncut_layer(full, every, h, 4, 2.5)
+    full, every = _layer_params(key, shared=shared)
+    whole = _uncut_layer(full, every, h, 4, scale, scoring=scoring)
     total, rows = jnp.zeros_like(h), 0.0
     for share in range(8):
-        p, _ = _layer_params(key, first=2 * share, count=2)
-        if share:
-            del p["shared"]  # what every chip computes alike: once
-        y, counters = MOE.moe_layer(p, h, (2 * share, 2), 4, 2.5)
+        p, _ = _layer_params(key, first=2 * share, count=2,
+                             shared=shared and not share)
+        # (what every chip computes alike, the shared expert: once)
+        y, counters = MOE.moe_layer(p, h, (2 * share, 2), 4, scale, scoring)
         total, rows = total + y, rows + float(counters[0])
         assert float(counters[1]) == 48 * 4
     np.testing.assert_allclose(total, whole, rtol=2e-5, atol=2e-5)
@@ -183,6 +189,22 @@ def test_windowed_attention_against_the_masked_product(heads, window,
     # off the TPU the model's attention IS the masked product
     np.testing.assert_array_equal(
         A.causal_attention(q, k, v, window=window), masked(q, k, v))
+
+
+def test_softmax_routing_ranks_over_all_experts_and_renormalises():
+    logits = jax.random.normal(jax.random.key(5), (12, 16))
+    top_e, top_w = MOE.route_top_k(logits, 4, 1.0, "softmax")
+    prob = np.asarray(jax.nn.softmax(logits, -1))
+    np.testing.assert_array_equal(
+        np.sort(top_e, -1), np.sort(np.argsort(-prob, -1)[:, :4], -1))
+    np.testing.assert_allclose(top_w.sum(-1), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(
+        top_w, np.take_along_axis(prob, np.asarray(top_e), -1)
+        / np.take_along_axis(prob, np.asarray(top_e), -1).sum(-1,
+                                                              keepdims=True),
+        rtol=1e-6)
+    with pytest.raises(KeyError):
+        MOE.route_top_k(logits, 4, 1.0, "argmax")
 
 
 def test_dropless_under_imbalance():
@@ -404,16 +426,16 @@ def test_rows_the_chip_leaves_unwritten_reach_nothing(case, monkeypatch):
     assert float(counters[3]) == (0.0 if case == "one_over" else 256.0)
 
 
-def _sim(config, block):
+def _sim(config, block, seq=TD.SEQ, vocab=TD.VOCAB):
     from fedml_tpu.algorithms.fedavg import FedAvgSim
     from fedml_tpu.data.federated import FederatedData
 
     rng = np.random.default_rng(5)
-    seq = rng.integers(0, TD.VOCAB, (32, TD.SEQ + 1)).astype(np.int32)
+    seq = rng.integers(0, vocab, (32, seq + 1)).astype(np.int32)
     maps = {c: np.arange(4 * c, 4 * c + 4) for c in range(8)}
     data = FederatedData(
         seq[:, :-1], seq[:, 1:], seq[:8, :-1], seq[:8, 1:], maps,
-        {c: np.arange(c, c + 1) for c in range(8)}, TD.VOCAB, "nwp")
+        {c: np.arange(c, c + 1) for c in range(8)}, vocab, "nwp")
     cfg = ExperimentConfig(
         data=DataConfig(dataset="tokens", num_clients=8, batch_size=2),
         model=_model_config(config), train=TrainConfig(lr=0.05, epochs=1),
@@ -554,3 +576,172 @@ def test_every_configuration_brings_its_reference():
             leaf = leaf[key]
         assert jax.tree.leaves(leaf), path
         assert ref.step_flops(2) == 2 * ref.step_flops(1) > 0
+
+
+# ---------------------------------------------------------------------------
+# a stack of sparse-attention layers (``keye-vl2-a3b-share8`` at tiny widths)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_keye(tmp_path_factory):
+    config = TK.tiny_config()
+    ref = TK.load_reference(str(tmp_path_factory.mktemp("keye_ref")), config)
+    model = create_model(_model_config(config))
+    variables = jax.jit(ref.init)(jax.random.key(3))
+    tokens = jax.random.randint(
+        jax.random.key(4), (2, TK.SEQ + 1), 0, TK.VOCAB)
+    return config, ref, model, variables, tokens
+
+
+INDEX_LEAVES = ("index_q_proj", "index_k_proj", "index_w_proj")
+
+
+def test_sparse_attention_stack_against_reference_logits_and_gradients(
+        tiny_keye):
+    """float32, two layers of selected-keys attention (64 tokens, 16
+    keys a query, per-head q / k norms) over softmax-routed experts (2
+    of 8 held, none shared): logits, every parameter's gradient — the
+    index projections' exactly zero on both sides — and the counters."""
+    _, ref, model, variables, tokens = tiny_keye
+    x, y = tokens[:, :-1], tokens[:, 1:]
+    assert (jax.tree.structure(model.init(jax.random.key(0)))
+            == jax.tree.structure(variables))
+
+    def program(params):
+        logits, _, counted = model.apply_train_counted(
+            {"params": params}, x, jax.random.key(0))
+        return _loss(logits, y), (logits, counted)
+
+    def reference(params):
+        logits, _ = ref.forward({"params": params}, x, True)
+        return _loss(logits, y), logits
+
+    (_, (ours, counted)), g_ours = jax.value_and_grad(
+        program, has_aux=True)(variables["params"])
+    (_, theirs), g_ref = jax.value_and_grad(
+        reference, has_aux=True)(variables["params"])
+    np.testing.assert_allclose(ours, theirs, rtol=2e-4, atol=2e-4)
+    flat_ref = dict(jax.tree_util.tree_leaves_with_path(g_ref))
+    for path, g in jax.tree_util.tree_leaves_with_path(g_ours):
+        r = flat_ref[path]
+        if any(name in jax.tree_util.keystr(path) for name in INDEX_LEAVES):
+            assert not np.asarray(g).any() and not np.asarray(r).any()
+            continue
+        scale = float(jnp.max(jnp.abs(r))) + 1e-12
+        assert float(jnp.max(jnp.abs(g - r))) <= 2e-3 * scale, (
+            jax.tree_util.keystr(path))
+        assert scale > 1e-9, jax.tree_util.keystr(path)  # it is trained
+    per_sequence = sum(min(t + 1, 16) for t in range(TK.SEQ))
+    assert float(counted["attn_keys_selected"]) == 2 * TK.LAYERS * (
+        per_sequence)
+    assert float(counted["attn_keys_causal"]) == 2 * TK.LAYERS * (
+        TK.SEQ * (TK.SEQ + 1) // 2)
+    assert float(counted["moe_rows_routed"]) == x.size * 2 * TK.LAYERS
+
+
+def test_topk_of_the_sequence_length_selects_every_causal_key(tmp_path):
+    """``topk >= T``: the selected share is 100 % and the stack is the
+    same stack with the index's choice taken away (every causal key),
+    which the reference of the same sizes confirms."""
+    config = TK.tiny_config(
+        sparse_attention={"index_heads": 4, "index_head_dim": 8,
+                          "topk": TK.SEQ})
+    ref = TK.load_reference(str(tmp_path), config)
+    model = create_model(_model_config(config))
+    variables = jax.jit(ref.init)(jax.random.key(6))
+    x = jax.random.randint(jax.random.key(7), (1, TK.SEQ), 0, TK.VOCAB)
+    logits, _, counted = model.apply_train_counted(
+        variables, x, jax.random.key(0))
+    assert float(counted["attn_keys_selected"]) == float(
+        counted["attn_keys_causal"]) == TK.LAYERS * TK.SEQ * (TK.SEQ + 1) / 2
+    np.testing.assert_allclose(
+        logits, ref.forward(variables, x, True)[0], rtol=2e-4, atol=2e-4)
+
+
+def test_a_round_returns_the_index_parameters_unchanged(tiny_keye):
+    """The selection is a set: under the next-token loss the index
+    projections get exactly zero gradient, so a federated round (bulk
+    engine, block of one) hands them back bit for bit while every other
+    leaf moves; the round's record carries the two attention counts."""
+    config = tiny_keye[0]
+
+    class Sink:
+        records = []
+
+        def log(self, record):
+            self.records.append(dict(record))
+
+    sim = _sim(config, 1, seq=TK.SEQ, vocab=TK.VOCAB)
+    before = jax.device_get(sim.init().variables)
+    after = jax.device_get(sim.run(metrics_sink=Sink()).variables)
+    flat = dict(jax.tree_util.tree_leaves_with_path(after))
+    moved = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(before):
+        name = jax.tree_util.keystr(path)
+        if any(n in name for n in INDEX_LEAVES):
+            np.testing.assert_array_equal(leaf, flat[path], err_msg=name)
+        else:
+            moved += int(not np.array_equal(leaf, flat[path]))
+    assert moved == len(flat) - 3 * TK.LAYERS
+    record = Sink.records[0]
+    steps = 2 * 2  # clients a round x steps a client, 2 sequences a step
+    assert record["attn_keys_causal"] == steps * 2 * TK.LAYERS * (
+        TK.SEQ * (TK.SEQ + 1) // 2)
+    assert 0 < record["attn_keys_selected"] < record["attn_keys_causal"]
+    assert record["moe_rows_routed"] == steps * 2 * TK.SEQ * 2 * TK.LAYERS
+
+
+@pytest.mark.parametrize("change, message", [
+    ({"sparse_attention": {"index_heads": 4, "index_head_dim": 8,
+                           "topk": 0}}, "at least 1"),
+    ({"sparse_attention": {"index_heads": 4, "topk": 16}},
+     "lacks index_head_dim"),
+    ({"sparse_attention": None}, "lacks index_heads"),
+    ({"router_scoring": "argmax"}, "unknown router_scoring"),
+])
+def test_a_configuration_that_cannot_be_built_is_refused(change, message):
+    extra = {**TK.tiny_config()["model"]["extra"], **change}
+    with pytest.raises(ValueError, match=message):
+        create_model(ModelConfig(
+            name="decoder", num_classes=TK.VOCAB, input_shape=(TK.SEQ,),
+            extra=tuple(extra.items())))
+
+
+def test_log_span_carries_the_attention_counters():
+    from fedml_tpu.core.tracing import log_span
+
+    attrs = log_span({"round": 3, "train_loss": 1.0,
+                      "attn_keys_selected": 904.0,
+                      "attn_keys_causal": 2080.0}).attrs
+    assert attrs == {"round": 3, "attn_keys_selected": 904,
+                     "attn_keys_causal": 2080}
+
+
+@pytest.mark.parametrize("layers, millions", [(5, 562.3), (6, 659.2)])
+def test_published_keye_share_parameter_count(layers, millions):
+    """The cut Keye-VL-2.0-30B-A3B language model as the configuration's
+    file gives it (5 layers) and at the depth it passed over (6),
+    counted from ``eval_shape`` alone."""
+    config = TK.real_config()
+    extra = config["model"]["extra"]
+    assert len(extra["layer_types"]) == 5  # what the file runs
+    for key in ("heads_per_layer", "layer_types", "mlp_layer_types"):
+        extra[key] = extra[key][:1] * layers
+    model = create_model(_model_config(config))
+    assert model.counters == MOE.MOE_COUNTERS + A.ATTN_COUNTERS
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    count = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    assert round(count / 1e6, 1) == millions, count
+    layer = shapes["params"]["layer_0"]
+    per_layer = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(layer))
+    assert round(per_layer / 1e6, 2) == 96.90
+    assert layer["experts_w1"].shape == (16, 2048, 768)
+    assert layer["router"].shape == (2048, 128)
+    assert layer["q_proj"]["kernel"].shape == (2048, 32 * 128)
+    assert layer["k_proj"]["kernel"].shape == (2048, 4 * 128)
+    assert layer["index_q_proj"]["kernel"].shape == (2048, 16 * 64)
+    assert layer["index_k_proj"]["kernel"].shape == (2048, 64)
+    assert layer["index_w_proj"]["kernel"].shape == (2048, 16)
+    assert layer["q_norm"]["scale"].shape == (128,)
+    assert shapes["params"]["lm_head"]["kernel"].shape == (2048, 18992)
