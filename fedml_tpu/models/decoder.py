@@ -36,8 +36,13 @@ Per layer ``l`` (pre-norm residual blocks, RMSNorm, no bias anywhere):
   ``shared_expert_intermediate_size``, none at 0).
 
 Untied embedding and head over ``vocab_size`` rows. Each layer is
-recomputed in the backward pass (``nn.remat``: only a layer's input is
-kept). What the layers count (:func:`counter_names`: the sparse layers'
+recomputed in the backward pass (``nn.remat``) but for what
+:data:`fedml_tpu.ops.attention.KEPT` names: beside a layer's input, its
+attention kernel's output and row log-sum-exp and, in a
+sparse-attention layer, the selection are kept, so the forward
+attention kernel, the index and the top-k run once a training step and
+the projections, norms, rotary, gate and the whole feed-forward twice.
+What the layers count (:func:`counter_names`: the sparse layers'
 :data:`fedml_tpu.ops.moe.MOE_COUNTERS` and, in a stack with
 sparse-attention layers, :data:`fedml_tpu.ops.attention.
 ATTN_COUNTERS`) is summed over layers and sown into the ``counters``
@@ -58,10 +63,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from fedml_tpu.models.transformer import AttnFn, DenseFactory, _dense
 from fedml_tpu.ops.attention import (
-    ATTN_COUNTERS, causal_attention, index_scores, select_top_k,
+    ATTN_COUNTERS, KEPT, KEPT_SELECTION, causal_attention, index_scores,
+    select_top_k,
 )
 from fedml_tpu.ops.moe import MOE_COUNTERS, SCORINGS, moe_layer
 
@@ -233,7 +240,11 @@ class DecoderLayer(nn.Module):
         """The keys each query of a sparse-attention layer reads, from
         the normed layer input ``h`` -> (``[B, T, T]`` bool,
         :data:`ATTN_COUNTERS`' two counts of this call). The index's
-        output is a set: no gradient reaches its projections."""
+        output is a set: no gradient reaches its projections. The set
+        is named :data:`KEPT_SELECTION`: a rematerialised layer keeps
+        it whole for its backward pass, ``B T^2`` bytes (67 MB a
+        sequence of 8,192 tokens, 1.07 GB at 32,768), and neither
+        scores nor ranks a second time."""
         sa = dict(dict(self.cfg)["sparse_attention"])
         b, t, _ = h.shape
         j, e = sa["index_heads"], sa["index_head_dim"]
@@ -247,7 +258,8 @@ class DecoderLayer(nn.Module):
             scores = index_scores(apply_rope(qi, cos, sin),
                                   apply_rope(ki, cos, sin)[:, :, 0], w)
         with jax.named_scope("fedml.model.attn.select"):
-            selection = select_top_k(scores, sa["topk"])
+            selection = checkpoint_name(
+                select_top_k(scores, sa["topk"]), KEPT_SELECTION)
             selected = jnp.sum(selection, dtype=jnp.float32)
         return selection, (selected, jnp.float32(b * t * (t + 1) // 2))
 
@@ -266,7 +278,9 @@ class DecoderLM(nn.Module):
         with jax.named_scope("fedml.model.embed"):
             x = nn.Embed(self.vocab_size, c["hidden_size"],
                          name="embed")(tokens)
-        layer = nn.remat(DecoderLayer)
+        layer = nn.remat(
+            DecoderLayer,
+            policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
         names = counter_names(c["layer_types"])
         counters = jnp.zeros((len(names),), jnp.float32)
         for l in range(len(c["layer_types"])):

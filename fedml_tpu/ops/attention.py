@@ -38,6 +38,22 @@ Learned sparse attention (a mask that is DATA) is three functions. With
   keeps a key is never visited), forward and backward; elsewhere the
   masked product. The selection is discrete: no gradient reaches the
   index through it.
+
+**What a rematerialised layer keeps for its backward pass.** Three
+values carry a ``jax.ad_checkpoint.checkpoint_name`` of :data:`KEPT`,
+and a ``jax.checkpoint`` (``nn.remat``) whose policy is
+``save_only_these_names(*KEPT)`` —
+:class:`fedml_tpu.models.decoder.DecoderLM`'s — keeps them where it
+would run their producers a second time: the attention's output and,
+from the blockwise kernel, its row log-sum-exp (all the kernel's
+backward pass needs of its forward: ``[B, T, H, D]`` and ``[B, H, T]``
+float32) under :data:`KEPT_OUTPUT`, and the selection, WHOLE, under
+:data:`KEPT_SELECTION` (named by the layer that hands it to
+:func:`selected_attention`: ``[B, T, T]`` bool, a byte a pair, so its
+bytes grow with ``T`` squared — 67 MB a layer at 8,192 tokens, 1.07 GB
+at 32,768). So the forward kernel, the index and the top-k run once a
+training step. The names do nothing anywhere else: without a gradient,
+or under no such policy.
 """
 
 from __future__ import annotations
@@ -47,10 +63,16 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 #: query / key block edge of the kernel (tokens); a sequence shorter
 #: than a block is one block
 BLOCK = 512
+
+#: ``checkpoint_name``s (module docstring): the attention's output (with
+#: the kernel's log-sum-exp), and a sparse-attention layer's selection
+KEPT_OUTPUT, KEPT_SELECTION = "fedml_attn_output", "fedml_attn_selection"
+KEPT = (KEPT_OUTPUT, KEPT_SELECTION)
 
 
 def _on_tpu() -> bool:
@@ -71,7 +93,8 @@ def masked_attention(q, k, v, window: int | None = None,
     """The arithmetic itself: scores ``q k^T / sqrt(D)``, the mask (the
     causal or window one, or ``selection`` ``[B, T, T]`` bool: the keys
     each query reads), a float32 softmax, the mix. Holds ``[B, H, T,
-    T]`` scores."""
+    T]`` scores. The output is named :data:`KEPT_OUTPUT`, as the
+    kernel's is."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
     qg = q.reshape(b, t, hkv, h // hkv, d)
@@ -82,7 +105,7 @@ def masked_attention(q, k, v, window: int | None = None,
     s = jnp.where(seen, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     a = jnp.einsum("bgnqk,bkgd->bqgnd", p.astype(v.dtype), v)
-    return a.reshape(b, t, h, d).astype(q.dtype)
+    return checkpoint_name(a.reshape(b, t, h, d).astype(q.dtype), KEPT_OUTPUT)
 
 
 @functools.lru_cache(maxsize=16)
@@ -117,7 +140,7 @@ def _splash_kernel(t: int, group: int, window: int | None,
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mqa_single_device(
             sm.MultiHeadMask([one] * group), block_sizes=sizes,
-            interpret=interpret)
+            residual_checkpoint_name=KEPT_OUTPUT, interpret=interpret)
 
 
 def splash_attention(q, k, v, window: int | None = None,
@@ -378,7 +401,7 @@ def selected_splash(q, k, v, selection, interpret: bool = False) -> jax.Array:
     for i in range(b):
         kernel = sk.make_splash_mqa_single_device(
             selection[i][None], block_sizes=_block_sizes(t),
-            interpret=interpret)
+            residual_checkpoint_name=KEPT_OUTPUT, interpret=interpret)
         heads = jax.vmap(jax.vmap(kernel, in_axes=(0, None, None)))
         out.append(heads(qg[i], kg[i], vg[i]))  # [Hkv, group, 1, T, D]
     a = jnp.stack(out)[:, :, :, 0]

@@ -140,6 +140,50 @@ def test_sparse_attention_compiles_for_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def test_rematerialised_sparse_layer_compiles_for_v5e(one_chip, monkeypatch):
+    """A training step (bfloat16) of ONE decoder layer at
+    Keye-VL-2.0-30B-A3B's widths — learned sparse attention over a
+    sequence of 8,192, a dense feed-forward in the experts' place —
+    under ``DecoderLM``'s remat: the layer keeps its kernel's output,
+    log-sum-exp and its selection, so the program holds the forward
+    kernel, the index and the top-k ONCE beside the two backward
+    kernels, and what it keeps (135 MB) fits beside the recomputed
+    rest."""
+    from fedml_tpu.models.decoder import decoder_from_extra
+    from fedml_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    model = decoder_from_extra({
+        "hidden_size": 2048, "head_dim": 128, "num_key_value_heads": 4,
+        "heads_per_layer": [32], "layer_types": ["sparse_attention"],
+        "mlp_layer_types": ["dense"], "intermediate_size": 768,
+        "qk_norm": True,
+        "rope": {"sparse_attention": {"rope_theta": 1e7}},
+        "sparse_attention": {"index_heads": 16, "index_head_dim": 64,
+                             "topk": 2048}}, 1024)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.key(0),
+                       jnp.zeros((1, 8192), jnp.int32))["params"])
+
+    def loss(params, tokens):
+        logits, _ = model.apply({"params": params}, tokens,
+                                mutable=["counters"])
+        return jnp.mean(logits.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, tokens).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    calls = lambda name: sum(f"%{name}" in line.split("=")[0]
+                             for line in kernels)
+    assert calls("splash_mqa_fwd") == 1
+    assert calls("splash_mqa_dq") == calls("splash_mqa_dkv") == 1
+    assert calls("sparse_select_top_k") == calls("sparse_index_scores") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 def test_grouped_expert_products_compile_for_v5e(one_chip):
     """One sparse layer's share at Laguna-XS.2's widths (4,096 tokens,
     8 of 256 experts a token, 32 held of width 512), forward and

@@ -5,6 +5,8 @@ of: windowed grouped-query attention, attention over the keys a learned
 index selects, the chip's share of a sparse-expert layer under either
 router scoring, the counters a round carries."""
 
+import contextlib
+import functools
 import os
 import sys
 
@@ -21,6 +23,7 @@ for _p in (os.path.join(ROOT, "benchmarks"),
 
 import tiny_decoder as TD  # noqa: E402
 import tiny_keye as TK  # noqa: E402
+from test_sparse_attention import _pallas_calls  # noqa: E402
 
 from fedml_tpu.config import (  # noqa: E402
     DataConfig, ExperimentConfig, FedConfig, ModelConfig, TrainConfig,
@@ -745,3 +748,123 @@ def test_published_keye_share_parameter_count(layers, millions):
     assert layer["index_w_proj"]["kernel"].shape == (2048, 16)
     assert layer["q_norm"]["scale"].shape == (128,)
     assert shapes["params"]["lm_head"]["kernel"].shape == (2048, 18992)
+
+
+# ---------------------------------------------------------------------------
+# what a rematerialised layer keeps for its backward pass
+# ---------------------------------------------------------------------------
+
+KINDS = ("full_attention", "sliding_attention", "sparse_attention")
+KERNELS = ("splash_mqa_fwd", "splash_mqa_dq", "splash_mqa_dkv",
+           "sparse_select_top_k", "sparse_index_scores")
+
+
+def _kept_stack(kind, layers=2):
+    """Two layers of one attention kind over dense feed-forwards, at
+    sizes the splash kernel tiles (heads of 128; 256 tokens are two
+    blocks of 128 under :func:`kernels`): a window of 40, or an index of
+    2 heads of 8 that keeps 40 keys a query."""
+    from fedml_tpu.models.decoder import decoder_from_extra
+
+    return decoder_from_extra({
+        "hidden_size": 64, "head_dim": 128, "num_key_value_heads": 1,
+        "heads_per_layer": [2] * layers, "layer_types": [kind] * layers,
+        "mlp_layer_types": ["dense"] * layers, "intermediate_size": 64,
+        "sliding_window": 40, "qk_norm": True,
+        "rope": {k: {"rope_theta": 1e4} for k in KINDS},
+        "sparse_attention": {"index_heads": 2, "index_head_dim": 8,
+                             "topk": 40}}, 32)
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """The chip's branch of ``ops/attention.py`` with its four kernels
+    in the Pallas interpreter, in blocks of 128."""
+    monkeypatch.setattr(A, "BLOCK", 128)
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    for name in ("splash_attention", "selected_splash",
+                 "index_scores_kernel", "select_top_k_kernel"):
+        monkeypatch.setattr(A, name, functools.partial(
+            getattr(A, name), interpret=True))
+
+
+@pytest.fixture
+def plain_remat(monkeypatch):
+    """-> a context in which ``DecoderLM`` wraps its layers in
+    ``nn.remat`` with no policy: only a layer's input is kept."""
+    import flax.linen as nn
+
+    keeps_names = nn.remat
+
+    @contextlib.contextmanager
+    def plain():
+        with monkeypatch.context() as m:
+            m.setattr(nn, "remat", lambda cls, policy: keeps_names(cls))
+            yield
+
+    return plain
+
+
+def _training_gradient(model, tokens):
+    def loss(params):
+        logits, _ = model.apply({"params": params}, tokens,
+                                mutable=["counters"])
+        return jnp.mean(logits ** 2)
+
+    return jax.grad(loss)
+
+
+def _kernel_calls(fn, *args):
+    """How often each of :data:`KERNELS` is called in ``fn``'s jaxpr."""
+    names = list(_pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr))
+    return {k: sum(name.startswith(k) for name in names) for k in KERNELS}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_layer_runs_its_forward_kernels_once_a_training_step(
+        kind, kernels, plain_remat):
+    """In the jaxpr of a training gradient every layer calls the
+    blockwise kernel's forward ONCE (its output and log-sum-exp are
+    kept: ``ops/attention.KEPT``) and its two backward kernels once —
+    and a sparse-attention layer its index and its top-k once, since
+    the selection is kept — where ``nn.remat`` with no policy runs each
+    forward a second time."""
+    model = _kept_stack(kind)
+    tokens = jax.random.randint(jax.random.key(0), (1, 256), 0, 32)
+    params = jax.eval_shape(model.init, jax.random.key(1), tokens)["params"]
+    selects = 2 * (kind == "sparse_attention")
+    once = {"splash_mqa_fwd": 2, "splash_mqa_dq": 2, "splash_mqa_dkv": 2,
+            "sparse_select_top_k": selects, "sparse_index_scores": selects}
+    assert _kernel_calls(_training_gradient(model, tokens), params) == once
+    with plain_remat():
+        assert _kernel_calls(_training_gradient(model, tokens), params) == {
+            **once, "splash_mqa_fwd": 4, "sparse_select_top_k": 2 * selects,
+            "sparse_index_scores": 2 * selects}
+    # no gradient, nothing to keep: the evaluator's forward pass
+    forward = lambda p: model.apply({"params": p}, tokens)
+    assert _kernel_calls(forward, params) == {
+        **once, "splash_mqa_dq": 0, "splash_mqa_dkv": 0}
+
+
+@pytest.mark.parametrize("path", ["written_out", "kernels"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_kept_values_leave_the_gradient_as_it_was(
+        kind, path, plain_remat, request):
+    """What a layer keeps is what its second run would compute again
+    from the same inputs: every parameter's gradient equals the one
+    under ``nn.remat`` with no policy to the BIT, on the path the CPU
+    runs and on the chip's kernels (Pallas interpreter)."""
+    if path == "kernels":
+        request.getfixturevalue("kernels")
+    model = _kept_stack(kind)
+    tokens = jax.random.randint(jax.random.key(0), (2, 256), 0, 32)
+    params = model.init(jax.random.key(1), tokens)["params"]
+    kept = jax.jit(_training_gradient(model, tokens))(params)
+    with plain_remat():
+        plain = jax.jit(_training_gradient(model, tokens))(params)
+    for (where, a), b in zip(jax.tree_util.tree_leaves_with_path(kept),
+                             jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(a, b, jax.tree_util.keystr(where))
+        trained = not any(n in jax.tree_util.keystr(where)
+                          for n in INDEX_LEAVES)
+        assert bool(jnp.any(a != 0)) == trained, jax.tree_util.keystr(where)

@@ -175,3 +175,61 @@ def test_selected_attention_kernel_against_the_written_out_product(
     # off the TPU the model's attention IS the masked product
     np.testing.assert_array_equal(
         A.causal_attention(q, k, v, selection=selection), masked(q, k, v))
+
+
+def _pallas_calls(jaxpr):
+    """The names of the Pallas kernels ``jaxpr`` and the programs it
+    calls hold, a call each."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("keep, forward_calls", [
+    (None, 2), ((A.KEPT_OUTPUT,), 1), (A.KEPT, 1)],
+    ids=["nothing", "output", "output_and_selection"])
+def test_a_checkpoint_keeps_what_is_named(keep, forward_calls, monkeypatch):
+    """Index, selection and attention over it (the chip's kernels in the
+    Pallas interpreter) inside a ``jax.checkpoint``: under a policy that
+    saves :data:`KEPT_OUTPUT` the forward kernel is not run again for
+    the backward pass, with :data:`KEPT_SELECTION` (the name a layer
+    puts on its selection) neither are the index and the top-k; the
+    names change no number, kept or not."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    monkeypatch.setattr(A, "BLOCK", 128)
+    t, d = 256, 128
+    ks = jax.random.split(jax.random.key(7), 7)
+    q = jax.random.normal(ks[0], (1, t, 2, d))
+    k = jax.random.normal(ks[1], (1, t, 1, d))
+    v = jax.random.normal(ks[2], (1, t, 1, d))
+    qi = jax.random.normal(ks[3], (1, t, 2, 8))
+    ki = jax.random.normal(ks[4], (1, t, 8))
+    w = jax.random.normal(ks[5], (1, t, 2))
+    out = jax.random.normal(ks[6], (2 * d, 4))
+
+    def layer(q, k, v, out):
+        scores = A.index_scores_kernel(qi, ki, w, interpret=True)
+        selection = checkpoint_name(
+            A.select_top_k_kernel(scores, 40, interpret=True),
+            A.KEPT_SELECTION)
+        a = A.selected_splash(q, k, v, selection, interpret=True)
+        return jnp.sum((a.reshape(1, t, 2 * d) @ out) ** 2)
+
+    policy = keep and jax.checkpoint_policies.save_only_these_names(*keep)
+    grad = jax.grad(jax.checkpoint(layer, policy=policy), argnums=(0, 1, 2, 3))
+    calls = list(_pallas_calls(jax.make_jaxpr(grad)(q, k, v, out).jaxpr))
+    count = lambda prefix: sum(name.startswith(prefix) for name in calls)
+    ranks = 1 if keep == A.KEPT else 2
+    assert count("splash_mqa_fwd") == forward_calls
+    assert count("splash_mqa_dq") == count("splash_mqa_dkv") == 1
+    assert count("sparse_select_top_k") == ranks
+    assert count("sparse_index_scores") == ranks
+    for got, want in zip(jax.jit(grad)(q, k, v, out), jax.jit(jax.grad(
+            layer, argnums=(0, 1, 2, 3)))(q, k, v, out)):
+        np.testing.assert_array_equal(got, want)
