@@ -50,11 +50,8 @@ class FedModel:
         dummy = jnp.zeros((1,) + tuple(self.input_shape), self.input_dtype)
         init = lambda r: self.module.init({"params": r}, dummy, train=False)
         variables = (jax.jit(init) if self.jit_init else init)(rng)
-        if self.counters:  # init makes every collection; this one is no state
-            variables = {
-                k: v for k, v in variables.items() if k != "counters"
-            }
-        return variables
+        # init makes every collection; what a module counts is no state
+        return {k: v for k, v in variables.items() if k != "counters"}
 
     def apply_train(
         self, variables: Variables, x: jax.Array, rng: jax.Array

@@ -3,11 +3,16 @@
 ``ModelConfig.extra``, so a published ``config.json`` — cut to a chip's
 share — is a configuration and not a new module.
 
-Per layer ``l`` (pre-norm residual blocks, RMSNorm, no bias anywhere):
+Layer ``l`` is pre-norm residual blocks (RMSNorm, no bias but the
+state-space convolution's): first the sequence mixer ``layer_types[l]``
+names, then the feed-forward ``mlp_layer_types[l]`` names. Either may
+be ``none`` (not both), so a layer can be a mixer alone, ``x +
+mixer(norm(x))``, and a published pattern of single-mixer layers is a
+configuration; any other name is refused.
 
 - attention: ``heads_per_layer[l]`` query heads over
   ``num_key_value_heads`` key-value heads of ``head_dim``;
-  ``layer_types[l]`` is one of three kinds, all causal:
+  ``layer_types[l]`` is one of three attention kinds, all causal:
   ``full_attention``; ``sliding_attention`` (within ``sliding_window``
   keys); ``sparse_attention`` (query ``t`` reads the ``min(t + 1,
   topk)`` keys a learned index ranks highest — ``sparse_attention:
@@ -20,11 +25,23 @@ Per layer ``l`` (pre-norm residual blocks, RMSNorm, no bias anywhere):
   own ``rope`` record (``rope_theta``, ``partial_rotary_factor``,
   ``rope_type`` ``default`` | ``yarn`` with ``factor``,
   ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
-  ``attention_factor``; rotate-half pairing); with ``qk_norm`` every
+  ``attention_factor``; rotate-half pairing; ``rope_type`` ``none``:
+  no rotary and no other position term); with ``qk_norm`` every
   query and key head is RMS-normed (one learned scale over
   ``head_dim``) before it is rotated; with ``gating`` a per-head
   sigmoid gate of the normed input scales each head's output before
   the output projection;
+- ``state_space`` (Mamba-2; sizes in the ``state_space`` record:
+  ``num_heads`` heads of ``head_dim``, ``n_groups`` groups of
+  ``state_size``, ``conv_kernel``, ``chunk_size``, the ``time_step_*``
+  of the weights' law): ``[z | xBC | dt] = h W_in``; ``xBC <-
+  silu(causal depthwise convolution, with bias)``, split into ``x``
+  (the heads), ``B`` and ``C`` (a group's, shared by its heads); ``dt
+  <- softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; the
+  recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t =
+  S_t C_t + D x_t`` in chunks (:func:`fedml_tpu.ops.ssm.ssd_chunked`);
+  ``y <- RMSNorm(y * silu(z)) * scale`` over each group's channels;
+  out ``= y W_out``;
 - feed-forward: ``mlp_layer_types[l]`` is ``dense`` (a gated
   feed-forward of ``intermediate_size``) or ``sparse``
   (:func:`fedml_tpu.ops.moe.moe_layer`: a router over ``num_experts``,
@@ -33,15 +50,36 @@ Per layer ``l`` (pre-norm residual blocks, RMSNorm, no bias anywhere):
   renormalised over the chosen and times ``routed_scaling_factor``,
   the experts ``experts_held = [first, count]`` of width
   ``moe_intermediate_size`` held here, and a shared expert of
-  ``shared_expert_intermediate_size``, none at 0).
+  ``shared_expert_intermediate_size``, none at 0). ``mlp_activation``
+  ``silu_gated`` (the default: three matrices an expert) or ``relu2``
+  (two, ``relu(x W1)^2 W2``, the shared expert too). With
+  ``moe_latent_size`` the experts work at that width between two
+  projections all of them share; the router and the shared expert read
+  the full hidden width.
+
+**A chip's share of a layer** (each ``[first, count]`` of the published
+count beside it; the whole where absent): ``experts_held`` of
+``num_experts`` (the router stays whole); ``state_space.heads_held`` of
+its ``num_heads``, whole groups only, with those groups' ``B`` / ``C``,
+convolution channels and gated-norm groups (the norm's statistics are a
+group's own); ``query_heads_held`` of ``heads_per_layer[l]`` over
+``key_value_heads_held`` of ``num_key_value_heads`` (the held query
+heads read held key-value heads, evenly);
+``shared_expert_columns_held`` of ``shared_expert_intermediate_size``.
+What the absent heads, columns and experts would add to a layer's
+output is left out, and nothing stands in for their exchange.
 
 Untied embedding and head over ``vocab_size`` rows. Each layer is
 recomputed in the backward pass (``nn.remat``) but for what
-:data:`fedml_tpu.ops.attention.KEPT` names: beside a layer's input, its
-attention kernel's output and row log-sum-exp and, in a
-sparse-attention layer, the selection are kept, so the forward
-attention kernel, the index and the top-k run once a training step and
-the projections, norms, rotary, gate and the whole feed-forward twice.
+:data:`KEPT` names: beside a layer's input, its
+attention kernel's output and row log-sum-exp, in a
+sparse-attention layer the selection, and in a state-space layer the
+states entering each chunk and the scan's result are kept, so the forward
+attention kernel, the index, the top-k and, of the chunked scan, the
+mix, the chunks' own states and the recurrence between them run once a
+training step and
+the projections, norms, rotary, gate, convolution and the whole
+feed-forward twice.
 What the layers count (:func:`counter_names`: the sparse layers'
 :data:`fedml_tpu.ops.moe.MOE_COUNTERS` and, in a stack with
 sparse-attention layers, :data:`fedml_tpu.ops.attention.
@@ -67,15 +105,24 @@ from jax.ad_checkpoint import checkpoint_name
 
 from fedml_tpu.models.transformer import AttnFn, DenseFactory, _dense
 from fedml_tpu.ops.attention import (
-    ATTN_COUNTERS, KEPT, KEPT_SELECTION, causal_attention, index_scores,
-    select_top_k,
+    ATTN_COUNTERS, KEPT as ATTENTION_KEPT, KEPT_SELECTION, causal_attention,
+    index_scores, select_top_k,
 )
 from fedml_tpu.ops.moe import MOE_COUNTERS, SCORINGS, moe_layer
+from fedml_tpu.ops.ssm import KEPT as SCAN_KEPT, ssd_chunked
 
-# any other layer type is full attention
-SLIDING, SELECTED = "sliding_attention", "sparse_attention"
+NONE = "none"  # a layer without this half; a rope record's ``rope_type``
+FULL, SLIDING, SELECTED = (
+    "full_attention", "sliding_attention", "sparse_attention")
+STATE_SPACE = "state_space"
+MIXERS = (FULL, SLIDING, SELECTED, STATE_SPACE, NONE)
 DENSE, SPARSE = "dense", "sparse"
+FEED_FORWARDS = (DENSE, SPARSE, NONE)
 SIGMOID = "sigmoid"  # the router's scoring where the configuration names none
+GATED, RELU2 = "silu_gated", "relu2"  # ``mlp_activation``
+
+#: what a rematerialised layer keeps (``checkpoint_name``s)
+KEPT = ATTENTION_KEPT + SCAN_KEPT
 
 
 def attention_counters(layer_types) -> tuple[str, ...]:
@@ -159,6 +206,39 @@ def _counted(routed, attended):
             else routed)
 
 
+def _inverse_softplus_steps(low: float, high: float, floor: float):
+    """``dt_bias``: the inverse softplus of a step drawn log-uniform in
+    ``[low, high]`` and floored."""
+    def init(key, shape, dtype=jnp.float32):
+        step = jnp.exp(jax.random.uniform(key, shape) * (
+            math.log(high) - math.log(low)) + math.log(low))
+        step = jnp.maximum(step, floor)
+        return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
+    return init
+
+
+def _log_uniform(low: float, high: float):
+    """``A_log``: the log of a rate drawn uniform in ``[low, high]``."""
+    def init(key, shape, dtype=jnp.float32):
+        return jnp.log(jax.random.uniform(
+            key, shape, minval=low, maxval=high)).astype(dtype)
+    return init
+
+
+def causal_depthwise_conv(x, kernel, bias):
+    """``x`` ``[B, T, C]``, ``kernel`` ``[K, C]``: channel ``c`` at ``t``
+    is ``bias[c] + sum_i kernel[i, c] x[t - (K - 1) + i, c]``, zeros
+    before the sequence."""
+    k, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return bias + sum(kernel[i] * padded[:, i:i + t] for i in range(k))
+
+
+def _held(c: dict, key: str, whole: int) -> int:
+    """How many of ``whole`` this chip holds under share key ``key``."""
+    return c[key][1] if c.get(key) else whole
+
+
 class DecoderLayer(nn.Module):
     cfg: Any  # the frozen configuration (a tuple of items)
     index: int
@@ -170,70 +250,143 @@ class DecoderLayer(nn.Module):
         c = dict(self.cfg)
         l = self.index
         b, t, d = x.shape
-        heads, kv, hd = c["heads_per_layer"][l], c["num_key_value_heads"], (
-            c["head_dim"])
         kind = c["layer_types"][l]
-        dense = lambda f, name: _dense(self.dense_cls, f, False, name)
-        with jax.named_scope("fedml.model.attn"):
-            h = RMSNorm(c["rms_norm_eps"], name="attn_norm")(x)
-            q = dense(heads * hd, "q_proj")(h).reshape(b, t, heads, hd)
-            k = dense(kv * hd, "k_proj")(h).reshape(b, t, kv, hd)
-            v = dense(kv * hd, "v_proj")(h).reshape(b, t, kv, hd)
-            if c["qk_norm"]:
-                q = RMSNorm(c["rms_norm_eps"], name="q_norm")(q)
-                k = RMSNorm(c["rms_norm_eps"], name="k_norm")(k)
-            rope = dict(dict(c["rope"])[kind])
-            cos, sin = rope_tables(rope, hd, t)
-            # a stack with sparse-attention layers counts in every layer
-            how, attended = {}, (jnp.float32(0),) * len(
-                attention_counters(c["layer_types"]))
-            if kind == SELECTED:
-                how["selection"], attended = self.select(h, rope)
-            a = self.attn_fn(
-                apply_rope(q, cos, sin), apply_rope(k, cos, sin), v,
-                causal=True,
-                window=c["sliding_window"] if kind == SLIDING else None,
-                **how)
-            if c["gating"]:
-                a = a * jax.nn.sigmoid(dense(heads, "g_proj")(h))[..., None]
-            x = x + dense(d, "o_proj")(a.reshape(b, t, heads * hd))
+        # a stack with sparse-attention layers counts in every layer
+        attended = (jnp.float32(0),) * len(
+            attention_counters(c["layer_types"]))
+        if kind == STATE_SPACE:
+            x = x + self.state_space(x)
+        elif kind != NONE:
+            x, attended = self.attention(x, kind, attended)
         if c["mlp_layer_types"][l] == DENSE:
             with jax.named_scope("fedml.model.mlp"):
                 h = RMSNorm(c["rms_norm_eps"], name="mlp_norm")(x)
                 up = jax.nn.silu(
-                    dense(c["intermediate_size"], "gate_proj")(h)
-                ) * dense(c["intermediate_size"], "up_proj")(h)
-                x = x + dense(d, "down_proj")(up)
+                    self.dense(c["intermediate_size"], "gate_proj")(h)
+                ) * self.dense(c["intermediate_size"], "up_proj")(h)
+                x = x + self.dense(d, "down_proj")(up)
+        if c["mlp_layer_types"][l] != SPARSE:
             return x, _counted(
                 jnp.zeros((len(MOE_COUNTERS),), jnp.float32), attended)
         with jax.named_scope("fedml.model.moe"):
             h = RMSNorm(c["rms_norm_eps"], name="mlp_norm")(x)
             first, count = c["experts_held"]
-            f, fs = c["moe_intermediate_size"], (
-                c["shared_expert_intermediate_size"])
+            gated = c["mlp_activation"] == GATED
+            latent = c["moe_latent_size"]
+            w, f = latent or d, c["moe_intermediate_size"]
+            fs = _held(c, "shared_expert_columns_held",
+                       c["shared_expert_intermediate_size"])
             into = nn.initializers.variance_scaling(
                 1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
                 batch_axis=(0,))
             flat = nn.initializers.lecun_normal()
+            leading = ("w1", "w3") if gated else ("w1",)
             params = {
                 "router": self.param(
                     "router", flat, (d, c["num_experts"])),
-                "w1": self.param("experts_w1", into, (count, d, f)),
-                "w3": self.param("experts_w3", into, (count, d, f)),
-                "w2": self.param("experts_w2", into, (count, f, d)),
+                **{m: self.param("experts_" + m, into, (count, w, f))
+                   for m in leading},
+                "w2": self.param("experts_w2", into, (count, f, w)),
             }
             if fs:
                 params["shared"] = (
-                    self.param("shared_w1", flat, (d, fs)),
-                    self.param("shared_w3", flat, (d, fs)),
+                    *(self.param("shared_" + m, flat, (d, fs))
+                      for m in leading),
                     self.param("shared_w2", flat, (fs, d)),
                 )
+            if latent:
+                params["latent"] = (
+                    self.param("latent_in", flat, (d, latent)),
+                    self.param("latent_out", flat, (latent, d)))
             params = jax.tree.map(lambda p: p.astype(x.dtype), params)
             y, counters = moe_layer(
                 params, h.reshape(b * t, d), (first, count),
                 c["num_experts_per_tok"], c["routed_scaling_factor"],
                 scoring=c["router_scoring"])
             return x + y.reshape(b, t, d), _counted(counters, attended)
+
+    @nn.nowrap
+    def dense(self, features: int, name: str):
+        """A projection of this layer, through the ``dense_cls`` hook."""
+        return _dense(self.dense_cls, features, False, name)
+
+    @nn.nowrap
+    def attention(self, x, kind: str, attended):
+        """``x + attention(norm(x))`` of kind ``kind`` -> (``x``, the
+        layer's :data:`ATTN_COUNTERS` counts or ``attended`` as it
+        came)."""
+        c = dict(self.cfg)
+        b, t, d = x.shape
+        hd = c["head_dim"]
+        heads = _held(c, "query_heads_held", c["heads_per_layer"][self.index])
+        kv = _held(c, "key_value_heads_held", c["num_key_value_heads"])
+        with jax.named_scope("fedml.model.attn"):
+            h = RMSNorm(c["rms_norm_eps"], name="attn_norm")(x)
+            q = self.dense(heads * hd, "q_proj")(h).reshape(b, t, heads, hd)
+            k = self.dense(kv * hd, "k_proj")(h).reshape(b, t, kv, hd)
+            v = self.dense(kv * hd, "v_proj")(h).reshape(b, t, kv, hd)
+            if c["qk_norm"]:
+                q = RMSNorm(c["rms_norm_eps"], name="q_norm")(q)
+                k = RMSNorm(c["rms_norm_eps"], name="k_norm")(k)
+            rope = dict(dict(c["rope"])[kind])
+            how = {}
+            if kind == SELECTED:
+                how["selection"], attended = self.select(h, rope)
+            if rope.get("rope_type") != NONE:
+                cos, sin = rope_tables(rope, hd, t)
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            a = self.attn_fn(
+                q, k, v, causal=True,
+                window=c["sliding_window"] if kind == SLIDING else None,
+                **how)
+            if c["gating"]:
+                gate = jax.nn.sigmoid(self.dense(heads, "g_proj")(h))
+                a = a * gate[..., None]
+            a = self.dense(d, "o_proj")(a.reshape(b, t, heads * hd))
+            return x + a, attended
+
+    @nn.nowrap
+    def state_space(self, x):
+        """``mixer(norm(x))`` of a Mamba-2 layer (module docstring) over
+        the heads and groups held here."""
+        c = dict(self.cfg)
+        s = dict(c["state_space"])
+        b, t, d = x.shape
+        p, n = s["head_dim"], s["state_size"]
+        heads = _held(s, "heads_held", s["num_heads"])
+        groups = heads // (s["num_heads"] // s["n_groups"])
+        inner, bc = heads * p, groups * n
+        own = lambda name, init, *shape: self.param(name, init, shape)
+        with jax.named_scope("fedml.model.ssm"):
+            h = RMSNorm(c["rms_norm_eps"], name="ssm_norm")(x)
+            z, xbc, dt = jnp.split(
+                self.dense(2 * inner + 2 * bc + heads, "in_proj")(h),
+                [inner, 2 * inner + 2 * bc], -1)
+            xbc = jax.nn.silu(causal_depthwise_conv(
+                xbc,
+                own("conv_kernel", nn.initializers.lecun_normal(),
+                    s["conv_kernel"], inner + 2 * bc).astype(x.dtype),
+                own("conv_bias", nn.initializers.zeros,
+                    inner + 2 * bc).astype(x.dtype)))
+            xs, bm, cm = jnp.split(xbc, [inner, inner + bc], -1)
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + own(
+                "dt_bias", _inverse_softplus_steps(
+                    s["time_step_min"], s["time_step_max"],
+                    s["time_step_floor"]), heads).astype(jnp.float32))
+            rate = -jnp.exp(own("A_log", _log_uniform(1.0, 16.0),
+                                heads).astype(jnp.float32))
+            y = ssd_chunked(
+                xs.reshape(b, t, heads, p), dt, rate,
+                bm.reshape(b, t, groups, n), cm.reshape(b, t, groups, n),
+                own("D", nn.initializers.ones, heads), s["chunk_size"])
+            y = y.reshape(b, t, inner).astype(jnp.float32) * jax.nn.silu(
+                z.astype(jnp.float32))
+            y = y.reshape(b, t, groups, inner // groups)
+            y = y * jax.lax.rsqrt(
+                jnp.mean(y * y, -1, keepdims=True) + c["rms_norm_eps"])
+            y = y.reshape(b, t, inner) * own(
+                "gate_norm", nn.initializers.ones, inner)
+            return self.dense(d, "out_proj")(y.astype(x.dtype))
 
     @nn.nowrap
     def select(self, h, rope: dict):
@@ -248,11 +401,10 @@ class DecoderLayer(nn.Module):
         sa = dict(dict(self.cfg)["sparse_attention"])
         b, t, _ = h.shape
         j, e = sa["index_heads"], sa["index_head_dim"]
-        dense = lambda f, name: _dense(self.dense_cls, f, False, name)
         with jax.named_scope("fedml.model.attn.index"):
-            qi = dense(j * e, "index_q_proj")(h).reshape(b, t, j, e)
-            ki = dense(e, "index_k_proj")(h).reshape(b, t, 1, e)
-            w = dense(j, "index_w_proj")(h)
+            qi = self.dense(j * e, "index_q_proj")(h).reshape(b, t, j, e)
+            ki = self.dense(e, "index_k_proj")(h).reshape(b, t, 1, e)
+            w = self.dense(j, "index_w_proj")(h)
             cos, sin = rope_tables({**rope, "partial_rotary_factor": 1.0},
                                    e, t)
             scores = index_scores(apply_rope(qi, cos, sin),
@@ -306,6 +458,52 @@ def _freeze(value):
     return value
 
 
+def _share_in(held, whole: int) -> bool:
+    """Is ``held`` a ``[first, count]`` inside ``whole``?"""
+    return (isinstance(held, (list, tuple)) and len(held) == 2
+            and 0 <= held[0] and held[1] >= 1 and held[0] + held[1] <= whole)
+
+
+def _check_state_space(s: dict) -> None:
+    sizes = ("num_heads", "head_dim", "n_groups", "state_size",
+             "conv_kernel", "chunk_size")
+    lacks = [k for k in sizes if not isinstance(s.get(k), int)] + [
+        k for k in ("time_step_min", "time_step_max", "time_step_floor")
+        if k not in s]
+    if lacks:
+        raise ValueError(f"decoder: state_space lacks {', '.join(lacks)}")
+    if min(s[k] for k in sizes) < 1 or s["num_heads"] % s["n_groups"]:
+        raise ValueError(
+            "decoder: state_space sizes must be at least 1 and n_groups "
+            f"divide num_heads: {s}")
+    per = s["num_heads"] // s["n_groups"]
+    held = s.get("heads_held") or [0, s["num_heads"]]
+    if not _share_in(held, s["num_heads"]) or held[0] % per or held[1] % per:
+        raise ValueError(
+            f"decoder: state_space heads_held {held} is not whole groups "
+            f"of {per} of the {s['num_heads']} heads")
+
+
+def _check_attention_share(c: dict, attention_heads: list) -> None:
+    """``query_heads_held`` over ``key_value_heads_held``: every held
+    query head reads a held key-value head, each of those as many."""
+    kv = c["num_key_value_heads"]
+    kf, kc = c.get("key_value_heads_held") or [0, kv]
+    if not _share_in([kf, kc], kv):
+        raise ValueError(
+            f"decoder: key_value_heads_held {[kf, kc]} does not lie in "
+            f"the {kv} key-value heads")
+    for heads in attention_heads:
+        qf, qc = c.get("query_heads_held") or [0, heads]
+        per = heads // kv  # query heads a key-value head, as published
+        one = kc == 1 and kf * per <= qf and qf + qc <= (kf + 1) * per
+        if not (_share_in([qf, qc], heads) and heads % kv == 0
+                and (one or (qf, qc) == (kf * per, kc * per))):
+            raise ValueError(
+                f"decoder: query_heads_held {[qf, qc]} of {heads} heads do "
+                f"not read key_value_heads_held {[kf, kc]} of {kv} evenly")
+
+
 def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
     """The module ``ModelConfig.extra`` describes (module docstring).
     ``vocab_size`` defaults to ``num_classes``."""
@@ -318,6 +516,7 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
     c = {
         "sliding_window": None, "gating": False, "qk_norm": False,
         "rms_norm_eps": 1e-6, "router_scoring": SIGMOID,
+        "mlp_activation": GATED, "moe_latent_size": 0,
         "moe_intermediate_size": 0, "shared_expert_intermediate_size": 0,
         "num_experts": 0, "num_experts_per_tok": 0,
         "routed_scaling_factor": 1.0, "experts_held": (0, 0),
@@ -328,6 +527,20 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         raise ValueError(
             "decoder: heads_per_layer, layer_types and mlp_layer_types "
             "must have one entry a layer")
+    for l, (mixer, forward) in enumerate(
+            zip(c["layer_types"], c["mlp_layer_types"])):
+        if mixer not in MIXERS or forward not in FEED_FORWARDS:
+            raise ValueError(
+                f"decoder: layer {l} is {mixer!r} + {forward!r}; known "
+                f"layer_types: {', '.join(MIXERS)}; mlp_layer_types: "
+                f"{', '.join(FEED_FORWARDS)}")
+        if mixer == forward == NONE:
+            raise ValueError(f"decoder: layer {l} is nothing at all")
+    if c["mlp_activation"] not in (GATED, RELU2) or (
+            c["mlp_activation"] == RELU2 and DENSE in c["mlp_layer_types"]):
+        raise ValueError(
+            f"decoder: mlp_activation {c['mlp_activation']!r} is not "
+            f"{GATED!r} or, in a stack with no dense layer, {RELU2!r}")
     first, count = c["experts_held"]
     if SPARSE in c["mlp_layer_types"] and not (
             0 <= first and count >= 1
@@ -336,10 +549,21 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         raise ValueError(
             f"decoder: experts_held {c['experts_held']} does not lie in "
             f"the router's {c['num_experts']} experts")
+    columns = c.get("shared_expert_columns_held")
+    if columns and not _share_in(
+            columns, c["shared_expert_intermediate_size"]):
+        raise ValueError(
+            f"decoder: shared_expert_columns_held {columns} does not lie "
+            f"in the {c['shared_expert_intermediate_size']} columns")
     if c["router_scoring"] not in SCORINGS:
         raise ValueError(
             f"decoder: unknown router_scoring {c['router_scoring']!r}; "
             f"known: {sorted(SCORINGS)}")
+    _check_attention_share(c, [
+        h for h, kind in zip(c["heads_per_layer"], c["layer_types"])
+        if kind in (FULL, SLIDING, SELECTED)])
+    if STATE_SPACE in c["layer_types"]:
+        _check_state_space(c.get("state_space") or {})
     if SELECTED in c["layer_types"]:
         sa = c.get("sparse_attention") or {}
         lacks = [k for k in ("index_heads", "index_head_dim", "topk")

@@ -151,16 +151,29 @@ MOE_COUNTERS = ("moe_rows_held", "moe_rows_routed", "moe_rows_max_expert",
                 "moe_rows_compact")
 
 #: the row buffer holds this many times the held experts' uniform share
-#: of the assignments, rounded up to the grouped product's row tile
-ROW_SHARE, ROW_TILE = 2, 128
+#: of the assignments, and never under this part of all of them,
+#: rounded up to the grouped product's row tile
+ROW_SHARE, ROW_FLOOR, ROW_TILE = 2, 1 / 8, 128
+#: rows of a memory tile: an array's second-to-last axis comes in these
+SUBLANES = 8
 
 ROUTE, EXPERTS = "fedml.model.moe.route", "fedml.model.moe.experts"
+LATENT = "fedml.model.moe.latent"
 
 
-def gated_ffn(x, w1, w3, w2):
-    """``(silu(x W1) * (x W3)) W2``, the gated feed-forward every expert
-    (and the dense layer, and the shared expert) is."""
-    return (jax.nn.silu(x @ w1) * (x @ w3)) @ w2
+def _middle(*into):
+    """What lies between a feed-forward's matrices, by how many lead
+    in: ``silu(a) * b`` (gated, two) or ``relu(a)^2`` (one)."""
+    if len(into) == 2:
+        return jax.nn.silu(into[0]) * into[1]
+    return jnp.square(jax.nn.relu(into[0]))
+
+
+def ffn(x, *w):
+    """The feed-forward every expert and the shared expert is, by its
+    matrices: three, ``(silu(x W1) * (x W3)) W2`` (gated, as the dense
+    layer is), or two, ``relu(x W1)^2 W2``."""
+    return _middle(*(x @ m for m in w[:-1])) @ w[-1]
 
 
 def _mapped(fn):
@@ -227,9 +240,14 @@ def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid"):
 def row_buffer(n: int, top_k: int, count: int, experts: int) -> int:
     """Rows of the bounded buffer for ``n`` tokens routed ``top_k`` ways
     over ``experts`` of which ``count`` are held: :data:`ROW_SHARE`
-    times what uniform routing sends here, in whole row tiles, and never
-    more than the worst case ``n * top_k``."""
-    share = -(-ROW_SHARE * n * top_k * count // experts)
+    times what uniform routing sends here or, where that is less,
+    :data:`ROW_FLOOR` of all ``n * top_k`` assignments (the fewer of
+    the experts a chip holds, the wider its load swings about the
+    uniform share: one expert that a frequent token favours is a large
+    part of 8 of 512 and a small part of 32 of 256), in whole row tiles,
+    and never more than the worst case ``n * top_k``."""
+    share = max(-(-ROW_SHARE * n * top_k * count // experts),
+                int(ROW_FLOOR * n * top_k))
     return min(n * top_k, -(-share // ROW_TILE) * ROW_TILE)
 
 
@@ -268,35 +286,50 @@ def _read_back(x, inverse, n_held):
     return x[jnp.where(inverse < n_held, inverse, x.shape[0] - 1)]
 
 
-def _held_rows_forward(r, h, w1, w3, w2, top_w, order, inverse, sizes,
-                       n_held):
+def _by_token(x, inverse, n_held, k: int):
+    """:func:`_read_back`'s rows grouped by their token -> (the rows,
+    their two leading axes): ``[N, k, ...]``, ``"nk"``, where ``k`` is
+    whole tiles of :data:`SUBLANES` rows, so that splitting the ``N x
+    k`` gathered rows is free; else each token's first, second, ...
+    assignment side by side, ``[k, N, ...]``, ``"kn"`` (22 ways: cut
+    token by token the rows are all copied into padded tiles, 369 MB
+    at 8,192 tokens of 1,024; cut way by way none is)."""
+    n = inverse.shape[0] // k
+    if k % SUBLANES == 0:
+        return _read_back(x, inverse, n_held).reshape(
+            n, k, *x.shape[1:]), "nk"
+    by_way = inverse.reshape(n, k).T.reshape(-1)
+    return _read_back(x, by_way, n_held).reshape(k, n, *x.shape[1:]), "kn"
+
+
+def _held_rows_forward(r, h, w, top_w, order, inverse, sizes, n_held):
     """The held experts' sum over a buffer of ``r`` rows (at least
-    ``n_held``): row ``i`` is token ``order[i] // k``'s. -> ``(y [N, D],
-    (rows, a, b, out))``, what the backward pass reads again, all of
-    ``r`` rows. Rows from ``n_held`` on are no held expert's: the
+    ``n_held``): row ``i`` is token ``order[i] // k``'s. ``w``: the
+    experts' matrices, ``(w1, w3, w2)`` or ``(w1, w2)`` (:func:`ffn`).
+    -> ``(y [N, D], (rows, into, out))``, what the backward pass reads
+    again (``into``: the rows through each matrix that leads in), all
+    of ``r`` rows. Rows from ``n_held`` on are no held expert's: the
     grouped products leave them unwritten, so ``out`` is masked."""
-    n, k = top_w.shape
+    k = top_w.shape[1]
     with jax.named_scope(ROUTE):
         rows = h[order[:r] // k]
     with jax.named_scope(EXPERTS):
-        a = grouped_product(rows, w1, sizes)
-        b = grouped_product(rows, w3, sizes)
-        out = grouped_product(jax.nn.silu(a) * b, w2, sizes)
+        into = tuple(grouped_product(rows, m, sizes) for m in w[:-1])
+        out = grouped_product(_middle(*into), w[-1], sizes)
     with jax.named_scope(ROUTE):
         out = jnp.where((jnp.arange(r) < n_held)[:, None], out, 0)
-        y = jnp.einsum(
-            "nkd,nk->nd", _read_back(out, inverse, n_held).reshape(n, k, -1),
-            top_w.astype(out.dtype))
-    return y, (rows, a, b, out)
+        theirs, axes = _by_token(out, inverse, n_held, k)
+        y = jnp.einsum(axes + "d,nk->nd", theirs, top_w.astype(out.dtype))
+    return y, (rows, into, out)
 
 
-def _held_rows_backward(r, kept, w1, w3, w2, top_w, order, inverse, sizes,
-                        n_held, g):
-    """:func:`_held_rows_forward`'s cotangents of ``h``, ``w1``, ``w3``,
-    ``w2`` and ``top_w`` for ``g`` ``[N, D]``, from what it kept. The
-    rows' cotangent is unwritten from ``n_held`` on as well, and masked
+def _held_rows_backward(r, kept, w, top_w, order, inverse, sizes, n_held,
+                        g):
+    """:func:`_held_rows_forward`'s cotangents of ``h``, ``w`` and
+    ``top_w`` for ``g`` ``[N, D]``, from what it kept. The rows'
+    cotangent is unwritten from ``n_held`` on as well, and masked
     before it reaches the tokens."""
-    rows, a, b, out = kept
+    rows, into, out = kept
     n, k = top_w.shape
     with jax.named_scope(ROUTE):
         g_rows = g[order[:r] // k]
@@ -307,16 +340,17 @@ def _held_rows_backward(r, kept, w1, w3, w2, top_w, order, inverse, sizes,
         d_top_w = _read_back(d_weight, inverse, n_held).reshape(
             n, k).astype(top_w.dtype)
     with jax.named_scope(EXPERTS):
-        up, gate = jax.vjp(lambda a, b: jax.nn.silu(a) * b, a, b)
-        d_up, d_w2 = _transposed(up, w2, sizes, d_out)
-        d_a, d_b = gate(d_up)
-        d_rows1, d_w1 = _transposed(rows, w1, sizes, d_a)
-        d_rows3, d_w3 = _transposed(rows, w3, sizes, d_b)
+        up, middle = jax.vjp(_middle, *into)
+        d_up, d_out_w = _transposed(up, w[-1], sizes, d_out)
+        d_rows, d_w = zip(*(
+            _transposed(rows, m, sizes, d_into)
+            for m, d_into in zip(w[:-1], middle(d_up))))
     with jax.named_scope(ROUTE):
         d_rows = jnp.where((jnp.arange(r) < n_held)[:, None],
-                           d_rows1 + d_rows3, 0)
-        d_h = _read_back(d_rows, inverse, n_held).reshape(n, k, -1).sum(1)
-    return d_h, d_w1, d_w3, d_w2, d_top_w
+                           sum(d_rows[1:], d_rows[0]), 0)
+        theirs, axes = _by_token(d_rows, inverse, n_held, k)
+        d_h = theirs.sum(axes.index("k"))
+    return d_h, (*d_w, d_out_w), d_top_w
 
 
 def _held_experts_run(c, keep, *args):
@@ -325,7 +359,7 @@ def _held_experts_run(c, keep, *args):
     the backward pass reads again if ``keep``), else over all ``N x
     top_k`` (``taken`` 0, ``kept`` zeros of the ``c``-row shapes: the
     backward pass runs that side's forward pass again)."""
-    top_w, n_held = args[4], args[-1]
+    top_w, n_held = args[2], args[-1]
     nk = top_w.size
 
     def over(r):
@@ -345,17 +379,17 @@ def _held_experts_run(c, keep, *args):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_experts(c, h, w1, w3, w2, top_w, order, inverse, sizes, n_held):
-    """What the held experts add to the tokens: ``(y [N, D], N x top_k
-    if the rows went through the bounded buffer of ``c`` rows, else
-    0)``.
+def _held_experts(c, h, w, top_w, order, inverse, sizes, n_held):
+    """What the held experts (matrices ``w``, :func:`ffn`'s) add to the
+    tokens: ``(y [N, D], N x top_k if the rows went through the bounded
+    buffer of ``c`` rows, else 0)``.
 
     The branch sits in the rules, not under them: differentiated by
     JAX, a ``cond`` returns the residuals of BOTH sides, zero-filled
     for the side not taken — the worst-case-sized arrays the bounded
     buffer exists to avoid."""
     return _held_experts_run(
-        c, False, h, w1, w3, w2, top_w, order, inverse, sizes, n_held)[:2]
+        c, False, h, w, top_w, order, inverse, sizes, n_held)[:2]
 
 
 def _held_experts_fwd(c, *args):
@@ -365,7 +399,7 @@ def _held_experts_fwd(c, *args):
 
 def _held_experts_bwd(c, res, cotangents):
     kept, taken, args = res
-    nk = args[4].size  # top_w
+    nk = args[2].size  # top_w
 
     def bounded(kept, g, h, *rest):
         return _held_rows_backward(c, kept, *rest, g)
@@ -392,11 +426,18 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
               scoring: str = "sigmoid"):
     """This chip's part of one sparse-expert layer, dropless.
 
-    ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts, ``w1`` /
-    ``w3`` ``[count, D, F]`` and ``w2`` ``[count, F, D]`` of the experts
-    held here — ids ``[first, first + count)``, ``held = (first,
-    count)`` — and ``shared`` (``w1, w3, w2`` of the shared expert) or
-    no such key. ``h``: ``[N, D]`` tokens.
+    ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts; the
+    matrices of the experts held here — ids ``[first, first + count)``,
+    ``held = (first, count)`` — gated, ``w1`` / ``w3`` ``[count, W, F]``
+    and ``w2`` ``[count, F, W]``, or, with no ``w3``, the two of
+    ``relu(x W1)^2 W2`` (:func:`ffn`); ``shared`` (the shared expert's
+    matrices, three or two, on the full ``D``) or no such key; and
+    ``latent`` (``[D, L]``, ``[L, D]``) or no such key. ``h``: ``[N, D]``
+    tokens. The experts' width ``W`` is ``D``, or ``L`` where the layer
+    is latent: its experts then read ``h`` through the first latent
+    projection and their weighted sum goes back through the second (no
+    activation on either; both shared by all experts), while the router
+    and the shared expert read ``h`` itself.
 
     Every token is routed over all ``E`` experts (:func:`route_top_k`,
     ``scoring`` its kind of probability); the assignments whose
@@ -407,7 +448,8 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     into their tokens; the shared expert is added. The row buffer holds
     what the held experts can be expected to receive
     (:func:`row_buffer`: twice their uniform share of the ``N x top_k``
-    assignments); a call that receives more goes through a buffer of
+    assignments, an eighth of them at least); a call that receives more
+    goes through a buffer of
     all ``N x top_k`` rows instead, so no assignment is ever dropped,
     and a mapped batch goes one way together. What the absent experts
     would add is left out: on one chip there is no exchange and nothing
@@ -431,12 +473,20 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
         sizes = jnp.bincount(group, length=count + 1)[:count].astype(
             jnp.int32)
         n_held = jnp.sum(sizes)
+    inside = h
+    if "latent" in params:
+        with jax.named_scope(LATENT):
+            inside = h @ params["latent"][0]
     y, bounded = _held_experts(
-        row_buffer(n, top_k, count, scores.shape[1]), h, params["w1"],
-        params["w3"], params["w2"], top_w, order, inverse, sizes, n_held)
+        row_buffer(n, top_k, count, scores.shape[1]), inside,
+        tuple(params[m] for m in ("w1", "w3", "w2") if m in params),
+        top_w, order, inverse, sizes, n_held)
+    if "latent" in params:
+        with jax.named_scope(LATENT):
+            y = y @ params["latent"][1]
     if "shared" in params:
         with jax.named_scope("fedml.model.mlp"):
-            y = y + gated_ffn(h, *params["shared"])
+            y = y + ffn(h, *params["shared"])
     counters = jnp.stack([
         n_held.astype(jnp.float32), jnp.float32(n * top_k),
         jnp.max(sizes).astype(jnp.float32), bounded])
