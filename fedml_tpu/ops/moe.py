@@ -146,9 +146,11 @@ def make_expert_parallel_moe(mesh, axis_name: str = "ep",
 # the chip's share of a sparse-expert layer (top-k, dropless)
 # ---------------------------------------------------------------------------
 
-#: what :func:`moe_layer` counts, in this order
+#: what :func:`moe_layer` counts, in this order (its docstring says what
+#: each is; the last, the rows the combine read back into the tokens, is
+#: ``N`` times the SLOTS a token has: ``min(top_k, count)``)
 MOE_COUNTERS = ("moe_rows_held", "moe_rows_routed", "moe_rows_max_expert",
-                "moe_rows_compact")
+                "moe_rows_compact", "moe_rows_combined")
 
 #: the row buffer holds this many times the held experts' uniform share
 #: of the assignments, and never under this part of all of them,
@@ -273,36 +275,87 @@ def _one_path_a_batch(fits_fn, other_fn):
     return branch
 
 
-def _read_back(x, inverse, n_held):
-    """``x`` ``[r, ...]``, zero from row ``n_held`` on, as each of the
-    ``N x top_k`` assignments reads it: its own row if its expert is
-    held (the first ``n_held`` of the order), else zero. Over the
-    worst-case buffer every assignment has a row; a shorter one gets a
+def _slot_rows(local, count: int, nk: int):
+    """A token's slots where fewer experts are held than it has ways:
+    one slot a held expert. ``local`` ``[N, k]``: each way's expert id
+    less the first held one. -> ``((pos [N, count], hit [N, k, count]),
+    sizes [count])``: ``hit[n, w, j]`` says that way ``w`` of token
+    ``n`` names held expert ``j`` — at most one way does, a token's
+    ``top_k`` ids being distinct — and ``pos[n, j]`` is the row of the
+    order that assignment stands on, or ``nk`` (past every buffer) where
+    no way names ``j``. The order is a STABLE sort by expert, so an
+    expert's rows stand in token order: the expert's first row plus the
+    earlier tokens that chose it — a running count over ``[N, count]``
+    in place of a second sort of the ``N x top_k`` ids, and its last row
+    is ``sizes``, the rows a held expert, in place of a count over all
+    of them."""
+    hit = local[:, :, None] == jnp.arange(count)
+    chose = jnp.any(hit, 1)
+    running = jnp.cumsum(chose, 0, dtype=jnp.int32)
+    sizes = running[-1]
+    starts = jnp.cumsum(sizes, 0) - sizes
+    return (jnp.where(chose, starts + running - chose, nk), hit), sizes
+
+
+def _read_back(x, index, n_held):
+    """``x`` ``[r, ...]``, zero from row ``n_held`` on, as ``index``
+    reads it: row ``index`` where that is one of the first ``n_held``
+    of the order, else zero. ``index`` is flat, the place in the order
+    of each of the ``N x top_k`` assignments (``inverse``: held experts'
+    first), or ``[N, slots]``, :func:`_slot_rows`' ``pos``. Flat over
+    the worst-case buffer every assignment has a row; any other gets a
     zero row after it for the rest to read, so that no mask pass follows
     the gather."""
-    if x.shape[0] == inverse.shape[0]:
-        return x[inverse]
+    if index.ndim == 1 and x.shape[0] == index.shape[0]:
+        return x[index]
     x = jnp.pad(x, ((0, 1),) + ((0, 0),) * (x.ndim - 1))
-    return x[jnp.where(inverse < n_held, inverse, x.shape[0] - 1)]
+    return x[jnp.where(index < n_held, index, x.shape[0] - 1)]
 
 
-def _by_token(x, inverse, n_held, k: int):
-    """:func:`_read_back`'s rows grouped by their token -> (the rows,
-    their two leading axes): ``[N, k, ...]``, ``"nk"``, where ``k`` is
-    whole tiles of :data:`SUBLANES` rows, so that splitting the ``N x
-    k`` gathered rows is free; else each token's first, second, ...
-    assignment side by side, ``[k, N, ...]``, ``"kn"`` (22 ways: cut
-    token by token the rows are all copied into padded tiles, 369 MB
-    at 8,192 tokens of 1,024; cut way by way none is)."""
-    n = inverse.shape[0] // k
+def _by_token(x, back, n_held, k: int):
+    """:func:`_read_back`'s rows grouped by their token, one a SLOT ->
+    (the rows, their two leading axes). A token's slots are its ``k``
+    ways where ``k <= count`` (``back``: ``inverse``, flat) and the
+    ``count`` held experts where there are fewer of them (``back``:
+    :func:`_slot_rows`' pair), so a token reads ``min(k, count)`` rows.
+    ``[N, slots, ...]``, ``"nk"``, where the slots are whole tiles of
+    :data:`SUBLANES` rows, so that splitting the gathered rows token by
+    token is free; else each token's first, second, ... slot side by
+    side, ``[slots, N, ...]``, ``"kn"`` (cut token by token such rows
+    are all copied into padded tiles; cut slot by slot none is)."""
+    if isinstance(back, tuple):
+        pos = back[0]
+        if pos.shape[1] % SUBLANES == 0:
+            return _read_back(x, pos, n_held), "nk"
+        return _read_back(x, pos.T, n_held), "kn"
+    n = back.shape[0] // k
     if k % SUBLANES == 0:
-        return _read_back(x, inverse, n_held).reshape(
+        return _read_back(x, back, n_held).reshape(
             n, k, *x.shape[1:]), "nk"
-    by_way = inverse.reshape(n, k).T.reshape(-1)
+    by_way = back.reshape(n, k).T.reshape(-1)
     return _read_back(x, by_way, n_held).reshape(k, n, *x.shape[1:]), "kn"
 
 
-def _held_rows_forward(r, h, w, top_w, order, inverse, sizes, n_held):
+def _slot_weights(top_w, back):
+    """``top_w`` ``[N, k]`` as a token's slots weigh their rows, ``[N,
+    slots]``: a held expert's slot has the weight of the way that names
+    it, 0 where none does."""
+    if isinstance(back, tuple):
+        return jnp.sum(jnp.where(back[1], top_w[:, :, None], 0), 1)
+    return top_w
+
+
+def _way_cotangents(d_weight, back, n_held, n: int, k: int):
+    """:func:`_slot_weights` transposed over the rows: ``d_weight``
+    ``[r]``, a row's cotangent of its weight -> ``[N, k]``, each way's
+    (0 for a way whose expert is not held)."""
+    if isinstance(back, tuple):
+        by_slot = _read_back(d_weight, back[0], n_held)
+        return jnp.sum(jnp.where(back[1], by_slot[:, None], 0), 2)
+    return _read_back(d_weight, back, n_held).reshape(n, k)
+
+
+def _held_rows_forward(r, h, w, top_w, order, back, sizes, n_held):
     """The held experts' sum over a buffer of ``r`` rows (at least
     ``n_held``): row ``i`` is token ``order[i] // k``'s. ``w``: the
     experts' matrices, ``(w1, w3, w2)`` or ``(w1, w2)`` (:func:`ffn`).
@@ -318,13 +371,13 @@ def _held_rows_forward(r, h, w, top_w, order, inverse, sizes, n_held):
         out = grouped_product(_middle(*into), w[-1], sizes)
     with jax.named_scope(ROUTE):
         out = jnp.where((jnp.arange(r) < n_held)[:, None], out, 0)
-        theirs, axes = _by_token(out, inverse, n_held, k)
-        y = jnp.einsum(axes + "d,nk->nd", theirs, top_w.astype(out.dtype))
+        theirs, axes = _by_token(out, back, n_held, k)
+        y = jnp.einsum(axes + "d,nk->nd", theirs,
+                       _slot_weights(top_w, back).astype(out.dtype))
     return y, (rows, into, out)
 
 
-def _held_rows_backward(r, kept, w, top_w, order, inverse, sizes, n_held,
-                        g):
+def _held_rows_backward(r, kept, w, top_w, order, back, sizes, n_held, g):
     """:func:`_held_rows_forward`'s cotangents of ``h``, ``w`` and
     ``top_w`` for ``g`` ``[N, D]``, from what it kept. The rows'
     cotangent is unwritten from ``n_held`` on as well, and masked
@@ -337,8 +390,8 @@ def _held_rows_backward(r, kept, w, top_w, order, inverse, sizes, n_held,
         d_out = g_rows * weight[:, None]
         d_weight = jnp.einsum("rd,rd->r", out, g_rows,
                               preferred_element_type=jnp.float32)
-        d_top_w = _read_back(d_weight, inverse, n_held).reshape(
-            n, k).astype(top_w.dtype)
+        d_top_w = _way_cotangents(d_weight, back, n_held, n, k).astype(
+            top_w.dtype)
     with jax.named_scope(EXPERTS):
         up, middle = jax.vjp(_middle, *into)
         d_up, d_out_w = _transposed(up, w[-1], sizes, d_out)
@@ -348,7 +401,7 @@ def _held_rows_backward(r, kept, w, top_w, order, inverse, sizes, n_held,
     with jax.named_scope(ROUTE):
         d_rows = jnp.where((jnp.arange(r) < n_held)[:, None],
                            sum(d_rows[1:], d_rows[0]), 0)
-        theirs, axes = _by_token(d_rows, inverse, n_held, k)
+        theirs, axes = _by_token(d_rows, back, n_held, k)
         d_h = theirs.sum(axes.index("k"))
     return d_h, (*d_w, d_out_w), d_top_w
 
@@ -379,7 +432,7 @@ def _held_experts_run(c, keep, *args):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_experts(c, h, w, top_w, order, inverse, sizes, n_held):
+def _held_experts(c, h, w, top_w, order, back, sizes, n_held):
     """What the held experts (matrices ``w``, :func:`ffn`'s) add to the
     tokens: ``(y [N, D], N x top_k if the rows went through the bounded
     buffer of ``c`` rows, else 0)``.
@@ -389,7 +442,7 @@ def _held_experts(c, h, w, top_w, order, inverse, sizes, n_held):
     for the side not taken — the worst-case-sized arrays the bounded
     buffer exists to avoid."""
     return _held_experts_run(
-        c, False, h, w, top_w, order, inverse, sizes, n_held)[:2]
+        c, False, h, w, top_w, order, back, sizes, n_held)[:2]
 
 
 def _held_experts_fwd(c, *args):
@@ -445,7 +498,16 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     products (:func:`grouped_product`: on the TPU a kernel whose work
     follows the rows present, never ``E x N``), are weighted —
     weights normalised over all ``top_k``, held or not — and summed back
-    into their tokens; the shared expert is added. The row buffer holds
+    into their tokens; the shared expert is added. That sum, and its
+    transpose in the backward rule, reads a row a SLOT of a token, of
+    which it has ``min(top_k, count)``, decided while tracing: its ways
+    where ``top_k <= count`` (each finds its row through ``inverse``,
+    the order's own ``argsort``), the held experts where ``count <
+    top_k`` (a token names an expert at most once; its row is found by
+    a running count, :func:`_slot_rows`, whose last row is the rows a
+    held expert, so neither ``inverse`` nor a count over the ``N x
+    top_k`` ids is computed; a slot no way names reads a zero row). The
+    row buffer holds
     what the held experts can be expected to receive
     (:func:`row_buffer`: twice their uniform share of the ``N x top_k``
     assignments, an eighth of them at least); a call that receives more
@@ -455,10 +517,11 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     would add is left out: on one chip there is no exchange and nothing
     stands in for one.
 
-    -> ``(y [N, D], counters float32 [4])`` in :data:`MOE_COUNTERS`'
+    -> ``(y [N, D], counters float32 [5])`` in :data:`MOE_COUNTERS`'
     order: assignments that landed on held experts, assignments made
     (``N x top_k``), rows of the fullest held expert, assignments made
-    in a call that went through the bounded buffer."""
+    in a call that went through the bounded buffer, rows the combine
+    read (``N`` times a token's slots)."""
     first, count = held
     n, _ = h.shape
     with jax.named_scope(ROUTE):
@@ -469,9 +532,13 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
         # an assignment to an absent expert sorts past every held group
         group = jnp.where((local >= 0) & (local < count), local, count)
         order = jnp.argsort(group, stable=True)
-        inverse = jnp.argsort(order)
-        sizes = jnp.bincount(group, length=count + 1)[:count].astype(
-            jnp.int32)
+        if top_k <= count:  # a token's slots are its ways
+            back = jnp.argsort(order)
+            sizes = jnp.bincount(group, length=count + 1)[:count].astype(
+                jnp.int32)
+        else:  # ... or the held experts, where those are the fewer
+            back, sizes = _slot_rows(
+                local.reshape(n, top_k), count, n * top_k)
         n_held = jnp.sum(sizes)
     inside = h
     if "latent" in params:
@@ -480,7 +547,7 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     y, bounded = _held_experts(
         row_buffer(n, top_k, count, scores.shape[1]), inside,
         tuple(params[m] for m in ("w1", "w3", "w2") if m in params),
-        top_w, order, inverse, sizes, n_held)
+        top_w, order, back, sizes, n_held)
     if "latent" in params:
         with jax.named_scope(LATENT):
             y = y @ params["latent"][1]
@@ -489,5 +556,6 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
             y = y + ffn(h, *params["shared"])
     counters = jnp.stack([
         n_held.astype(jnp.float32), jnp.float32(n * top_k),
-        jnp.max(sizes).astype(jnp.float32), bounded])
+        jnp.max(sizes).astype(jnp.float32), bounded,
+        jnp.float32(n * min(top_k, count))])
     return y.astype(h.dtype), counters

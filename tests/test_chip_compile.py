@@ -368,9 +368,10 @@ def test_nemotron_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
     ma = compiled.memory_analysis()
     need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
             + ma.generated_code_size_in_bytes)
-    # 15.95 GB by the compiler's count (13.34 of it scratch), of the
+    # 15.16 GB by the compiler's count (12.56 of it scratch; 15.95 and
+    # 13.34 before PR 35's combine read a row a held expert), of the
     # 16.9 GB the chip's allocator reports; the chip itself read 14.1
-    # GB (the committed tree 14.2) where this count read 15.5 (PERF.md)
+    # GB (PR 33's tree 14.2) where this count read 15.5 (PERF.md)
     assert 10e9 < need < 16.9e9, ma
     evaluator, operands = sim._global_eval()
     ma = evaluator.lower(

@@ -286,7 +286,8 @@ def test_bounded_buffer_against_the_written_out_layer(case):
     """(d') held rows well under the bounded buffer's 128, exactly 128
     and 129: values, the gradient of the tokens and of every parameter
     against the written-out share; the fourth counter says which buffer
-    the call went through."""
+    the call went through, the fifth what the combine read (4 ways over
+    4 held: a row a way)."""
     key = jax.random.key(13)
     p, h, held_rows = _steered(key, *STEERED[case])
     assert MOE.row_buffer(64, 4, 4, 16) == 128
@@ -298,7 +299,7 @@ def test_bounded_buffer_against_the_written_out_layer(case):
     assert float(jnp.max(jnp.abs(grads[0]["router"]))) > 0
     assert [float(c) for c in counters] == [
         held_rows, 256.0, sum(STEERED[case]),  # expert 4 is the fullest
-        0.0 if case == "one_over" else 256.0]
+        0.0 if case == "one_over" else 256.0, 256.0]
 
 
 @pytest.mark.parametrize("cases", [("one_over", "well_under"),
@@ -477,11 +478,13 @@ def test_round_at_block_one_equals_the_stacked_round(tiny):
         assert stacked["moe_rows_routed"] == 2 * 2 * 2 * TD.SEQ * 4 * 4
         for name in ("moe_rows_held", "moe_rows_routed",
                      "moe_rows_max_expert", "moe_rows_compact",
-                     "moe_rows_held_by_client"):
+                     "moe_rows_combined", "moe_rows_held_by_client"):
             assert stacked[name] == bulk[name], name
         # 64 tokens top-4 a step, 4 of 16 experts held: a bounded buffer
         # of 128 rows, which fresh routers (64 rows expected) stay under
         assert 0 < stacked["moe_rows_compact"] <= stacked["moe_rows_routed"]
+        # 4 ways over 4 held: a token's slots are its ways
+        assert stacked["moe_rows_combined"] == stacked["moe_rows_routed"]
         assert len(bulk["moe_rows_held_by_client"]) == 2
         assert sum(bulk["moe_rows_held_by_client"]) == bulk["moe_rows_held"]
         np.testing.assert_allclose(

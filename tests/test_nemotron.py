@@ -20,7 +20,7 @@ for _p in (os.path.join(ROOT, "benchmarks"),
         sys.path.insert(0, _p)
 
 import tiny_nemotron as TN  # noqa: E402
-from test_decoder import _loss, _model_config, _sim  # noqa: E402
+from test_decoder import _eqns, _loss, _model_config, _sim  # noqa: E402
 from test_ssm import _count  # noqa: E402
 
 from fedml_tpu.config import ModelConfig  # noqa: E402
@@ -246,23 +246,77 @@ def _written_out(p, h, first, count, top_k, scale):
     return y + MOE.ffn(h, *p["shared"])
 
 
-@pytest.mark.parametrize("top_k", [4, 8], ids=["by_way", "by_token"])
-@pytest.mark.parametrize("latent", [0, 24], ids=["hidden", "latent"])
-@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
-def test_moe_layer_against_the_written_out_share(gated, latent, top_k):
+# (experts, first held, held, ways a token): what decides the combine's
+# form. A token's slots are its ways where ``top_k <= count`` and the
+# held experts where those are fewer; whole tiles of 8 slots are summed
+# token by token, others slot by slot (``ops/moe._by_token``).
+SHAPES = {
+    "ways_4_of_4_held": (16, 4, 4, 4),
+    "ways_8_of_8_held": (32, 4, 8, 8),
+    "slots_4_held_8_ways": (32, 4, 4, 8),
+    "slots_3_held_4_ways": (16, 4, 3, 4),
+    "slots_8_held_22_ways": (64, 8, 8, 22),
+    # half the experts held: the bounded buffer is the worst case, one path
+    "slots_2_held_3_ways_no_smaller_buffer": (4, 1, 2, 3),
+}
+TOKENS = 64
+
+
+def _cases():
+    """Every shape with gated and squared-ReLU experts at the hidden and
+    at a latent width (fresh routers: the bounded side, unmapped), and,
+    for latent squared-ReLU experts, on the worst-case side (``crowded``:
+    every token names every held expert it can) and under ``vmap``."""
+    for shape in SHAPES:
+        yield shape, False, 24, "fresh", False
+        yield shape, False, 24, "fresh", True
+        if "no_smaller_buffer" in shape:  # one path: nothing to crowd
+            continue
+        for gated, latent in ((True, 0), (True, 24), (False, 0)):
+            yield shape, gated, latent, "fresh", False
+        yield shape, False, 24, "crowded", False
+        yield shape, False, 24, "crowded", True
+
+
+def _case_id(case):
+    shape, gated, latent, steer, mapped = case
+    return "-".join([shape, "gated" if gated else "relu2",
+                     "latent" if latent else "hidden", steer,
+                     "vmap" if mapped else "unmapped"])
+
+
+def _routed(key, p, first, count, steer):
+    """-> (``p``, tokens for its router): ``fresh`` as drawn;
+    ``crowded`` with a marker feature, and a router row for it, that
+    sends every token to all the held experts its ways can name."""
+    h = jax.random.normal(key, (TOKENS, 64))
+    if steer == "crowded":
+        p = {**p, "router": (0.1 * p["router"]).at[0].set(0.0).at[
+            0, first:first + count].set(9.0)}
+        h = h.at[:, 0].set(1.0)
+    return p, h
+
+
+@pytest.mark.parametrize("case", list(_cases()), ids=_case_id)
+def test_moe_layer_against_the_written_out_share(case):
     """Three gated matrices an expert (the two decoder configurations'
     path, which its own tests hold too) or two with ``relu(.)^2``
     between, at the hidden width or in a latent one: values, the
-    tokens' gradient and every parameter's, 4 of 16 experts held. 8
-    ways a token are whole tiles and summed token by token, 4 (as 22)
-    are not and are summed way by way (``ops/moe._by_token``)."""
+    tokens' gradient and every parameter's (the router's too) against
+    the share written out, in both forms of the combine (:data:`SHAPES`),
+    through the bounded buffer and through the worst-case one, unmapped
+    and as a mapped batch, which goes one way together."""
+    shape, gated, latent, steer, mapped = case
+    experts, first, count, top_k = SHAPES[shape]
     key = jax.random.key(29)
-    p = _moe_params(key, gated, latent)
-    first, count = 4, 4
+    p = _moe_params(key, gated, latent, experts=experts)
     mine = {**p, **{m: p[m][first:first + count]
                     for m in ("w1", "w3", "w2") if m in p}}
-    h = jax.random.normal(jax.random.fold_in(key, 1), (48, 64))
+    mine, h = _routed(jax.random.fold_in(key, 1), mine, first, count, steer)
     weigh = jax.random.normal(jax.random.fold_in(key, 2), h.shape)
+    if mapped:  # the second instance fresh: the batch follows the first
+        h = jnp.stack([h, jax.random.normal(
+            jax.random.fold_in(key, 3), h.shape)])
 
     def share(p, h):
         y, counters = MOE.moe_layer(p, h, (first, count), top_k, 5.0)
@@ -272,18 +326,125 @@ def test_moe_layer_against_the_written_out_share(gated, latent, top_k):
         y = _written_out(p, h, first, count, top_k, 5.0)
         return jnp.sum(y * weigh), y
 
-    (_, (y, counters)), grads = jax.value_and_grad(
-        share, argnums=(0, 1), has_aux=True)(mine, h)
-    (_, want), want_grads = jax.value_and_grad(
-        plain, argnums=(0, 1), has_aux=True)(mine, h)
+    both = lambda fn: jax.value_and_grad(fn, argnums=(0, 1), has_aux=True)
+    ours, theirs = both(share), both(plain)
+    if mapped:
+        ours, theirs = (jax.vmap(fn, in_axes=(None, 0))
+                        for fn in (ours, theirs))
+    (_, (y, counters)), grads = jax.jit(ours)(mine, h)
+    (_, want), want_grads = jax.jit(theirs)(mine, h)
     np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
     flat = dict(jax.tree_util.tree_leaves_with_path(want_grads))
     for path, g in jax.tree_util.tree_leaves_with_path(grads):
         scale = float(jnp.max(jnp.abs(flat[path]))) + 1e-12
         assert float(jnp.max(jnp.abs(g - flat[path]))) <= 2e-4 * scale, (
             jax.tree_util.keystr(path))
-    assert float(counters[1]) == 48 * top_k
-    assert 0 < float(counters[0]) < 48 * top_k
+    assert float(jnp.max(jnp.abs(grads[0]["router"]))) > 0
+    counters = counters.reshape(-1, len(MOE.MOE_COUNTERS))
+    nk = TOKENS * top_k
+    buffer = MOE.row_buffer(TOKENS, top_k, count, experts)
+    assert (buffer < nk) == ("no_smaller_buffer" not in shape)
+    for held, routed, _, compact, combined in counters.tolist():
+        assert routed == nk and 0 < held <= nk
+        assert compact == (nk if steer == "fresh" and buffer < nk else 0.0)
+        assert combined == TOKENS * min(top_k, count)
+    if steer == "crowded" and buffer < nk:  # over it, as steered
+        assert counters[0, 0] == TOKENS * min(top_k, count) > buffer
+
+
+@pytest.mark.parametrize("steer", [
+    "random", "an_expert_with_no_rows", "every_token_on_one_expert",
+    "tied_scores"])
+def test_a_slots_row_is_where_the_orders_own_inverse_puts_it(steer):
+    """6 held of 24 experts, 9 ways: ``_slot_rows``' running count finds
+    for every held assignment the row ``argsort(order)`` gives it, the
+    one past every buffer for a slot no way names, and in its last row
+    the rows a held expert as counted over all ids. That rests on two
+    facts: the sort by expert is STABLE (the group keys are all ties
+    within an expert), and a token's ``top_k`` ids are distinct (also
+    where its scores tie)."""
+    n, experts, first, count, k = 40, 24, 5, 6, 9
+    scores = jax.random.normal(jax.random.key(31), (n, experts))
+    if steer == "an_expert_with_no_rows":
+        scores = scores.at[:, first + 2].set(-30.0)
+    elif steer == "every_token_on_one_expert":
+        scores = scores.at[:, first + 1:first + count].set(-30.0).at[
+            :, first].set(30.0)
+    elif steer == "tied_scores":  # probabilities of exactly 1 and 0
+        scores = 60.0 * jnp.sign(scores)
+    top_e, _ = MOE.route_top_k(scores, k, 1.0)
+    ids = np.sort(np.asarray(top_e), -1)
+    assert (ids[:, 1:] != ids[:, :-1]).all()
+    local = np.asarray(top_e) - first
+    held = (local >= 0) & (local < count)
+    group = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    inverse = np.asarray(jnp.argsort(order)).reshape(n, k)
+    sizes = jnp.bincount(group, length=count + 1)[:count].astype(jnp.int32)
+    (pos, hit), counted = jax.jit(MOE._slot_rows, static_argnums=(1, 2))(
+        jnp.asarray(local), count, n * k)
+    pos, hit = np.asarray(pos), np.asarray(hit)
+    assert counted.tolist() == sizes.tolist()
+    assert held.any() and (hit.any(-1) == held).all()
+    assert (hit.sum(1) <= 1).all()
+    token, way = np.nonzero(held)
+    assert (pos[token, local[token, way]] == inverse[token, way]).all()
+    assert (pos[~hit.any(1)] == n * k).all()
+    assert (pos[hit.any(1)] < int(sizes.sum())).all()
+    if steer == "an_expert_with_no_rows":
+        assert int(sizes[2]) == 0
+    if steer == "every_token_on_one_expert":
+        assert sizes.tolist() == [n, 0, 0, 0, 0, 0]
+
+
+@pytest.mark.parametrize("shape", ["ways_4_of_4_held", "slots_3_held_4_ways",
+                                   "slots_8_held_22_ways"])
+def test_one_sort_a_layer_pass_and_no_row_a_way_where_slots_are_fewer(shape):
+    """Forward, recomputation and backward of a layer share under
+    ``remat`` (two layer passes that order the assignments). A token's
+    slots its ways: two sorts a pass (``order`` and its ``inverse``) and,
+    outside the ``cond`` sides that run when the rows do not fit, the
+    wide arrays ``tests/test_decoder.py::
+    test_no_worst_case_sized_array_on_the_bounded_path`` names. The
+    held experts: ONE sort a pass, and no array of ``N x top_k`` rows by
+    an expert or model width there, only ``N x count``."""
+    experts, first, count, top_k = SHAPES[shape]
+    key = jax.random.key(37)
+    p = _moe_params(key, False, 0, experts=experts)
+    p = {**p, "w1": p["w1"][first:first + count],
+         "w2": p["w2"][first:first + count]}
+    h = jax.random.normal(jax.random.fold_in(key, 1), (TOKENS, 64))
+    layer = jax.checkpoint(
+        lambda p, h: MOE.moe_layer(p, h, (first, count), top_k, 5.0)[0])
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, h: jnp.sum(layer(p, h)), argnums=(0, 1)))(p, h).jaxpr
+    slots = min(top_k, count)
+    sorts = [e for e in _eqns(jaxpr) if e.primitive.name == "sort"]
+    assert len(sorts) == 2 * (2 if top_k <= count else 1)
+    own = {leaf.shape for leaf in jax.tree.leaves(p)}
+    nk = TOKENS * top_k
+
+    def wide(eqns, rows):
+        """Shapes of ``rows`` rows (flat, or a token's side by side) by
+        the model's width or an expert's."""
+        return {
+            s for eqn in eqns for v in eqn.outvars
+            for s in [v.aval.shape]
+            if s not in own and len(s) >= 2 and s[-1] in (64, 32) and (
+                s[-2] == TOKENS * rows
+                or s[-3:-1] in ((rows, TOKENS), (TOKENS, rows)))}
+
+    bounded = list(_eqns(jaxpr))
+    by_slot = ({(TOKENS, slots, 64)} if slots % MOE.SUBLANES == 0
+               else {(slots, TOKENS, 64)})
+    if top_k <= count:
+        assert wide(bounded, top_k) == {(nk, 64)} | by_slot
+    else:
+        assert wide(bounded, top_k) == set()
+        assert wide(bounded, slots) == by_slot
+        # the other side holds the worst-case buffer, as it must
+        assert (nk, 32) in wide(_eqns(jaxpr, bounded_side_only=False),
+                                top_k)
 
 
 # ---------------------------------------------------------------------------
@@ -392,7 +553,7 @@ def test_a_round_trains_every_leaf_and_carries_the_expert_counters():
     """``FedAvgSim``, bulk engine at a block of one, over the tiny stack
     through ``run``'s own loop: every parameter moves (the state-space
     layers' ``A_log``, ``D``, ``dt_bias`` and convolution too) and the
-    round record carries the four expert counters."""
+    round record carries the expert counters."""
     sim = _sim(TN.tiny_config(), 1, seq=TN.SEQ, vocab=TN.VOCAB)
 
     class Sink:
@@ -410,6 +571,8 @@ def test_a_round_trains_every_leaf_and_carries_the_expert_counters():
     for record in Sink.records:
         assert record["moe_rows_routed"] == steps * 2 * TN.SEQ * 4 * sparse
         assert 0 < record["moe_rows_held"] < record["moe_rows_routed"]
+        # 4 ways over 4 held: the combine reads a row a way
+        assert record["moe_rows_combined"] == record["moe_rows_routed"]
     assert "test_acc" in Sink.records[-1]
 
 
