@@ -32,7 +32,7 @@ import optax
 
 from fedml_tpu.config import ExperimentConfig, FedConfig, TrainConfig
 from fedml_tpu.core import adversary as A
-from fedml_tpu.core.tracing import span
+from fedml_tpu.core.tracing import build_span, span
 from fedml_tpu.core import bulk as BK
 from fedml_tpu.core import compress as C
 from fedml_tpu.core import elastic as E
@@ -392,6 +392,7 @@ class FedAvgSim:
     """Compiled federated simulation on one chip (see
     :mod:`fedml_tpu.parallel` for the mesh-sharded version)."""
 
+    @build_span
     def __init__(
         self,
         model: FedModel,

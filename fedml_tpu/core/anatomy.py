@@ -100,7 +100,7 @@ class RoundAnatomy:
     check at each call site. The sims' loops hold no clock of their
     own: their phases arrive from the span sites
     (:func:`fedml_tpu.core.tracing.span` with ``phase=``), which read
-    the clock only while this plane (or the tracer ring) is on.
+    the clock for the process ring in any case.
     """
 
     def __init__(self, ring_capacity: int = RING_CAPACITY):

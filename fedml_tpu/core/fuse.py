@@ -223,10 +223,11 @@ def drive_rounds(
       there), still inside the round's span and anatomy window.
 
     ONE set of boundaries (core/tracing.span): each span is a profiler
-    annotation, a ring event under --trace, and — where it names a
-    phase — the anatomy plane's clock. They sit at sync points the loop
-    ALREADY has (the dispatch return, the one batched device_get), so
-    the off path is a flag check a span and nothing adds a device sync.
+    annotation, a record in the process ring (always), and — where it
+    names a phase — the anatomy plane's clock. They sit at sync points
+    the loop ALREADY has (the dispatch return, the one batched
+    device_get), so a span costs a few microseconds of host time and
+    nothing adds a device sync.
     The round wall time is taken AFTER the metric host conversion forces
     the device, so the monitor and a capture window measure execution,
     not dispatch."""

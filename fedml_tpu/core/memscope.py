@@ -410,10 +410,19 @@ class ProgramSite:
 
         with self._lock:
             exe = self._exes.get(key)
-        if exe is None:
+        compiled = exe is None
+        if compiled:
+            # set-up spans (docs/OBSERVABILITY.md "Spans and scopes"):
+            # tracing + lowering and the backend's compile (or its
+            # cache read) apart; what is left of ``fedml.compile`` is
+            # the accounting and the kept module text
             with span("fedml.compile", family=self.family, key=str(key)):
                 t0 = time.perf_counter()
-                exe = self._jit.lower(*args).compile()
+                with span("fedml.compile.lower"):
+                    lowered = self._jit.lower(*args)
+                with span("fedml.compile.backend"):
+                    exe = lowered.compile()
+                del lowered  # the module's text dies here, as before
                 wall = time.perf_counter() - t0
                 with self._lock:
                     self._exes[key] = exe
@@ -431,7 +440,14 @@ class ProgramSite:
                             if i not in self._static)
         else:
             dynamic = args
-        out = exe(*dynamic)
+        if compiled:
+            # program load and first enqueue; every later call pays the
+            # truth test above and opens no span
+            with span("fedml.first_call", family=self.family,
+                      key=str(key)):
+                out = exe(*dynamic)
+        else:
+            out = exe(*dynamic)
         if audit:
             self._audited.add(key)
             audit_donation(self.family, key, donated)

@@ -27,8 +27,10 @@ hangs off (docs/OBSERVABILITY.md):
 
 Disabled is the default and costs nothing per message: :data:`METRICS`
 starts ``enabled=False`` (every ``inc``/``gauge``/``observe`` early-
-returns), :data:`TRACER` is ``None`` (all tracing sites are guarded by
-an ``is not None`` check and allocate no ids), and the recorder ring
+returns), :data:`TRACER` is ``None`` (all message-level tracing sites
+are guarded by an ``is not None`` check and allocate no ids; the round
+loops' ``tracing.span`` boundaries fill the process ring regardless, a
+few microseconds a span), and the recorder ring
 accepts nothing. :func:`configure` — called by ``run.py`` under
 ``--telemetry_dir``/``--trace`` and by ``deploy.run_role`` — switches
 the plane on for THIS process.
@@ -53,7 +55,7 @@ import time
 import uuid
 from typing import Any
 
-from fedml_tpu.core.tracing import Tracer
+from fedml_tpu.core.tracing import RING, Tracer
 
 
 def percentiles_from_histogram(
@@ -368,9 +370,10 @@ class FlightRecorder:
 METRICS = MetricsRegistry(enabled=False)
 #: Process-global flight recorder — disabled until :func:`configure`.
 RECORDER = FlightRecorder()
-#: Process-global tracer — ``None`` until :func:`configure(trace=True)`.
-#: Every tracing site guards on ``TRACER is not None`` so the disabled
-#: path allocates nothing per message.
+#: ``None`` until :func:`configure(trace=True)`, then the process ring
+#: (:data:`fedml_tpu.core.tracing.RING`, which ``tracing.span`` fills
+#: regardless). Every message-level tracing site guards on ``TRACER is
+#: not None`` so the disabled path allocates nothing per message.
 TRACER: Tracer | None = None
 
 _DIR: str | None = None
@@ -483,9 +486,11 @@ def configure(
     """Enable telemetry for THIS process (idempotent).
 
     - metrics counting switches on unconditionally;
-    - ``trace=True`` creates the process tracer: spans
-      (:func:`fedml_tpu.core.tracing.span`) then append to its ring as
-      well as annotating any active ``jax.profiler`` session;
+    - ``trace=True`` sets :data:`TRACER` to the process ring
+      (:data:`fedml_tpu.core.tracing.RING` — the spans of
+      :func:`fedml_tpu.core.tracing.span` are in it whether or not
+      this is called) with this rank: the message-level sites, which
+      guard on it, start recording into the same ring;
     - a ``telemetry_dir`` additionally arms the flight recorder, the
       crash hooks (sys/threading excepthook -> flight dump), and the
       exit flush that writes ``trace_rank<r>.json`` +
@@ -512,10 +517,8 @@ def configure(
     METRICS.enabled = True
     RECORDER.rank = rank
     if trace:
-        if TRACER is None:
-            TRACER = Tracer(rank=rank)
-        else:
-            TRACER.rank = rank
+        TRACER = RING
+        TRACER.rank = rank
     if telemetry_dir:
         os.makedirs(telemetry_dir, exist_ok=True)
         _DIR = telemetry_dir
@@ -770,6 +773,8 @@ def shutdown() -> None:
     RECORDER._ring.clear()
     RECORDER._dumps = 0
     TRACER = None
+    RING.clear()
+    RING.rank = None
     _DIR = None
     _SUFFIX = ""
     set_current_trace(None)

@@ -44,6 +44,7 @@ from fedml_tpu.core import elastic as E
 from fedml_tpu.core import memscope as M
 from fedml_tpu.core import random as R
 from fedml_tpu.core import robust
+from fedml_tpu.core.tracing import build_span
 from fedml_tpu.data.federated import FederatedData, shard_client_banks
 from fedml_tpu.algorithms.base import (
     build_cohort_local_update,
@@ -80,6 +81,7 @@ class ShardedFedAvg(FedAvgSim):
     (``peak_per_chip x mesh.devices.size``, resolved by
     ``perf.build_sim_perf`` from :attr:`mesh`), not one chip."""
 
+    @build_span
     def __init__(
         self,
         model: FedModel,

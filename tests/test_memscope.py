@@ -113,6 +113,34 @@ def test_program_site_records_analysis_and_compile_time(metrics_on):
     assert site._cache_size() == 1
 
 
+def test_program_site_leaves_setup_spans_once_a_key():
+    """Every compile of a ``ProgramSite`` is in the process ring with
+    no plane switched on: ``fedml.compile`` (family, key) holding
+    ``.lower`` and ``.backend``, then ``fedml.first_call`` round the
+    call that compiled; a key's later calls open no span."""
+    from fedml_tpu.core import tracing
+
+    assert telemetry.TRACER is None
+    site = memscope.ProgramSite(lambda x, k: x * k, family="spans",
+                                static_argnums=(1,))
+    tracing.RING.clear()
+    for key in (3, 3, 5, 3, 5):
+        out = site(key, jnp.ones((4,)), key)
+        np.testing.assert_array_equal(np.asarray(out), float(key))
+    evs = list(tracing.RING.events)
+    assert [e["name"] for e in evs] == 2 * [
+        "fedml.compile.lower", "fedml.compile.backend", "fedml.compile",
+        "fedml.first_call"]
+    assert [e["key"] for e in evs if "key" in e] == ["3", "3", "5", "5"]
+    assert {e["family"] for e in evs if "family" in e} == {"spans"}
+    low, back, comp, first = evs[:4]
+    assert low["parent"] == back["parent"] == "fedml.compile"
+    assert comp["parent"] is first["parent"] is None
+    assert (comp["t0"] <= low["t0"] <= low["t0"] + low["seconds"]
+            <= back["t0"] <= comp["t0"] + comp["seconds"] <= first["t0"])
+    tracing.RING.clear()
+
+
 def test_sim_round_program_slug_and_cohort_growth(metrics_on):
     """The FedAvgSim round registers under (family=sim_round,
     key=bucket) and its argument bytes grow with the cohort — the O(C)

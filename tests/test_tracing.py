@@ -8,19 +8,33 @@ from fedml_tpu.core.tracing import Tracer
 
 
 def test_tracer_comm_and_rounds(tmp_path):
+    """The collector's three kinds: a round (``log_round_start`` /
+    ``_end``), an instant event (what the message sites write) and a
+    span; every record stands on both clocks."""
     tr = Tracer()
     tr.log_round_start(0)
-    tr.log_communication_tick(0, 1, "sync")
-    tr.log_communication_tock(0, 1, "sync")
+    tr.event("msg_send", sender=0, receiver=1, tag="sync")
     tr.log_round_end(0)
+    tr.log_round_end(5)  # never started: no record
     with tr.span("aggregate", round=0):
         pass
     s = tr.summary()
-    assert s["comm"]["count"] == 1
+    assert s["msg_send"]["count"] == 1
     assert s["round"]["count"] == 1
     assert s["aggregate"]["count"] == 1
-    tr.dump(str(tmp_path / "trace.json"))
-    assert (tmp_path / "trace.json").exists()
+    kinds = [e["kind"] for e in tr.events]
+    assert kinds == ["event", "round", "span"]
+    assert all(e["ts"] > 0 and "t0" in e and e["seconds"] >= 0
+               for e in tr.events)
+    send, rnd, _ = tr.events
+    assert rnd["t0"] <= send["t0"] <= rnd["t0"] + rnd["seconds"]
+    assert not hasattr(tr, "log_communication_tick")
+    tr.dump(str(tmp_path / "trace.json"), note="x")
+    import json
+
+    dump = json.loads((tmp_path / "trace.json").read_text())
+    assert dump["note"] == "x" and dump["dropped"] == 0
+    assert dump["complete_from"] is None and len(dump["events"]) == 3
 
 
 @pytest.mark.slow
@@ -72,7 +86,7 @@ from fedml_tpu.config import (  # noqa: E402
 )
 from fedml_tpu.core import anatomy, memscope, telemetry, tracing  # noqa: E402
 from fedml_tpu.core.anatomy import ANATOMY  # noqa: E402
-from fedml_tpu.core.tracing import span  # noqa: E402
+from fedml_tpu.core.tracing import RING, span  # noqa: E402
 from fedml_tpu.data.loaders import load_dataset  # noqa: E402
 from fedml_tpu.models import create_model  # noqa: E402
 from fedml_tpu.parallel import ShardedFedAvg, make_mesh  # noqa: E402
@@ -143,26 +157,112 @@ def _run_captured(sim, trace_dir, sink=None):
         jax.profiler.stop_trace()
 
 
-def test_span_off_is_transparent():
-    """No profiler session, no tracer, no anatomy: nothing is recorded,
-    no clock is read, and the body's value and exception pass."""
+@pytest.fixture
+def ring():
+    """The process ring, emptied: earlier tests' sims filled it."""
+    RING.clear()
+    yield RING
+    RING.clear()
+
+
+def _ring_spans(ring, name=None):
+    return [e for e in ring.events if e["kind"] == "span"
+            and (name is None or e["name"] == name)]
+
+
+def test_span_lands_in_the_ring_with_no_configure(ring):
+    """No profiler session, no ``telemetry.configure``, no anatomy: the
+    span is in the process ring all the same, on the host's clock, and
+    the body's value and exception pass."""
+    import time
+
     assert telemetry.TRACER is None and not ANATOMY.enabled
+    before = time.perf_counter()
 
     def body():
         with span("fedml.x", round=3, phase="eval") as sp:
             assert tracing._open_spans()[-1] == ("fedml.x", 3)
+            with span("fedml.y"):
+                pass
             return sp, 41 + 1
 
     sp, value = body()
-    assert value == 42 and sp.seconds == 0.0
+    after = time.perf_counter()
+    assert value == 42
     with pytest.raises(KeyError):
-        with span("fedml.y"):
+        with span("fedml.z"):
             raise KeyError("boom")
     assert tracing._open_spans() == []
     assert ANATOMY.tracez()["entries"] == []
+    y, x, z = _ring_spans(ring)
+    assert (y["name"], y["parent"], y["round"]) == ("fedml.y", "fedml.x", 3)
+    assert (x["name"], x["parent"], x["round"]) == ("fedml.x", None, 3)
+    assert x["seconds"] == sp.seconds
+    assert "KeyError" in z["error"] and "error" not in x
+    # t0 is perf_counter() at the start: the child lies inside its parent
+    assert before <= x["t0"] <= y["t0"]
+    assert y["t0"] + y["seconds"] <= x["t0"] + x["seconds"] <= after
+    assert x["ts"] > 1e9 and x["rank"] is None
 
 
-def test_span_ring_records_parent_round_and_error(tmp_path):
+def test_configure_hands_out_the_same_ring(ring, tmp_path):
+    """``telemetry.configure(trace=True)`` creates no second collector:
+    ``TRACER`` IS the process ring with the rank set, spans opened
+    before it are still there, and the message-level sites (which guard
+    on ``TRACER``) wrote nothing before it."""
+    from fedml_tpu.core.message import Message
+    from fedml_tpu.core.transport.loopback import LoopbackHub
+
+    def deliver(i):  # transport/base.py:deliver, one of the sites
+        msg = Message(100, 0, 1, {"i": i})
+        msg.trace = ("trace", f"span{i}")
+        receiver.deliver(msg)
+
+    receiver = LoopbackHub().create(1)
+    with span("fedml.build", sim="early"):
+        deliver(0)
+    assert [e["name"] for e in ring.events] == ["fedml.build"]
+    telemetry.configure(telemetry_dir=str(tmp_path / "t"), rank=4)
+    try:
+        assert telemetry.TRACER is ring is tracing.RING
+        assert ring.rank == 4
+        deliver(1)
+        with span("fedml.round", round=0):
+            pass
+        assert [(e["kind"], e["name"]) for e in ring.events] == [
+            ("span", "fedml.build"), ("event", "msg_deliver"),
+            ("span", "fedml.round")]
+        assert ring.events[1]["span_id"] == "span1"
+        assert ring.events[-1]["rank"] == 4
+    finally:
+        telemetry.shutdown()
+    # back to the unconfigured state: silent sites, an empty ring
+    assert telemetry.TRACER is None
+    assert len(ring.events) == 0 and ring.rank is None
+
+
+def test_ring_is_bounded_and_says_what_it_dropped():
+    tr = Tracer(max_events=4)
+    for i in range(4):
+        with tr.span("s", i=i):
+            pass
+    assert (tr.dropped, tr.complete_from) == (0, None)
+    ends = [e["t0"] + e["seconds"] for e in tr.events]
+    for i in range(4, 7):
+        with tr.span("s", i=i):
+            pass
+    assert len(tr.events) == 4 and tr.dropped == 3
+    assert [e["i"] for e in tr.events] == [3, 4, 5, 6]
+    # whole from the end of the newest evicted record on: every span
+    # that began at or after it is held
+    assert tr.complete_from == ends[2]
+    assert all(e["t0"] >= tr.complete_from for e in tr.events)
+    assert tracing.RING.events.maxlen == tracing.RING_CAPACITY
+    tr.clear()
+    assert (len(tr.events), tr.dropped, tr.complete_from) == (0, 0, None)
+
+
+def test_span_ring_records_parent_round_and_error(ring, tmp_path):
     telemetry.configure(telemetry_dir=str(tmp_path / "t"), rank=0)
     try:
         with span("fedml.round", round=7):
@@ -191,8 +291,76 @@ def test_span_ring_records_parent_round_and_error(tmp_path):
                 pass
         assert [(e["name"], e["parent"]) for e in own.events] == [
             ("b", "a"), ("a", None)]
+        assert not _ring_spans(ring, "a")
     finally:
         telemetry.shutdown()
+
+
+def test_build_and_compile_leave_setup_spans(ring):
+    """Constructing a simulator leaves one ``fedml.build``; a
+    ``ProgramSite`` compile leaves ``fedml.compile`` > ``.lower``,
+    ``.backend`` and one ``fedml.first_call``; a second call of the
+    same key leaves none."""
+    sim = _sim(rounds=1)
+    (build,) = _ring_spans(ring, "fedml.build")
+    assert build["sim"] == "FedAvgSim" and build["parent"] is None
+    assert not _ring_spans(ring, "fedml.compile")
+    state, _ = sim.run_round(sim.init())
+    (comp,) = _ring_spans(ring, "fedml.compile")
+    (low,) = _ring_spans(ring, "fedml.compile.lower")
+    (back,) = _ring_spans(ring, "fedml.compile.backend")
+    (first,) = _ring_spans(ring, "fedml.first_call")
+    assert comp["family"] == first["family"] == "sim_round"
+    assert comp["key"] == first["key"]
+    assert low["parent"] == back["parent"] == "fedml.compile"
+    assert comp["t0"] <= low["t0"] <= back["t0"]
+    assert (back["t0"] + back["seconds"] <= comp["t0"] + comp["seconds"]
+            <= first["t0"])
+    assert low["seconds"] + back["seconds"] <= comp["seconds"]
+    n = len(ring.events)
+    sim.run_round(state)
+    assert len(ring.events) == n  # no span outside a loop, none new
+
+
+def test_sharded_build_nests_its_base(ring):
+    _sim(sharded=True, rounds=1)
+    inner, outer = _ring_spans(ring, "fedml.build")
+    assert (outer["sim"], outer["parent"]) == ("ShardedFedAvg", None)
+    assert (inner["sim"], inner["parent"]) == ("FedAvgSim", "fedml.build")
+    assert outer["t0"] <= inner["t0"]
+    assert (inner["t0"] + inner["seconds"]
+            <= outer["t0"] + outer["seconds"])
+
+
+def test_run_loop_leaves_three_rounds_in_order(ring):
+    """``run_loop`` over three rounds with no profiler and no
+    configure: three ``fedml.round``, each holding dispatch, fetch and
+    log (and the evaluation where one is due) with ``t0``s in order."""
+    from fedml_tpu.metrics import MetricsSink
+
+    sim = _sim(rounds=3)
+    sim.run(metrics_sink=MetricsSink())
+    rounds = _ring_spans(ring, "fedml.round")
+    assert [r["round"] for r in rounds] == [0, 1, 2]
+    assert all(a["t0"] + a["seconds"] <= b["t0"]
+               for a, b in zip(rounds, rounds[1:]))
+    for r in rounds:
+        kids = sorted((e for e in _ring_spans(ring)
+                       if e["parent"] == "fedml.round"
+                       and e["round"] == r["round"]),
+                      key=lambda e: e["t0"])
+        want = ["fedml.dispatch", "fedml.fetch", "fedml.log"]
+        if r["round"] in (1, 2):  # eval_every=2, and the last round
+            want.insert(2, "fedml.eval")
+        assert [k["name"] for k in kids] == want
+        assert r["t0"] <= kids[0]["t0"]
+        assert all(a["t0"] + a["seconds"] <= b["t0"]
+                   for a, b in zip(kids, kids[1:]))
+        assert (kids[-1]["t0"] + kids[-1]["seconds"]
+                <= r["t0"] + r["seconds"])
+    # the first dispatch compiled, under its own spans
+    (comp,) = _ring_spans(ring, "fedml.compile")
+    assert (comp["parent"], comp["round"]) == ("fedml.dispatch", 0)
 
 
 def test_span_phase_feeds_anatomy_and_amends(tmp_path):
