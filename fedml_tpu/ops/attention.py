@@ -54,6 +54,37 @@ bytes grow with ``T`` squared — 67 MB a layer at 8,192 tokens, 1.07 GB
 at 32,768). So the forward kernel, the index and the top-k run once a
 training step. The names do nothing anywhere else: without a gradient,
 or under no such policy.
+
+**The kernel's backward pass walks its score blocks once**
+(:func:`_block_sizes`). The library's backward pass is two kernels that
+each walk every visited (query block, key block) pair — a dq kernel
+(scores, mask, exponentials, dP, then dQ: three block products) and a
+dk/dv kernel (the same again, then dV and dK: four) — or, as here, ONE
+(``use_fused_bwd_kernel``): the dk/dv kernel also forms ``dS K`` and
+writes it as one partial of dQ a key block (``dq_unreduced``, ``[T /
+block_kv_dkv, heads, T, D]`` in the compute dtype), five products a
+pair and one softmax recomputation. One attention call alone on a v5e
+(forward + backward, bfloat16, the cells' shapes; PERF.md section 6,
+PR 38) reads 31.30 -> 23.97 ms over a selection of 8,192 tokens x 32
+heads, 6.65 -> 5.52 causal and 6.58 -> 6.22 under a window of 512 at 2
+x 2,048 x 48 / 64 heads, 2.34 -> 1.84 causal at 8,192 x 4: the kernel
+is 7-21 % slower for the fifth product (68 % under a window, whose
+grid no longer shrinks to the pairs the mask leaves: every pair is
+stepped over, its partial written as zeros) and the dq pass, 75-88 %
+of it, goes — every use wins, so there is one launch shape and no
+choice. What the partials cost: ``T / block_kv_dkv`` times dQ's own
+bytes, written by the kernel and read back by the sum — at key blocks
+of 1,024 tokens 8 x 67 MB = 537 MB a layer call at 8,192 tokens x 32
+heads (1.07 GB at blocks of 512; blocks of 2,048 do not fit the
+dynamic mask's fast memory), transient, and growing with ``T``
+squared: 8.6 GB at 32,768 tokens, where this form cannot stand.
+Inside a key block dQ's terms add up across the compute blocks in a
+float32 scratch; the partials are rounded to the compute dtype once
+each and their sum (the library's ``dq_unreduced.sum(axis=0)``)
+accumulates in float32 — ``jnp.sum`` widens a bfloat16 operand for the
+reduction — and is rounded once: the parent's arithmetic in another
+order of summation (dk and dv to the bit; dq 1.3e-4 to 3.1e-4 from the
+parent's, as far from float32 as the parent's).
 """
 
 from __future__ import annotations
@@ -108,8 +139,14 @@ def masked_attention(q, k, v, window: int | None = None,
     return checkpoint_name(a.reshape(b, t, h, d).astype(q.dtype), KEPT_OUTPUT)
 
 
-@functools.lru_cache(maxsize=16)
 def _block_sizes(t: int):
+    """The kernel's launch shape at ``t`` tokens, one for every use
+    (module docstring): the forward pass in blocks of :data:`BLOCK`;
+    the backward pass ONE kernel (``use_fused_bwd_kernel``; the library
+    refuses dq edges beside it) over query blocks of :data:`BLOCK` and
+    key blocks of two — ``t / (2 BLOCK)`` partials of dQ — in compute
+    blocks of :data:`BLOCK`; one block of each where ``t`` is not whole
+    double blocks."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
     )
@@ -117,8 +154,8 @@ def _block_sizes(t: int):
     blk = min(BLOCK, t)
     return sk.BlockSizes(
         block_q=blk, block_kv=blk, block_kv_compute=blk,
-        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
-        block_q_dq=blk, block_kv_dq=blk,
+        block_q_dkv=blk, block_kv_dkv=blk if t % (2 * blk) else 2 * blk,
+        block_kv_dkv_compute=blk, use_fused_bwd_kernel=True,
     )
 
 

@@ -89,7 +89,8 @@ def test_decoder_attention_compiles_for_v5e(one_chip, monkeypatch, heads,
     """The decoder stack's attention at Laguna-XS.2's widths (48 full /
     64 sliding query heads over 8 key-value heads of 128, 2 sequences of
     2,048, window 512), forward and backward: the blockwise kernel is in
-    the program, three calls of it (forward, dq, dkv)."""
+    the program, two calls of it (forward, and the ONE backward kernel:
+    dq comes out of the dk/dv kernel as two partials)."""
     from fedml_tpu.ops import attention as A
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
@@ -104,7 +105,9 @@ def test_decoder_attention_compiles_for_v5e(one_chip, monkeypatch, heads,
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "splash_mqa_dkv" in text and "splash_mqa_dq" not in text
     # no [T, T] score tensor: the scratch is far under one head's scores
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2048 * (
         2048 * heads * 2)
@@ -115,8 +118,9 @@ def test_sparse_attention_compiles_for_v5e(one_chip, monkeypatch):
     sequence of 8,192; an index of 16 heads of 64 over one key head that
     keeps 2,048 keys a query; 32 query heads over 4 key-value heads of
     128): the index and selection kernels, and attention over the
-    selection forward and backward — five Mosaic kernels in the program
-    and no ``[heads, T, T]`` tensor in its scratch."""
+    selection forward and backward — four Mosaic kernels in the program
+    (the backward pass is ONE: dq comes out of the dk/dv kernel) and no
+    ``[heads, T, T]`` tensor in its scratch."""
     from fedml_tpu.ops import attention as A
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
@@ -133,10 +137,12 @@ def test_sparse_attention_compiles_for_v5e(one_chip, monkeypatch):
         sds(1, t, 32, 128), sds(1, t, 4, 128), sds(1, t, 4, 128),
         sds(1, t, 16, 64), sds(1, t, 64), sds(1, t, 16)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 5
+    assert text.count("tpu_custom_call") >= 4
+    assert "splash_mqa_dkv" in text and "splash_mqa_dq" not in text
     assert "sparse_index_scores" in text and "sparse_select_top_k" in text
-    # the scores (268 MB), the selection and its three blocked copies
-    # (67 MB each): far under one key-value head's [8, T, T] scores
+    # the scores (268 MB), the selection and its two blocked copies
+    # (67 MB each), dq's 8 partials (67 MB each): far under one
+    # key-value head's [8, T, T] scores
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
@@ -146,9 +152,10 @@ def test_rematerialised_sparse_layer_compiles_for_v5e(one_chip, monkeypatch):
     sequence of 8,192, a dense feed-forward in the experts' place —
     under ``DecoderLM``'s remat: the layer keeps its kernel's output,
     log-sum-exp and its selection, so the program holds the forward
-    kernel, the index and the top-k ONCE beside the two backward
-    kernels, and what it keeps (135 MB) fits beside the recomputed
-    rest."""
+    kernel, the index and the top-k ONCE beside the ONE backward kernel
+    (dq comes out of the dk/dv kernel as a partial a key block of 1,024:
+    8 x 67 MB, summed after the call), and what it keeps (135 MB) fits
+    beside the recomputed rest."""
     from fedml_tpu.models.decoder import decoder_from_extra
     from fedml_tpu.ops import attention as A
 
@@ -179,7 +186,8 @@ def test_rematerialised_sparse_layer_compiles_for_v5e(one_chip, monkeypatch):
     calls = lambda name: sum(f"%{name}" in line.split("=")[0]
                              for line in kernels)
     assert calls("splash_mqa_fwd") == 1
-    assert calls("splash_mqa_dq") == calls("splash_mqa_dkv") == 1
+    # one walk over the score blocks: dq comes out of the dk/dv kernel
+    assert calls("splash_mqa_dq") == 0 and calls("splash_mqa_dkv") == 1
     assert calls("sparse_select_top_k") == calls("sparse_index_scores") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 

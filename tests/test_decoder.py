@@ -831,15 +831,16 @@ def test_a_layer_runs_its_forward_kernels_once_a_training_step(
         kind, kernels, plain_remat):
     """In the jaxpr of a training gradient every layer calls the
     blockwise kernel's forward ONCE (its output and log-sum-exp are
-    kept: ``ops/attention.KEPT``) and its two backward kernels once —
-    and a sparse-attention layer its index and its top-k once, since
-    the selection is kept — where ``nn.remat`` with no policy runs each
+    kept: ``ops/attention.KEPT``) and its ONE backward kernel once (the
+    dk/dv kernel forms dq as well: ``ops/attention._block_sizes``) —
+    and a sparse-attention layer its index and its top-k once, since the
+    selection is kept — where ``nn.remat`` with no policy runs each
     forward a second time."""
     model = _kept_stack(kind)
     tokens = jax.random.randint(jax.random.key(0), (1, 256), 0, 32)
     params = jax.eval_shape(model.init, jax.random.key(1), tokens)["params"]
     selects = 2 * (kind == "sparse_attention")
-    once = {"splash_mqa_fwd": 2, "splash_mqa_dq": 2, "splash_mqa_dkv": 2,
+    once = {"splash_mqa_fwd": 2, "splash_mqa_dq": 0, "splash_mqa_dkv": 2,
             "sparse_select_top_k": selects, "sparse_index_scores": selects}
     assert _kernel_calls(_training_gradient(model, tokens), params) == once
     with plain_remat():
@@ -849,7 +850,7 @@ def test_a_layer_runs_its_forward_kernels_once_a_training_step(
     # no gradient, nothing to keep: the evaluator's forward pass
     forward = lambda p: model.apply({"params": p}, tokens)
     assert _kernel_calls(forward, params) == {
-        **once, "splash_mqa_dq": 0, "splash_mqa_dkv": 0}
+        **once, "splash_mqa_dkv": 0}
 
 
 @pytest.mark.parametrize("path", ["written_out", "kernels"])
