@@ -51,11 +51,23 @@ configuration; any other name is refused.
   the experts ``experts_held = [first, count]`` of width
   ``moe_intermediate_size`` held here, and a shared expert of
   ``shared_expert_intermediate_size``, none at 0). ``mlp_activation``
-  ``silu_gated`` (the default: three matrices an expert) or ``relu2``
-  (two, ``relu(x W1)^2 W2``, the shared expert too). With
+  (:data:`fedml_tpu.ops.moe.ACTIVATIONS`, the shared expert's too):
+  ``silu_gated`` (the default: three matrices an expert, ``(silu(x W1)
+  * (x W3)) W2``, as the dense layer is), ``relu_gated`` (three,
+  ``(relu(x W1) * (x W3)) W2``) or ``relu2`` (two, ``relu(x W1)^2
+  W2``); the last two in a stack with no dense layer. With
   ``moe_latent_size`` the experts work at that width between two
   projections all of them share; the router and the shared expert read
-  the full hidden width.
+  the full hidden width. ``router_input`` says what a sparse layer's
+  router reads: ``feed_forward_input`` (the default: the layer's own
+  ``RMSNorm(x + attention)``, which its experts read) or
+  ``attention_input`` (the attention's normed input, so the router
+  stands BEFORE attention: the experts are chosen and weighted from
+  ``RMSNorm_in(x)`` and fed ``RMSNorm_post(x + attention)``, a
+  gradient reaches ``x`` by both, and the router's logits, top-k and
+  weights run under the scope ``fedml.model.moe.router``, apart from
+  ``fedml.model.moe.route``; every sparse layer of such a stack has an
+  attention mixer).
 
 **A chip's share of a layer** (each ``[first, count]`` of the published
 count beside it; the whole where absent): ``experts_held`` of
@@ -115,7 +127,9 @@ from fedml_tpu.ops.attention import (
     ATTN_COUNTERS, KEPT as ATTENTION_KEPT, KEPT_SELECTION, causal_attention,
     index_scores, select_top_k,
 )
-from fedml_tpu.ops.moe import MOE_COUNTERS, SCORINGS, moe_layer
+from fedml_tpu.ops.moe import (
+    ACTIVATIONS, MOE_COUNTERS, SCORINGS, SILU_GATED, leading, moe_layer,
+)
 from fedml_tpu.ops.ssm import KEPT as SCAN_KEPT, ssd_chunked
 
 NONE = "none"  # a layer without this half; a rope record's ``rope_type``
@@ -126,7 +140,10 @@ MIXERS = (FULL, SLIDING, SELECTED, STATE_SPACE, NONE)
 DENSE, SPARSE = "dense", "sparse"
 FEED_FORWARDS = (DENSE, SPARSE, NONE)
 SIGMOID = "sigmoid"  # the router's scoring where the configuration names none
-GATED, RELU2 = "silu_gated", "relu2"  # ``mlp_activation``
+# ``router_input``: what a sparse layer's router reads
+FEED_FORWARD_INPUT, ATTENTION_INPUT = "feed_forward_input", "attention_input"
+ROUTER_INPUTS = (FEED_FORWARD_INPUT, ATTENTION_INPUT)
+ATTENTIONS = (FULL, SLIDING, SELECTED)
 
 #: what a rematerialised layer keeps (``checkpoint_name``s)
 KEPT = ATTENTION_KEPT + SCAN_KEPT
@@ -261,10 +278,11 @@ class DecoderLayer(nn.Module):
         # a stack with sparse-attention layers counts in every layer
         attended = (jnp.float32(0),) * len(
             attention_counters(c["layer_types"]))
+        read = None  # what the attention read: its normed input
         if kind == STATE_SPACE:
             x = x + self.state_space(x)
         elif kind != NONE:
-            x, attended = self.attention(x, kind, attended)
+            x, attended, read = self.attention(x, kind, attended)
         if c["mlp_layer_types"][l] == DENSE:
             with jax.named_scope("fedml.model.mlp"):
                 h = RMSNorm(c["rms_norm_eps"], name="mlp_norm")(x)
@@ -278,7 +296,7 @@ class DecoderLayer(nn.Module):
         with jax.named_scope("fedml.model.moe"):
             h = RMSNorm(c["rms_norm_eps"], name="mlp_norm")(x)
             first, count = c["experts_held"]
-            gated = c["mlp_activation"] == GATED
+            activation = c["mlp_activation"]
             latent = c["moe_latent_size"]
             w, f = latent or d, c["moe_intermediate_size"]
             fs = _held(c, "shared_expert_columns_held",
@@ -287,18 +305,18 @@ class DecoderLayer(nn.Module):
                 1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
                 batch_axis=(0,))
             flat = nn.initializers.lecun_normal()
-            leading = ("w1", "w3") if gated else ("w1",)
+            into_middle = leading(activation)
             params = {
                 "router": self.param(
                     "router", flat, (d, c["num_experts"])),
                 **{m: self.param("experts_" + m, into, (count, w, f))
-                   for m in leading},
+                   for m in into_middle},
                 "w2": self.param("experts_w2", into, (count, f, w)),
             }
             if fs:
                 params["shared"] = (
                     *(self.param("shared_" + m, flat, (d, fs))
-                      for m in leading),
+                      for m in into_middle),
                     self.param("shared_w2", flat, (fs, d)),
                 )
             if latent:
@@ -309,7 +327,9 @@ class DecoderLayer(nn.Module):
             y, counters = moe_layer(
                 params, h.reshape(b * t, d), (first, count),
                 c["num_experts_per_tok"], c["routed_scaling_factor"],
-                scoring=c["router_scoring"])
+                scoring=c["router_scoring"], activation=activation,
+                router_input=read.reshape(b * t, d) if (
+                    c["router_input"] == ATTENTION_INPUT) else None)
             return x + y.reshape(b, t, d), _counted(counters, attended)
 
     @nn.nowrap
@@ -321,7 +341,7 @@ class DecoderLayer(nn.Module):
     def attention(self, x, kind: str, attended):
         """``x + attention(norm(x))`` of kind ``kind`` -> (``x``, the
         layer's :data:`ATTN_COUNTERS` counts or ``attended`` as it
-        came)."""
+        came, ``norm(x)``: what a router before attention reads)."""
         c = dict(self.cfg)
         b, t, d = x.shape
         hd = c["head_dim"]
@@ -350,7 +370,7 @@ class DecoderLayer(nn.Module):
                 gate = jax.nn.sigmoid(self.dense(heads, "g_proj")(h))
                 a = a * gate[..., None]
             a = self.dense(d, "o_proj")(a.reshape(b, t, heads * hd))
-            return x + a, attended
+            return x + a, attended, h
 
     @nn.nowrap
     def state_space(self, x):
@@ -523,7 +543,8 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
     c = {
         "sliding_window": None, "gating": False, "qk_norm": False,
         "rms_norm_eps": 1e-6, "router_scoring": SIGMOID,
-        "mlp_activation": GATED, "moe_latent_size": 0,
+        "router_input": FEED_FORWARD_INPUT,
+        "mlp_activation": SILU_GATED, "moe_latent_size": 0,
         "moe_intermediate_size": 0, "shared_expert_intermediate_size": 0,
         "num_experts": 0, "num_experts_per_tok": 0,
         "routed_scaling_factor": 1.0, "experts_held": (0, 0),
@@ -543,11 +564,26 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
                 f"{', '.join(FEED_FORWARDS)}")
         if mixer == forward == NONE:
             raise ValueError(f"decoder: layer {l} is nothing at all")
-    if c["mlp_activation"] not in (GATED, RELU2) or (
-            c["mlp_activation"] == RELU2 and DENSE in c["mlp_layer_types"]):
+    if c["mlp_activation"] not in ACTIVATIONS or (
+            c["mlp_activation"] != SILU_GATED
+            and DENSE in c["mlp_layer_types"]):
+        others = sorted(set(ACTIVATIONS) - {SILU_GATED})
         raise ValueError(
             f"decoder: mlp_activation {c['mlp_activation']!r} is not "
-            f"{GATED!r} or, in a stack with no dense layer, {RELU2!r}")
+            f"{SILU_GATED!r} or, in a stack with no dense layer, one of "
+            f"{', '.join(map(repr, others))}")
+    if c["router_input"] not in ROUTER_INPUTS:
+        raise ValueError(
+            f"decoder: unknown router_input {c['router_input']!r}; known: "
+            f"{', '.join(ROUTER_INPUTS)}")
+    before = [l for l, (mixer, forward) in enumerate(
+        zip(c["layer_types"], c["mlp_layer_types"]))
+        if forward == SPARSE and mixer not in ATTENTIONS]
+    if c["router_input"] == ATTENTION_INPUT and before:
+        raise ValueError(
+            f"decoder: router_input {ATTENTION_INPUT!r} needs an attention "
+            f"mixer in every sparse layer; layer {before[0]} has "
+            f"{c['layer_types'][before[0]]!r}")
     first, count = c["experts_held"]
     if SPARSE in c["mlp_layer_types"] and not (
             0 <= first and count >= 1
@@ -568,7 +604,7 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
             f"known: {sorted(SCORINGS)}")
     _check_attention_share(c, [
         h for h, kind in zip(c["heads_per_layer"], c["layer_types"])
-        if kind in (FULL, SLIDING, SELECTED)])
+        if kind in ATTENTIONS])
     if STATE_SPACE in c["layer_types"]:
         _check_state_space(c.get("state_space") or {})
     if SELECTED in c["layer_types"]:

@@ -16,6 +16,7 @@ carries a fixed ``capacity`` of token slots; overflow tokens are dropped
 from __future__ import annotations
 
 import functools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -161,21 +162,40 @@ SUBLANES = 8
 
 ROUTE, EXPERTS = "fedml.model.moe.route", "fedml.model.moe.experts"
 LATENT = "fedml.model.moe.latent"
+#: a router that reads another tensor than the rows it routes (the
+#: attention's input: it runs before attention) is timed apart: logits,
+#: top-k and weights, not the ordering, which stays under :data:`ROUTE`
+ROUTER = "fedml.model.moe.router"
+
+SILU_GATED, RELU_GATED, RELU2 = "silu_gated", "relu_gated", "relu2"
+#: a feed-forward's activation by name -> (matrices that lead in, what
+#: lies between them and the last matrix)
+ACTIVATIONS = {
+    SILU_GATED: (2, lambda a, b: jax.nn.silu(a) * b),
+    RELU_GATED: (2, lambda a, b: jax.nn.relu(a) * b),
+    RELU2: (1, lambda a: jnp.square(jax.nn.relu(a))),
+}
 
 
-def _middle(*into):
-    """What lies between a feed-forward's matrices, by how many lead
-    in: ``silu(a) * b`` (gated, two) or ``relu(a)^2`` (one)."""
-    if len(into) == 2:
-        return jax.nn.silu(into[0]) * into[1]
-    return jnp.square(jax.nn.relu(into[0]))
+def leading(activation: str) -> tuple[str, ...]:
+    """The names of the matrices that lead into ``activation``: ``w1``
+    and, for a gated one, ``w3`` (``w2`` leads out)."""
+    return ("w1", "w3")[:ACTIVATIONS[activation][0]]
 
 
-def ffn(x, *w):
-    """The feed-forward every expert and the shared expert is, by its
-    matrices: three, ``(silu(x W1) * (x W3)) W2`` (gated, as the dense
-    layer is), or two, ``relu(x W1)^2 W2``."""
-    return _middle(*(x @ m for m in w[:-1])) @ w[-1]
+def _middle(activation: str, *into):
+    """What lies between a feed-forward's matrices: ``silu(a) * b``,
+    ``relu(a) * b`` (both gated: two matrices lead in) or ``relu(a)^2``
+    (one)."""
+    return ACTIVATIONS[activation][1](*into)
+
+
+def ffn(activation: str, x, *w):
+    """The feed-forward every expert and the shared expert is:
+    ``(act(x W1) * (x W3)) W2`` with ``act`` ``silu`` (``silu_gated``,
+    as the dense layer is) or ``relu`` (``relu_gated``), three matrices;
+    or ``relu(x W1)^2 W2`` (``relu2``), two."""
+    return _middle(activation, *(x @ m for m in w[:-1])) @ w[-1]
 
 
 def _mapped(fn):
@@ -355,10 +375,12 @@ def _way_cotangents(d_weight, back, n_held, n: int, k: int):
     return _read_back(d_weight, back, n_held).reshape(n, k)
 
 
-def _held_rows_forward(r, h, w, top_w, order, back, sizes, n_held):
+def _held_rows_forward(r, activation, h, w, top_w, order, back, sizes,
+                       n_held):
     """The held experts' sum over a buffer of ``r`` rows (at least
     ``n_held``): row ``i`` is token ``order[i] // k``'s. ``w``: the
-    experts' matrices, ``(w1, w3, w2)`` or ``(w1, w2)`` (:func:`ffn`).
+    experts' matrices, ``(w1, w3, w2)`` or ``(w1, w2)``, as
+    ``activation`` has them (:func:`ffn`).
     -> ``(y [N, D], (rows, into, out))``, what the backward pass reads
     again (``into``: the rows through each matrix that leads in), all
     of ``r`` rows. Rows from ``n_held`` on are no held expert's: the
@@ -368,7 +390,7 @@ def _held_rows_forward(r, h, w, top_w, order, back, sizes, n_held):
         rows = h[order[:r] // k]
     with jax.named_scope(EXPERTS):
         into = tuple(grouped_product(rows, m, sizes) for m in w[:-1])
-        out = grouped_product(_middle(*into), w[-1], sizes)
+        out = grouped_product(_middle(activation, *into), w[-1], sizes)
     with jax.named_scope(ROUTE):
         out = jnp.where((jnp.arange(r) < n_held)[:, None], out, 0)
         theirs, axes = _by_token(out, back, n_held, k)
@@ -377,7 +399,8 @@ def _held_rows_forward(r, h, w, top_w, order, back, sizes, n_held):
     return y, (rows, into, out)
 
 
-def _held_rows_backward(r, kept, w, top_w, order, back, sizes, n_held, g):
+def _held_rows_backward(r, activation, kept, w, top_w, order, back, sizes,
+                        n_held, g):
     """:func:`_held_rows_forward`'s cotangents of ``h``, ``w`` and
     ``top_w`` for ``g`` ``[N, D]``, from what it kept. The rows'
     cotangent is unwritten from ``n_held`` on as well, and masked
@@ -393,7 +416,8 @@ def _held_rows_backward(r, kept, w, top_w, order, back, sizes, n_held, g):
         d_top_w = _way_cotangents(d_weight, back, n_held, n, k).astype(
             top_w.dtype)
     with jax.named_scope(EXPERTS):
-        up, middle = jax.vjp(_middle, *into)
+        up, middle = jax.vjp(
+            functools.partial(_middle, activation), *into)
         d_up, d_out_w = _transposed(up, w[-1], sizes, d_out)
         d_rows, d_w = zip(*(
             _transposed(rows, m, sizes, d_into)
@@ -406,7 +430,7 @@ def _held_rows_backward(r, kept, w, top_w, order, back, sizes, n_held, g):
     return d_h, (*d_w, d_out_w), d_top_w
 
 
-def _held_experts_run(c, keep, *args):
+def _held_experts_run(c, activation, keep, *args):
     """-> ``(y, taken, kept)``: over ``c`` rows where the held
     assignments fit them (``taken`` = ``N x top_k``, ``kept`` = what
     the backward pass reads again if ``keep``), else over all ``N x
@@ -417,7 +441,7 @@ def _held_experts_run(c, keep, *args):
 
     def over(r):
         def run(*args):
-            y, kept = _held_rows_forward(r, *args)
+            y, kept = _held_rows_forward(r, activation, *args)
             if not keep:
                 kept = ()
             elif r != c:
@@ -431,35 +455,35 @@ def _held_experts_run(c, keep, *args):
     return _one_path_a_batch(over(c), over(nk))(n_held <= c, *args)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_experts(c, h, w, top_w, order, back, sizes, n_held):
-    """What the held experts (matrices ``w``, :func:`ffn`'s) add to the
-    tokens: ``(y [N, D], N x top_k if the rows went through the bounded
-    buffer of ``c`` rows, else 0)``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(c, activation, h, w, top_w, order, back, sizes, n_held):
+    """What the held experts (matrices ``w`` around ``activation``,
+    :func:`ffn`'s) add to the tokens: ``(y [N, D], N x top_k if the
+    rows went through the bounded buffer of ``c`` rows, else 0)``.
 
     The branch sits in the rules, not under them: differentiated by
     JAX, a ``cond`` returns the residuals of BOTH sides, zero-filled
     for the side not taken — the worst-case-sized arrays the bounded
     buffer exists to avoid."""
     return _held_experts_run(
-        c, False, h, w, top_w, order, back, sizes, n_held)[:2]
+        c, activation, False, h, w, top_w, order, back, sizes, n_held)[:2]
 
 
-def _held_experts_fwd(c, *args):
-    y, taken, kept = _held_experts_run(c, True, *args)
+def _held_experts_fwd(c, activation, *args):
+    y, taken, kept = _held_experts_run(c, activation, True, *args)
     return (y, taken), (kept, taken, args)
 
 
-def _held_experts_bwd(c, res, cotangents):
+def _held_experts_bwd(c, activation, res, cotangents):
     kept, taken, args = res
     nk = args[2].size  # top_w
 
     def bounded(kept, g, h, *rest):
-        return _held_rows_backward(c, kept, *rest, g)
+        return _held_rows_backward(c, activation, kept, *rest, g)
 
     def worst(kept, g, h, *rest):
-        _, kept = _held_rows_forward(nk, h, *rest)
-        return _held_rows_backward(nk, kept, *rest, g)
+        _, kept = _held_rows_forward(nk, activation, h, *rest)
+        return _held_rows_backward(nk, activation, kept, *rest, g)
 
     if c == nk:
         grads = bounded(kept, cotangents[0], *args)
@@ -475,25 +499,84 @@ def _held_experts_bwd(c, res, cotangents):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+class Routing(NamedTuple):
+    """What a router decided for ``N`` tokens (:func:`route`):
+    ``top_e``, ``top_w`` ``[N, k]`` (:func:`route_top_k`'s); ``order``
+    ``[N k]``, the assignments sorted by held expert, absent experts'
+    last; ``back``, where a token's slots find their rows in that order
+    (:func:`_by_token`); ``sizes`` ``[count]`` rows a held expert and
+    ``n_held`` their sum."""
+    top_e: jax.Array
+    top_w: jax.Array
+    order: jax.Array
+    back: Any
+    sizes: jax.Array
+    n_held: jax.Array
+
+
+def route(router, x, held: tuple[int, int], top_k: int, scale: float,
+          scoring: str = "sigmoid", scope: str = ROUTE) -> Routing:
+    """From what the router reads to the routing: ``router`` ``[D, E]``
+    over ALL ``E`` experts, ``x`` ``[N, D]`` — the rows the experts
+    will be given, or any other tensor of the same tokens (a router
+    that reads its layer's attention input) — ``held = (first, count)``
+    the experts held here. Logits (float32), top-k and weights run
+    under ``scope``; ordering the assignments by held expert under
+    :data:`ROUTE`. A token's slots are its ways where ``top_k <= count``
+    (``back``: the order's own ``argsort``) and the held experts where
+    those are the fewer (``back``: :func:`_slot_rows`' pair; neither
+    the inverse nor a count over the ``N x top_k`` ids is computed)."""
+    first, count = held
+    n = x.shape[0]
+    with jax.named_scope(scope):
+        scores = jnp.dot(x, router, preferred_element_type=jnp.float32)
+        top_e, top_w = route_top_k(scores, top_k, scale, scoring)
+    with jax.named_scope(ROUTE):
+        local = top_e.reshape(-1) - first
+        # an assignment to an absent expert sorts past every held group
+        group = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(group, stable=True)
+        if top_k <= count:  # a token's slots are its ways
+            back = jnp.argsort(order)
+            sizes = jnp.bincount(group, length=count + 1)[:count].astype(
+                jnp.int32)
+        else:  # ... or the held experts, where those are the fewer
+            back, sizes = _slot_rows(
+                local.reshape(n, top_k), count, n * top_k)
+        n_held = jnp.sum(sizes)
+    return Routing(top_e, top_w, order, back, sizes, n_held)
+
+
 def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
-              scoring: str = "sigmoid"):
+              scoring: str = "sigmoid", activation: str = SILU_GATED,
+              router_input=None):
     """This chip's part of one sparse-expert layer, dropless.
 
     ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts; the
     matrices of the experts held here — ids ``[first, first + count)``,
-    ``held = (first, count)`` — gated, ``w1`` / ``w3`` ``[count, W, F]``
-    and ``w2`` ``[count, F, W]``, or, with no ``w3``, the two of
-    ``relu(x W1)^2 W2`` (:func:`ffn`); ``shared`` (the shared expert's
-    matrices, three or two, on the full ``D``) or no such key; and
-    ``latent`` (``[D, L]``, ``[L, D]``) or no such key. ``h``: ``[N, D]``
-    tokens. The experts' width ``W`` is ``D``, or ``L`` where the layer
-    is latent: its experts then read ``h`` through the first latent
-    projection and their weighted sum goes back through the second (no
-    activation on either; both shared by all experts), while the router
-    and the shared expert read ``h`` itself.
+    ``held = (first, count)`` — as ``activation`` has them
+    (:data:`ACTIVATIONS`, :func:`ffn`): gated (``silu_gated``,
+    ``relu_gated``), ``w1`` / ``w3`` ``[count, W, F]`` and ``w2``
+    ``[count, F, W]``, or the two of ``relu(x W1)^2 W2`` (``relu2``: no
+    ``w3``); ``shared`` (the shared expert's matrices, as many, on the
+    full ``D``) or no such key; and ``latent`` (``[D, L]``, ``[L, D]``)
+    or no such key. ``h``: ``[N, D]`` tokens, the rows the experts
+    read. ``router_input``: ``[N, D]``, what the router reads of the
+    same tokens where that is not ``h`` (a layer whose router reads its
+    attention's input): the choice and the weights then come from it
+    and the rows from ``h``, so a gradient reaches the layer's input by
+    both (through the weights into ``router_input``, through the rows
+    into ``h``), and the router's logits, top-k and weights are timed
+    under :data:`ROUTER`, not :data:`ROUTE`. The experts' width ``W``
+    is ``D``, or ``L`` where the layer is latent: its experts then read
+    ``h`` through the first latent projection and their weighted sum
+    goes back through the second (no activation on either; both shared
+    by all experts), while the router and the shared expert read the
+    full width.
 
-    Every token is routed over all ``E`` experts (:func:`route_top_k`,
-    ``scoring`` its kind of probability); the assignments whose
+    Every token is routed over all ``E`` experts (:func:`route`,
+    :func:`route_top_k`, ``scoring`` its kind of probability); the
+    assignments whose
     expert is held are ordered by expert and go through grouped matrix
     products (:func:`grouped_product`: on the TPU a kernel whose work
     follows the rows present, never ``E x N``), are weighted —
@@ -522,40 +605,28 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     (``N x top_k``), rows of the fullest held expert, assignments made
     in a call that went through the bounded buffer, rows the combine
     read (``N`` times a token's slots)."""
-    first, count = held
+    count = held[1]
     n, _ = h.shape
-    with jax.named_scope(ROUTE):
-        scores = jnp.dot(h, params["router"],
-                         preferred_element_type=jnp.float32)
-        top_e, top_w = route_top_k(scores, top_k, scale, scoring)
-        local = top_e.reshape(-1) - first
-        # an assignment to an absent expert sorts past every held group
-        group = jnp.where((local >= 0) & (local < count), local, count)
-        order = jnp.argsort(group, stable=True)
-        if top_k <= count:  # a token's slots are its ways
-            back = jnp.argsort(order)
-            sizes = jnp.bincount(group, length=count + 1)[:count].astype(
-                jnp.int32)
-        else:  # ... or the held experts, where those are the fewer
-            back, sizes = _slot_rows(
-                local.reshape(n, top_k), count, n * top_k)
-        n_held = jnp.sum(sizes)
+    apart = router_input is not None  # a router with an input of its own
+    routing = route(params["router"], router_input if apart else h, held,
+                    top_k, scale, scoring, scope=ROUTER if apart else ROUTE)
     inside = h
     if "latent" in params:
         with jax.named_scope(LATENT):
             inside = h @ params["latent"][0]
     y, bounded = _held_experts(
-        row_buffer(n, top_k, count, scores.shape[1]), inside,
-        tuple(params[m] for m in ("w1", "w3", "w2") if m in params),
-        top_w, order, back, sizes, n_held)
+        row_buffer(n, top_k, count, params["router"].shape[1]), activation,
+        inside, tuple(params[m] for m in (*leading(activation), "w2")),
+        routing.top_w, routing.order, routing.back, routing.sizes,
+        routing.n_held)
     if "latent" in params:
         with jax.named_scope(LATENT):
             y = y @ params["latent"][1]
     if "shared" in params:
         with jax.named_scope("fedml.model.mlp"):
-            y = y + ffn(h, *params["shared"])
+            y = y + ffn(activation, h, *params["shared"])
     counters = jnp.stack([
-        n_held.astype(jnp.float32), jnp.float32(n * top_k),
-        jnp.max(sizes).astype(jnp.float32), bounded,
+        routing.n_held.astype(jnp.float32), jnp.float32(n * top_k),
+        jnp.max(routing.sizes).astype(jnp.float32), bounded,
         jnp.float32(n * min(top_k, count))])
     return y.astype(h.dtype), counters

@@ -126,8 +126,11 @@ def _uncut_layer(p, every, h, top_k, scale, first=0, scoring="sigmoid"):
     for e in range(every["w1"].shape[0]):
         share = jnp.where(top_e == first + e, w, 0.0).sum(-1)
         y += share[:, None] * MOE.ffn(
-            h, every["w1"][e], every["w3"][e], every["w2"][e])
-    return y + MOE.ffn(h, *p["shared"]) if "shared" in p else y
+            MOE.SILU_GATED, h, every["w1"][e], every["w3"][e],
+            every["w2"][e])
+    if "shared" in p:
+        y = y + MOE.ffn(MOE.SILU_GATED, h, *p["shared"])
+    return y
 
 
 @pytest.mark.parametrize("scoring, scale, shared", [

@@ -230,10 +230,18 @@ def _moe_params(key, gated, latent, d=64, experts=16, f=32):
     return p
 
 
-def _written_out(p, h, first, count, top_k, scale):
+def _activation(p):
+    """What :func:`_moe_params` drew ``p`` for, where no test says."""
+    return MOE.SILU_GATED if "w3" in p else MOE.RELU2
+
+
+def _written_out(p, h, first, count, top_k, scale, activation=None,
+                 router_input=None):
     """The share written out: every held expert on every token, a mask
     for the chosen ones."""
-    prob = jax.nn.sigmoid(h @ p["router"])
+    activation = activation or _activation(p)
+    read = h if router_input is None else router_input
+    prob = jax.nn.sigmoid(read @ p["router"])
     top_p, top_e = jax.lax.top_k(prob, top_k)
     w = scale * top_p / top_p.sum(-1, keepdims=True)
     u = h @ p["latent"][0] if "latent" in p else h
@@ -241,9 +249,10 @@ def _written_out(p, h, first, count, top_k, scale):
     for e in range(count):
         share = jnp.where(top_e == first + e, w, 0.0).sum(-1)
         y += share[:, None] * MOE.ffn(
-            u, *(p[m][e] for m in ("w1", "w3", "w2") if m in p))
+            activation, u,
+            *(p[m][e] for m in ("w1", "w3", "w2") if m in p))
     y = y @ p["latent"][1] if "latent" in p else y
-    return y + MOE.ffn(h, *p["shared"])
+    return y + MOE.ffn(activation, h, *p["shared"])
 
 
 # (experts, first held, held, ways a token): what decides the combine's
@@ -319,7 +328,8 @@ def test_moe_layer_against_the_written_out_share(case):
             jax.random.fold_in(key, 3), h.shape)])
 
     def share(p, h):
-        y, counters = MOE.moe_layer(p, h, (first, count), top_k, 5.0)
+        y, counters = MOE.moe_layer(p, h, (first, count), top_k, 5.0,
+                                    activation=_activation(p))
         return jnp.sum(y * weigh), (y, counters)
 
     def plain(p, h):
@@ -415,7 +425,8 @@ def test_one_sort_a_layer_pass_and_no_row_a_way_where_slots_are_fewer(shape):
          "w2": p["w2"][first:first + count]}
     h = jax.random.normal(jax.random.fold_in(key, 1), (TOKENS, 64))
     layer = jax.checkpoint(
-        lambda p, h: MOE.moe_layer(p, h, (first, count), top_k, 5.0)[0])
+        lambda p, h: MOE.moe_layer(p, h, (first, count), top_k, 5.0,
+                                   activation=MOE.RELU2)[0])
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p, h: jnp.sum(layer(p, h)), argnums=(0, 1)))(p, h).jaxpr
     slots = min(top_k, count)
