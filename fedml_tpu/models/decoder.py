@@ -83,15 +83,22 @@ output is left out, and nothing stands in for their exchange.
 
 Untied embedding and head over ``vocab_size`` rows. Each layer is
 recomputed in the backward pass (``nn.remat``) but for what
-:data:`KEPT` names: beside a layer's input, its
-attention kernel's output and row log-sum-exp, in a
-sparse-attention layer the selection, and in a state-space layer the
-states entering each chunk and the scan's result are kept, so the forward
-attention kernel, the index, the top-k and, of the chunked scan, the
-mix, the chunks' own states and the recurrence between them run once a
-training step and
-the projections, norms, rotary, gate, convolution and the whole
-feed-forward twice.
+:data:`KEPT` names. Beside a layer's input it keeps its attention
+kernel's output and row log-sum-exp; in a sparse-attention layer the
+selection; in a state-space layer the states entering each chunk and
+the scan's result; and of a sparse feed-forward the routing (the
+router's logits, the chosen experts and their weights, the order of
+the assignments, where a token's slots find their rows, the rows a
+held expert) and what the held experts' backward rule reads (the row
+buffer's rows, those rows through each matrix that leads in, and their
+result; :data:`fedml_tpu.ops.moe.KEPT`). So these run ONCE a training
+step: the forward attention kernel, the index and its top-k; of the
+chunked scan the mix, the chunks' own states and the recurrence between
+them; the router's product, its scoring's ranking (``lax.top_k``), the
+sorts and the count, the row gather, the grouped products forward and
+the combine. And these twice: the norms, the attention's projections,
+rotary, gate and output projection, the convolution, the dense
+feed-forward, the shared expert and the latent projections.
 What the layers count (:func:`counter_names`: the sparse layers'
 :data:`fedml_tpu.ops.moe.MOE_COUNTERS` — the assignments that landed
 on held experts, those made, the fullest held expert's, those of calls
@@ -128,7 +135,8 @@ from fedml_tpu.ops.attention import (
     index_scores, select_top_k,
 )
 from fedml_tpu.ops.moe import (
-    ACTIVATIONS, MOE_COUNTERS, SCORINGS, SILU_GATED, leading, moe_layer,
+    ACTIVATIONS, KEPT as MOE_KEPT, MOE_COUNTERS, SCORINGS, SILU_GATED,
+    leading, moe_layer,
 )
 from fedml_tpu.ops.ssm import KEPT as SCAN_KEPT, ssd_chunked
 
@@ -146,7 +154,7 @@ ROUTER_INPUTS = (FEED_FORWARD_INPUT, ATTENTION_INPUT)
 ATTENTIONS = (FULL, SLIDING, SELECTED)
 
 #: what a rematerialised layer keeps (``checkpoint_name``s)
-KEPT = ATTENTION_KEPT + SCAN_KEPT
+KEPT = ATTENTION_KEPT + SCAN_KEPT + MOE_KEPT
 
 
 def attention_counters(layer_types) -> tuple[str, ...]:

@@ -20,6 +20,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 def init_moe_params(key, num_experts: int, d_model: int, d_hidden: int):
@@ -167,6 +168,24 @@ LATENT = "fedml.model.moe.latent"
 #: top-k and weights, not the ordering, which stays under :data:`ROUTE`
 ROUTER = "fedml.model.moe.router"
 
+#: ``checkpoint_name``s of what the sparse layer's backward rules read,
+#: for a rematerialised layer to keep (``models/decoder.py:KEPT``), so
+#: that its second run computes none of it again: the routing (the
+#: router's logits in float32, the chosen probabilities and
+#: :class:`Routing`: a few MB a layer) and the held rows (``(rows, into,
+#: out)`` of the row buffer, which buffer it was, and the combine's
+#: result, which a latent layer's projection back reads). ALL of
+#: ``(rows, into, out)``, for every model: with them kept the four
+#: decoder cells hold 13.9 to 15.1 GB at their peak on a v5e (13.1 to
+#: 14.1 without; 0.2 to 1.3 GB a cell, ``PERF.md`` section 6, PR 41) of
+#: the 15.4 a cell may. Were that to stop fitting, ``rows`` go first
+#: (gathered again from the kept ``order``: 14.57 for 15.07 GB and
+#: 19 ms more of a 686 ms round in the tightest cell), then ``out`` (one
+#: product and the activation again); the routing and ``into`` are what
+#: remove the sorts and two of the three forward products.
+KEPT_ROUTING, KEPT_ROWS = "fedml_moe_routing", "fedml_moe_rows"
+KEPT = (KEPT_ROUTING, KEPT_ROWS)
+
 SILU_GATED, RELU_GATED, RELU2 = "silu_gated", "relu_gated", "relu2"
 #: a feed-forward's activation by name -> (matrices that lead in, what
 #: lies between them and the last matrix)
@@ -216,8 +235,13 @@ def _mapped(fn):
 
 
 _ragged = _mapped(lambda x, w, sizes: jax.lax.ragged_dot(x, w, sizes))
-_ragged_transposed = _mapped(lambda x, w, sizes, g: jax.vjp(
-    lambda x, w: jax.lax.ragged_dot(x, w, sizes), x, w)[1](g))
+# the product is linear in the rows and in the matrices: each transpose
+# alone, so that no forward product is traced beside them
+_ragged_transposed = _mapped(lambda x, w, sizes, g: (
+    *jax.linear_transpose(
+        lambda x: jax.lax.ragged_dot(x, w, sizes), x)(g),
+    *jax.linear_transpose(
+        lambda w: jax.lax.ragged_dot(x, w, sizes), w)(g)))
 
 
 @jax.custom_vjp
@@ -237,26 +261,57 @@ grouped_product.defvjp(
     lambda res, g: (*_ragged_transposed(*res, g), None))
 
 
-def _transposed(x, w, sizes, g):
-    """:func:`grouped_product`'s cotangents of ``x`` and ``w`` (its
-    forward product is unused and removed by the compiler)."""
-    return jax.vjp(lambda x, w: grouped_product(x, w, sizes), x, w)[1](g)
-
-
 #: how a router turns its logits into the probabilities it ranks
 SCORINGS = {"sigmoid": jax.nn.sigmoid,
             "softmax": lambda scores: jax.nn.softmax(scores, -1)}
 
 
+def _weights(top_p, scale: float):
+    """The chosen probabilities renormalised over themselves, times
+    ``scale``."""
+    return scale * top_p / jnp.sum(top_p, -1, keepdims=True)
+
+
+def _ranked(scores, top_k: int, scoring: str):
+    """The ``top_k`` largest probabilities a token and their experts."""
+    return jax.lax.top_k(SCORINGS[scoring](scores), top_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
 def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid"):
     """Router logits ``[N, E]`` (float32) over ALL experts -> the
     ``top_k`` a token of largest probability — ``scoring`` ``sigmoid``
     (each expert's own) or ``softmax`` (over all ``E``, in float32) —
     their weights renormalised over those ``top_k`` and times
-    ``scale``: ``(expert ids [N, k], weights [N, k])``."""
-    p = SCORINGS[scoring](scores)
-    top_p, top_e = jax.lax.top_k(p, top_k)
-    return top_e, scale * top_p / jnp.sum(top_p, -1, keepdims=True)
+    ``scale``: ``(expert ids [N, k], weights [N, k])``.
+
+    The choice is a set, so the weights' cotangent reaches the
+    probabilities as ``lax.top_k``'s own rule sends it, scattered to
+    the ids the forward pass chose; the rule reads the logits, the
+    chosen probabilities and the ids alone, named
+    :data:`KEPT_ROUTING`, so a rematerialised layer ranks once (the
+    scoring, elementwise, is run again for its own rule)."""
+    top_p, top_e = _ranked(scores, top_k, scoring)
+    return top_e, _weights(top_p, scale)
+
+
+def _route_top_k_fwd(scores, top_k, scale, scoring):
+    scores, (top_p, top_e) = checkpoint_name(
+        (scores, _ranked(scores, top_k, scoring)), KEPT_ROUTING)
+    return (top_e, _weights(top_p, scale)), (scores, top_p, top_e)
+
+
+def _route_top_k_bwd(top_k, scale, scoring, res, cotangents):
+    scores, top_p, top_e = res
+    d_top_p, = jax.vjp(lambda top_p: _weights(top_p, scale), top_p)[1](
+        cotangents[1])
+    p, scored = jax.vjp(SCORINGS[scoring], scores)
+    d_p, = jax.linear_transpose(
+        lambda p: jnp.take_along_axis(p, top_e, -1), p)(d_top_p)
+    return scored(d_p)
+
+
+route_top_k.defvjp(_route_top_k_fwd, _route_top_k_bwd)
 
 
 def row_buffer(n: int, top_k: int, count: int, experts: int) -> int:
@@ -418,9 +473,9 @@ def _held_rows_backward(r, activation, kept, w, top_w, order, back, sizes,
     with jax.named_scope(EXPERTS):
         up, middle = jax.vjp(
             functools.partial(_middle, activation), *into)
-        d_up, d_out_w = _transposed(up, w[-1], sizes, d_out)
+        d_up, d_out_w = _ragged_transposed(up, w[-1], sizes, d_out)
         d_rows, d_w = zip(*(
-            _transposed(rows, m, sizes, d_into)
+            _ragged_transposed(rows, m, sizes, d_into)
             for m, d_into in zip(w[:-1], middle(d_up))))
     with jax.named_scope(ROUTE):
         d_rows = jnp.where((jnp.arange(r) < n_held)[:, None],
@@ -470,7 +525,10 @@ def _held_experts(c, activation, h, w, top_w, order, back, sizes, n_held):
 
 
 def _held_experts_fwd(c, activation, *args):
-    y, taken, kept = _held_experts_run(c, activation, True, *args)
+    # named after the branch: one name for whichever side ran (``y``:
+    # a latent layer's projection back reads it for its own gradient)
+    y, taken, kept = checkpoint_name(
+        _held_experts_run(c, activation, True, *args), KEPT_ROWS)
     return (y, taken), (kept, taken, args)
 
 
@@ -544,7 +602,8 @@ def route(router, x, held: tuple[int, int], top_k: int, scale: float,
             back, sizes = _slot_rows(
                 local.reshape(n, top_k), count, n * top_k)
         n_held = jnp.sum(sizes)
-    return Routing(top_e, top_w, order, back, sizes, n_held)
+    return Routing(top_e, *checkpoint_name(
+        (top_w, order, back, sizes, n_held), KEPT_ROUTING))
 
 
 def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,

@@ -149,13 +149,18 @@ def test_sparse_attention_compiles_for_v5e(one_chip, monkeypatch):
 def test_rematerialised_sparse_layer_compiles_for_v5e(one_chip, monkeypatch):
     """A training step (bfloat16) of ONE decoder layer at
     Keye-VL-2.0-30B-A3B's widths — learned sparse attention over a
-    sequence of 8,192, a dense feed-forward in the experts' place —
-    under ``DecoderLM``'s remat: the layer keeps its kernel's output,
-    log-sum-exp and its selection, so the program holds the forward
-    kernel, the index and the top-k ONCE beside the ONE backward kernel
-    (dq comes out of the dk/dv kernel as a partial a key block of 1,024:
-    8 x 67 MB, summed after the call), and what it keeps (135 MB) fits
-    beside the recomputed rest."""
+    sequence of 8,192, then 16 held of 128 experts of width 768, 8 a
+    token — under ``DecoderLM``'s remat. The layer keeps its kernel's
+    output, log-sum-exp and its selection, so the program holds the
+    forward kernel, the index and the top-k ONCE beside the ONE backward
+    kernel (dq comes out of the dk/dv kernel as a partial a key block of
+    1,024: 8 x 67 MB, summed after the call); and it keeps the sparse
+    layer's routing and ``(rows, into, out)`` of the bounded buffer of
+    16,384 rows, so each side of the row buffer's branch holds its three
+    grouped products once forward and twice backward, and no second
+    forward. What it keeps (135 MB of attention, 185 MB of rows, 5 MB
+    of routing: the router's logits in float32 and six arrays of
+    integers) fits beside the recomputed rest."""
     from fedml_tpu.models.decoder import decoder_from_extra
     from fedml_tpu.ops import attention as A
 
@@ -163,8 +168,10 @@ def test_rematerialised_sparse_layer_compiles_for_v5e(one_chip, monkeypatch):
     model = decoder_from_extra({
         "hidden_size": 2048, "head_dim": 128, "num_key_value_heads": 4,
         "heads_per_layer": [32], "layer_types": ["sparse_attention"],
-        "mlp_layer_types": ["dense"], "intermediate_size": 768,
-        "qk_norm": True,
+        "mlp_layer_types": ["sparse"], "intermediate_size": 768,
+        "num_experts": 128, "num_experts_per_tok": 8,
+        "experts_held": [0, 16], "moe_intermediate_size": 768,
+        "router_scoring": "softmax", "qk_norm": True,
         "rope": {"sparse_attention": {"rope_theta": 1e7}},
         "sparse_attention": {"index_heads": 16, "index_head_dim": 64,
                              "topk": 2048}}, 1024)
@@ -189,6 +196,10 @@ def test_rematerialised_sparse_layer_compiles_for_v5e(one_chip, monkeypatch):
     # one walk over the score blocks: dq comes out of the dk/dv kernel
     assert calls("splash_mqa_dq") == 0 and calls("splash_mqa_dkv") == 1
     assert calls("sparse_select_top_k") == calls("sparse_index_scores") == 1
+    # the side that fits the bounded buffer: 3 forward, 6 backward; the
+    # worst-case side: 3 forward, and 3 + 6 in its own backward rule
+    # (27 with a second forward of both sides)
+    assert calls("ragged-dot-none") == 3 + 6 + 3 + 9
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 

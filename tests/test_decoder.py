@@ -29,6 +29,9 @@ from fedml_tpu.config import (  # noqa: E402
     DataConfig, ExperimentConfig, FedConfig, ModelConfig, TrainConfig,
 )
 from fedml_tpu.models import create_model  # noqa: E402
+from fedml_tpu.models.decoder import (  # noqa: E402
+    KEPT as DECODER_KEPT, decoder_from_extra,
+)
 from fedml_tpu.ops import attention as A  # noqa: E402
 from fedml_tpu.ops import moe as MOE  # noqa: E402
 
@@ -351,9 +354,19 @@ def _eqns(jaxpr, bounded_side_only=True):
                     yield from _eqns(inner, bounded_side_only)
 
 
+#: a layer share under ``remat``: with no policy (forward, recomputation
+#: and backward) and with ``DecoderLM``'s (forward and backward: the
+#: routing and the rows are kept)
+REMATS = {"no_policy": None,
+          "decoder_policy": jax.checkpoint_policies.save_only_these_names(
+              *DECODER_KEPT)}
+
+
+@pytest.mark.parametrize("remat", list(REMATS))
 @pytest.mark.parametrize("mapped", [False, True], ids=["unmapped", "vmap"])
-def test_no_worst_case_sized_array_on_the_bounded_path(mapped):
-    """Forward, recomputation and backward of a layer share under
+def test_no_worst_case_sized_array_on_the_bounded_path(mapped, remat):
+    """Forward, recomputation (where the policy keeps nothing) and
+    backward of a layer share under
     ``remat``: outside the ``cond`` sides that run when the rows do not
     fit, no array has ``N x top_k`` rows by a model or expert width (a
     differentiated ``cond`` would return both sides' residuals) — but
@@ -362,7 +375,7 @@ def test_no_worst_case_sized_array_on_the_bounded_path(mapped):
     tile, so it is cut way by way: ``ops/moe._by_token``)."""
     p, h, _ = _steered(jax.random.key(19), *STEERED["well_under"])
     weigh = jnp.ones_like(h)
-    layer = jax.checkpoint(_share)
+    layer = jax.checkpoint(_share, policy=REMATS[remat])
     fn = lambda p, h: _value_and_gradients(layer, p, h, weigh)[2]
     if mapped:
         fn, h = jax.vmap(fn, in_axes=(None, 0)), jnp.stack([h, h])
@@ -773,8 +786,6 @@ def _kept_stack(kind, layers=2):
     sizes the splash kernel tiles (heads of 128; 256 tokens are two
     blocks of 128 under :func:`kernels`): a window of 40, or an index of
     2 heads of 8 that keeps 40 keys a query."""
-    from fedml_tpu.models.decoder import decoder_from_extra
-
     return decoder_from_extra({
         "hidden_size": 64, "head_dim": 128, "num_key_value_heads": 1,
         "heads_per_layer": [2] * layers, "layer_types": [kind] * layers,
@@ -878,3 +889,107 @@ def test_the_kept_values_leave_the_gradient_as_it_was(
         trained = not any(n in jax.tree_util.keystr(where)
                           for n in INDEX_LEAVES)
         assert bool(jnp.any(a != 0)) == trained, jax.tree_util.keystr(where)
+
+
+# ... and of a sparse layer: its routing and the rows its backward reads
+
+SLOT_FORMS = {"a_slot_a_way": (4, 4), "a_slot_a_held_expert": (3, 4)}
+# each activation as its configuration has it: Laguna's and Keye's
+# (sigmoid router, a shared expert), SmallThinker's (softmax router
+# before attention), Nemotron's (two matrices, latent experts)
+AS_CONFIGURED = {
+    MOE.SILU_GATED: {"shared_expert_intermediate_size": 32},
+    MOE.RELU_GATED: {"router_scoring": "softmax",
+                     "router_input": "attention_input"},
+    MOE.RELU2: {"moe_latent_size": 16, "shared_expert_intermediate_size": 32},
+}
+
+
+def _kept_sparse_stack(form, activation, layers=2):
+    """Two full-attention layers (heads of 128, as :func:`_kept_stack`)
+    over sparse feed-forwards: ``count`` of 16 experts held from the
+    fifth on, ``top_k`` a token (:data:`SLOT_FORMS`)."""
+    count, top_k = SLOT_FORMS[form]
+    return decoder_from_extra({
+        "hidden_size": 64, "head_dim": 128, "num_key_value_heads": 1,
+        "heads_per_layer": [2] * layers, "qk_norm": True,
+        "layer_types": ["full_attention"] * layers,
+        "mlp_layer_types": ["sparse"] * layers, "intermediate_size": 64,
+        "rope": {"full_attention": {"rope_theta": 1e4}},
+        "num_experts": 16, "num_experts_per_tok": top_k,
+        "experts_held": [4, count], "moe_intermediate_size": 32,
+        "routed_scaling_factor": 2.5, "mlp_activation": activation,
+        **AS_CONFIGURED[activation]}, 32)
+
+
+def _sparse_layer_calls(fn, *args):
+    """How often ``fn``'s jaxpr calls the grouped product (on the side
+    of a ``cond`` that runs when the rows fit the bounded buffer),
+    ``top_k`` and ``sort``."""
+    names = [e.primitive.name
+             for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)]
+    return {k: names.count(k)
+            for k in ("ragged_dot_general", "top_k", "sort")}
+
+
+@pytest.mark.parametrize("activation", list(AS_CONFIGURED))
+@pytest.mark.parametrize("form", list(SLOT_FORMS))
+def test_a_sparse_layer_runs_forward_once_a_training_step(
+        form, activation, plain_remat):
+    """In the jaxpr of a training gradient every sparse layer calls the
+    grouped product once forward and twice backward (by the rows, by
+    the matrices) for each of its experts' matrices — 9 calls, 6 where
+    the experts have two — ranks once and sorts once (twice where a
+    token's slots are its ways: the order and its inverse): its routing
+    and ``(rows, into, out)`` are kept (``ops/moe.KEPT``). ``nn.remat``
+    with no policy runs the forward products, the top-k and the sorts a
+    second time: 12 (8) calls."""
+    model = _kept_sparse_stack(form, activation)
+    count, top_k = SLOT_FORMS[form]
+    tokens = jax.random.randint(jax.random.key(0), (1, 256), 0, 32)
+    params = jax.eval_shape(model.init, jax.random.key(1), tokens)["params"]
+    layers, matrices = 2, len(MOE.leading(activation)) + 1
+    sorts = 2 if top_k <= count else 1
+    once = {"ragged_dot_general": layers * 3 * matrices, "top_k": layers,
+            "sort": layers * sorts}
+    gradient = _training_gradient(model, tokens)
+    assert _sparse_layer_calls(gradient, params) == once
+    with plain_remat():
+        assert _sparse_layer_calls(
+            _training_gradient(model, tokens), params) == {
+                "ragged_dot_general": layers * 4 * matrices,
+                "top_k": 2 * layers, "sort": 2 * layers * sorts}
+    # no gradient, nothing to keep: the evaluator's forward pass
+    forward = lambda p: model.apply({"params": p}, tokens)
+    assert _sparse_layer_calls(forward, params) == {
+        **once, "ragged_dot_general": layers * matrices}
+
+
+@pytest.mark.parametrize("side", ["bounded", "worst_case"])
+@pytest.mark.parametrize("activation", list(AS_CONFIGURED))
+@pytest.mark.parametrize("form", list(SLOT_FORMS))
+def test_the_kept_routing_and_rows_leave_the_gradient_as_it_was(
+        form, activation, side, plain_remat, monkeypatch):
+    """What a sparse layer keeps is what its second run would compute
+    again from the same inputs: every parameter's gradient equals the
+    one under ``nn.remat`` with no policy to the BIT — through the
+    bounded row buffer (which keeps its rows) and, with a buffer of one
+    tile that the held rows overflow, through the worst-case one (which
+    keeps zeros and runs forward again inside its own rule)."""
+    if side == "worst_case":
+        monkeypatch.setattr(MOE, "ROW_SHARE", 0)
+        monkeypatch.setattr(MOE, "ROW_FLOOR", 1 / 16)
+    model = _kept_sparse_stack(form, activation)
+    tokens = jax.random.randint(jax.random.key(0), (2, 256), 0, 32)
+    params = model.init(jax.random.key(1), tokens)["params"]
+    _, counted = model.apply({"params": params}, tokens,
+                             mutable=["counters"])
+    compact = float(counted["counters"]["moe_rows_compact"])
+    assert compact == (2 * 512 * 4 if side == "bounded" else 0.0)
+    kept = jax.jit(_training_gradient(model, tokens))(params)
+    with plain_remat():
+        plain = jax.jit(_training_gradient(model, tokens))(params)
+    for (where, a), b in zip(jax.tree_util.tree_leaves_with_path(kept),
+                             jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(a, b, jax.tree_util.keystr(where))
+        assert bool(jnp.any(a != 0)), jax.tree_util.keystr(where)

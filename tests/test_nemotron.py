@@ -20,7 +20,9 @@ for _p in (os.path.join(ROOT, "benchmarks"),
         sys.path.insert(0, _p)
 
 import tiny_nemotron as TN  # noqa: E402
-from test_decoder import _eqns, _loss, _model_config, _sim  # noqa: E402
+from test_decoder import (  # noqa: E402
+    REMATS, _eqns, _loss, _model_config, _sim,
+)
 from test_ssm import _count  # noqa: E402
 
 from fedml_tpu.config import ModelConfig  # noqa: E402
@@ -407,11 +409,14 @@ def test_a_slots_row_is_where_the_orders_own_inverse_puts_it(steer):
         assert sizes.tolist() == [n, 0, 0, 0, 0, 0]
 
 
+@pytest.mark.parametrize("remat", list(REMATS))
 @pytest.mark.parametrize("shape", ["ways_4_of_4_held", "slots_3_held_4_ways",
                                    "slots_8_held_22_ways"])
-def test_one_sort_a_layer_pass_and_no_row_a_way_where_slots_are_fewer(shape):
+def test_one_sort_a_layer_pass_and_no_row_a_way_where_slots_are_fewer(
+        shape, remat):
     """Forward, recomputation and backward of a layer share under
-    ``remat`` (two layer passes that order the assignments). A token's
+    ``remat``: two layer passes that order the assignments with no
+    policy, ONE with ``DecoderLM``'s, which keeps the routing. A token's
     slots its ways: two sorts a pass (``order`` and its ``inverse``) and,
     outside the ``cond`` sides that run when the rows do not fit, the
     wide arrays ``tests/test_decoder.py::
@@ -426,12 +431,14 @@ def test_one_sort_a_layer_pass_and_no_row_a_way_where_slots_are_fewer(shape):
     h = jax.random.normal(jax.random.fold_in(key, 1), (TOKENS, 64))
     layer = jax.checkpoint(
         lambda p, h: MOE.moe_layer(p, h, (first, count), top_k, 5.0,
-                                   activation=MOE.RELU2)[0])
+                                   activation=MOE.RELU2)[0],
+        policy=REMATS[remat])
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p, h: jnp.sum(layer(p, h)), argnums=(0, 1)))(p, h).jaxpr
     slots = min(top_k, count)
     sorts = [e for e in _eqns(jaxpr) if e.primitive.name == "sort"]
-    assert len(sorts) == 2 * (2 if top_k <= count else 1)
+    passes = 2 if remat == "no_policy" else 1
+    assert len(sorts) == passes * (2 if top_k <= count else 1)
     own = {leaf.shape for leaf in jax.tree.leaves(p)}
     nk = TOKENS * top_k
 
