@@ -12,7 +12,8 @@ configuration; any other name is refused.
 
 - attention: ``heads_per_layer[l]`` query heads over
   ``num_key_value_heads`` key-value heads of ``head_dim``;
-  ``layer_types[l]`` is one of three attention kinds, all causal:
+  ``layer_types[l]`` is one of four attention kinds, all causal, the
+  first three over grouped-query heads of that ONE size:
   ``full_attention``; ``sliding_attention`` (within ``sliding_window``
   keys); ``sparse_attention`` (query ``t`` reads the ``min(t + 1,
   topk)`` keys a learned index ranks highest — ``sparse_attention:
@@ -25,12 +26,31 @@ configuration; any other name is refused.
   own ``rope`` record (``rope_theta``, ``partial_rotary_factor``,
   ``rope_type`` ``default`` | ``yarn`` with ``factor``,
   ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
-  ``attention_factor``; rotate-half pairing; ``rope_type`` ``none``:
+  ``attention_factor``; ``rope_pairing`` ``half``, the default —
+  dimension ``i`` of the rotated part turns with ``i + rot / 2`` — or
+  ``adjacent``, ``2i`` with ``2i + 1``; ``rope_type`` ``none``:
   no rotary and no other position term); with ``qk_norm`` every
   query and key head is RMS-normed (one learned scale over
   ``head_dim``) before it is rotated; with ``gating`` a per-head
   sigmoid gate of the normed input scales each head's output before
   the output projection;
+- ``latent_attention`` (full causal attention whose queries, keys and
+  values come through low-rank projections; sizes in the
+  ``latent_attention`` record: ``q_lora_rank``, ``kv_lora_rank``,
+  ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``): ``c_q =
+  RMSNorm(h W_qa)``, ``q = c_q W_qb`` as ``heads_per_layer[l]`` heads
+  of ``[nope | rope]``; ``[c_kv | k_r] = h W_kva``, ``c_kv <-
+  RMSNorm(c_kv)``, ``[k_nope | v] = c_kv W_kvb`` as heads of ``nope +
+  v_head_dim``; the rotary (the kind's ``rope`` record, over
+  ``qk_rope_head_dim`` dimensions) turns the LAST ``rope`` dimensions
+  of every query head and the ONE key head ``k_r``, which then stands
+  beside every head's own ``k_nope``: keys of ``nope + rope`` beside
+  values of ``v_head_dim``, scores scaled by ``1 / sqrt(nope +
+  rope)``, every head with keys of its own (the kernel runs them as
+  key-value heads of group 1); all heads are held (no
+  ``query_heads_held``) and ``head_dim`` is not read. The two
+  down-projections, their norms and the two up-projections run under
+  the scope ``fedml.model.attn.latent``, inside ``fedml.model.attn``;
 - ``state_space`` (Mamba-2; sizes in the ``state_space`` record:
   ``num_heads`` heads of ``head_dim``, ``n_groups`` groups of
   ``state_size``, ``conv_kernel``, ``chunk_size``, the ``time_step_*``
@@ -50,7 +70,13 @@ configuration; any other name is refused.
   renormalised over the chosen and times ``routed_scaling_factor``,
   the experts ``experts_held = [first, count]`` of width
   ``moe_intermediate_size`` held here, and a shared expert of
-  ``shared_expert_intermediate_size``, none at 0). ``mlp_activation``
+  ``shared_expert_intermediate_size``, none at 0). With
+  ``router_score_bias`` every sparse layer has a ``router_bias`` leaf
+  (``[num_experts]`` float32, drawn :data:`ROUTER_BIAS_STD` normal): a
+  score-correction bias that the CHOICE reads — the experts of largest
+  probability plus bias — and the weights do not (the chosen experts'
+  unbiased probabilities renormalised), so no gradient reaches it and
+  a round leaves it as it came. ``mlp_activation``
   (:data:`fedml_tpu.ops.moe.ACTIVATIONS`, the shared expert's too):
   ``silu_gated`` (the default: three matrices an expert, ``(silu(x W1)
   * (x W3)) W2``, as the dense layer is), ``relu_gated`` (three,
@@ -141,17 +167,28 @@ from fedml_tpu.ops.moe import (
 from fedml_tpu.ops.ssm import KEPT as SCAN_KEPT, ssd_chunked
 
 NONE = "none"  # a layer without this half; a rope record's ``rope_type``
-FULL, SLIDING, SELECTED = (
-    "full_attention", "sliding_attention", "sparse_attention")
+FULL, SLIDING, SELECTED, LATENT = (
+    "full_attention", "sliding_attention", "sparse_attention",
+    "latent_attention")
 STATE_SPACE = "state_space"
-MIXERS = (FULL, SLIDING, SELECTED, STATE_SPACE, NONE)
+MIXERS = (FULL, SLIDING, SELECTED, LATENT, STATE_SPACE, NONE)
 DENSE, SPARSE = "dense", "sparse"
 FEED_FORWARDS = (DENSE, SPARSE, NONE)
 SIGMOID = "sigmoid"  # the router's scoring where the configuration names none
 # ``router_input``: what a sparse layer's router reads
 FEED_FORWARD_INPUT, ATTENTION_INPUT = "feed_forward_input", "attention_input"
 ROUTER_INPUTS = (FEED_FORWARD_INPUT, ATTENTION_INPUT)
-ATTENTIONS = (FULL, SLIDING, SELECTED)
+#: the law of a fresh ``router_bias``: normal of this deviation, beside
+#: probabilities in (0, 1) — it reorders experts whose probabilities
+#: lie this close and leaves the rest of a token's choice alone
+ROUTER_BIAS_STD = 0.01
+ATTENTIONS = (FULL, SLIDING, SELECTED, LATENT)
+# a rope record's ``rope_pairing``: which dimensions turn together
+HALF, ADJACENT = "half", "adjacent"
+PAIRINGS = (HALF, ADJACENT)
+# the sizes a ``latent_attention`` record gives
+LATENT_SIZES = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim")
 
 #: what a rematerialised layer keeps (``checkpoint_name``s)
 KEPT = ATTENTION_KEPT + SCAN_KEPT + MOE_KEPT
@@ -198,23 +235,44 @@ def rope_inverse_frequencies(rope: dict, head_dim: int):
     return inv, float(rope.get("attention_factor", 1.0)), rot
 
 
+def rope_pairing(rope: dict) -> str:
+    """Which dimensions of a rotated part turn together, by the rope
+    record's ``rope_pairing``: ``half`` (the default: ``i`` with ``i +
+    rot / 2``, rotate-half) or ``adjacent`` (``2i`` with ``2i + 1``)."""
+    pairing = rope.get("rope_pairing", HALF)
+    if pairing not in PAIRINGS:
+        raise ValueError(
+            f"decoder: unknown rope_pairing {pairing!r}; known: "
+            f"{', '.join(PAIRINGS)}")
+    return pairing
+
+
 def rope_tables(rope: dict, head_dim: int, t: int):
-    """``cos, sin`` float32 ``[T, rot]`` (the half-tables repeated, for
-    rotate-half pairing), the attention factor folded in."""
+    """``cos, sin`` float32 ``[T, rot]``, the attention factor folded
+    in: pair ``i``'s angle at both of its dimensions (the half-tables
+    side by side under ``half`` pairing, each entry twice under
+    ``adjacent``)."""
     inv, factor, _ = rope_inverse_frequencies(rope, head_dim)
     angles = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
-    angles = np.concatenate([angles, angles], axis=-1)
+    angles = (np.repeat(angles, 2, axis=-1) if rope_pairing(rope) == ADJACENT
+              else np.concatenate([angles, angles], axis=-1))
     return (np.float32(np.cos(angles) * factor),
             np.float32(np.sin(angles) * factor))
 
 
-def apply_rope(x, cos, sin):
-    """``x`` ``[B, T, H, D]``: rotate the first ``rot`` dimensions
-    (rotate-half pairing), pass the rest through."""
+def apply_rope(x, cos, sin, pairing: str = HALF):
+    """``x`` ``[B, T, H, D]``: rotate the first ``rot`` dimensions in
+    pairs by ``pairing`` (:func:`rope_pairing`; the tables are
+    :func:`rope_tables`' for the same), pass the rest through."""
     rot = cos.shape[-1]
     xr, rest = x[..., :rot], x[..., rot:]
     half = rot // 2
-    turned = jnp.concatenate([-xr[..., half:], xr[..., :half]], axis=-1)
+    if pairing == ADJACENT:
+        pairs = xr.reshape(*xr.shape[:-1], half, 2)
+        turned = jnp.stack([-pairs[..., 1], pairs[..., 0]], -1).reshape(
+            xr.shape)
+    else:
+        turned = jnp.concatenate([-xr[..., half:], xr[..., :half]], axis=-1)
     c = jnp.asarray(cos, x.dtype)[None, :, None, :]
     s = jnp.asarray(sin, x.dtype)[None, :, None, :]
     return jnp.concatenate([xr * c + turned * s, rest], axis=-1)
@@ -332,6 +390,10 @@ class DecoderLayer(nn.Module):
                     self.param("latent_in", flat, (d, latent)),
                     self.param("latent_out", flat, (latent, d)))
             params = jax.tree.map(lambda p: p.astype(x.dtype), params)
+            if c["router_score_bias"]:  # float32; the choice alone reads it
+                params["router_bias"] = self.param(
+                    "router_bias", nn.initializers.normal(ROUTER_BIAS_STD),
+                    (c["num_experts"],))
             y, counters = moe_layer(
                 params, h.reshape(b * t, d), (first, count),
                 c["num_experts_per_tok"], c["routed_scaling_factor"],
@@ -352,24 +414,42 @@ class DecoderLayer(nn.Module):
         came, ``norm(x)``: what a router before attention reads)."""
         c = dict(self.cfg)
         b, t, d = x.shape
-        hd = c["head_dim"]
-        heads = _held(c, "query_heads_held", c["heads_per_layer"][self.index])
-        kv = _held(c, "key_value_heads_held", c["num_key_value_heads"])
+        rope = dict(dict(c["rope"])[kind])
+        pairing = rope_pairing(rope)
+        turns = rope.get("rope_type") != NONE
         with jax.named_scope("fedml.model.attn"):
             h = RMSNorm(c["rms_norm_eps"], name="attn_norm")(x)
-            q = self.dense(heads * hd, "q_proj")(h).reshape(b, t, heads, hd)
-            k = self.dense(kv * hd, "k_proj")(h).reshape(b, t, kv, hd)
-            v = self.dense(kv * hd, "v_proj")(h).reshape(b, t, kv, hd)
-            if c["qk_norm"]:
-                q = RMSNorm(c["rms_norm_eps"], name="q_norm")(q)
-                k = RMSNorm(c["rms_norm_eps"], name="k_norm")(k)
-            rope = dict(dict(c["rope"])[kind])
             how = {}
-            if kind == SELECTED:
-                how["selection"], attended = self.select(h, rope)
-            if rope.get("rope_type") != NONE:
-                cos, sin = rope_tables(rope, hd, t)
-                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            if kind == LATENT:
+                heads = c["heads_per_layer"][self.index]
+                q, k, v, k_rope = self.latent_heads(h, heads)
+                if turns:
+                    nope = k.shape[-1]
+                    cos, sin = rope_tables(rope, k_rope.shape[-1], t)
+                    q = jnp.concatenate([q[..., :nope], apply_rope(
+                        q[..., nope:], cos, sin, pairing)], -1)
+                    k_rope = apply_rope(k_rope, cos, sin, pairing)
+                # the ONE rotary key head stands beside every head's own
+                k = jnp.concatenate([k, jnp.broadcast_to(
+                    k_rope, (b, t, heads, k_rope.shape[-1]))], -1)
+            else:
+                hd = c["head_dim"]
+                heads = _held(
+                    c, "query_heads_held", c["heads_per_layer"][self.index])
+                kv = _held(c, "key_value_heads_held", c["num_key_value_heads"])
+                q = self.dense(heads * hd, "q_proj")(h).reshape(
+                    b, t, heads, hd)
+                k = self.dense(kv * hd, "k_proj")(h).reshape(b, t, kv, hd)
+                v = self.dense(kv * hd, "v_proj")(h).reshape(b, t, kv, hd)
+                if c["qk_norm"]:
+                    q = RMSNorm(c["rms_norm_eps"], name="q_norm")(q)
+                    k = RMSNorm(c["rms_norm_eps"], name="k_norm")(k)
+                if kind == SELECTED:
+                    how["selection"], attended = self.select(h, rope)
+                if turns:
+                    cos, sin = rope_tables(rope, hd, t)
+                    q = apply_rope(q, cos, sin, pairing)
+                    k = apply_rope(k, cos, sin, pairing)
             a = self.attn_fn(
                 q, k, v, causal=True,
                 window=c["sliding_window"] if kind == SLIDING else None,
@@ -377,8 +457,35 @@ class DecoderLayer(nn.Module):
             if c["gating"]:
                 gate = jax.nn.sigmoid(self.dense(heads, "g_proj")(h))
                 a = a * gate[..., None]
-            a = self.dense(d, "o_proj")(a.reshape(b, t, heads * hd))
+            a = self.dense(d, "o_proj")(a.reshape(b, t, -1))
             return x + a, attended, h
+
+    @nn.nowrap
+    def latent_heads(self, h, heads: int):
+        """A latent-attention layer's heads from its normed input ``h``
+        (module docstring) -> (``q`` ``[B, T, heads, nope + rope]``, the
+        keys' own part ``[B, T, heads, nope]``, ``v`` ``[B, T, heads,
+        v_head_dim]``, the one rotary key head ``[B, T, 1, rope]``),
+        nothing rotated yet: the two down-projections, their norms and
+        the two up-projections, under a scope of their own."""
+        c = dict(self.cfg)
+        la = dict(c["latent_attention"])
+        b, t, _ = h.shape
+        nope, rope, dv = (la["qk_nope_head_dim"], la["qk_rope_head_dim"],
+                          la["v_head_dim"])
+        with jax.named_scope("fedml.model.attn.latent"):
+            cq = RMSNorm(c["rms_norm_eps"], name="q_a_norm")(
+                self.dense(la["q_lora_rank"], "q_a_proj")(h))
+            q = self.dense(heads * (nope + rope), "q_b_proj")(cq)
+            ckv, k_rope = jnp.split(self.dense(
+                la["kv_lora_rank"] + rope, "kv_a_proj")(h),
+                [la["kv_lora_rank"]], -1)
+            ckv = RMSNorm(c["rms_norm_eps"], name="kv_a_norm")(ckv)
+            k, v = jnp.split(self.dense(
+                heads * (nope + dv), "kv_b_proj")(ckv).reshape(
+                    b, t, heads, nope + dv), [nope], -1)
+        return (q.reshape(b, t, heads, nope + rope), k, v,
+                k_rope.reshape(b, t, 1, rope))
 
     @nn.nowrap
     def state_space(self, x):
@@ -442,8 +549,10 @@ class DecoderLayer(nn.Module):
             w = self.dense(j, "index_w_proj")(h)
             cos, sin = rope_tables({**rope, "partial_rotary_factor": 1.0},
                                    e, t)
-            scores = index_scores(apply_rope(qi, cos, sin),
-                                  apply_rope(ki, cos, sin)[:, :, 0], w)
+            pairing = rope_pairing(rope)
+            scores = index_scores(
+                apply_rope(qi, cos, sin, pairing),
+                apply_rope(ki, cos, sin, pairing)[:, :, 0], w)
         with jax.named_scope("fedml.model.attn.select"):
             selection = checkpoint_name(
                 select_top_k(scores, sa["topk"]), KEPT_SELECTION)
@@ -539,6 +648,27 @@ def _check_attention_share(c: dict, attention_heads: list) -> None:
                 f"not read key_value_heads_held {[kf, kc]} of {kv} evenly")
 
 
+def _check_latent_attention(c: dict) -> None:
+    la = c.get("latent_attention") or {}
+    lacks = [k for k in LATENT_SIZES if not isinstance(la.get(k), int)]
+    if lacks:
+        raise ValueError(
+            f"decoder: latent_attention lacks {', '.join(lacks)}")
+    unknown = sorted(set(la) - set(LATENT_SIZES))
+    if unknown or min(la[k] for k in LATENT_SIZES) < 1 or (
+            la["qk_rope_head_dim"] % 2):
+        raise ValueError(
+            "decoder: latent_attention sizes must be at least 1, the "
+            f"rotary part even, and no other key given: {la}")
+    shares = [k for k in ("query_heads_held", "key_value_heads_held")
+              if c.get(k)]
+    if shares or c["qk_norm"]:
+        raise ValueError(
+            "decoder: a stack with latent_attention layers holds all its "
+            "heads and norms its latents alone; it has no "
+            f"{', '.join(shares + ['qk_norm'] * bool(c['qk_norm']))}")
+
+
 def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
     """The module ``ModelConfig.extra`` describes (module docstring).
     ``vocab_size`` defaults to ``num_classes``."""
@@ -551,7 +681,7 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
     c = {
         "sliding_window": None, "gating": False, "qk_norm": False,
         "rms_norm_eps": 1e-6, "router_scoring": SIGMOID,
-        "router_input": FEED_FORWARD_INPUT,
+        "router_input": FEED_FORWARD_INPUT, "router_score_bias": False,
         "mlp_activation": SILU_GATED, "moe_latent_size": 0,
         "moe_intermediate_size": 0, "shared_expert_intermediate_size": 0,
         "num_experts": 0, "num_experts_per_tok": 0,
@@ -610,9 +740,17 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         raise ValueError(
             f"decoder: unknown router_scoring {c['router_scoring']!r}; "
             f"known: {sorted(SCORINGS)}")
+    if c["router_score_bias"] and SPARSE not in c["mlp_layer_types"]:
+        raise ValueError(
+            "decoder: router_score_bias needs a sparse layer: the bias is "
+            "its router's")
+    for rope in dict(c["rope"]).values():
+        rope_pairing(dict(rope))
     _check_attention_share(c, [
         h for h, kind in zip(c["heads_per_layer"], c["layer_types"])
-        if kind in ATTENTIONS])
+        if kind in ATTENTIONS and kind != LATENT])
+    if LATENT in c["layer_types"]:
+        _check_latent_attention(c)
     if STATE_SPACE in c["layer_types"]:
         _check_state_space(c.get("state_space") or {})
     if SELECTED in c["layer_types"]:

@@ -3,10 +3,19 @@ keys chosen a query by a learned index, and grouped-query heads: the
 attention the configured decoder stack
 (:mod:`fedml_tpu.models.decoder`) calls.
 
-``q`` is ``[B, T, H, D]``; ``k`` and ``v`` are ``[B, T, Hkv, D]`` with
-``H`` a multiple of ``Hkv``: query head ``j`` reads key-value head
-``j // (H / Hkv)``. Query ``i`` sees keys ``j <= i`` and, with
-``window=w``, only ``j > i - w`` (``w`` keys, itself included).
+``q`` is ``[B, T, H, D]``; ``k`` is ``[B, T, Hkv, D]`` and ``v``
+``[B, T, Hkv, Dv]`` with ``H`` a multiple of ``Hkv``: query head ``j``
+reads key-value head ``j // (H / Hkv)``. Query ``i`` sees keys ``j <=
+i`` and, with ``window=w``, only ``j > i - w`` (``w`` keys, itself
+included). The values' head size ``Dv`` need not be the keys' ``D`` (a
+latent-attention layer has keys of 192 beside values of 128): scores
+are scaled by ``1 / sqrt(D)``, the keys' size, and the output is ``[B,
+T, H, Dv]``. The kernel takes ``D = 192`` as it is, one and a half
+lane tiles: on a v5e one call of 8,192 tokens x 32 heads, forward +
+backward under the layer's checkpoint policy in bfloat16, reads 29.24
+ms (9.10 forward) against 29.97 (9.55) with queries and keys
+zero-padded to 256, which gives the same output and gradients to the
+bit (PERF.md section 6, PR 42) — so nothing is padded.
 
 On the TPU this is JAX's bundled splash attention (a Pallas kernel:
 blockwise, online softmax, forward and backward, no ``[T, T]`` score
@@ -123,11 +132,11 @@ def masked_attention(q, k, v, window: int | None = None,
                      selection=None) -> jax.Array:
     """The arithmetic itself: scores ``q k^T / sqrt(D)``, the mask (the
     causal or window one, or ``selection`` ``[B, T, T]`` bool: the keys
-    each query reads), a float32 softmax, the mix. Holds ``[B, H, T,
-    T]`` scores. The output is named :data:`KEPT_OUTPUT`, as the
-    kernel's is."""
+    each query reads), a float32 softmax, the mix ``[B, T, H, Dv]``.
+    Holds ``[B, H, T, T]`` scores. The output is named
+    :data:`KEPT_OUTPUT`, as the kernel's is."""
     b, t, h, d = q.shape
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[-1]
     qg = q.reshape(b, t, hkv, h // hkv, d)
     s = jnp.einsum("bqgnd,bkgd->bgnqk", qg, k,
                    preferred_element_type=jnp.float32) / np.sqrt(d)
@@ -136,7 +145,8 @@ def masked_attention(q, k, v, window: int | None = None,
     s = jnp.where(seen, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     a = jnp.einsum("bgnqk,bkgd->bqgnd", p.astype(v.dtype), v)
-    return checkpoint_name(a.reshape(b, t, h, d).astype(q.dtype), KEPT_OUTPUT)
+    return checkpoint_name(
+        a.reshape(b, t, h, dv).astype(q.dtype), KEPT_OUTPUT)
 
 
 def _block_sizes(t: int):
@@ -192,13 +202,13 @@ def splash_attention(q, k, v, window: int | None = None,
     qg = (q * (d ** -0.5)).astype(q.dtype).reshape(b, t, hkv, h // hkv, d)
     qg = qg.transpose(0, 2, 3, 1, 4)  # [B, Hkv, group, T, D]
     kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    a = jax.vmap(jax.vmap(kernel))(qg, kg, vg)  # [B, Hkv, group, T, D]
-    return a.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
+    a = jax.vmap(jax.vmap(kernel))(qg, kg, vg)  # [B, Hkv, group, T, Dv]
+    return a.transpose(0, 3, 1, 2, 4).reshape(b, t, h, v.shape[-1])
 
 
 def causal_attention(q, k, v, causal: bool = True,
                      window: int | None = None, selection=None) -> jax.Array:
-    """``[B, T, H, D]`` causal grouped-query attention — within
+    """``[B, T, H, Dv]`` causal grouped-query attention — within
     ``window`` keys, or over ``selection`` (``[B, T, T]`` bool from
     :func:`select_top_k`: :func:`selected_attention`) — behind the
     ``AttnFn`` contract of :mod:`fedml_tpu.models.transformer`; the
@@ -442,7 +452,7 @@ def selected_splash(q, k, v, selection, interpret: bool = False) -> jax.Array:
         heads = jax.vmap(jax.vmap(kernel, in_axes=(0, None, None)))
         out.append(heads(qg[i], kg[i], vg[i]))  # [Hkv, group, 1, T, D]
     a = jnp.stack(out)[:, :, :, 0]
-    return a.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
+    return a.transpose(0, 3, 1, 2, 4).reshape(b, t, h, v.shape[-1])
 
 
 def selected_attention(q, k, v, selection) -> jax.Array:

@@ -272,18 +272,30 @@ def _weights(top_p, scale: float):
     return scale * top_p / jnp.sum(top_p, -1, keepdims=True)
 
 
-def _ranked(scores, top_k: int, scoring: str):
-    """The ``top_k`` largest probabilities a token and their experts."""
-    return jax.lax.top_k(SCORINGS[scoring](scores), top_k)
+def _ranked(scores, top_k: int, scoring: str, choice_bias=None):
+    """The ``top_k`` probabilities a token that rank highest and their
+    experts. With ``choice_bias`` ``[E]`` the RANKING reads probability
+    plus bias and the probabilities returned are the chosen experts'
+    own, without it."""
+    p = SCORINGS[scoring](scores)
+    if choice_bias is None:
+        return jax.lax.top_k(p, top_k)
+    top_e = jax.lax.top_k(p + choice_bias, top_k)[1]
+    return jnp.take_along_axis(p, top_e, -1), top_e
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid"):
+def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
+                choice_bias=None):
     """Router logits ``[N, E]`` (float32) over ALL experts -> the
     ``top_k`` a token of largest probability — ``scoring`` ``sigmoid``
     (each expert's own) or ``softmax`` (over all ``E``, in float32) —
     their weights renormalised over those ``top_k`` and times
-    ``scale``: ``(expert ids [N, k], weights [N, k])``.
+    ``scale``: ``(expert ids [N, k], weights [N, k])``. With
+    ``choice_bias`` ``[E]`` float32 (a score-correction bias) the
+    ``top_k`` are those of largest probability PLUS bias; the weights
+    stay the chosen experts' unbiased probabilities renormalised, so
+    the bias moves the set and nothing else, and gets no gradient.
 
     The choice is a set, so the weights' cotangent reaches the
     probabilities as ``lax.top_k``'s own rule sends it, scattered to
@@ -291,24 +303,25 @@ def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid"):
     chosen probabilities and the ids alone, named
     :data:`KEPT_ROUTING`, so a rematerialised layer ranks once (the
     scoring, elementwise, is run again for its own rule)."""
-    top_p, top_e = _ranked(scores, top_k, scoring)
+    top_p, top_e = _ranked(scores, top_k, scoring, choice_bias)
     return top_e, _weights(top_p, scale)
 
 
-def _route_top_k_fwd(scores, top_k, scale, scoring):
+def _route_top_k_fwd(scores, top_k, scale, scoring, choice_bias):
     scores, (top_p, top_e) = checkpoint_name(
-        (scores, _ranked(scores, top_k, scoring)), KEPT_ROUTING)
-    return (top_e, _weights(top_p, scale)), (scores, top_p, top_e)
+        (scores, _ranked(scores, top_k, scoring, choice_bias)), KEPT_ROUTING)
+    return (top_e, _weights(top_p, scale)), (
+        scores, top_p, top_e, choice_bias)
 
 
 def _route_top_k_bwd(top_k, scale, scoring, res, cotangents):
-    scores, top_p, top_e = res
+    scores, top_p, top_e, choice_bias = res
     d_top_p, = jax.vjp(lambda top_p: _weights(top_p, scale), top_p)[1](
         cotangents[1])
     p, scored = jax.vjp(SCORINGS[scoring], scores)
     d_p, = jax.linear_transpose(
         lambda p: jnp.take_along_axis(p, top_e, -1), p)(d_top_p)
-    return scored(d_p)
+    return (*scored(d_p), jax.tree.map(jnp.zeros_like, choice_bias))
 
 
 route_top_k.defvjp(_route_top_k_fwd, _route_top_k_bwd)
@@ -573,12 +586,14 @@ class Routing(NamedTuple):
 
 
 def route(router, x, held: tuple[int, int], top_k: int, scale: float,
-          scoring: str = "sigmoid", scope: str = ROUTE) -> Routing:
+          scoring: str = "sigmoid", scope: str = ROUTE,
+          choice_bias=None) -> Routing:
     """From what the router reads to the routing: ``router`` ``[D, E]``
     over ALL ``E`` experts, ``x`` ``[N, D]`` — the rows the experts
     will be given, or any other tensor of the same tokens (a router
     that reads its layer's attention input) — ``held = (first, count)``
-    the experts held here. Logits (float32), top-k and weights run
+    the experts held here; ``choice_bias`` ``[E]`` float32 or None,
+    :func:`route_top_k`'s. Logits (float32), top-k and weights run
     under ``scope``; ordering the assignments by held expert under
     :data:`ROUTE`. A token's slots are its ways where ``top_k <= count``
     (``back``: the order's own ``argsort``) and the held experts where
@@ -588,7 +603,8 @@ def route(router, x, held: tuple[int, int], top_k: int, scale: float,
     n = x.shape[0]
     with jax.named_scope(scope):
         scores = jnp.dot(x, router, preferred_element_type=jnp.float32)
-        top_e, top_w = route_top_k(scores, top_k, scale, scoring)
+        top_e, top_w = route_top_k(scores, top_k, scale, scoring,
+                                   choice_bias)
     with jax.named_scope(ROUTE):
         local = top_e.reshape(-1) - first
         # an assignment to an absent expert sorts past every held group
@@ -611,7 +627,10 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
               router_input=None):
     """This chip's part of one sparse-expert layer, dropless.
 
-    ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts; the
+    ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts;
+    ``router_bias`` ``[E]`` float32 or no such key (a score-correction
+    bias: :func:`route_top_k`'s ``choice_bias``, which moves the set of
+    experts chosen and not their weights, and gets no gradient); the
     matrices of the experts held here — ids ``[first, first + count)``,
     ``held = (first, count)`` — as ``activation`` has them
     (:data:`ACTIVATIONS`, :func:`ffn`): gated (``silu_gated``,
@@ -668,7 +687,8 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     n, _ = h.shape
     apart = router_input is not None  # a router with an input of its own
     routing = route(params["router"], router_input if apart else h, held,
-                    top_k, scale, scoring, scope=ROUTER if apart else ROUTE)
+                    top_k, scale, scoring, scope=ROUTER if apart else ROUTE,
+                    choice_bias=params.get("router_bias"))
     inside = h
     if "latent" in params:
         with jax.named_scope(LATENT):
