@@ -124,7 +124,10 @@ them; the router's product, its scoring's ranking (``lax.top_k``), the
 sorts and the count, the row gather, the grouped products forward and
 the combine. And these twice: the norms, the attention's projections,
 rotary, gate and output projection, the convolution, the dense
-feed-forward, the shared expert and the latent projections.
+feed-forward, the shared expert and the latent projections. The
+embedding, outside the layers, gathers its rows once forward and forms
+its table's gradient once backward by a rule of its own
+(:class:`DecoderLM`).
 What the layers count (:func:`counter_names`: the sparse layers'
 :data:`fedml_tpu.ops.moe.MOE_COUNTERS` — the assignments that landed
 on held experts, those made, the fullest held expert's, those of calls
@@ -160,6 +163,7 @@ from fedml_tpu.ops.attention import (
     ATTN_COUNTERS, KEPT as ATTENTION_KEPT, KEPT_SELECTION, causal_attention,
     index_scores, select_top_k,
 )
+from fedml_tpu.ops.embedding import embedding_lookup
 from fedml_tpu.ops.moe import (
     ACTIVATIONS, KEPT as MOE_KEPT, MOE_COUNTERS, SCORINGS, SILU_GATED,
     leading, moe_layer,
@@ -560,8 +564,34 @@ class DecoderLayer(nn.Module):
         return selection, (selected, jnp.float32(b * t * (t + 1) // 2))
 
 
+class Embedding(nn.Embed):
+    """``flax.linen.Embed`` — its parameter ``embedding``, its
+    initialiser, its row gather — under
+    :func:`fedml_tpu.ops.embedding.embedding_lookup`'s backward rule."""
+
+    def __call__(self, inputs):
+        (embedding,) = self.promote_dtype(
+            self.embedding, dtype=self.dtype, inexact=False)
+        return embedding_lookup(embedding, inputs)
+
+
 class DecoderLM(nn.Module):
-    """Tokens ``[B, T]`` int32 -> logits ``[B, T, vocab_size]``."""
+    """Tokens ``[B, T]`` int32 -> logits ``[B, T, vocab_size]``.
+
+    Under the scope ``fedml.model.embed`` run, forward, the gather of a
+    row of ``embed/embedding`` a token (``jnp.take``, what
+    ``flax.linen.Embed`` makes, to the bit) and, backward, that table's
+    gradient by :func:`fedml_tpu.ops.embedding.embedding_lookup`'s own
+    rule: the step's ``B T`` ids sorted, the cotangent rows of each
+    DISTINCT id summed in float32 by one-hot products over blocks of
+    the sorted tokens, and every table row written once by a gather.
+    It is NOT the scatter-add of a row a token that ``jnp.take``
+    transposes to: text repeats its ids, the TPU compiler's loop for a
+    scatter whose indices repeat walks the table at 0.4 us a row where
+    the table is 2,560 wide (15 ms a step into 37,984 rows, for 84 MB
+    of traffic), and the rule costs a tenth of that there and less
+    than the scatter at every decoder cell's table and bfloat16 rows
+    (``PERF.md`` section 6, PR 43)."""
 
     cfg: Any  # tuple of (key, value) items: hashable, as flax wants
     vocab_size: int
@@ -572,8 +602,8 @@ class DecoderLM(nn.Module):
     def __call__(self, tokens, train: bool = False):
         c = dict(self.cfg)
         with jax.named_scope("fedml.model.embed"):
-            x = nn.Embed(self.vocab_size, c["hidden_size"],
-                         name="embed")(tokens)
+            x = Embedding(self.vocab_size, c["hidden_size"],
+                          name="embed")(tokens)
         layer = nn.remat(
             DecoderLayer,
             policy=jax.checkpoint_policies.save_only_these_names(*KEPT))

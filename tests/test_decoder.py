@@ -3,7 +3,8 @@ against the plain references of ``benchmarks/configs/laguna-xs2-share8``
 and ``keye-vl2-a3b-share8`` at tiny widths, and the pieces it is made
 of: windowed grouped-query attention, attention over the keys a learned
 index selects, the chip's share of a sparse-expert layer under either
-router scoring, the counters a round carries."""
+router scoring, the counters a round carries, and the embedding
+lookup's own backward rule against ``jnp.take``'s."""
 
 import contextlib
 import functools
@@ -29,10 +30,12 @@ from fedml_tpu.config import (  # noqa: E402
     DataConfig, ExperimentConfig, FedConfig, ModelConfig, TrainConfig,
 )
 from fedml_tpu.models import create_model  # noqa: E402
+from fedml_tpu.models import decoder as DEC  # noqa: E402
 from fedml_tpu.models.decoder import (  # noqa: E402
     KEPT as DECODER_KEPT, decoder_from_extra,
 )
 from fedml_tpu.ops import attention as A  # noqa: E402
+from fedml_tpu.ops import embedding as EMB  # noqa: E402
 from fedml_tpu.ops import moe as MOE  # noqa: E402
 
 
@@ -943,7 +946,9 @@ def test_a_sparse_layer_runs_forward_once_a_training_step(
     token's slots are its ways: the order and its inverse): its routing
     and ``(rows, into, out)`` are kept (``ops/moe.KEPT``). ``nn.remat``
     with no policy runs the forward products, the top-k and the sorts a
-    second time: 12 (8) calls."""
+    second time: 12 (8) calls. One more sort, in the backward pass
+    alone, is the embedding's: its rule orders the step's token ids
+    (``ops/embedding.py``)."""
     model = _kept_sparse_stack(form, activation)
     count, top_k = SLOT_FORMS[form]
     tokens = jax.random.randint(jax.random.key(0), (1, 256), 0, 32)
@@ -951,18 +956,19 @@ def test_a_sparse_layer_runs_forward_once_a_training_step(
     layers, matrices = 2, len(MOE.leading(activation)) + 1
     sorts = 2 if top_k <= count else 1
     once = {"ragged_dot_general": layers * 3 * matrices, "top_k": layers,
-            "sort": layers * sorts}
+            "sort": layers * sorts + 1}
     gradient = _training_gradient(model, tokens)
     assert _sparse_layer_calls(gradient, params) == once
     with plain_remat():
         assert _sparse_layer_calls(
             _training_gradient(model, tokens), params) == {
                 "ragged_dot_general": layers * 4 * matrices,
-                "top_k": 2 * layers, "sort": 2 * layers * sorts}
+                "top_k": 2 * layers, "sort": 2 * layers * sorts + 1}
     # no gradient, nothing to keep: the evaluator's forward pass
     forward = lambda p: model.apply({"params": p}, tokens)
     assert _sparse_layer_calls(forward, params) == {
-        **once, "ragged_dot_general": layers * matrices}
+        "ragged_dot_general": layers * matrices, "top_k": layers,
+        "sort": layers * sorts}
 
 
 @pytest.mark.parametrize("side", ["bounded", "worst_case"])
@@ -993,3 +999,184 @@ def test_the_kept_routing_and_rows_leave_the_gradient_as_it_was(
                              jax.tree.leaves(plain)):
         np.testing.assert_array_equal(a, b, jax.tree_util.keystr(where))
         assert bool(jnp.any(a != 0)), jax.tree_util.keystr(where)
+
+
+# ---------------------------------------------------------------------------
+# the embedding's gradient: ``ops/embedding.py`` against ``jnp.take``'s
+# ---------------------------------------------------------------------------
+
+ROWS, WIDTH, TOKENS, MAPPED = 96, 16, 64, 3
+
+
+def _zipf(rng, shape, rows=ROWS):
+    """Ids by weight 1 / rank over a permutation of the rows, as
+    ``benchmarks/lib/traffic.py`` draws a topic's tokens."""
+    p = 1.0 / np.arange(1, rows + 1)
+    return rng.permutation(rows)[rng.choice(rows, size=shape, p=p / p.sum())]
+
+
+#: ids of one client's step by how they repeat -> (draw, table rows,
+#: width); the last two are the cells' ``B x T`` at a small width
+ID_CASES = {
+    "all_distinct": (lambda rng: rng.permutation(ROWS)[:TOKENS], ROWS, WIDTH),
+    "one_id_every_token": (lambda rng: np.full((TOKENS,), 41), ROWS, WIDTH),
+    "zipf": (lambda rng: _zipf(rng, (TOKENS,)), ROWS, WIDTH),
+    "first_and_last_row": (
+        lambda rng: rng.choice([0, ROWS - 1, 5], size=(TOKENS,)), ROWS, WIDTH),
+    # jnp.take wraps a negative id once and reads NaN past either end
+    "outside_the_table": (lambda rng: rng.choice(
+        [-1, -ROWS, -ROWS - 1, ROWS, ROWS + 7, 3], size=(TOKENS,)),
+        ROWS, WIDTH),
+    "b2_t2048": (lambda rng: _zipf(rng, (2, 2048), 1000), 1000, 8),
+    "b1_t8192": (lambda rng: _zipf(rng, (1, 8192), 1000), 1000, 8),
+}
+
+
+def _table_gradient(lookup):
+    """The table's gradient of ``sum(lookup(table, ids) * w)``: the rows
+    of ``w`` are the cotangent rows."""
+    return jax.grad(lambda table, ids, w: jnp.sum(lookup(table, ids) * w))
+
+
+def _take(table, ids):
+    return jnp.take(table, ids, axis=0)
+
+
+def _lookup_operands(case, mapped=False, dtype=jnp.float32):
+    draw, rows, width = ID_CASES[case]
+    rng = np.random.default_rng(7)
+    ids = np.stack([draw(rng) for _ in range(MAPPED)])
+    ids = jnp.asarray(ids if mapped else ids[0], jnp.int32)
+    lead = (MAPPED,) if mapped else ()
+    table = jnp.asarray(rng.normal(size=lead + (rows, width)), dtype)
+    w = jnp.asarray(rng.normal(size=ids.shape + (width,)), dtype)
+    return table, ids, w
+
+
+def _assert_float32_rounding(got, want):
+    """Equal to 1e-5 of the largest entry: the order of a float32 sum
+    differs and nothing else."""
+    assert got.dtype == want.dtype and got.shape == want.shape
+    largest = float(jnp.max(jnp.abs(want)))
+    assert largest > 0.1
+    assert float(jnp.max(jnp.abs(got - want))) <= 1e-5 * largest
+
+
+@pytest.mark.parametrize("mapped", [False, True], ids=["alone", "vmap"])
+@pytest.mark.parametrize("case", list(ID_CASES))
+def test_embedding_rule_equals_the_gathers_own_gradient(case, mapped):
+    """float32: the lookup is ``jnp.take``'s bit for bit and the rule's
+    table gradient ``jax.grad``'s through it to float32 rounding — under
+    ``jit``, alone and under ``vmap`` over a leading block axis (every
+    operand mapped, as the bulk engine maps a block of clients)."""
+    table, ids, w = _lookup_operands(case, mapped)
+    wrap = (lambda f: jax.jit(jax.vmap(f))) if mapped else jax.jit
+    np.testing.assert_array_equal(
+        wrap(EMB.embedding_lookup)(table, ids), wrap(_take)(table, ids))
+    _assert_float32_rounding(
+        wrap(_table_gradient(EMB.embedding_lookup))(table, ids, w),
+        wrap(_table_gradient(_take))(table, ids, w))
+
+
+@pytest.mark.parametrize(
+    "dtype", [jnp.int8, jnp.uint8, jnp.int16, jnp.uint32, jnp.int32])
+def test_embedding_rule_takes_ids_of_any_integer_type(dtype):
+    table, ids, w = _lookup_operands("zipf")
+    _assert_float32_rounding(
+        _table_gradient(EMB.embedding_lookup)(table, ids.astype(dtype), w),
+        _table_gradient(_take)(table, ids, w))
+
+
+@pytest.mark.parametrize("block", [5, 8, TOKENS])
+@pytest.mark.parametrize("case", [
+    "zipf", "one_id_every_token", "first_and_last_row", "outside_the_table"])
+def test_embedding_sums_do_not_depend_on_where_the_blocks_are_cut(
+        case, block):
+    """``block`` sorted tokens a product: a run of one id that crosses
+    block ends (``one_id_every_token`` crosses every one) still sums
+    whole, and a token count that is no multiple of the block is filled
+    up."""
+    table, ids, w = _lookup_operands(case)
+    _assert_float32_rounding(
+        EMB.distinct_row_sums(w, ids, ROWS, block=block),
+        _table_gradient(_take)(table, ids, w))
+
+
+@pytest.mark.parametrize("case", ["zipf", "one_id_every_token", "b2_t2048"])
+def test_embedding_rule_sums_bfloat16_rows_in_float32(case):
+    """bfloat16 table and cotangent rows: the rule sums in float32 and
+    rounds once, ``jnp.take``'s scatter-add rounds at every row, so the
+    rule is no further from the float32 sums of the same rows — one
+    rounding of them."""
+    table, ids, w = _lookup_operands(case, dtype=jnp.bfloat16)
+    exact = _table_gradient(_take)(
+        table.astype(jnp.float32), ids, w.astype(jnp.float32))
+    got = _table_gradient(EMB.embedding_lookup)(table, ids, w)
+    scattered = _table_gradient(_take)(table, ids, w)
+    assert got.dtype == scattered.dtype == jnp.bfloat16
+    far = lambda g: float(jnp.max(jnp.abs(g.astype(jnp.float32) - exact)))
+    assert far(got) <= far(scattered)
+    assert far(got) <= 2.0 ** -8 * float(jnp.max(jnp.abs(exact)))
+
+
+@contextlib.contextmanager
+def _plain_embed(monkeypatch):
+    """``DecoderLM`` over ``flax.linen.Embed`` itself, as it was."""
+    import flax.linen as nn
+
+    with monkeypatch.context() as m:
+        m.setattr(DEC, "Embedding", nn.Embed)
+        yield
+
+
+def _table_scatter_adds(fn, params, table_shape):
+    """-> ``unique_indices`` of every scatter-add in ``fn``'s jaxpr
+    whose operand is a ``table_shape`` array."""
+    jaxpr = jax.make_jaxpr(fn)(params).jaxpr
+    return [eqn.params["unique_indices"]
+            for eqn in _eqns(jaxpr, bounded_side_only=False)
+            if eqn.primitive.name in ("scatter-add", "scatter_add")
+            and eqn.invars[0].aval.shape == table_shape]
+
+
+def test_decoder_embedding_is_flaxs_but_for_the_gradients_form(
+        tiny, monkeypatch):
+    """Against the same stack over ``flax.linen.Embed``: the parameter
+    tree (names, shapes, dtypes, the drawn values) and the logits are
+    the same to the bit, every parameter's gradient to float32 rounding,
+    and the training gradient's jaxpr holds no scatter-add into a
+    ``[vocabulary, hidden]`` array whose indices are not marked unique
+    (``jnp.take``'s transpose is one, and is found where it runs)."""
+    config, _, model, _, tokens = tiny
+    x, y = tokens[:, :-1], tokens[:, 1:]
+    shape = (TD.VOCAB, config["model"]["extra"]["hidden_size"])
+
+    def built(m):
+        params = m.init(jax.random.key(5))["params"]
+
+        def step(params):
+            logits, _, _ = m.apply_train_counted(
+                {"params": params}, x, jax.random.key(0))
+            return _loss(logits, y), logits
+
+        (_, logits), grads = jax.jit(
+            jax.value_and_grad(step, has_aux=True))(params)
+        return params, logits, grads, _table_scatter_adds(
+            jax.grad(lambda p: step(p)[0]), params, shape)
+
+    ours = built(model)
+    with _plain_embed(monkeypatch):
+        plain = built(create_model(_model_config(config)))
+    assert ours[0]["embed"]["embedding"].shape == shape
+    assert (jax.tree.map(lambda a: (a.shape, a.dtype), ours[0])
+            == jax.tree.map(lambda a: (a.shape, a.dtype), plain[0]))
+    jax.tree.map(np.testing.assert_array_equal, ours[0], plain[0])
+    np.testing.assert_array_equal(ours[1], plain[1])
+    wanted = dict(jax.tree_util.tree_leaves_with_path(plain[2]))
+    for path, g in jax.tree_util.tree_leaves_with_path(ours[2]):
+        want = wanted[path]
+        scale = float(jnp.max(jnp.abs(want)))
+        assert float(jnp.max(jnp.abs(g - want))) <= 1e-5 * scale, (
+            jax.tree_util.keystr(path))
+    assert all(ours[3]), ours[3]
+    assert plain[3] == [False]
