@@ -120,7 +120,7 @@ buffer's rows, those rows through each matrix that leads in, and their
 result; :data:`fedml_tpu.ops.moe.KEPT`). So these run ONCE a training
 step: the forward attention kernel, the index and its top-k; of the
 chunked scan the mix, the chunks' own states and the recurrence between
-them; the router's product, its scoring's ranking (``lax.top_k``), the
+them; the router's product, its scoring's ranking (``ops.moe.largest``), the
 sorts and the count, the row gather, the grouped products forward and
 the combine. And these twice: the norms, the attention's projections,
 rotary, gate and output projection, the convolution, the dense

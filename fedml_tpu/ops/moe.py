@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from fedml_tpu.ops import attention
+
 
 def init_moe_params(key, num_experts: int, d_model: int, d_hidden: int):
     """Router + stacked expert FFNs ([E, ...] leaves)."""
@@ -272,34 +274,129 @@ def _weights(top_p, scale: float):
     return scale * top_p / jnp.sum(top_p, -1, keepdims=True)
 
 
+#: tokens a step of the ranking kernel holds in fast memory (22 of 512
+#: read 0.30 ms a call on the chip at 128 tokens and 0.34 to 0.36 at 256
+#: to 1,024; the cells' other shapes read alike at every size:
+#: ``PERF.md`` section 6, PR 44)
+RANK_TOKENS = 128
+
+
+def _largest(b, p, k: int):
+    """``b`` ``[E, R]``, finite: of each COLUMN its ``k`` largest values
+    in ``jax.lax.top_k``'s order — descending, equal values by the lower
+    row — by taking the largest that is left ``k`` times: ``(rows [k, R]
+    int32, values [k, R])``, the values ``p``'s at those rows where
+    ``p`` (as ``b``) is given, else ``b``'s own. Every pass runs down
+    the experts with the tokens side by side, so that what a pass finds
+    of a token is one number a lane: ``2 k`` reductions a token (``3 k``
+    with ``p``)."""
+    e, r = b.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (e, r), 0)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (k, r), 0)
+
+    def take(j, carry):
+        b, ids, values = carry
+        top = jnp.max(b, 0, keepdims=True)
+        at = jnp.min(jnp.where(b == top, rows, e), 0, keepdims=True)
+        hit = rows == at
+        if p is not None:
+            top = jnp.sum(jnp.where(hit, p, 0), 0, keepdims=True)
+        return (jnp.where(hit, -jnp.inf, b), jnp.where(slot == j, at, ids),
+                jnp.where(slot == j, top, values))
+
+    return jax.lax.fori_loop(0, k, take, (
+        b, jnp.zeros((k, r), jnp.int32), jnp.zeros((k, r), b.dtype)))[1:]
+
+
+def largest_kernel(b, p, k: int, tokens: int = RANK_TOKENS,
+                   interpret: bool = False):
+    """:func:`_largest` of ``b`` ``[E, N]`` (and ``p``, or None) as the
+    TPU's kernel: ``tokens`` of them a grid step, held in fast memory
+    through all ``k`` passes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    e, n = b.shape
+    operands = (b,) if p is None else (b, p)
+
+    def kernel(*refs):
+        *ins, ids_ref, values_ref = refs
+        own = ins[1][...] if len(ins) == 2 else None
+        ids_ref[...], values_ref[...] = _largest(ins[0][...], own, k)
+
+    columns = lambda rows: pl.BlockSpec((rows, tokens), lambda i: (0, i))
+    return pl.pallas_call(
+        kernel, grid=(n // tokens,),
+        in_specs=[columns(e)] * len(operands), out_specs=[columns(k)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((k, n), jnp.int32),
+                   jax.ShapeDtypeStruct((k, n), b.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret, name="moe_rank_top_k",
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def largest(ranked, own, k: int, kernel: bool):
+    """``ranked`` ``[N, E]`` -> ``(own [N, k], ids [N, k])`` of each
+    token's ``k`` largest, as ``jax.lax.top_k(ranked, k)`` orders and
+    chooses them (:func:`_largest`), the values read from ``own`` (as
+    ``ranked``) where that is not None; through the TPU's ``kernel`` or
+    by the same passes over the whole array. One program a shape,
+    whichever layers call it."""
+    columns = (ranked.T, None if own is None else own.T)
+    top_e, top_p = (largest_kernel if kernel else _largest)(*columns, k)
+    return top_p.T, top_e.T
+
+
 def _ranked(scores, top_k: int, scoring: str, choice_bias=None):
     """The ``top_k`` probabilities a token that rank highest and their
-    experts. With ``choice_bias`` ``[E]`` the RANKING reads probability
-    plus bias and the probabilities returned are the chosen experts'
-    own, without it."""
+    experts, as ``jax.lax.top_k`` returns them TO THE BIT — descending,
+    equal probabilities by the lower expert id — without its sort of
+    ``[N, E]`` (:func:`largest`; on the TPU its kernel, over whole
+    blocks of :data:`RANK_TOKENS` tokens). With ``choice_bias`` ``[E]``
+    the RANKING reads probability plus bias and the probabilities
+    returned are the chosen experts' own, without it.
+
+    ``ops/attention.py:select_top_k`` keeps its own selection, by
+    counting passes: it marks 2,048 of 8,192 keys in place and orders
+    nothing, where this brings 6 to 22 of 64 to 512 out in order."""
     p = SCORINGS[scoring](scores)
+    kernel = attention._on_tpu() and p.shape[0] % RANK_TOKENS == 0
     if choice_bias is None:
-        return jax.lax.top_k(p, top_k)
-    top_e = jax.lax.top_k(p + choice_bias, top_k)[1]
-    return jnp.take_along_axis(p, top_e, -1), top_e
+        return largest(p, None, top_k, kernel)
+    return largest(p + choice_bias, p, top_k, kernel)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _chosen_to_experts(top_e, d_top_p, experts: int):
+    """``take_along_axis(p, top_e, -1)`` transposed: ``d_p[n, e] = sum_k
+    (top_e[n, k] == e) d_top_p[n, k]``. A token's ids are distinct, so
+    at most one term a place is not zero and the sum is, to the bit,
+    what a scatter-add of the ``N x k`` updates into zeros leaves."""
+    hit = top_e[..., None] == jnp.arange(experts, dtype=top_e.dtype)
+    return jnp.sum(jnp.where(hit, d_top_p[..., None], 0), -2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 5))
 def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
-                choice_bias=None):
+                choice_bias=None, scope: str = ROUTE):
     """Router logits ``[N, E]`` (float32) over ALL experts -> the
     ``top_k`` a token of largest probability — ``scoring`` ``sigmoid``
     (each expert's own) or ``softmax`` (over all ``E``, in float32) —
     their weights renormalised over those ``top_k`` and times
-    ``scale``: ``(expert ids [N, k], weights [N, k])``. With
+    ``scale``: ``(expert ids [N, k], weights [N, k])``, in
+    ``jax.lax.top_k``'s order and with its choice at a tie (the lower
+    expert id), bit for bit (:func:`_ranked`). With
     ``choice_bias`` ``[E]`` float32 (a score-correction bias) the
     ``top_k`` are those of largest probability PLUS bias; the weights
     stay the chosen experts' unbiased probabilities renormalised, so
     the bias moves the set and nothing else, and gets no gradient.
 
     The choice is a set, so the weights' cotangent reaches the
-    probabilities as ``lax.top_k``'s own rule sends it, scattered to
-    the ids the forward pass chose; the rule reads the logits, the
+    probabilities as ``lax.top_k``'s own rule sends it, to the ids the
+    forward pass chose and to the same bits, by a compare and a sum in
+    place of its scatter (:func:`_chosen_to_experts`), under ``scope``;
+    the rule reads the logits, the
     chosen probabilities and the ids alone, named
     :data:`KEPT_ROUTING`, so a rematerialised layer ranks once (the
     scoring, elementwise, is run again for its own rule)."""
@@ -307,21 +404,21 @@ def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
     return top_e, _weights(top_p, scale)
 
 
-def _route_top_k_fwd(scores, top_k, scale, scoring, choice_bias):
+def _route_top_k_fwd(scores, top_k, scale, scoring, choice_bias, scope):
     scores, (top_p, top_e) = checkpoint_name(
         (scores, _ranked(scores, top_k, scoring, choice_bias)), KEPT_ROUTING)
     return (top_e, _weights(top_p, scale)), (
         scores, top_p, top_e, choice_bias)
 
 
-def _route_top_k_bwd(top_k, scale, scoring, res, cotangents):
+def _route_top_k_bwd(top_k, scale, scoring, scope, res, cotangents):
     scores, top_p, top_e, choice_bias = res
-    d_top_p, = jax.vjp(lambda top_p: _weights(top_p, scale), top_p)[1](
-        cotangents[1])
-    p, scored = jax.vjp(SCORINGS[scoring], scores)
-    d_p, = jax.linear_transpose(
-        lambda p: jnp.take_along_axis(p, top_e, -1), p)(d_top_p)
-    return (*scored(d_p), jax.tree.map(jnp.zeros_like, choice_bias))
+    with jax.named_scope(scope):
+        d_top_p, = jax.vjp(lambda top_p: _weights(top_p, scale), top_p)[1](
+            cotangents[1])
+        _, scored = jax.vjp(SCORINGS[scoring], scores)
+        d_p = _chosen_to_experts(top_e, d_top_p, scores.shape[-1])
+        return (*scored(d_p), jax.tree.map(jnp.zeros_like, choice_bias))
 
 
 route_top_k.defvjp(_route_top_k_fwd, _route_top_k_bwd)
@@ -604,7 +701,7 @@ def route(router, x, held: tuple[int, int], top_k: int, scale: float,
     with jax.named_scope(scope):
         scores = jnp.dot(x, router, preferred_element_type=jnp.float32)
         top_e, top_w = route_top_k(scores, top_k, scale, scoring,
-                                   choice_bias)
+                                   choice_bias, scope)
     with jax.named_scope(ROUTE):
         local = top_e.reshape(-1) - first
         # an assignment to an absent expert sorts past every held group

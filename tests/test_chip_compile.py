@@ -220,11 +220,47 @@ def test_rematerialised_sparse_layer_compiles_for_v5e(one_chip, monkeypatch):
     # one walk over the score blocks: dq comes out of the dk/dv kernel
     assert calls("splash_mqa_dq") == 0 and calls("splash_mqa_dkv") == 1
     assert calls("sparse_select_top_k") == calls("sparse_index_scores") == 1
+    # the router ranks once, in its own kernel: the routing is kept
+    assert calls("moe_rank_top_k") == 1
     # the side that fits the bounded buffer: 3 forward, 6 backward; the
     # worst-case side: 3 forward, and 3 + 6 in its own backward rule
     # (27 with a second forward of both sides)
     assert calls("ragged-dot-none") == 3 + 6 + 3 + 9
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+@pytest.mark.parametrize("tokens, experts, top_k, scoring, biased", [
+    (8192, 512, 22, "sigmoid", False), (8192, 128, 8, "softmax", False),
+    (4096, 256, 8, "sigmoid", False), (8192, 64, 6, "softmax", False),
+    (8192, 256, 8, "sigmoid", True)],
+    ids=["nemotron", "keye", "laguna", "smallthinker", "joyai"])
+def test_the_routers_choice_and_rule_compile_for_v5e(
+        one_chip, monkeypatch, tokens, experts, top_k, scoring, biased):
+    """A router's ``top_k`` and its backward rule at the five decoder
+    cells' shapes, alone and under the cohort's ``vmap`` of one client:
+    ONE Mosaic kernel ranks (``moe_rank_top_k``: the rule reads what it
+    kept) and the compiled program holds neither a sort — ``lax.top_k``
+    of ``[N, E]`` is one on this chip — nor a scatter."""
+    from fedml_tpu.ops import attention as A
+    from fedml_tpu.ops import moe as MOE
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+    bias = (sds(experts),) if biased else ()
+
+    def loss(scores, g, *bias):
+        return jnp.sum(g * MOE.route_top_k(
+            scores, top_k, 2.5, scoring, *bias)[1])
+
+    for grad, lead in ((jax.grad(loss), ()), (jax.vmap(jax.grad(loss)), (1,))):
+        text = jax.jit(grad).lower(
+            sds(*lead, tokens, experts), sds(*lead, tokens, top_k),
+            *(sds(*lead, *b.shape) for b in bias)).compile().as_text()
+        kernels = [line for line in text.splitlines()
+                   if "tpu_custom_call" in line and " custom-call(" in line]
+        assert len(kernels) == 1 and "moe_rank_top_k" in kernels[0]
+        assert " sort(" not in text and " scatter(" not in text
 
 
 def test_grouped_expert_products_compile_for_v5e(one_chip):

@@ -927,12 +927,15 @@ def _kept_sparse_stack(form, activation, layers=2):
 
 def _sparse_layer_calls(fn, *args):
     """How often ``fn``'s jaxpr calls the grouped product (on the side
-    of a ``cond`` that runs when the rows fit the bounded buffer),
-    ``top_k`` and ``sort``."""
-    names = [e.primitive.name
-             for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)]
-    return {k: names.count(k)
-            for k in ("ragged_dot_general", "top_k", "sort")}
+    of a ``cond`` that runs when the rows fit the bounded buffer), the
+    router's ranking (``ops/moe.py:largest``, a program of its own
+    name) and ``sort``."""
+    eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    names = [e.primitive.name for e in eqns]
+    return {"ragged_dot_general": names.count("ragged_dot_general"),
+            "largest": sum(e.primitive.name == "jit"
+                           and e.params["name"] == "largest" for e in eqns),
+            "sort": names.count("sort")}
 
 
 @pytest.mark.parametrize("activation", list(AS_CONFIGURED))
@@ -955,20 +958,20 @@ def test_a_sparse_layer_runs_forward_once_a_training_step(
     params = jax.eval_shape(model.init, jax.random.key(1), tokens)["params"]
     layers, matrices = 2, len(MOE.leading(activation)) + 1
     sorts = 2 if top_k <= count else 1
-    once = {"ragged_dot_general": layers * 3 * matrices, "top_k": layers,
-            "sort": layers * sorts + 1}
+    once = {"ragged_dot_general": layers * 3 * matrices,
+            "largest": layers, "sort": layers * sorts + 1}
     gradient = _training_gradient(model, tokens)
     assert _sparse_layer_calls(gradient, params) == once
     with plain_remat():
         assert _sparse_layer_calls(
             _training_gradient(model, tokens), params) == {
                 "ragged_dot_general": layers * 4 * matrices,
-                "top_k": 2 * layers, "sort": 2 * layers * sorts + 1}
+                "largest": 2 * layers, "sort": 2 * layers * sorts + 1}
     # no gradient, nothing to keep: the evaluator's forward pass
     forward = lambda p: model.apply({"params": p}, tokens)
     assert _sparse_layer_calls(forward, params) == {
-        "ragged_dot_general": layers * matrices, "top_k": layers,
-        "sort": layers * sorts}
+        "ragged_dot_general": layers * matrices,
+        "largest": layers, "sort": layers * sorts}
 
 
 @pytest.mark.parametrize("side", ["bounded", "worst_case"])

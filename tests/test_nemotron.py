@@ -444,11 +444,15 @@ def test_one_sort_a_layer_pass_and_no_row_a_way_where_slots_are_fewer(
 
     def wide(eqns, rows):
         """Shapes of ``rows`` rows (flat, or a token's side by side) by
-        the model's width or an expert's."""
+        the model's width or an expert's — but for the router's rule,
+        which compares a token's ways with every expert id, ``[N,
+        top_k, E]`` inside its sum (the 64 experts of one shape are as
+        many as the model is wide)."""
         return {
             s for eqn in eqns for v in eqn.outvars
             for s in [v.aval.shape]
-            if s not in own and len(s) >= 2 and s[-1] in (64, 32) and (
+            if s not in own and s != (TOKENS, top_k, experts)
+            and len(s) >= 2 and s[-1] in (64, 32) and (
                 s[-2] == TOKENS * rows
                 or s[-3:-1] in ((rows, TOKENS), (TOKENS, rows)))}
 
