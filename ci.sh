@@ -27,15 +27,11 @@ echo "== fedlint: project-invariant static analysis (ratcheted) =="
 # contracts (docs/STATIC_ANALYSIS.md). Fails on any finding not frozen
 # in fedlint_baseline.json; the JSON artifact lands next to the
 # telemetry artifacts for the round notes.
-python scripts/fedlint.py fedml_tpu bench.py scripts \
+python scripts/fedlint.py fedml_tpu scripts \
   --baseline fedlint_baseline.json --json "$OUT/fedlint.json"
 
 echo "== 1/3 fast test tier =="
 python -m pytest tests -m "not slow" -q -x -p no:cacheprovider
-
-# doc perf tables must match the bench artifact (generated, never
-# hand-edited; skips cleanly when no artifact exists on a fresh clone)
-python scripts/render_perf_tables.py --check
 
 echo "== telemetry smoke: 2-rank loopback trace -> merge -> validate =="
 # a 1-server + 1-worker loopback world with the telemetry plane on must
@@ -194,16 +190,6 @@ print(f"perf smoke ok: {len(perf['rounds'])} profiled rounds, "
       f"{len(rows)} time-series rows")
 EOF
 
-echo "== mem smoke: per-program HBM accounting + donation audit + /statusz memory =="
-# the memory-observability plane end-to-end on CPU: mem.program.*
-# argument bytes grow with cohort size, mem.compile_s histograms have
-# entries, the donation audit passes on the real fused round and flags
-# an undonated control, the monitor runs on the marked RSS fallback,
-# /metrics + /statusz serve the mem vocabulary, and the
-# peak_round_hbm_mb_c{8,64,256}_k{1,8} bench records diff
-# lower-is-better (docs/OBSERVABILITY.md "Memory & compilation")
-JAX_PLATFORMS=cpu python scripts/mem_smoke.py "$OUT/mem"
-
 echo "== bulk smoke: O(block) streaming round + convergence + bulk.* gauges =="
 # the bulk-client engine end-to-end on CPU: the block program's
 # argument/temp bytes stay FLAT from C=64 to C=256 at B=16 (fixed
@@ -243,19 +229,6 @@ echo "== fuse smoke: --fuse_rounds 4 parity + one compile per (bucket, K) =="
 # boundary rounds even though eval_every % K != 0
 # (docs/PERFORMANCE.md "Round fusion")
 JAX_PLATFORMS=cpu python scripts/fuse_smoke.py
-
-echo "== bench_diff (advisory): newest two BENCH artifacts =="
-# regression comparator over the last two driver BENCH records —
-# advisory only (CPU-marked records are reported as skipped, never
-# compared)
-B_NEW=$(ls BENCH_r*.json 2>/dev/null | sort | tail -1)
-B_OLD=$(ls BENCH_r*.json 2>/dev/null | sort | tail -2 | head -1)
-if [ -n "$B_OLD" ] && [ "$B_OLD" != "$B_NEW" ]; then
-  python scripts/bench_diff.py "$B_OLD" "$B_NEW" \
-    || echo "(advisory bench_diff failed — non-fatal)"
-else
-  echo "fewer than two BENCH_r*.json artifacts; diff skipped"
-fi
 
 echo "== 2/3 smoke matrix (tiny runs) =="
 # one process for the whole matrix: same CLI argv surface via

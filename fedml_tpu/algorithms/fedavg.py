@@ -1473,8 +1473,8 @@ class FedAvgSim:
         train_metrics = {
             "train_loss": fin["loss"],
             "train_acc": fin["acc"],
-            # LAST so rate_bench's first-value sync stays train_loss;
-            # consumed host-side by consume_round_counters (the
+            # LAST: a reader that syncs on the first value gets
+            # train_loss; consumed host-side by consume_round_counters (the
             # robust.nonfinite_rejected counter)
             "nonfinite_rejected": rejected,
         }

@@ -13,8 +13,8 @@ via a frozen baseline (``scripts/fedlint.py --baseline``).
 The analyzer (:mod:`.core` + :mod:`.rules`) imports NOTHING from the
 code it lints — it parses it — so linting cannot perturb what it
 lints; stdlib ``ast`` only, no jax. One module here IS runtime-shared
-by design: :mod:`.flags`, the flag-registration checker run.py /
-bench.py / the deploy supervisor call at startup (the runtime twin of
+by design: :mod:`.flags`, the flag-registration checker run.py and
+the deploy supervisor call at startup (the runtime twin of
 the parse-time-validation rule). This ``__init__`` stays import-free
 so that runtime path pulls in none of the analyzer.
 """

@@ -225,8 +225,8 @@ class AnalysisConfig:
 
     ``exempt`` maps rule name -> list of relpath glob patterns the rule
     skips entirely (policy exemptions live HERE, visible in one file —
-    e.g. bench.py is exempt from jit-purity because its measurement
-    loops intentionally time host work; inline ``# fedlint: disable``
+    e.g. ``scripts/*.py`` is exempt from jit-purity because the smoke
+    scripts intentionally time host work; inline ``# fedlint: disable``
     comments are for single intentional sites, with a reason).
     """
 

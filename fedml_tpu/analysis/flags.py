@@ -1,22 +1,21 @@
 """Shared CLI flag-registration checker — the RUNTIME twin of the
 parse-time-validation lint rule (docs/STATIC_ANALYSIS.md).
 
-One registration contract for every entrypoint (run.py, bench.py, the
-deploy/supervisor argv builders) instead of bench.py's hand-rolled
-``_assert_no_reserved_flags``:
+One registration contract for every entrypoint (run.py, the
+deploy/supervisor argv builders):
 
 - :data:`RESERVED_RUN_FLAGS` names the option strings owned by the run
   CLI's SLO/export plane. ``--slo`` means an SloSpec and
   ``--metrics_port`` means the OpenMetrics listener on EVERY
-  entrypoint — a bench stage minting its own ``--slo`` would shadow
+  entrypoint — another program minting its own ``--slo`` would shadow
   those semantics, so registering a collision fails loudly at parser
   build, not at first confused use. (Duplicate option strings need no
   runtime check: argparse already raises at ``add_argument`` time —
   the STATIC side of this contract, including literal duplicates, is
   the fedlint parse-time-validation rule.)
 
-``check_flag_registry(parser)`` is called by non-owning entrypoints
-(bench.py); the owner (run.py) calls it with ``owner=True``, which
+``check_flag_registry(parser)`` is the check for a non-owning
+entrypoint; the owner (run.py) calls it with ``owner=True``, which
 additionally asserts the reserved flags are actually registered — the
 reservation must never outlive the plane it protects.
 """

@@ -16,7 +16,7 @@ review round moved it; this rule keeps it moved). Three checks:
 - **reserved flags**: option strings owned by the run CLI
   (``options.parse-time-validation.reserved_flags``, the runtime twin
   is ``fedml_tpu.analysis.flags.check_flag_registry``) registered by
-  any other module — bench.py minting its own ``--slo`` would shadow
+  any other module — a script minting its own ``--slo`` would shadow
   the SloSpec semantics operators rely on.
 """
 

@@ -307,10 +307,6 @@ class RoundAnatomy:
 
     # -- /tracez -----------------------------------------------------------
 
-    def ring_snapshot(self) -> list[dict]:
-        with self._lock:
-            return [dict(e) for e in self._ring]
-
     def tracez(self, rank: int = 0) -> dict:
         """The ``/tracez`` section payload (core/export.py): the last-N
         closed rounds' anatomy entries, newest last."""
@@ -524,46 +520,6 @@ class BreachProfiler:
             except Exception:
                 pass
         self._stop()
-
-
-def fetch_corrected_time(fn, *args, n: int = 30,
-                         warmup: int = 2) -> float:
-    """The ONE amortized device-timing path the offline profiling
-    scripts share (``scripts/profile_round.py`` and friends used to
-    hand-roll three drifting copies of this loop): run ``warmup``
-    dispatches, measure the D2H fetch cost of one scalar leaf, then
-    time ``n`` dispatches closed by a single scalar fetch — the fetch
-    cost is subtracted so the figure is device execution, not host
-    turnaround. Returns per-call seconds.
-
-    This times a *compiled callable in a loop*; the live per-round
-    attribution is :class:`RoundAnatomy`, which never adds syncs. The
-    scripts pair this with :class:`~fedml_tpu.core.memscope.ProgramSite`
-    so their compiles land in the same ``mem.program.*`` accounting as
-    the production sims."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    out = None
-    for _ in range(max(1, warmup)):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    leaf = jax.tree.leaves(out)[0]
-    float(np.asarray(jax.device_get(jnp.sum(leaf))))
-    fs = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        float(np.asarray(jax.device_get(jnp.sum(leaf))))
-        fs.append(time.perf_counter() - t0)
-    fetch = min(fs)
-    t0 = time.perf_counter()
-    for _ in range(n):
-        out = fn(*args)
-    leaf = jax.tree.leaves(out)[0]
-    float(np.asarray(jax.device_get(jnp.sum(leaf))))
-    wall = time.perf_counter() - t0
-    return max(wall - fetch, wall / 2) / n
 
 
 #: Process-global anatomy plane — disabled until :func:`configure`.

@@ -186,7 +186,7 @@ class AsyncBuffer:
 
 
 # ---------------------------------------------------------------------------
-# open-loop world simulation (the --async-bench stage + its test pin)
+# open-loop world simulation (tests/test_async.py's acceptance shape)
 # ---------------------------------------------------------------------------
 
 
@@ -221,8 +221,8 @@ def simulate_open_loop(
     re-sync forever, with seeded lognormal per-result latencies
     (``sigma`` controls the straggler tail). Aggregators are SERIAL
     resources — a fold occupies the aggregator for ``fold_cost_s``
-    (the real per-arrival cost the bench measures on the live
-    AsyncBuffer code) and an emission for ``emit_cost_s``.
+    (the per-arrival cost of the live AsyncBuffer code) and an
+    emission for ``emit_cost_s``.
 
     Topology: clients are dealt round-robin over ``n_leaves`` leaf
     aggregators; each leaf forwards one partial upstream every
@@ -243,9 +243,9 @@ def simulate_open_loop(
     ROADMAP item 1).
 
     This is a MODEL of the control plane, not a wall-clock
-    measurement: the aggregation costs are real (measured), the
+    measurement: the aggregation costs are the caller's, the
     client latencies are a seeded synthetic population, and virtual
-    time makes the result exactly reproducible — the bench records the
+    time makes the result exactly reproducible — it gives the
     scaling SHAPE (emits/sec vs fan-in), never absolute device time.
     """
     if n_clients < 1 or n_leaves < 1 or buffer_k < 1:

@@ -2,10 +2,8 @@
 
 Every simulated client in the stacked round is a row of a ``[C, ...]``
 operand inside one compiled program, so HBM grows linearly with cohort
-size — the O(C) law ``bench.py --mem-bench`` pinned
-(``peak_round_hbm_mb_c{8,64,256}``: 0.62 → 4.5 → 18.0 MB) and the
-reason the 10k-client acceptance previously ran in a discrete-event
-model instead of real training. This module is the FedJAX
+size — the O(C) law ``tests/test_memscope.py`` pins on the round
+program's argument bytes. This module is the FedJAX
 ``for_each_client`` idiom (PAPERS.md), ROADMAP item 2: stream the
 sampled cohort through the device in fixed-size **blocks** of ``B``
 clients. Each block runs the existing vmapped local update and is

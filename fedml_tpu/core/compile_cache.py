@@ -1,6 +1,6 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``,
+One rule for every entry point (``chip_smoke.py``,
 ``fedml_tpu.experiments.run``, ``tests/conftest.py``): where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and nothing is set
 in code; where it is not, the cache is one fixed directory inside the

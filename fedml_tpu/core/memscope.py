@@ -175,8 +175,8 @@ def note_program(family: str, key, compiled,
 
 
 def program_record(family: str, key) -> dict | None:
-    """Read one recorded program's accounting (bench's ``--mem-bench``
-    stage and the smoke assertions)."""
+    """Read one recorded program's accounting (the smoke scripts'
+    and the tests' assertions)."""
     with _LOCK:
         rec = _PROGRAMS.get(program_slug(family, key))
         return dict(rec) if rec is not None else None

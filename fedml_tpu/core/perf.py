@@ -1,17 +1,11 @@
 """Performance observability: device-time breakdowns, MFU gauges, and
-the analytic round-cost model shared with ``bench.py``.
+the analytic round-cost model behind them.
 
-ROADMAP item 5 diagnosed the headline problem — ~19 rounds/s at ~5% MFU
-— but until now the only device-time evidence lived in one-off scripts
-(``scripts/profile_round.py``) that nothing in the runtime ever ran,
-and ``bench.py``'s ``mfu < 0.005`` warning fired once into a JSON line
-nobody monitors. This module promotes that ad-hoc layer into a
-first-class runtime subsystem (docs/OBSERVABILITY.md "Performance
-observability"):
+The runtime's own device-time evidence (docs/OBSERVABILITY.md
+"Performance observability"):
 
 - :func:`useful_round_cost` — the analytic USEFUL-FLOPs model of one
-  FedAvg round (moved here from ``bench.py:406`` so the bench and the
-  runtime MFU gauge share ONE definition and can never drift);
+  FedAvg round, the ONE definition behind the runtime MFU gauge;
 - :class:`RoundProfiler` — programmatic ``jax.profiler`` capture
   windows around the first K compiled rounds (``--profile_rounds K`` /
   ``FedConfig.profile_rounds``), each parsed into a per-round
@@ -26,7 +20,7 @@ observability"):
   same cost model over a smoothed round rate, plus the
   **dispatch-bound detector**: ``mfu < mfu_floor`` becomes a
   ``perf.dispatch_bound_rounds`` counter, a ``perf.latency_bound``
-  gauge, and a flight-recorder event instead of a one-shot bench note;
+  gauge, and one flight-recorder event a run;
 - trace parsing (:func:`load_trace_events`,
   :func:`device_time_breakdown`) over the ``*.trace.json.gz``
   Chrome-trace files ``jax.profiler`` writes — dependency-free (no
@@ -56,7 +50,7 @@ import numpy as np
 from fedml_tpu.core import telemetry
 
 # ---------------------------------------------------------------------------
-# chip peaks + the analytic round-cost model (shared with bench.py)
+# chip peaks + the analytic round-cost model
 # ---------------------------------------------------------------------------
 
 # (bf16 peak FLOP/s, HBM bandwidth B/s, HBM capacity bytes) per chip.
@@ -100,10 +94,9 @@ def useful_round_cost(sim) -> float | None:
     skipped at runtime) and HLO cost analysis counts loop bodies once —
     so MFU is reported against the work the *semantics* require, making
     it an honest utilization number: padding waste and grouped-conv
-    expansion lower it, exactly as they should. ONE definition, shared
-    by ``bench.py``'s record fields and the runtime ``perf.mfu`` gauge
-    (:class:`PerfMonitor`), so the two can never drift. (Bytes moved
-    are handled separately by ``bench.compulsory_round_bytes``.)"""
+    expansion lower it, exactly as they should. The runtime
+    ``perf.mfu`` gauge (:class:`PerfMonitor`) reads this definition.
+    Bytes moved are not modelled here."""
     import jax
     import jax.numpy as jnp
 
@@ -568,19 +561,16 @@ class PerfMonitor:
       percentile estimation — the round-latency SLO surface);
     - ``perf.rounds_per_s`` gauge (EWMA-smoothed);
     - ``perf.mfu`` / ``perf.delivered_flops_per_s`` gauges when the
-      analytic round cost and the chip peak are known — the SAME
-      :func:`useful_round_cost` model as ``bench.py``, so the live
-      gauge and the bench record agree by construction;
-    - the detector: ``mfu < mfu_floor`` (bench's one-shot 0.005
-      warning, now a runtime signal) increments
+      analytic round cost (:func:`useful_round_cost`) and the chip
+      peak are known;
+    - the detector: ``mfu < mfu_floor`` (default 0.005) increments
       ``perf.dispatch_bound_rounds``, sets ``perf.latency_bound`` and
       leaves ONE flight-recorder event per run — the round is bounded
       by dispatch/lowering latency, not the MXU.
 
     The first ``warmup_rounds`` rounds (default 1) are EXCLUDED from
     the histogram, the EWMA, and the detector — round 0's wall is
-    dominated by the XLA compile (bench.py pays the same discipline
-    with its explicit warmup execution), and folding it in would both
+    dominated by the XLA compile, and folding it in would both
     skew the p99 the docs call the SLO surface and spuriously consume
     the per-run dispatch-bound event on a healthy run. The skipped
     wall is still visible as the ``perf.warmup_round_wall_s`` gauge.

@@ -105,8 +105,8 @@ class _SubWriter:
     """Per-connection outbound queue + writer thread. Routing threads
     enqueue and move on; only THIS thread ever blocks on the subscriber's
     socket, so one wedged consumer cannot stall routing from any
-    publisher (ADVICE round-5: the old per-connection write lock held the
-    publisher's reader thread hostage)."""
+    publisher (a write lock per connection would hold the publisher's
+    reader thread hostage)."""
 
     def __init__(self, conn: socket.socket, on_dead):
         self.conn = conn

@@ -488,7 +488,7 @@ def load_dataset(cfg: DataConfig) -> FederatedData:
         # checked BEFORE the synthetic_(a)_(b) prefix family below:
         # the EXPLICITLY-REQUESTED seeded StackOverflow-shaped
         # stand-in (same vocab ids and [B, T] int32 contract as the
-        # real TFF split) — how CI/bench run the transformer workload
+        # real TFF split) — how CI runs the transformer workload
         # without the 3424-client download. Deliberately a distinct
         # dataset name: a typo'd --data_dir on the real dataset must
         # hard-fail, never silently train on synthetic data

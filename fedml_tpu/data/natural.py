@@ -529,7 +529,7 @@ def synthetic_stackoverflow_nwp(
     like the real split. Surfaced as the EXPLICIT dataset name
     ``synthetic_stackoverflow_nwp`` (data/loaders.py) and as
     :func:`load_stackoverflow_nwp`'s ``fallback_clients`` opt-in, so
-    CI and the bench can run the transformer workload offline — the
+    CI can run the transformer workload offline — the
     real dataset name with missing files still fails loudly."""
     rng = np.random.default_rng(seed)
     V = vocab_size

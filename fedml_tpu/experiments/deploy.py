@@ -224,8 +224,7 @@ def _server_dead_peer_cb(server: ServerManager):
     death — the round closes over the survivors or aborts with a quorum
     diagnostic. Actors without it (SplitNN's strictly-sequential
     round-robin cannot skip a rank) record the failure and stop the
-    transport, which is exactly the "fail loudly instead of hanging"
-    contract from ADVICE round-5 (``deploy.py:125``)."""
+    transport: fail loudly instead of hanging."""
 
     def on_dead(rank: int) -> None:
         handler = getattr(server, "on_peer_dead", None)
@@ -1011,7 +1010,7 @@ class Supervisor:
 
         from fedml_tpu.analysis.flags import check_rank_argv
 
-        # one registration contract across run.py/bench.py/this
+        # one registration contract across run.py and this
         # supervisor (fedml_tpu/analysis/flags.py): a client argv
         # carrying a rank-0-only bind flag (--metrics_port) means the
         # caller built its RankSpecs without run.py's strip — fail at

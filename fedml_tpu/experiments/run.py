@@ -497,8 +497,7 @@ def parse_args(argv=None) -> tuple[ExperimentConfig, argparse.Namespace]:
     # the shared registration checker (fedml_tpu/analysis/flags.py):
     # run.py OWNS the reserved --slo/--metrics_port names, so owner
     # mode asserts they are registered AND nothing is duplicated —
-    # bench.py and the supervisor run the non-owner side of the same
-    # contract
+    # the supervisor runs the non-owner side of the same contract
     from fedml_tpu.analysis.flags import check_flag_registry
 
     check_flag_registry(p, owner=True,

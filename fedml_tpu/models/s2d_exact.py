@@ -42,8 +42,7 @@ training/eval (stage-1 channels 4x wider); in the cohort-grouped
 federated round it is a wash (~49 vs 48 ms headline) — the grouped
 convs dense-expand either way, so the 4x stage-1 FLOP inflation cancels
 the width win. Use it for parity-preserving single-model work
-(centralized training, evaluation, GKT-style warm starts); the bench's
-default story remains ``resnet56_s2d``.
+(centralized training, evaluation, GKT-style warm starts).
 """
 
 from __future__ import annotations

@@ -143,7 +143,7 @@ def compound_wire_ratio(plan: "PeftPlan", cspec, params: Pytree) -> float:
     codec-compressed) bytes of the aggregated adapter subtree — the
     multiplicative stack of the partition (adapter/full) and the PR 7
     codec (compressed/dense), reported as the ``peft.wire_ratio``
-    gauge and tracked by the ``lora_wire_reduction_x`` bench record."""
+    gauge."""
     from fedml_tpu.core import compress as C
     from fedml_tpu.peft.partition import _leaf_bytes
 

@@ -2,7 +2,7 @@
 """fedlint CLI: project-invariant static analysis with a CI ratchet.
 
 Usage:
-    python scripts/fedlint.py fedml_tpu/ [bench.py scripts/ ...]
+    python scripts/fedlint.py fedml_tpu/ [scripts/ ...]
         [--baseline fedlint_baseline.json] [--write-baseline]
         [--json out.json] [--rules jit-purity,lock-hygiene]
         [--config fedlint.json] [--root .] [--list-rules]

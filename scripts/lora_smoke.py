@@ -10,8 +10,7 @@ A CPU-only end-to-end pass over the adapter subsystem
    optimizer state, no delta, no drift;
 3. the per-round wire bytes of the adapter+head subtree with the
    codec stacked are <= 1/50 of the full-delta payload at the SAME
-   shape (the delta-size law the bench tracks as
-   ``lora_wire_reduction_x``);
+   shape (the delta-size law, the ``peft.wire_ratio`` gauge);
 4. the donation audit reports zero misses on the partitioned round
    program;
 5. the ``peft.*`` vocabulary is live on a real ``/metrics`` scrape

@@ -12,8 +12,8 @@ Tiers of coverage:
    flat world's aggregate; per-tier quarantine isolation (a leaf's
    Byzantine client never pollutes the sibling leaf's reputation);
 4. the open-loop acceptance pin: async emit throughput SCALES with
-   aggregator fan-in while sync FedAvg saturates flat (the
-   ``--async-bench`` shape, pinned on fixed costs);
+   aggregator fan-in while sync FedAvg saturates flat (pinned on
+   fixed costs);
 5. the SIGKILL e2e: an async gRPC root is killed mid-run with folds
    pending; the relaunched incarnation restores the staleness buffer
    — not just the params — from the round checkpoint and converges;
@@ -444,16 +444,14 @@ def test_async_progress_deadline_unwedges_silent_member():
 
 
 # ---------------------------------------------------------------------------
-# 4. the open-loop acceptance pin (the --async-bench shape)
+# 4. the open-loop acceptance pin
 # ---------------------------------------------------------------------------
 
 
 def test_open_loop_async_scales_sync_saturates():
     """ROADMAP item 1's acceptance shape, on FIXED aggregation costs
     so the pin is deterministic: emit throughput scales with fan-in
-    1 -> 4 while the synchronous barrier saturates flat. The bench
-    stage (`bench.py --async-bench`) records the same shape with
-    MEASURED costs."""
+    1 -> 4 while the synchronous barrier saturates flat."""
     kw = dict(n_clients=10_000, buffer_k=4, flush_every=8,
               horizon_s=5.0, seed=0, fold_cost_s=4e-4,
               emit_cost_s=2e-3)

@@ -4,7 +4,7 @@ The TPU compiler is installed without a chip attached: it compiles for
 a topology that is described, not present, and refuses what the chip
 would refuse (tiling, fast-memory and HBM limits, partitioning). These
 tests keep the Pallas kernel, the ResNet-56 cohort local update at
-bench width and the four-chip ``ShardedFedAvg`` round compiling for
+headline width and the four-chip ``ShardedFedAvg`` round compiling for
 ``v5e:2x2`` at no chip time. Nothing runs: a compile that passes is not
 a chip run (``chip_smoke.py`` is).
 
@@ -301,14 +301,14 @@ def test_grouped_expert_products_compile_for_v5e(one_chip):
 def test_resnet56_cohort_update_compiles_for_v5e(one_chip):
     """The headline local update: the whole 10-client cohort as one
     widened ResNet-56 (``ops/cohort_conv``'s custom primitive), batch
-    32, bf16 — ``bench.headline_config``'s widths."""
-    import bench
+    32, bf16 — ``chip_smoke.headline_config``'s widths."""
+    import chip_smoke
     from fedml_tpu.algorithms.base import build_cohort_local_update
     from fedml_tpu.algorithms.fedavg import FedAvgSim
     from fedml_tpu.data.loaders import load_dataset
     from fedml_tpu.models import create_model
 
-    cfg = bench.headline_config()
+    cfg = chip_smoke.headline_config()
     assert cfg.train.compute_dtype == "bfloat16"
     sim = FedAvgSim(create_model(cfg.model), load_dataset(cfg.data), cfg)
     cohort = cfg.fed.clients_per_round
@@ -341,12 +341,12 @@ def mesh_sim(topo):
     """The headline job as ``ShardedFedAvg`` built on four of this
     process's devices, its mesh then steered onto the described chips:
     same axes, same layout."""
-    import bench
+    import chip_smoke
     from fedml_tpu.data.loaders import load_dataset
     from fedml_tpu.models import create_model
     from fedml_tpu.parallel import ShardedFedAvg, make_mesh
 
-    cfg = bench.headline_config()
+    cfg = chip_smoke.headline_config()
     cfg = dataclasses.replace(
         cfg, fed=dataclasses.replace(cfg.fed, clients_per_round=8)
     )

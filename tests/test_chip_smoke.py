@@ -1,8 +1,10 @@
 """What keeps a run without a chip from passing for a run on one:
-``chip_smoke.py`` fails on the CPU, ``bench.py`` has no fallback, the
-compile cache is placed in one way, the peaks table refuses an unknown
-TPU, and the ``--supervise`` parent stays off every backend."""
+``chip_smoke.py`` fails on the CPU, no program that reaches the chip
+has a fallback, the compile cache is placed in one way, the peaks table
+refuses an unknown TPU, and the ``--supervise`` parent stays off every
+backend."""
 
+import importlib.util
 import inspect
 import os
 import subprocess
@@ -72,13 +74,23 @@ def test_unknown_tpu_kind_is_an_error_not_a_default():
         perf.device_peaks(unknown)
 
 
-def test_bench_has_no_fallback_path():
-    import bench
-
-    leftovers = [n for n in dir(bench)
+@pytest.mark.parametrize("entry", ["chip_smoke.py", "benchmarks/run.py"])
+def test_entry_point_has_no_fallback_path(entry):
+    """The programs that reach the chip have no probe child and no
+    road to another backend."""
+    spec = importlib.util.spec_from_file_location(
+        "entry_point_under_test", os.path.join(REPO, entry)
+    )
+    mod = importlib.util.module_from_spec(spec)
+    path = list(sys.path)  # benchmarks/run.py puts its own directory first
+    try:
+        spec.loader.exec_module(mod)
+    finally:
+        sys.path[:] = path
+    leftovers = [n for n in dir(mod)
                  if "fallback" in n.lower() or "probe" in n.lower()]
     assert leftovers == []
-    src = inspect.getsource(bench.main)
+    src = inspect.getsource(mod.main)
     assert "subprocess" not in src and "--fallback" not in src
 
 

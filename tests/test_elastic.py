@@ -982,7 +982,7 @@ def test_manager_finish_reason_captured():
 def test_supervised_elastic_deploy_join_leave_sigkill(tmp_path):
     """The PR's end-to-end contract: a supervised 1-server + 2-client
     gRPC world runs with --elastic; client rank 3 (beyond the launch
-    world) is spawned mid-run and ADMITTED; client 2 LEAVEs gracefully
+    world) JOINs mid-run and is ADMITTED; client 2 LEAVEs gracefully
     after round 3; once a checkpoint carrying both membership events
     lands, the server is SIGKILLed; its restarted incarnation restores
     the ledger (serves {1, 3}, does not wait for the departed rank 2),
@@ -1033,34 +1033,27 @@ def test_supervised_elastic_deploy_join_leave_sigkill(tmp_path):
             errors.append(e)
 
     t = threading.Thread(target=drive, daemon=True)
-    t.start()
 
+    import socket
     import subprocess
 
-    def unsup(r, *extra):
-        log = open(tmp_path / f"rank{r}.log", "w")
+    def unsup(r, *extra, log_name=None):
+        log = open(tmp_path / (log_name or f"rank{r}.log"), "w")
         proc = subprocess.Popen(
             client(r, *extra), env=_subproc_env(), cwd=REPO,
             stdout=log, stderr=subprocess.STDOUT,
         )
         return proc, log
 
-    leaver, leaver_log = unsup(2, "--leave_after_round",
-                               str(leave_after))
-
-    # spawn the LATE JOINER (rank 3, beyond world_size=3) once the
-    # world is demonstrably past round 0 (first checkpoint on disk)
     ckpt_dir = os.path.join(str(tmp_path), "deploy", "ckpt")
     metrics0 = tmp_path / "telemetry" / "metrics_rank0.json"
     late_procs = []
     late_stop = threading.Event()
 
     def spawn_late():
-        log = open(tmp_path / f"rank3_try{len(late_procs)}.log", "w")
-        late_procs.append((subprocess.Popen(
-            client(3), env=_subproc_env(), cwd=REPO,
-            stdout=log, stderr=subprocess.STDOUT,
-        ), log))
+        late_procs.append(
+            unsup(3, log_name=f"rank3_try{len(late_procs)}.log"))
+        return late_procs[-1][0]
 
     def babysit_late():
         # the late joiner lives OUTSIDE the Supervisor (whose world is
@@ -1077,16 +1070,48 @@ def test_supervised_elastic_deploy_join_leave_sigkill(tmp_path):
             time.sleep(0.1)
 
     babysitter = threading.Thread(target=babysit_late, daemon=True)
-    killed = False
-    deadline = time.monotonic() + 300
+    leaver = leaver_log = None
+    killed = released = False
     try:
+        # the LATE JOINER (rank 3, beyond world_size=3) starts FIRST,
+        # before there is a server, and is held (SIGSTOP) in its
+        # announce loop: its start-up — under a busy machine longer
+        # than the world's ten rounds — is then no part of the race
+        # between its JOIN and the world's end. The event is its
+        # endpoint accepting a connection: the transport starts after
+        # model and data are built, right before the first JOIN,
+        # which finds no server to reach.
+        late = spawn_late()
+        warm_by = time.monotonic() + 240
+        while True:
+            assert late.poll() is None, (
+                "late joiner died warming up: "
+                + (tmp_path / "rank3_try0.log").read_text()[-2000:]
+            )
+            try:
+                socket.create_connection(
+                    ("127.0.0.1", ports[3]), timeout=1).close()
+                break
+            except OSError:
+                assert time.monotonic() < warm_by, "late joiner never up"
+                time.sleep(0.05)
+        os.kill(late.pid, signal.SIGSTOP)
+
+        t.start()
+        leaver, leaver_log = unsup(2, "--leave_after_round",
+                                   str(leave_after))
+        deadline = time.monotonic() + 300
         while time.monotonic() < deadline and not killed:
             steps = []
             if os.path.isdir(ckpt_dir):
                 steps = [int(d) for d in os.listdir(ckpt_dir)
                          if d.isdigit()]
-            if not late_procs and steps:
-                spawn_late()
+            if not released and steps:
+                # the world is demonstrably past round 0 (first
+                # checkpoint on disk): the held joiner's next JOIN is
+                # a MID-RUN admission
+                os.kill(late.pid, signal.SIGCONT)
+                released = True
                 babysitter.start()
             counters = {}
             if metrics0.exists():
@@ -1104,6 +1129,10 @@ def test_supervised_elastic_deploy_join_leave_sigkill(tmp_path):
                 if proc is not None and proc.poll() is None:
                     os.kill(proc.pid, signal.SIGKILL)
                     killed = True
+            if not killed and (
+                    not t.is_alive()
+                    or "transport.bytes_by_type.finish" in counters):
+                break  # the world ended first: nothing left to wait for
             time.sleep(0.05)
         assert killed, (
             "join+leave-covering checkpoint never appeared "
@@ -1151,7 +1180,8 @@ def test_supervised_elastic_deploy_join_leave_sigkill(tmp_path):
         assert checked >= 1
     finally:
         late_stop.set()
-        for proc, log in (*late_procs, (leaver, leaver_log)):
+        owned = late_procs + [(leaver, leaver_log)] * (leaver is not None)
+        for proc, log in owned:
             if proc.poll() is None:
                 proc.kill()
             log.close()

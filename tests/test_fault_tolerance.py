@@ -439,8 +439,7 @@ def test_ready_is_acked_before_barrier_completes():
     """A client that announces READY gets the S2C ACK immediately — even
     while the barrier is still waiting on other ranks. Pre-ACK, a
     later-rank SplitNN client could only learn the server was alive from
-    its first WORK message, which may be minutes away (ADVICE round-5,
-    deploy.py:128)."""
+    its first WORK message, which may be minutes away."""
     from fedml_tpu.experiments.deploy import (
         DeployConfig,
         _announce_until_first_message,

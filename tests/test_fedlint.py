@@ -1097,13 +1097,12 @@ class TestPreFixViolations:
 
     def test_whole_tree_scan_is_clean(self):
         """The e2e acceptance pin: fedlint over the real fedml_tpu/ +
-        bench.py + scripts/ exits 0 with the SHIPPED baseline (and the
+        scripts/ (what ci.sh scans) exits 0 with the SHIPPED baseline (and the
         shipped baseline is genuinely empty: every pre-existing
         violation was fixed, not frozen)."""
         r = subprocess.run(
             [sys.executable, "scripts/fedlint.py", "fedml_tpu",
-             "bench.py", "scripts", "--baseline",
-             "fedlint_baseline.json"],
+             "scripts", "--baseline", "fedlint_baseline.json"],
             capture_output=True, text=True, cwd=REPO,
             env=dict(os.environ, PYTHONPATH=REPO))
         assert r.returncode == 0, r.stdout + r.stderr
@@ -1126,12 +1125,12 @@ class TestFlagRegistry:
 
     def test_non_owner_clean(self):
         check_flag_registry(self._parser("--rounds", "--family"),
-                            entrypoint="bench.py")
+                            entrypoint="chip_smoke.py")
 
     def test_non_owner_reserved_rejected(self):
         with pytest.raises(SystemExit, match="--slo"):
             check_flag_registry(self._parser("--rounds", "--slo"),
-                                entrypoint="bench.py")
+                                entrypoint="chip_smoke.py")
 
     def test_owner_must_register_reserved(self):
         p = self._parser("--slo", "--metrics_port")
@@ -1139,17 +1138,6 @@ class TestFlagRegistry:
         with pytest.raises(SystemExit, match="metrics_port"):
             check_flag_registry(self._parser("--slo"), owner=True,
                                 entrypoint="run")
-
-    def test_bench_reexports_reserved_names(self):
-        # callers pinned bench.RESERVED_RUN_FLAGS before the helper
-        # moved to fedml_tpu.analysis.flags — the re-export must hold
-        sys.path.insert(0, REPO)
-        try:
-            import bench
-        finally:
-            sys.path.pop(0)
-        assert bench.RESERVED_RUN_FLAGS == RESERVED_RUN_FLAGS
-        assert set(RESERVED_RUN_FLAGS) == {"--slo", "--metrics_port"}
 
     def test_rank_argv_check(self):
         check_rank_argv(["run", "--metrics_port", "0"], rank=0)

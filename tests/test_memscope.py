@@ -16,9 +16,7 @@ The pins, in dependency order:
     undonated control is flagged (``mem.donation_misses`` + one flight
     event naming the program), and the count never double-fires for
     one program;
-4.  the bench surface: ``peak_round_hbm_mb_c{C}_k{K}`` record shape,
-    the ``MB peak`` unit diffing lower-is-better, and bench_diff
-    refusing a fallback-vs-clean pair for the new unit;
+4.  the capacity column of the peaks table;
 5.  ``/metrics`` exposition of a registry carrying ``mem.*`` gauges +
     compile histograms passes the PR 11 STRICT parser (the renderer
     still never grades its own homework);
@@ -317,63 +315,8 @@ def test_audit_empty_leaves_is_vacuously_ok(metrics_on):
 
 
 # ---------------------------------------------------------------------------
-# 4. the bench surface
+# 4. the peaks table's capacity column
 # ---------------------------------------------------------------------------
-
-
-def test_mem_bench_record_shape(metrics_on):
-    import bench
-
-    records = bench.mem_bench_records(cohorts=(4, 8), fuses=(1, 2))
-    assert {r["metric"] for r in records} == {
-        "peak_round_hbm_mb_c4_k1", "peak_round_hbm_mb_c4_k2",
-        "peak_round_hbm_mb_c8_k1", "peak_round_hbm_mb_c8_k2",
-    }
-    for r in records:
-        assert r["unit"] == "MB peak"
-        assert r["value"] > 0
-        assert r["temp_mb"] >= 0 and r["argument_mb"] > 0
-        assert isinstance(r["analytic"], bool)
-        # on the CPU backend there is no allocator peak: the value is
-        # the analytic temp+argument bytes and says so
-        if jax.default_backend() == "cpu":
-            assert r["analytic"] is True
-            np.testing.assert_allclose(
-                r["value"], round(r["temp_mb"] + r["argument_mb"], 3),
-                atol=2e-3,
-            )
-    by = {r["metric"]: r for r in records}
-    assert (by["peak_round_hbm_mb_c8_k1"]["argument_mb"]
-            > by["peak_round_hbm_mb_c4_k1"]["argument_mb"])
-
-
-def test_mb_peak_unit_diffs_lower_is_better():
-    from scripts import bench_diff
-
-    assert bench_diff._direction("MB peak") == (-1, True)
-    old = {"peak_round_hbm_mb_c8_k1": {
-        "metric": "peak_round_hbm_mb_c8_k1", "value": 10.0,
-        "unit": "MB peak"}}
-    worse = {"peak_round_hbm_mb_c8_k1": {
-        "metric": "peak_round_hbm_mb_c8_k1", "value": 20.0,
-        "unit": "MB peak"}}
-    d = bench_diff.diff_records(old, worse, threshold=0.08)
-    assert len(d["regressions"]) == 1  # memory UP is a regression
-    d = bench_diff.diff_records(worse, old, threshold=0.08)
-    assert len(d["improvements"]) == 1
-
-
-def test_bench_diff_refuses_fallback_pair_for_mb_peak():
-    from scripts import bench_diff
-
-    fb = {"peak_round_hbm_mb_c8_k1": {
-        "metric": "peak_round_hbm_mb_c8_k1", "value": 10.0,
-        "unit": "MB peak", "fallback": "cpu"}}
-    clean = {"peak_round_hbm_mb_c8_k1": {
-        "metric": "peak_round_hbm_mb_c8_k1", "value": 5.0,
-        "unit": "MB peak"}}
-    d = bench_diff.diff_records(fb, clean, threshold=0.08)
-    assert len(d["skipped"]) == 1 and not d["regressions"]
 
 
 def test_peaks_table_has_capacity_column():

@@ -666,7 +666,7 @@ def test_real_leaf_synthetic_fedavg_learns():
     """FedAvg + LR on the REAL synthetic(1,1) data with the reference
     benchmark hyperparameters (30 clients, 10/round, batch 10, SGD lr
     .01) climbs well past chance within 30 rounds — the short-horizon
-    version of the >60-acc-at-200-rounds row bench.py reproduces."""
+    version of the >60-acc-at-200-rounds row of BASELINE.md."""
     from fedml_tpu.algorithms.fedavg import FedAvgSim
     from fedml_tpu.models import create_model
 

@@ -1,5 +1,5 @@
 """Performance-observability suite (core/perf.py + the percentile /
-time-series / bench-fallback satellites; docs/OBSERVABILITY.md
+time-series satellites; docs/OBSERVABILITY.md
 "Performance observability").
 
 The pins, in dependency order:
@@ -14,24 +14,18 @@ The pins, in dependency order:
    actual XLA ops in it;
 3. ``useful_round_cost`` equals a hand-lowered ``cost_analysis``
    step-FLOPs value times the sampled-work multiplier, and the live
-   ``perf.mfu`` gauge agrees with the bench-style analytic MFU by
-   construction (the acceptance bar is 10%; shared model makes it
-   exact for equal rate estimates);
+   ``perf.mfu`` gauge agrees with the analytic MFU over the same rate
+   (the acceptance bar is 10%);
 4. the dispatch-bound detector turns ``mfu < floor`` into the
    ``perf.*`` counter + flight-recorder event;
 5. percentile estimation: exact for single-valued histograms, bounded
    by the power-of-two bucket width across buckets, surfaced in
    ``snapshot()``, ``summary.json``, and the periodic
-   ``metrics_rank<r>.jsonl`` time series;
-6. the marked CPU-fallback bench record shape, and ``bench_diff.py``
-   flagging a seeded regression while refusing fallback-vs-TPU
-   comparisons.
+   ``metrics_rank<r>.jsonl`` time series.
 """
 
-import importlib.util
 import json
 import os
-import sys
 import time
 
 import pytest
@@ -41,8 +35,6 @@ from fedml_tpu.core.telemetry import (
     MetricsRegistry,
     percentiles_from_histogram,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -250,16 +242,6 @@ def test_useful_round_cost_matches_hand_computed_cost_analysis():
                                                          rel=1e-6)
 
 
-def test_bench_imports_the_shared_cost_model():
-    import bench
-
-    # one definition: the bench's mfu field and the runtime gauge can
-    # never drift (the ISSUE's acceptance bar is agreement within 10%;
-    # a shared function makes it exact for equal rate estimates)
-    assert bench.useful_round_cost is perf.useful_round_cost
-    assert bench.PEAKS is perf.PEAKS
-
-
 def test_perf_monitor_warmup_round_is_excluded(telem):
     telemetry.METRICS.reset()
     mon = perf.PerfMonitor(flops_per_round=1e9, peak_flops=1e12)
@@ -285,9 +267,9 @@ def test_perf_monitor_mfu_gauge_agrees_with_analytic(telem):
     assert g["perf.rounds_per_s"] == pytest.approx(1000.0)
     assert g["perf.delivered_flops_per_s"] == pytest.approx(1e12)
     assert g["perf.latency_bound"] == 0.0
-    # bench-style analytic MFU over the same rate: identical (<10%)
-    bench_mfu = 1e9 * g["perf.rounds_per_s"] / 1e12
-    assert abs(g["perf.mfu"] - bench_mfu) <= 0.1 * bench_mfu
+    # the analytic MFU over the same rate: identical (<10%)
+    analytic_mfu = 1e9 * g["perf.rounds_per_s"] / 1e12
+    assert abs(g["perf.mfu"] - analytic_mfu) <= 0.1 * analytic_mfu
     # the wall-time histogram is the SLO surface
     h = telemetry.METRICS.snapshot()["histograms"]["perf.round_wall_s"]
     assert h["count"] == 1 and "p50" in h
@@ -461,91 +443,3 @@ def test_metrics_timeseries_appends_rows(tmp_path):
     assert h["p50"] == pytest.approx(0.25)
     assert "buckets" not in h  # rows are compact; the .json keeps them
     assert rows[0]["ts"] <= last["ts"]
-
-
-# ---------------------------------------------------------------------------
-# 6. bench_diff
-# ---------------------------------------------------------------------------
-
-
-def _bench_diff():
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff", os.path.join(REPO, "scripts", "bench_diff.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _write_jsonl(path, recs):
-    with open(path, "w") as f:
-        for r in recs:
-            f.write(json.dumps(r) + "\n")
-    return str(path)
-
-
-def test_bench_diff_flags_seeded_regression(tmp_path):
-    bd = _bench_diff()
-    old = _write_jsonl(tmp_path / "old.jsonl", [
-        {"metric": "fedavg_rounds_per_sec_x", "value": 20.0,
-         "unit": "rounds/sec"},
-        {"metric": "time_to_acc", "value": 10.0, "unit": "seconds"},
-        {"metric": "steady", "value": 5.0, "unit": "rounds/sec"},
-    ])
-    new = _write_jsonl(tmp_path / "new.jsonl", [
-        {"metric": "fedavg_rounds_per_sec_x", "value": 10.0,
-         "unit": "rounds/sec"},  # -50%: regression (higher is better)
-        {"metric": "time_to_acc", "value": 20.0,
-         "unit": "seconds"},  # +100%: regression (lower is better)
-        {"metric": "steady", "value": 5.1,
-         "unit": "rounds/sec"},  # +2%: inside the noise threshold
-    ])
-    d = bd.diff_records(bd.load_bench(old), bd.load_bench(new),
-                        threshold=0.08)
-    flagged = {e["metric"] for e in d["regressions"]}
-    assert flagged == {"fedavg_rounds_per_sec_x", "time_to_acc"}
-    assert {e["metric"] for e in d["unchanged"]} == {"steady"}
-    # advisory mode exits 0, --strict exits 1
-    assert bd.main([old, new]) == 0
-    assert bd.main([old, new, "--strict"]) == 1
-
-
-def test_bench_diff_never_compares_fallback_to_tpu(tmp_path):
-    bd = _bench_diff()
-    old = _write_jsonl(tmp_path / "old.jsonl", [
-        {"metric": "m", "value": 20.0, "unit": "rounds/sec",
-         "device": "TPU v5 lite"},
-    ])
-    new = _write_jsonl(tmp_path / "new.jsonl", [
-        {"metric": "m", "value": 0.5, "unit": "rounds/sec",
-         "fallback": "cpu"},  # 40x slower, but a MARKED cpu record
-    ])
-    d = bd.diff_records(bd.load_bench(old), bd.load_bench(new),
-                        threshold=0.08)
-    assert d["regressions"] == []
-    assert len(d["skipped"]) == 1
-    assert "fallback" in d["skipped"][0]["reason"]
-    assert bd.main([old, new, "--strict"]) == 0
-
-
-def test_bench_diff_reads_driver_wrapper_artifacts(tmp_path):
-    bd = _bench_diff()
-    tail = (
-        '[bench] noise line\n'
-        '{"metric": "m", "value": 19.0, "unit": "rounds/sec"}\n'
-    )
-    old = tmp_path / "BENCH_r04.json"
-    old.write_text(json.dumps(
-        {"n": 4, "cmd": "python bench.py", "rc": 0, "tail": tail}
-    ))
-    # a run that produced nothing: rc=3, no records at all
-    new = tmp_path / "BENCH_r05.json"
-    new.write_text(json.dumps(
-        {"n": 5, "cmd": "python bench.py", "rc": 3,
-         "tail": "[bench] FATAL: ...\n", "parsed": None}
-    ))
-    assert bd.load_bench(str(old)) == {
-        "m": {"metric": "m", "value": 19.0, "unit": "rounds/sec"}
-    }
-    assert bd.load_bench(str(new)) == {}
-    assert bd.main([str(old), str(new)]) == 0  # advisory, never crashes
