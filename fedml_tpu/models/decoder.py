@@ -3,8 +3,9 @@
 ``ModelConfig.extra``, so a published ``config.json`` — cut to a chip's
 share — is a configuration and not a new module.
 
-Layer ``l`` is pre-norm residual blocks (RMSNorm, no bias but the
-state-space convolution's): first the sequence mixer ``layer_types[l]``
+Layer ``l`` is pre-norm residual blocks (RMSNorm, no bias but a
+convolution's, where its record asks for one): first the sequence mixer
+``layer_types[l]``
 names, then the feed-forward ``mlp_layer_types[l]`` names. Either may
 be ``none`` (not both), so a layer can be a mixer alone, ``x +
 mixer(norm(x))``, and a published pattern of single-mixer layers is a
@@ -62,13 +63,26 @@ configuration; any other name is refused.
   S_t C_t + D x_t`` in chunks (:func:`fedml_tpu.ops.ssm.ssd_chunked`);
   ``y <- RMSNorm(y * silu(z)) * scale`` over each group's channels;
   out ``= y W_out``;
+- ``short_conv`` (a gated short convolution; sizes in the
+  ``short_conv`` record: ``kernel`` taps, ``bias`` true or false):
+  ``[B | C | u] = h W_in``, three chunks of the hidden width in this
+  order from ONE projection; ``z = causal depthwise convolution of B *
+  u`` (:func:`causal_depthwise_conv`: ``kernel`` taps a channel, zeros
+  before the sequence, a bias a channel only where ``bias`` is true, NO
+  activation); out ``= (C * z) W_out``. The channels are whole on every
+  chip: a stack with such layers takes no head share. The whole mixer
+  runs under the scope ``fedml.model.conv`` and the two gates and the
+  taps, the part no matrix product does, under ``fedml.model.conv.mix``
+  inside it;
 - feed-forward: ``mlp_layer_types[l]`` is ``dense`` (a gated
   feed-forward of ``intermediate_size``) or ``sparse``
   (:func:`fedml_tpu.ops.moe.moe_layer`: a router over ``num_experts``,
   ``num_experts_per_tok`` a token, probabilities by ``router_scoring``
   — ``sigmoid``, the default, or ``softmax`` over all experts —
   renormalised over the chosen and times ``routed_scaling_factor``,
-  the experts ``experts_held = [first, count]`` of width
+  (``router_renorm_epsilon``, 0 where absent, is added to the sum the
+  chosen probabilities are divided by), the experts ``experts_held =
+  [first, count]`` of width
   ``moe_intermediate_size`` held here, and a shared expert of
   ``shared_expert_intermediate_size``, none at 0). With
   ``router_score_bias`` every sparse layer has a ``router_bias`` leaf
@@ -107,12 +121,18 @@ heads read held key-value heads, evenly);
 What the absent heads, columns and experts would add to a layer's
 output is left out, and nothing stands in for their exchange.
 
-Untied embedding and head over ``vocab_size`` rows. Each layer is
+Embedding and head over ``vocab_size`` rows: two tables (``embed``
+and ``lm_head``) or, with ``tie_word_embeddings`` true, ONE — ``logits
+= x embedding^T`` in the compute dtype, no ``lm_head`` leaf, and the
+table's gradient JAX's sum of its two roads (the lookup's own backward
+rule and the head's product); ``peft/`` is refused beside it
+(:func:`fedml_tpu.peft.lora.apply_lora`). Each layer is
 recomputed in the backward pass (``nn.remat``) but for what
 :data:`KEPT` names. Beside a layer's input it keeps its attention
 kernel's output and row log-sum-exp; in a sparse-attention layer the
 selection; in a state-space layer the states entering each chunk and
-the scan's result; and of a sparse feed-forward the routing (the
+the scan's result; in a short-convolution layer nothing more (the
+mixer runs twice a step); and of a sparse feed-forward the routing (the
 router's logits, the chosen experts and their weights, the order of
 the assignments, where a token's slots find their rows, the rows a
 held expert) and what the held experts' backward rule reads (the row
@@ -174,8 +194,8 @@ NONE = "none"  # a layer without this half; a rope record's ``rope_type``
 FULL, SLIDING, SELECTED, LATENT = (
     "full_attention", "sliding_attention", "sparse_attention",
     "latent_attention")
-STATE_SPACE = "state_space"
-MIXERS = (FULL, SLIDING, SELECTED, LATENT, STATE_SPACE, NONE)
+STATE_SPACE, SHORT_CONV = "state_space", "short_conv"
+MIXERS = (FULL, SLIDING, SELECTED, LATENT, STATE_SPACE, SHORT_CONV, NONE)
 DENSE, SPARSE = "dense", "sparse"
 FEED_FORWARDS = (DENSE, SPARSE, NONE)
 SIGMOID = "sigmoid"  # the router's scoring where the configuration names none
@@ -193,6 +213,10 @@ PAIRINGS = (HALF, ADJACENT)
 # the sizes a ``latent_attention`` record gives
 LATENT_SIZES = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                 "qk_rope_head_dim", "v_head_dim")
+# what a ``short_conv`` record gives, and each key's type
+SHORT_CONV_KEYS = {"kernel": int, "bias": bool}
+# the shares of attention heads a chip may hold
+HEAD_SHARES = ("query_heads_held", "key_value_heads_held")
 
 #: what a rematerialised layer keeps (``checkpoint_name``s)
 KEPT = ATTENTION_KEPT + SCAN_KEPT + MOE_KEPT
@@ -319,13 +343,16 @@ def _log_uniform(low: float, high: float):
     return init
 
 
-def causal_depthwise_conv(x, kernel, bias):
+def causal_depthwise_conv(x, kernel, bias=None):
     """``x`` ``[B, T, C]``, ``kernel`` ``[K, C]``: channel ``c`` at ``t``
-    is ``bias[c] + sum_i kernel[i, c] x[t - (K - 1) + i, c]``, zeros
-    before the sequence."""
+    is ``sum_i kernel[i, c] x[t - (K - 1) + i, c]``, zeros before the
+    sequence, plus ``bias[c]`` where a ``bias`` ``[C]`` is given (a
+    state-space layer's; a short-convolution layer's where its record
+    asks for one)."""
     k, t = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    return bias + sum(kernel[i] * padded[:, i:i + t] for i in range(k))
+    taps = sum(kernel[i] * padded[:, i:i + t] for i in range(k))
+    return taps if bias is None else bias + taps
 
 
 def _held(c: dict, key: str, whole: int) -> int:
@@ -351,6 +378,8 @@ class DecoderLayer(nn.Module):
         read = None  # what the attention read: its normed input
         if kind == STATE_SPACE:
             x = x + self.state_space(x)
+        elif kind == SHORT_CONV:
+            x = x + self.short_conv(x)
         elif kind != NONE:
             x, attended, read = self.attention(x, kind, attended)
         if c["mlp_layer_types"][l] == DENSE:
@@ -403,7 +432,8 @@ class DecoderLayer(nn.Module):
                 c["num_experts_per_tok"], c["routed_scaling_factor"],
                 scoring=c["router_scoring"], activation=activation,
                 router_input=read.reshape(b * t, d) if (
-                    c["router_input"] == ATTENTION_INPUT) else None)
+                    c["router_input"] == ATTENTION_INPUT) else None,
+                renorm_epsilon=c["router_renorm_epsilon"])
             return x + y.reshape(b, t, d), _counted(counters, attended)
 
     @nn.nowrap
@@ -535,6 +565,25 @@ class DecoderLayer(nn.Module):
             return self.dense(d, "out_proj")(y.astype(x.dtype))
 
     @nn.nowrap
+    def short_conv(self, x):
+        """``mixer(norm(x))`` of a gated short-convolution layer (module
+        docstring): every channel, whole."""
+        c = dict(self.cfg)
+        s = dict(c["short_conv"])
+        d = x.shape[-1]
+        with jax.named_scope("fedml.model.conv"):
+            h = RMSNorm(c["rms_norm_eps"], name="conv_norm")(x)
+            bm, cm, u = jnp.split(self.dense(3 * d, "in_proj")(h), 3, -1)
+            kernel = self.param(
+                "conv_kernel", nn.initializers.lecun_normal(),
+                (s["kernel"], d)).astype(x.dtype)
+            bias = self.param("conv_bias", nn.initializers.zeros, (d,)
+                              ).astype(x.dtype) if s["bias"] else None
+            with jax.named_scope("fedml.model.conv.mix"):
+                y = cm * causal_depthwise_conv(bm * u, kernel, bias)
+            return self.dense(d, "out_proj")(y)
+
+    @nn.nowrap
     def select(self, h, rope: dict):
         """The keys each query of a sparse-attention layer reads, from
         the normed layer input ``h`` -> (``[B, T, T]`` bool,
@@ -578,6 +627,12 @@ class Embedding(nn.Embed):
 class DecoderLM(nn.Module):
     """Tokens ``[B, T]`` int32 -> logits ``[B, T, vocab_size]``.
 
+    With ``tie_word_embeddings`` the head is ``x embedding^T`` over the
+    table the tokens were looked up in (``flax.linen.Embed.attend``: the
+    table in the step's compute dtype), under ``fedml.model.head``, and
+    the table's gradient is JAX's sum of that product's and the rule's
+    below.
+
     Under the scope ``fedml.model.embed`` run, forward, the gather of a
     row of ``embed/embedding`` a token (``jnp.take``, what
     ``flax.linen.Embed`` makes, to the bit) and, backward, that table's
@@ -601,9 +656,9 @@ class DecoderLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         c = dict(self.cfg)
+        embed = Embedding(self.vocab_size, c["hidden_size"], name="embed")
         with jax.named_scope("fedml.model.embed"):
-            x = Embedding(self.vocab_size, c["hidden_size"],
-                          name="embed")(tokens)
+            x = embed(tokens)
         layer = nn.remat(
             DecoderLayer,
             policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
@@ -619,6 +674,8 @@ class DecoderLM(nn.Module):
                      init_fn=lambda: jnp.zeros((), jnp.float32))
         with jax.named_scope("fedml.model.head"):
             x = RMSNorm(c["rms_norm_eps"], name="final_norm")(x)
+            if c["tie_word_embeddings"]:  # logits = x embedding^T
+                return embed.attend(x)
             return _dense(self.dense_cls, self.vocab_size, False,
                           "lm_head")(x)
 
@@ -690,13 +747,31 @@ def _check_latent_attention(c: dict) -> None:
         raise ValueError(
             "decoder: latent_attention sizes must be at least 1, the "
             f"rotary part even, and no other key given: {la}")
-    shares = [k for k in ("query_heads_held", "key_value_heads_held")
-              if c.get(k)]
+    shares = [k for k in HEAD_SHARES if c.get(k)]
     if shares or c["qk_norm"]:
         raise ValueError(
             "decoder: a stack with latent_attention layers holds all its "
             "heads and norms its latents alone; it has no "
             f"{', '.join(shares + ['qk_norm'] * bool(c['qk_norm']))}")
+
+
+def _check_short_conv(c: dict) -> None:
+    s = c.get("short_conv") or {}
+    lacks = [k for k, kind in SHORT_CONV_KEYS.items()
+             if not isinstance(s.get(k), kind)]
+    if lacks:
+        raise ValueError(f"decoder: short_conv lacks {', '.join(lacks)}")
+    unknown = sorted(set(s) - set(SHORT_CONV_KEYS))
+    if unknown or s["kernel"] < 1:
+        raise ValueError(
+            "decoder: short_conv has at least one tap and no other key "
+            f"than {', '.join(SHORT_CONV_KEYS)}: {s}")
+    shares = [k for k in HEAD_SHARES if c.get(k)]
+    if shares:
+        raise ValueError(
+            "decoder: a stack with short_conv layers holds every channel, "
+            f"so its attention holds every head; it has no "
+            f"{', '.join(shares)}")
 
 
 def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
@@ -712,6 +787,7 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         "sliding_window": None, "gating": False, "qk_norm": False,
         "rms_norm_eps": 1e-6, "router_scoring": SIGMOID,
         "router_input": FEED_FORWARD_INPUT, "router_score_bias": False,
+        "router_renorm_epsilon": 0.0, "tie_word_embeddings": False,
         "mlp_activation": SILU_GATED, "moe_latent_size": 0,
         "moe_intermediate_size": 0, "shared_expert_intermediate_size": 0,
         "num_experts": 0, "num_experts_per_tok": 0,
@@ -770,6 +846,10 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         raise ValueError(
             f"decoder: unknown router_scoring {c['router_scoring']!r}; "
             f"known: {sorted(SCORINGS)}")
+    if not c["router_renorm_epsilon"] >= 0:
+        raise ValueError(
+            "decoder: router_renorm_epsilon is added to a sum of "
+            f"probabilities and cannot be {c['router_renorm_epsilon']}")
     if c["router_score_bias"] and SPARSE not in c["mlp_layer_types"]:
         raise ValueError(
             "decoder: router_score_bias needs a sparse layer: the bias is "
@@ -783,6 +863,8 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         _check_latent_attention(c)
     if STATE_SPACE in c["layer_types"]:
         _check_state_space(c.get("state_space") or {})
+    if SHORT_CONV in c["layer_types"]:
+        _check_short_conv(c)
     if SELECTED in c["layer_types"]:
         sa = c.get("sparse_attention") or {}
         lacks = [k for k in ("index_heads", "index_head_dim", "topk")

@@ -15,7 +15,16 @@ lane tiles: on a v5e one call of 8,192 tokens x 32 heads, forward +
 backward under the layer's checkpoint policy in bfloat16, reads 29.24
 ms (9.10 forward) against 29.97 (9.55) with queries and keys
 zero-padded to 256, which gives the same output and gradients to the
-bit (PERF.md section 6, PR 42) — so nothing is padded.
+bit (PERF.md section 6, PR 42) — so nothing is padded. Heads of 64,
+half a lane tile (32 query heads over 8 key-value heads, the same
+tokens), run as they are too: 17.43 ms forward + backward (6.08
+forward) against 17.45 (5.79) with queries, keys and values zero-padded
+to 128 and the output cut back, and 17.46 (5.76) with queries and keys
+alone padded, output and every gradient the same to the bit; scores are
+scaled by ``1 / 8`` either way. The padded forward call is 0.3 ms
+shorter and the padded backward as much longer, so a training step
+gains nothing and the pads and the cut would be three more passes: ONE
+launch shape, the head size as it comes (PERF.md section 6, PR 46).
 
 On the TPU this is JAX's bundled splash attention (a Pallas kernel:
 blockwise, online softmax, forward and backward, no ``[T, T]`` score

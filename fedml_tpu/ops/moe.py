@@ -268,10 +268,13 @@ SCORINGS = {"sigmoid": jax.nn.sigmoid,
             "softmax": lambda scores: jax.nn.softmax(scores, -1)}
 
 
-def _weights(top_p, scale: float):
+def _weights(top_p, scale: float, epsilon: float = 0.0):
     """The chosen probabilities renormalised over themselves, times
-    ``scale``."""
-    return scale * top_p / jnp.sum(top_p, -1, keepdims=True)
+    ``scale``; a family that guards the division adds its ``epsilon``
+    to the sum (0: the bare sum, and the program of before)."""
+    scaled = scale * top_p
+    total = jnp.sum(top_p, -1, keepdims=True)
+    return scaled / (total + epsilon if epsilon else total)
 
 
 #: tokens a step of the ranking kernel holds in fast memory (22 of 512
@@ -377,9 +380,9 @@ def _chosen_to_experts(top_e, d_top_p, experts: int):
     return jnp.sum(jnp.where(hit, d_top_p[..., None], 0), -2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 5, 6))
 def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
-                choice_bias=None, scope: str = ROUTE):
+                choice_bias=None, scope: str = ROUTE, epsilon: float = 0.0):
     """Router logits ``[N, E]`` (float32) over ALL experts -> the
     ``top_k`` a token of largest probability — ``scoring`` ``sigmoid``
     (each expert's own) or ``softmax`` (over all ``E``, in float32) —
@@ -391,6 +394,8 @@ def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
     ``top_k`` are those of largest probability PLUS bias; the weights
     stay the chosen experts' unbiased probabilities renormalised, so
     the bias moves the set and nothing else, and gets no gradient.
+    ``epsilon`` (static) is added to the sum the weights are divided by
+    (:func:`_weights`), forward and in the rule alike.
 
     The choice is a set, so the weights' cotangent reaches the
     probabilities as ``lax.top_k``'s own rule sends it, to the ids the
@@ -401,21 +406,23 @@ def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
     :data:`KEPT_ROUTING`, so a rematerialised layer ranks once (the
     scoring, elementwise, is run again for its own rule)."""
     top_p, top_e = _ranked(scores, top_k, scoring, choice_bias)
-    return top_e, _weights(top_p, scale)
+    return top_e, _weights(top_p, scale, epsilon)
 
 
-def _route_top_k_fwd(scores, top_k, scale, scoring, choice_bias, scope):
+def _route_top_k_fwd(scores, top_k, scale, scoring, choice_bias, scope,
+                     epsilon=0.0):
     scores, (top_p, top_e) = checkpoint_name(
         (scores, _ranked(scores, top_k, scoring, choice_bias)), KEPT_ROUTING)
-    return (top_e, _weights(top_p, scale)), (
+    return (top_e, _weights(top_p, scale, epsilon)), (
         scores, top_p, top_e, choice_bias)
 
 
-def _route_top_k_bwd(top_k, scale, scoring, scope, res, cotangents):
+def _route_top_k_bwd(top_k, scale, scoring, scope, epsilon, res, cotangents):
     scores, top_p, top_e, choice_bias = res
     with jax.named_scope(scope):
-        d_top_p, = jax.vjp(lambda top_p: _weights(top_p, scale), top_p)[1](
-            cotangents[1])
+        d_top_p, = jax.vjp(
+            lambda top_p: _weights(top_p, scale, epsilon), top_p)[1](
+                cotangents[1])
         _, scored = jax.vjp(SCORINGS[scoring], scores)
         d_p = _chosen_to_experts(top_e, d_top_p, scores.shape[-1])
         return (*scored(d_p), jax.tree.map(jnp.zeros_like, choice_bias))
@@ -684,14 +691,14 @@ class Routing(NamedTuple):
 
 def route(router, x, held: tuple[int, int], top_k: int, scale: float,
           scoring: str = "sigmoid", scope: str = ROUTE,
-          choice_bias=None) -> Routing:
+          choice_bias=None, renorm_epsilon: float = 0.0) -> Routing:
     """From what the router reads to the routing: ``router`` ``[D, E]``
     over ALL ``E`` experts, ``x`` ``[N, D]`` — the rows the experts
     will be given, or any other tensor of the same tokens (a router
     that reads its layer's attention input) — ``held = (first, count)``
-    the experts held here; ``choice_bias`` ``[E]`` float32 or None,
-    :func:`route_top_k`'s. Logits (float32), top-k and weights run
-    under ``scope``; ordering the assignments by held expert under
+    the experts held here; ``choice_bias`` ``[E]`` float32 or None and
+    ``renorm_epsilon``, :func:`route_top_k`'s. Logits (float32), top-k
+    and weights run under ``scope``; ordering the assignments by held expert under
     :data:`ROUTE`. A token's slots are its ways where ``top_k <= count``
     (``back``: the order's own ``argsort``) and the held experts where
     those are the fewer (``back``: :func:`_slot_rows`' pair; neither
@@ -701,7 +708,7 @@ def route(router, x, held: tuple[int, int], top_k: int, scale: float,
     with jax.named_scope(scope):
         scores = jnp.dot(x, router, preferred_element_type=jnp.float32)
         top_e, top_w = route_top_k(scores, top_k, scale, scoring,
-                                   choice_bias, scope)
+                                   choice_bias, scope, renorm_epsilon)
     with jax.named_scope(ROUTE):
         local = top_e.reshape(-1) - first
         # an assignment to an absent expert sorts past every held group
@@ -721,7 +728,7 @@ def route(router, x, held: tuple[int, int], top_k: int, scale: float,
 
 def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
               scoring: str = "sigmoid", activation: str = SILU_GATED,
-              router_input=None):
+              router_input=None, renorm_epsilon: float = 0.0):
     """This chip's part of one sparse-expert layer, dropless.
 
     ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts;
@@ -742,7 +749,10 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     and the rows from ``h``, so a gradient reaches the layer's input by
     both (through the weights into ``router_input``, through the rows
     into ``h``), and the router's logits, top-k and weights are timed
-    under :data:`ROUTER`, not :data:`ROUTE`. The experts' width ``W``
+    under :data:`ROUTER`, not :data:`ROUTE`. ``renorm_epsilon``
+    (static): what the family adds to the sum of the chosen
+    probabilities before it divides by it (:func:`_weights`; 0 where it
+    adds nothing). The experts' width ``W``
     is ``D``, or ``L`` where the layer is latent: its experts then read
     ``h`` through the first latent projection and their weighted sum
     goes back through the second (no activation on either; both shared
@@ -785,7 +795,8 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     apart = router_input is not None  # a router with an input of its own
     routing = route(params["router"], router_input if apart else h, held,
                     top_k, scale, scoring, scope=ROUTER if apart else ROUTE,
-                    choice_bias=params.get("router_bias"))
+                    choice_bias=params.get("router_bias"),
+                    renorm_epsilon=renorm_epsilon)
     inside = h
     if "latent" in params:
         with jax.named_scope(LATENT):

@@ -161,6 +161,11 @@ def apply_lora(model, spec: LoRASpec):
 
     from fedml_tpu.models.transformer import TransformerLM
 
+    if dict(getattr(model.module, "cfg", ())).get("tie_word_embeddings"):
+        raise ValueError(
+            "peft='lora' beside tie_word_embeddings: a tied stack has no "
+            "lm_head module to train densely, and no adapter on the tied "
+            "table is built")
     if not isinstance(model.module, TransformerLM):
         raise ValueError(
             f"peft='lora' targets the TransformerLM's named Dense "

@@ -137,6 +137,31 @@ def test_latent_attention_kernel_compiles_for_v5e(one_chip, monkeypatch):
     assert "splash_mqa_dkv" in text and "splash_mqa_dq" not in text
 
 
+def test_attention_kernel_at_heads_of_64_compiles_for_v5e(
+        one_chip, monkeypatch):
+    """The blockwise kernel at LFM2-8B-A1B's attention shapes (one
+    sequence of 8,192; 32 query heads over 8 key-value heads of 64, half
+    a lane tile), forward and the one-walk backward: Mosaic takes 64 as
+    it is, and every gradient comes out at 64."""
+    from fedml_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return A.causal_attention(q, k, v).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv)
+    assert [g.shape[-2:] for g in lowered.out_info] == [
+        (32, 64), (8, 64), (8, 64)]
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "splash_mqa_dkv" in text and "splash_mqa_dq" not in text
+
+
 def test_sparse_attention_compiles_for_v5e(one_chip, monkeypatch):
     """Learned sparse attention at Keye-VL-2.0-30B-A3B's widths (one
     sequence of 8,192; an index of 16 heads of 64 over one key head that
@@ -553,5 +578,53 @@ def test_joyai_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
         state.variables, *_shapes(operands, one_chip)
     ).compile().memory_analysis()
     # beside the state a window holds (the global copy, 2.26 GB)
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < (
+        V5E_HBM_BYTES - _tree_bytes(state.variables)), ma
+
+
+# slow for the same reason: 90 s of compiling on many threads
+@pytest.mark.slow
+def test_lfm2_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
+    """``lfm2-8b-a1b-share4`` as its cell runs it — ``FedAvgSim``'s bulk
+    round at a block of one and the evaluator, from the configuration's
+    and the traffic's own files at the published widths (568,647,936
+    parameters, ONE table for embedding and head, one sequence of 8,192
+    tokens a step through five gated short convolutions and one
+    attention layer of 64-wide heads) — for a described v5e: the
+    blockwise kernel and the grouped products are in the round program,
+    and each program's scratch with its arguments and its code stays
+    under the chip's memory."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks"))
+    import run
+    from fedml_tpu.ops import attention as A
+    from lib import traffic as TR
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    cell = run.load_cell("lfm2-8b-c2of32-b1x8192")
+    config, traffic = cell["config"], cell["traffic"]
+    sim = run.build_sim(
+        run.experiment_config(config, traffic), traffic,
+        TR.make_population(config["dataset"], traffic, 1),
+        cell["reference"].TASK)
+    state = _shapes(jax.eval_shape(sim.init), one_chip)
+    assert _tree_bytes(state.variables) == 4 * 568_647_936
+    compiled = jax.jit(
+        sim._round, donate_argnums=sim._donate_argnums()
+    ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
+    text = compiled.as_text()
+    assert "splash_mqa_fwd" in text and "ragged-dot" in text
+    ma = compiled.memory_analysis()
+    need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.generated_code_size_in_bytes)
+    # 15.76 GB by the compiler's count (13.23 of it scratch)
+    assert 10e9 < need < 16.9e9, ma
+    evaluator, operands = sim._global_eval()
+    ma = evaluator.lower(
+        state.variables, *_shapes(operands, one_chip)
+    ).compile().memory_analysis()
+    # beside the state a window holds (the global copy, 2.27 GB)
     assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < (
         V5E_HBM_BYTES - _tree_bytes(state.variables)), ma
