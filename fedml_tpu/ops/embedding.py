@@ -38,6 +38,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from fedml_tpu.ops.mapped import once_a_client
+
 #: sorted tokens a product (``PERF.md`` section 6, PR 43, has the chip's
 #: readings of 256, 512 and 1,024 at the cells' shapes)
 BLOCK = 256
@@ -110,22 +112,15 @@ def _lookup_fwd(table, ids):
     return embedding_lookup(table, ids), (ids, table[:, :0])
 
 
-@jax.custom_batching.custom_vmap
+@once_a_client
 def _table_gradient(g, ids, like):
-    return distinct_row_sums(
-        g.reshape(-1, g.shape[-1]), ids.reshape(-1), like.shape[0],
-        like.dtype)
-
-
-@_table_gradient.def_vmap
-def _table_gradient_each(axis_size, in_batched, *operands):
     """Mapped over a block of clients, the rule runs once a client: the
     batched gathers a plain ``vmap`` makes of it take the chip three to
     four times as long as the same gathers alone (``PERF.md`` section 6,
     PR 43)."""
-    one = lambda k: _table_gradient(*(
-        x[k] if mapped else x for x, mapped in zip(operands, in_batched)))
-    return jnp.stack([one(k) for k in range(axis_size)]), True
+    return distinct_row_sums(
+        g.reshape(-1, g.shape[-1]), ids.reshape(-1), like.shape[0],
+        like.dtype)
 
 
 def _lookup_bwd(kept, g):

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from fedml_tpu.ops import attention
+from fedml_tpu.ops.mapped import once_a_client
 
 
 def init_moe_params(key, num_experts: int, d_model: int, d_hidden: int):
@@ -151,10 +152,13 @@ def make_expert_parallel_moe(mesh, axis_name: str = "ep",
 # ---------------------------------------------------------------------------
 
 #: what :func:`moe_layer` counts, in this order (its docstring says what
-#: each is; the last, the rows the combine read back into the tokens, is
-#: ``N`` times the SLOTS a token has: ``min(top_k, count)``)
+#: each is; ``moe_rows_combined``, the rows the combine read back into
+#: the tokens, is ``N`` times the SLOTS a token has: ``min(top_k,
+#: count)``; ``moe_rows_gathered``, the rows a training step's four
+#: gathers move, over ``4 x moe_rows_held`` is the rows moved a held
+#: row)
 MOE_COUNTERS = ("moe_rows_held", "moe_rows_routed", "moe_rows_max_expert",
-                "moe_rows_compact", "moe_rows_combined")
+                "moe_rows_compact", "moe_rows_combined", "moe_rows_gathered")
 
 #: the row buffer holds this many times the held experts' uniform share
 #: of the assignments, and never under this part of all of them,
@@ -180,7 +184,15 @@ ROUTER = "fedml.model.moe.router"
 #: ``(rows, into, out)``, for every model: with them kept the four
 #: decoder cells hold 13.9 to 15.1 GB at their peak on a v5e (13.1 to
 #: 14.1 without; 0.2 to 1.3 GB a cell, ``PERF.md`` section 6, PR 41) of
-#: the 15.4 a cell may. Were that to stop fitting, ``rows`` go first
+#: the 15.4 a cell may. Their SHAPE is the row buffer's
+#: (:func:`row_buffer`, or all ``N x top_k`` on the worst-case side), and
+#: that is what they cost in memory and in the ONE gather that fills
+#: ``rows`` (10 ns a row of 2,560 on a v5e: cheaper whole than walked
+#: over the held rows' tiles); past the ``n_held`` held rows nothing
+#: else touches them: the grouped products visit the row tiles present,
+#: no pass masks them, no zero row is appended to them, and the combine
+#: reads no place there (:func:`_read_weighed`, :func:`_read_back`).
+#: Were they to stop fitting, ``rows`` go first
 #: (gathered again from the kept ``order``: 14.57 for 15.07 GB and
 #: 19 ms more of a 686 ms round in the tightest cell), then ``out`` (one
 #: product and the activation again); the routing and ``into`` are what
@@ -252,7 +264,8 @@ def grouped_product(x, w, sizes):
     (``w`` ``[G, K, N]``), ``sizes`` ``[G]`` rows a group -> ``[M, N]``;
     rows past ``sum(sizes)`` are not to be read, of the result and of
     the rows' cotangent alike (the TPU kernel leaves them unwritten:
-    the caller masks both ends). ``jax.lax.ragged_dot``
+    the caller reads neither end there, :func:`_read_back`).
+    ``jax.lax.ragged_dot``
     (on the TPU a Mosaic kernel that visits the row tiles present) and
     its own two transposes, made to run under ``vmap``."""
     return _ragged(x, w, sizes)
@@ -489,43 +502,120 @@ def _slot_rows(local, count: int, nk: int):
     return (jnp.where(chose, starts + running - chose, nk), hit), sizes
 
 
+@once_a_client
+def _all_rows(x, index):
+    """``x[index]``, unbatched under ``vmap`` (:func:`once_a_client`):
+    the gather into the row buffer, all its rows. (A walk over the held
+    rows' share of them alone, :data:`ROW_TILE`s at a time into zeros,
+    is slower than this one gather of twice as many: ``PERF.md`` section
+    6, PR 47.)"""
+    return x.at[index].get(mode="promise_in_bounds")
+
+
+#: a gather whose operand is no larger than this reads it from the
+#: chip's fast memory: on a v5e 49,152 rows of 2,560 come out of 8,192 or
+#: 16,384 such rows (40 and 80 MiB) in 0.42 ms, 8.5 ns a row, and out of
+#: 24,576 (120 MiB) in 2.07 ms, 42 ns a row (``PERF.md`` section 6, PR 47)
+RESIDENT_BYTES = 80 * 2 ** 20
+
+
+def _resident_rows(x) -> int:
+    """The leading rows of ``x`` ``[r, ...]`` that make a gather's
+    operand of at most :data:`RESIDENT_BYTES`, in whole row tiles: all
+    ``r`` where ``x`` is that small."""
+    row = x.dtype.itemsize * (x.size // x.shape[0])
+    rows = RESIDENT_BYTES // row // ROW_TILE * ROW_TILE
+    return min(rows, x.shape[0]) or x.shape[0]
+
+
+def _gathered(x, index, n_held, then=lambda rows: rows):
+    """``then(x[index])``, where ``index`` names one of the first
+    ``n_held`` rows, and row 0 read where it does not, for ``then`` or
+    the caller to make a zero of. Read out of the leading
+    :func:`_resident_rows` of ``x`` alone when the held rows are fewer
+    than those, so that a buffer too large for the chip's fast memory
+    is read out of it all the same: what is gathered from follows
+    ``n_held``, not the buffer. Else, and where ``x`` is small enough
+    whole, out of all of it. (``then`` runs inside the ``cond`` that
+    picks: a select and a sum applied after it the compiler parts, the
+    select into the branches as a pass of its own.)"""
+    at = jnp.where(index < n_held, index, 0)
+    read = lambda rows: lambda: then(
+        x[:rows].at[at].get(mode="promise_in_bounds"))
+    part = _resident_rows(x)
+    if part == x.shape[0]:
+        return read(part)()
+    return jax.lax.cond(n_held < part, read(part), read(x.shape[0]))
+
+
+def _zero_past_held(rows, index, n_held):
+    """``rows`` ``[*index.shape, ...]`` with zeros where ``index`` lies
+    past the held rows."""
+    held = (index < n_held).reshape(
+        index.shape + (1,) * (rows.ndim - index.ndim))
+    return jnp.where(held, rows, 0)
+
+
+@once_a_client
 def _read_back(x, index, n_held):
-    """``x`` ``[r, ...]``, zero from row ``n_held`` on, as ``index``
-    reads it: row ``index`` where that is one of the first ``n_held``
-    of the order, else zero. ``index`` is flat, the place in the order
-    of each of the ``N x top_k`` assignments (``inverse``: held experts'
-    first), or ``[N, slots]``, :func:`_slot_rows`' ``pos``. Flat over
-    the worst-case buffer every assignment has a row; any other gets a
-    zero row after it for the rest to read, so that no mask pass follows
-    the gather."""
-    if index.ndim == 1 and x.shape[0] == index.shape[0]:
-        return x[index]
-    x = jnp.pad(x, ((0, 1),) + ((0, 0),) * (x.ndim - 1))
-    return x[jnp.where(index < n_held, index, x.shape[0] - 1)]
+    """``x`` ``[r, ...]``, of which the first ``n_held`` rows are held
+    experts' and the rest anything at all (the chip leaves them
+    unwritten), as ``index`` reads it: row ``index`` where that is one
+    of the first ``n_held`` of the order, else zero. ``index``:
+    ``[N, slots]`` or ``[slots, N]``, the place in the order of each of
+    a token's slots (:func:`_slot_places`). A place past the held rows
+    reads row 0 (:func:`_gathered`) and a select puts the zero in its
+    stead: no row is appended to the buffer and no mask pass runs over
+    it. Unbatched under ``vmap`` (:func:`once_a_client`)."""
+    return _gathered(x, index, n_held,
+                     lambda rows: _zero_past_held(rows, index, n_held))
 
 
-def _by_token(x, back, n_held, k: int):
-    """:func:`_read_back`'s rows grouped by their token, one a SLOT ->
-    (the rows, their two leading axes). A token's slots are its ``k``
-    ways where ``k <= count`` (``back``: ``inverse``, flat) and the
-    ``count`` held experts where there are fewer of them (``back``:
-    :func:`_slot_rows`' pair), so a token reads ``min(k, count)`` rows.
-    ``[N, slots, ...]``, ``"nk"``, where the slots are whole tiles of
+def _read_summed(axis: int, x, index, n_held):
+    """:func:`_read_back` summed over ``axis``, a token's slots: the
+    select is part of the sum's own pass."""
+    return _gathered(
+        x, index, n_held,
+        lambda rows: _zero_past_held(rows, index, n_held).sum(axis))
+
+
+@once_a_client
+def _read_weighed(x, index, n_held):
+    """:func:`_read_back` for a sum that WEIGHS its rows by
+    :func:`_held_weights`: a place past the held rows reads row 0 — a
+    held expert's, so finite — and its weight is the zero. No select
+    over the ``N x slots`` rows read: the weighted sum is a product the
+    compiler moves out of the branch the rows are read in, and a select
+    would stay behind as a pass of its own over all of them (nor a row
+    of the buffer zeroed for the purpose: reading one row of a grouped
+    product's result to write it back cost a pass over the buffer on the
+    chip). Where NO row is held row 0 is anything at all, and what is
+    read is zeros."""
+    return jax.lax.cond(
+        n_held > 0, lambda: _gathered(x, index, n_held),
+        lambda: jnp.zeros(index.shape + x.shape[1:], x.dtype))
+
+
+def _slot_places(back, k: int):
+    """Where a token's slots find their rows, ``[N, slots]``."""
+    return back[0] if isinstance(back, tuple) else back.reshape(-1, k)
+
+
+def _by_slot(back, k: int):
+    """Where a token's slots find their rows and how they lie:
+    ``[N, slots]``, ``"nk"``, where the slots are whole tiles of
     :data:`SUBLANES` rows, so that splitting the gathered rows token by
     token is free; else each token's first, second, ... slot side by
-    side, ``[slots, N, ...]``, ``"kn"`` (cut token by token such rows
-    are all copied into padded tiles; cut slot by slot none is)."""
-    if isinstance(back, tuple):
-        pos = back[0]
-        if pos.shape[1] % SUBLANES == 0:
-            return _read_back(x, pos, n_held), "nk"
-        return _read_back(x, pos.T, n_held), "kn"
-    n = back.shape[0] // k
-    if k % SUBLANES == 0:
-        return _read_back(x, back, n_held).reshape(
-            n, k, *x.shape[1:]), "nk"
-    by_way = back.reshape(n, k).T.reshape(-1)
-    return _read_back(x, by_way, n_held).reshape(k, n, *x.shape[1:]), "kn"
+    side, ``[slots, N]``, ``"kn"`` (cut token by token such rows are all
+    copied into padded tiles; cut slot by slot none is). A token's
+    slots are its ``k`` ways where ``k <= count`` (``back``:
+    ``inverse``, flat) and the ``count`` held experts where there are
+    fewer of them (``back``: :func:`_slot_rows`' pair), so a token reads
+    ``min(k, count)`` rows."""
+    places = _slot_places(back, k)
+    if places.shape[1] % SUBLANES == 0:
+        return places, "nk"
+    return places.T, "kn"
 
 
 def _slot_weights(top_w, back):
@@ -535,6 +625,13 @@ def _slot_weights(top_w, back):
     if isinstance(back, tuple):
         return jnp.sum(jnp.where(back[1], top_w[:, :, None], 0), 1)
     return top_w
+
+
+def _held_weights(top_w, back, n_held):
+    """:func:`_slot_weights`, and 0 for a slot whose place lies past
+    the held rows: what :func:`_read_weighed`'s rows are weighed by."""
+    held = _slot_places(back, top_w.shape[1]) < n_held
+    return jnp.where(held, _slot_weights(top_w, back), 0)
 
 
 def _way_cotangents(d_weight, back, n_held, n: int, k: int):
@@ -547,27 +644,33 @@ def _way_cotangents(d_weight, back, n_held, n: int, k: int):
     return _read_back(d_weight, back, n_held).reshape(n, k)
 
 
-def _held_rows_forward(r, activation, h, w, top_w, order, back, sizes,
-                       n_held):
+def _held_rows_forward(r, activation, slot_w, h, w, top_w, order, back,
+                       sizes, n_held):
     """The held experts' sum over a buffer of ``r`` rows (at least
     ``n_held``): row ``i`` is token ``order[i] // k``'s. ``w``: the
     experts' matrices, ``(w1, w3, w2)`` or ``(w1, w2)``, as
-    ``activation`` has them (:func:`ffn`).
+    ``activation`` has them (:func:`ffn`); ``slot_w``: the weights of
+    a token's slots (:func:`_held_weights`), or None where ``y`` is not
+    asked for.
     -> ``(y [N, D], (rows, into, out))``, what the backward pass reads
     again (``into``: the rows through each matrix that leads in), all
     of ``r`` rows. Rows from ``n_held`` on are no held expert's: the
-    grouped products leave them unwritten, so ``out`` is masked."""
+    grouped products leave them unwritten and the combine reads a held
+    row at weight zero in their stead (:func:`_read_weighed`), so no
+    pass masks them."""
     k = top_w.shape[1]
     with jax.named_scope(ROUTE):
-        rows = h[order[:r] // k]
+        rows = _all_rows(h, order[:r] // k)
     with jax.named_scope(EXPERTS):
         into = tuple(grouped_product(rows, m, sizes) for m in w[:-1])
         out = grouped_product(_middle(activation, *into), w[-1], sizes)
+    if slot_w is None:
+        return None, (rows, into, out)
     with jax.named_scope(ROUTE):
-        out = jnp.where((jnp.arange(r) < n_held)[:, None], out, 0)
-        theirs, axes = _by_token(out, back, n_held, k)
-        y = jnp.einsum(axes + "d,nk->nd", theirs,
-                       _slot_weights(top_w, back).astype(out.dtype))
+        places, axes = _by_slot(back, k)
+        y = jnp.einsum(axes + "d,nk->nd",
+                       _read_weighed(out, places, n_held),
+                       slot_w.astype(out.dtype))
     return y, (rows, into, out)
 
 
@@ -575,13 +678,15 @@ def _held_rows_backward(r, activation, kept, w, top_w, order, back, sizes,
                         n_held, g):
     """:func:`_held_rows_forward`'s cotangents of ``h``, ``w`` and
     ``top_w`` for ``g`` ``[N, D]``, from what it kept. The rows'
-    cotangent is unwritten from ``n_held`` on as well, and masked
-    before it reaches the tokens."""
+    cotangent is unwritten from ``n_held`` on as well, and what it holds
+    there (as ``d_weight`` does: ``out`` times rows not gathered)
+    reaches nothing: the gathers back read a zero for every place past
+    the held rows."""
     rows, into, out = kept
     n, k = top_w.shape
     with jax.named_scope(ROUTE):
-        g_rows = g[order[:r] // k]
-        weight = top_w.reshape(-1)[order[:r]].astype(out.dtype)
+        g_rows = _all_rows(g, order[:r] // k)
+        weight = _all_rows(top_w.reshape(-1), order[:r]).astype(out.dtype)
         d_out = g_rows * weight[:, None]
         d_weight = jnp.einsum("rd,rd->r", out, g_rows,
                               preferred_element_type=jnp.float32)
@@ -595,10 +700,10 @@ def _held_rows_backward(r, activation, kept, w, top_w, order, back, sizes,
             _ragged_transposed(rows, m, sizes, d_into)
             for m, d_into in zip(w[:-1], middle(d_up))))
     with jax.named_scope(ROUTE):
-        d_rows = jnp.where((jnp.arange(r) < n_held)[:, None],
-                           sum(d_rows[1:], d_rows[0]), 0)
-        theirs, axes = _by_token(d_rows, back, n_held, k)
-        d_h = theirs.sum(axes.index("k"))
+        places, axes = _by_slot(back, k)
+        d_h = once_a_client(functools.partial(
+            _read_summed, axes.index("k")))(
+                sum(d_rows[1:], d_rows[0]), places, n_held)
     return d_h, (*d_w, d_out_w), d_top_w
 
 
@@ -608,12 +713,17 @@ def _held_experts_run(c, activation, keep, *args):
     the backward pass reads again if ``keep``), else over all ``N x
     top_k`` (``taken`` 0, ``kept`` zeros of the ``c``-row shapes: the
     backward pass runs that side's forward pass again)."""
-    top_w, n_held = args[2], args[-1]
+    top_w, back, n_held = args[2], args[4], args[-1]
     nk = top_w.size
+    # (outside the branch: made inside it, the weighted sum that reads
+    # them stays there with all a token's rows, 170 MB more of scratch
+    # in a round of four layers)
+    with jax.named_scope(ROUTE):
+        slot_w = _held_weights(top_w, back, n_held)
 
     def over(r):
-        def run(*args):
-            y, kept = _held_rows_forward(r, activation, *args)
+        def run(slot_w, *args):
+            y, kept = _held_rows_forward(r, activation, slot_w, *args)
             if not keep:
                 kept = ()
             elif r != c:
@@ -623,8 +733,8 @@ def _held_experts_run(c, activation, keep, *args):
         return run
 
     if c == nk:  # no smaller buffer: one path, decided while tracing
-        return over(nk)(*args)
-    return _one_path_a_batch(over(c), over(nk))(n_held <= c, *args)
+        return over(nk)(slot_w, *args)
+    return _one_path_a_batch(over(c), over(nk))(n_held <= c, slot_w, *args)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -657,7 +767,7 @@ def _held_experts_bwd(c, activation, res, cotangents):
         return _held_rows_backward(c, activation, kept, *rest, g)
 
     def worst(kept, g, h, *rest):
-        _, kept = _held_rows_forward(nk, activation, h, *rest)
+        _, kept = _held_rows_forward(nk, activation, None, h, *rest)
         return _held_rows_backward(nk, activation, kept, *rest, g)
 
     if c == nk:
@@ -679,7 +789,7 @@ class Routing(NamedTuple):
     ``top_e``, ``top_w`` ``[N, k]`` (:func:`route_top_k`'s); ``order``
     ``[N k]``, the assignments sorted by held expert, absent experts'
     last; ``back``, where a token's slots find their rows in that order
-    (:func:`_by_token`); ``sizes`` ``[count]`` rows a held expert and
+    (:func:`_by_slot`); ``sizes`` ``[count]`` rows a held expert and
     ``n_held`` their sum."""
     top_e: jax.Array
     top_w: jax.Array
@@ -774,22 +884,33 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     top_k`` (a token names an expert at most once; its row is found by
     a running count, :func:`_slot_rows`, whose last row is the rows a
     held expert, so neither ``inverse`` nor a count over the ``N x
-    top_k`` ids is computed; a slot no way names reads a zero row). The
-    row buffer holds
+    top_k`` ids is computed; a slot no way names reads a zero). The
+    row buffer has the SHAPE of
     what the held experts can be expected to receive
     (:func:`row_buffer`: twice their uniform share of the ``N x top_k``
     assignments, an eighth of them at least); a call that receives more
     goes through a buffer of
     all ``N x top_k`` rows instead, so no assignment is ever dropped,
-    and a mapped batch goes one way together. What the absent experts
-    would add is left out: on one chip there is no exchange and nothing
-    stands in for one.
+    and a mapped batch goes one way together. Past the one gather that
+    fills it (:func:`_all_rows`) the rows TOUCHED follow the held count,
+    not the buffer: the products visit the row tiles present, and the
+    gather back reads a zero for a place past the held rows with no
+    zero row appended to the buffer and no mask pass over it
+    (:func:`_read_weighed` forward, :func:`_read_back` in the rule); the
+    gathers run unbatched under ``vmap``
+    (:func:`fedml_tpu.ops.mapped.once_a_client`). What the absent
+    experts would add is left out: on one chip there is no exchange and
+    nothing stands in for one.
 
-    -> ``(y [N, D], counters float32 [5])`` in :data:`MOE_COUNTERS`'
+    -> ``(y [N, D], counters float32 [6])`` in :data:`MOE_COUNTERS`'
     order: assignments that landed on held experts, assignments made
     (``N x top_k``), rows of the fullest held expert, assignments made
     in a call that went through the bounded buffer, rows the combine
-    read (``N`` times a token's slots)."""
+    read (``N`` times a token's slots), and the rows of the tokens'
+    width that the four gathers of a training step move: twice the
+    buffer of the side taken (forward the tokens' rows, in the rule
+    their cotangents') and twice what the combine reads (forward, and
+    its transpose in the rule)."""
     count = held[1]
     n, _ = h.shape
     apart = router_input is not None  # a router with an input of its own
@@ -801,9 +922,10 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     if "latent" in params:
         with jax.named_scope(LATENT):
             inside = h @ params["latent"][0]
+    buffer = row_buffer(n, top_k, count, params["router"].shape[1])
     y, bounded = _held_experts(
-        row_buffer(n, top_k, count, params["router"].shape[1]), activation,
-        inside, tuple(params[m] for m in (*leading(activation), "w2")),
+        buffer, activation, inside,
+        tuple(params[m] for m in (*leading(activation), "w2")),
         routing.top_w, routing.order, routing.back, routing.sizes,
         routing.n_held)
     if "latent" in params:
@@ -812,8 +934,11 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     if "shared" in params:
         with jax.named_scope("fedml.model.mlp"):
             y = y + ffn(activation, h, *params["shared"])
+    combined = n * min(top_k, count)
+    buffered = jnp.where(bounded > 0, buffer, n * top_k)  # the side taken
     counters = jnp.stack([
         routing.n_held.astype(jnp.float32), jnp.float32(n * top_k),
         jnp.max(routing.sizes).astype(jnp.float32), bounded,
-        jnp.float32(n * min(top_k, count))])
+        jnp.float32(combined),
+        2 * buffered.astype(jnp.float32) + 2 * combined])
     return y.astype(h.dtype), counters

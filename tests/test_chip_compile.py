@@ -316,11 +316,57 @@ def test_grouped_expert_products_compile_for_v5e(one_chip):
     # of the branch a call over them takes, the worst-case ones
     # ([32768, 2048] = 134 MB each): never 32 experts x 32,768 rows
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
-    assert text.count(" conditional(") == 2  # forward rule, backward rule
+    # forward rule, backward rule, and the forward combine's own on
+    # either side (zeros where no row is held)
+    assert 2 <= text.count(" conditional(") <= 4
     scopes = parse_scopes(text)
     assert {v for k, v in scopes.items()
             if k.startswith("ragged-dot-none")} == {
                 "fedml.model.moe.experts"}
+
+
+def test_sparse_layer_reads_back_without_a_zero_row_for_v5e(
+        one_chip, monkeypatch):
+    """A training step of ONE sparse layer's share at SmallThinker's
+    widths (8,192 tokens of 2,560, 6 of 64 experts a token, 16 held: a
+    bounded buffer of 24,576 rows), value and gradients: no zero row is
+    appended to a buffer (no array of 24,577 or 49,153 rows); the
+    combine gathers from the buffer's leading 16,384 rows, a slice made
+    in the chip's fast memory; forward no select runs over a token's
+    rows, the weights carry the zero; no loop fills a buffer; and the
+    scratch is the plain form's 1.77 GB."""
+    from fedml_tpu.ops import attention as A
+    from fedml_tpu.ops.moe import RELU_GATED, moe_layer
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+    params = {"router": sds(2560, 64), "w1": sds(16, 2560, 768),
+              "w3": sds(16, 2560, 768), "w2": sds(16, 768, 2560)}
+
+    def loss(params, h, g):
+        y, counters = moe_layer(params, h, (0, 16), 6, 1.0, "softmax",
+                                RELU_GATED)
+        return jnp.sum((y * g).astype(jnp.float32)), counters
+
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)).lower(
+            params, sds(8192, 2560), sds(8192, 2560)).compile()
+    text = compiled.as_text()
+    assert "[24577,2560]" not in text and "[49153,2560]" not in text
+    assert " pad(" not in text and " while(" not in text
+    # the leading 16,384 rows (80 MiB), sliced straight into the fast
+    # memory space: forward and in the rule, on the bounded side
+    parts = [line for line in text.splitlines() if " slice(" in line
+             and line.split("=")[1].lstrip().startswith("bf16[16384,2560]")]
+    assert len(parts) >= 2 and all("S(1)" in line.split(" slice(")[0]
+                                   for line in parts)
+    # a select over all a token's rows stands in the rule alone, whose
+    # sum has no weights to carry the zero
+    assert not [line for line in text.splitlines()
+                if " select(" in line and "2560]" in line.split("=")[1]
+                and "transpose(jvp" not in line]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.85e9
 
 
 def test_resnet56_cohort_update_compiles_for_v5e(one_chip):

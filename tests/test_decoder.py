@@ -306,9 +306,14 @@ def test_bounded_buffer_against_the_written_out_layer(case):
     np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
     _assert_trees_close(grads, want_grads)
     assert float(jnp.max(jnp.abs(grads[0]["router"]))) > 0
-    assert [float(c) for c in counters] == [
-        held_rows, 256.0, sum(STEERED[case]),  # expert 4 is the fullest
-        0.0 if case == "one_over" else 256.0, 256.0]
+    # the buffer of the side taken is filled forward and in the rule
+    buffered = 256.0 if case == "one_over" else 128.0
+    assert dict(zip(MOE.MOE_COUNTERS, map(float, counters))) == {
+        "moe_rows_held": held_rows, "moe_rows_routed": 256.0,
+        "moe_rows_max_expert": sum(STEERED[case]),  # expert 4: the fullest
+        "moe_rows_compact": 0.0 if case == "one_over" else 256.0,
+        "moe_rows_combined": 256.0,
+        "moe_rows_gathered": 2 * buffered + 2 * 256.0}
 
 
 @pytest.mark.parametrize("cases", [("one_over", "well_under"),
@@ -374,8 +379,9 @@ def test_no_worst_case_sized_array_on_the_bounded_path(mapped, remat):
     fit, no array has ``N x top_k`` rows by a model or expert width (a
     differentiated ``cond`` would return both sides' residuals) — but
     for the result of the gather in which every assignment reads its
-    row, or the zero row, of the 128 + 1 (4 ways a token are no whole
-    tile, so it is cut way by way: ``ops/moe._by_token``)."""
+    row of the buffer's 128, and the select that makes a zero of what no
+    held expert fills (4 ways a token are no whole tile, so it is cut
+    way by way: ``ops/moe._by_slot``)."""
     p, h, _ = _steered(jax.random.key(19), *STEERED["well_under"])
     weigh = jnp.ones_like(h)
     layer = jax.checkpoint(_share, policy=REMATS[remat])
@@ -391,17 +397,27 @@ def test_no_worst_case_sized_array_on_the_bounded_path(mapped, remat):
         found = {}
         for eqn in eqns:
             for v in eqn.outvars:
-                s = v.aval.shape[len(lead):]
+                s = v.aval.shape
+                # a mapped batch: the stacked arrays, and one client's
+                # on its way into the stack
+                s = s[1:] if mapped and s[:1] in (lead, (1,)) else s
                 if s not in own and len(s) >= 2 and s[-1] in (64, 32) and (
                         s[-2] == 256 or s[-3:-1] == (4, 64)):
                     found.setdefault(s, []).append(eqn)
         return found
 
     found = wide(_eqns(jaxpr))
-    assert set(found) == {(256, 64), (4, 64, 64)}
-    assert all(e.primitive.name == "gather" and e.invars[0].aval.shape
-               == lead + (129, 64) for e in found[(256, 64)])
-    assert all(e.primitive.name == "reshape" for e in found[(4, 64, 64)])
+    assert set(found) == {(4, 64, 64)}
+    made = {e.primitive.name for e in found[(4, 64, 64)]}
+    # the gather, way by way (once a client: no mapped axis on it;
+    # forward inside the ``cond`` that reads zeros where no row is
+    # held), the select that puts the zeros in in the rule, and a mapped
+    # batch's stack of them
+    assert "gather" in made and made <= {
+        "gather", "cond", "select_n", "broadcast_in_dim", "jit",
+        "custom_vmap_call", "concatenate"}
+    assert all(e.invars[0].aval.shape == (128, 64)
+               for e in found[(4, 64, 64)] if e.primitive.name == "gather")
     # the other side holds them, as it must: it is looked at
     assert (256, 32) in wide(_eqns(jaxpr, bounded_side_only=False))
 

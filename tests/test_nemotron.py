@@ -260,7 +260,7 @@ def _written_out(p, h, first, count, top_k, scale, activation=None,
 # (experts, first held, held, ways a token): what decides the combine's
 # form. A token's slots are its ways where ``top_k <= count`` and the
 # held experts where those are fewer; whole tiles of 8 slots are summed
-# token by token, others slot by slot (``ops/moe._by_token``).
+# token by token, others slot by slot (``ops/moe._by_slot``).
 SHAPES = {
     "ways_4_of_4_held": (16, 4, 4, 4),
     "ways_8_of_8_held": (32, 4, 8, 8),
@@ -356,7 +356,10 @@ def test_moe_layer_against_the_written_out_share(case):
     nk = TOKENS * top_k
     buffer = MOE.row_buffer(TOKENS, top_k, count, experts)
     assert (buffer < nk) == ("no_smaller_buffer" not in shape)
-    for held, routed, _, compact, combined in counters.tolist():
+    for row in counters.tolist():
+        held, routed, compact, combined = (
+            row[MOE.MOE_COUNTERS.index("moe_rows_" + name)]
+            for name in ("held", "routed", "compact", "combined"))
         assert routed == nk and 0 < held <= nk
         assert compact == (nk if steer == "fresh" and buffer < nk else 0.0)
         assert combined == TOKENS * min(top_k, count)
@@ -459,8 +462,8 @@ def test_one_sort_a_layer_pass_and_no_row_a_way_where_slots_are_fewer(
     bounded = list(_eqns(jaxpr))
     by_slot = ({(TOKENS, slots, 64)} if slots % MOE.SUBLANES == 0
                else {(slots, TOKENS, 64)})
-    if top_k <= count:
-        assert wide(bounded, top_k) == {(nk, 64)} | by_slot
+    if top_k <= count:  # gathered slot by slot: no flat ``N x top_k``
+        assert wide(bounded, top_k) == by_slot
     else:
         assert wide(bounded, top_k) == set()
         assert wide(bounded, slots) == by_slot

@@ -284,7 +284,10 @@ def test_relu_gated_share_against_the_written_out_share(case):
     nk = TOKENS * top_k
     buffer = MOE.row_buffer(TOKENS, top_k, count, experts)
     assert buffer < nk
-    for held, routed, _, compact, combined in counters.tolist():
+    for row in counters.tolist():
+        held, routed, compact, combined = (
+            row[MOE.MOE_COUNTERS.index("moe_rows_" + name)]
+            for name in ("held", "routed", "compact", "combined"))
         assert routed == nk and 0 < held <= nk
         assert compact == (nk if steer == "fresh" else 0.0)
         assert combined == TOKENS * top_k
