@@ -216,7 +216,13 @@ _CALLS_RE = re.compile(r"\bfusion\(.*calls=%?([\w.\-]+)")
 # ``ragged-dot-metadata``) after a PREFIX of the name stack that stops
 # short of the model's scopes (``.../fedml.local/closed_call/
 # ragged-dot-none`` on the chip's round). The program has one caller
-# of it, ``ops/moe.py:moe_layer``, under this scope.
+# of it, ``ops/moe.py:moe_layer``, under this scope. Since PR 48 only
+# the grouped products that ``ops/moe.py:product_tiles`` leaves to
+# ``ragged_dot`` need the rename (float32 operands: the evaluator's
+# stack; shapes with few rows a group; a mapped call): the row-tiled
+# kernels of ``ops/grouped.py`` (``moe_rows_product``,
+# ``moe_matrices_product``) are Pallas calls of the program's own, keep
+# their whole ``op_name`` and are found by the scope in it.
 _RENAMED = {
     "ragged-dot-none": "fedml.model.moe.experts",
     "ragged-dot-metadata": "fedml.model.moe.experts",

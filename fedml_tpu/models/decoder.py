@@ -156,8 +156,9 @@ read back, one a SLOT of a token: a token has ``min(num_experts_per_tok,
 experts held)`` slots, its ways where those are the fewer (each finds
 its row through the order's ``inverse``, a second sort) and the held
 experts where THOSE are (no ``inverse`` is computed: :func:`fedml_tpu.
-ops.moe.moe_layer`), and the rows a training step's four gathers into
-and out of the row buffer move — and, in a stack with
+ops.moe.moe_layer`), the rows a training step's four gathers into
+and out of the row buffer move, and the held rows whose grouped
+products ran in the row-tiled kernels — and, in a stack with
 sparse-attention layers, :data:`fedml_tpu.ops.attention.
 ATTN_COUNTERS`) is summed over layers and sown into the ``counters``
 collection, which :meth:`fedml_tpu.models.base.FedModel.

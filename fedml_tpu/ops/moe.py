@@ -11,6 +11,19 @@ all_to_all back, unsort).
 Static shapes throughout: each (source shard -> destination shard) lane
 carries a fixed ``capacity`` of token slots; overflow tokens are dropped
 (standard MoE capacity semantics) and masked slots contribute zero.
+
+The second half of the module is ONE chip's share of a dropless top-k
+sparse layer (:func:`moe_layer`): a router over all experts, the
+assignments to the experts held here ordered by expert into a bounded
+row buffer, and grouped matrix products over those rows
+(:func:`grouped_product`). The products are the layer's largest cost
+and run, on the TPU in bfloat16, in row-tiled Pallas kernels of the
+repo's own (:mod:`fedml_tpu.ops.grouped`, 1.4 to 2.9 times the speed of
+the compiler's ``jax.lax.ragged_dot`` rewrite at the benchmark's
+shapes); the shape rule that sends a call there
+(:func:`fedml_tpu.ops.grouped.tiles`) reads nothing but the operands'
+shape and dtype, and everything else — the CPU, float32, a mapped call
+— keeps ``ragged_dot``.
 """
 
 from __future__ import annotations
@@ -22,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from fedml_tpu.ops import attention
+from fedml_tpu.ops import attention, grouped
 from fedml_tpu.ops.mapped import once_a_client
 
 
@@ -156,9 +169,12 @@ def make_expert_parallel_moe(mesh, axis_name: str = "ep",
 #: the tokens, is ``N`` times the SLOTS a token has: ``min(top_k,
 #: count)``; ``moe_rows_gathered``, the rows a training step's four
 #: gathers move, over ``4 x moe_rows_held`` is the rows moved a held
-#: row)
+#: row; ``moe_rows_tiled``, the held rows of a call whose grouped
+#: products ran in the row-tiled kernels, 0 of one the shape rule left
+#: to ``ragged_dot``: :func:`product_tiles`)
 MOE_COUNTERS = ("moe_rows_held", "moe_rows_routed", "moe_rows_max_expert",
-                "moe_rows_compact", "moe_rows_combined", "moe_rows_gathered")
+                "moe_rows_compact", "moe_rows_combined", "moe_rows_gathered",
+                "moe_rows_tiled")
 
 #: the row buffer holds this many times the held experts' uniform share
 #: of the assignments, and never under this part of all of them,
@@ -231,12 +247,14 @@ def ffn(activation: str, x, *w):
     return _middle(activation, *(x @ m for m in w[:-1])) @ w[-1]
 
 
-def _mapped(fn):
+def _mapped(fn, alone):
     """``fn`` with a ``vmap`` rule that first gives every operand the
     mapped axis: ``ragged_dot`` maps only when all three of its operands
     are mapped along axis 0, and under the cohort's ``vmap`` the weights
-    of a client's first step are not (they are the global ones)."""
-    wrapped = jax.custom_batching.custom_vmap(fn)
+    of a client's first step are not (they are the global ones).
+    ``alone``: what a call that is NOT mapped runs in ``fn``'s stead
+    (the same product; ``fn`` stays the form that is mapped)."""
+    wrapped = jax.custom_batching.custom_vmap(alone)
 
     @wrapped.def_vmap
     def rule(axis_size, in_batched, *args):
@@ -248,14 +266,67 @@ def _mapped(fn):
     return wrapped
 
 
-_ragged = _mapped(lambda x, w, sizes: jax.lax.ragged_dot(x, w, sizes))
-# the product is linear in the rows and in the matrices: each transpose
-# alone, so that no forward product is traced beside them
-_ragged_transposed = _mapped(lambda x, w, sizes, g: (
-    *jax.linear_transpose(
-        lambda x: jax.lax.ragged_dot(x, w, sizes), x)(g),
-    *jax.linear_transpose(
-        lambda w: jax.lax.ragged_dot(x, w, sizes), w)(g)))
+def product_tiles(m: int, k: int, n: int, groups: int, dtype):
+    """How a grouped product of ``[m, k]`` rows by ``groups`` matrices
+    ``[k, n]`` of ``dtype`` runs HERE: the row tiles of the TPU's tiled
+    kernels (:func:`fedml_tpu.ops.grouped.tiles`, the shape rule), or
+    None for ``jax.lax.ragged_dot`` — off the TPU always."""
+    if not attention._on_tpu():
+        return None
+    return grouped.tiles(m, k, n, groups, dtype)
+
+
+def _tiles_of(x, w):
+    """:func:`product_tiles` of rows ``x`` by matrices ``w`` (None where
+    their dtypes differ)."""
+    return product_tiles(*x.shape, w.shape[2], w.shape[0],
+                         x.dtype if x.dtype == w.dtype else None)
+
+
+def _ragged(x, w, sizes):
+    return jax.lax.ragged_dot(x, w, sizes)
+
+
+def _ragged_transposed(x, w, sizes, g):
+    # the product is linear in the rows and in the matrices: each
+    # transpose alone, so that no forward product is traced beside them
+    return (*jax.linear_transpose(lambda x: _ragged(x, w, sizes), x)(g),
+            *jax.linear_transpose(lambda w: _ragged(x, w, sizes), w)(g))
+
+
+def _product(x, w, sizes):
+    tiles = _tiles_of(x, w)
+    if tiles is None:
+        return _ragged(x, w, sizes)
+    return grouped.rows_product(x, w, sizes, tm=tiles.tm, sub=tiles.sub,
+                                tn=w.shape[2])
+
+
+def _product_transposed(x, w, sizes, g):
+    tiles = _tiles_of(x, w)
+    if tiles is None or g.dtype != x.dtype:
+        return _ragged_transposed(x, w, sizes, g)
+    return (grouped.rows_product(g, w, sizes, tm=tiles.tm, sub=tiles.sub,
+                                 tn=w.shape[1], transposed=True),
+            grouped.matrices_product(x, g, sizes, tm=tiles.matrices,
+                                     tk=w.shape[1], tn=w.shape[2]))
+
+
+_grouped = _mapped(_ragged, _product)
+_grouped_transposed = _mapped(_ragged_transposed, _product_transposed)
+
+
+@jax.custom_batching.custom_vmap
+def _rows_tiled(n_held, tiled):
+    """``n_held`` where ``tiled``, else 0 — and 0 under ``vmap``, which
+    keeps the ``ragged_dot`` form (:func:`_mapped`): the held rows whose
+    products ran in the tiled kernels."""
+    return jnp.where(tiled, n_held, 0)
+
+
+@_rows_tiled.def_vmap
+def _rows_tiled_mapped(axis_size, in_batched, n_held, tiled):
+    return jnp.zeros((axis_size,), n_held.dtype), True
 
 
 @jax.custom_vjp
@@ -263,17 +334,45 @@ def grouped_product(x, w, sizes):
     """Rows ``[M, K]`` sorted by group, one ``[K, N]`` matrix a group
     (``w`` ``[G, K, N]``), ``sizes`` ``[G]`` rows a group -> ``[M, N]``;
     rows past ``sum(sizes)`` are not to be read, of the result and of
-    the rows' cotangent alike (the TPU kernel leaves them unwritten:
+    the rows' cotangent alike (the TPU kernels leave them unwritten:
     the caller reads neither end there, :func:`_read_back`).
-    ``jax.lax.ragged_dot``
-    (on the TPU a Mosaic kernel that visits the row tiles present) and
-    its own two transposes, made to run under ``vmap``."""
-    return _ragged(x, w, sizes)
+
+    What runs where (:func:`product_tiles`, decided while tracing from
+    ``M``, ``K``, ``N``, ``G``, the dtype, the backend and whether the
+    call is mapped — nothing else):
+
+    * on the TPU, bfloat16 operands, a call of its own: the row-tiled
+      kernels of :mod:`fedml_tpu.ops.grouped` — :func:`~fedml_tpu.ops.
+      grouped.rows_product` forward and for the rows' cotangent,
+      :func:`~fedml_tpu.ops.grouped.matrices_product` for the matrices'
+      — in the tiles :func:`~fedml_tpu.ops.grouped.tiles` gives the
+      shape: a group's whole matrix in fast memory, tiles of 512 rows
+      worked in parts of 128, the work following the rows present. On a
+      v5e a product of LFM2's 8,260 held rows by 8 matrices of 2,048 x
+      1,792 takes 0.42 ms (146 TFLOP/s) against ``ragged_dot``'s 0.92
+      (66), and every shape of the benchmark's six cells, from 128 rows
+      an expert to 1,024, reads 1.4 to 2.9 times faster in each of the
+      three kinds (that function's table; ``PERF.md`` section 6,
+      PR 48). bfloat16 products summed in float32 over the whole
+      contraction and rounded once: ``ragged_dot``'s sums in another
+      order;
+    * everywhere else ``jax.lax.ragged_dot`` (on the TPU the compiler's
+      own rewrite, ``ragged-dot-none`` in a trace) and its two
+      ``linear_transpose``s: off the TPU; float32 operands (the
+      evaluator's stack, whose arithmetic is the configuration's stated
+      one); fewer rows a group than was measured; widths off the lanes;
+      and under ``vmap`` (:func:`_mapped`: the mapped form is the one
+      ``ragged_dot`` has).
+
+    A kernel's result lies where the kernel wrote it, not in the chip's
+    fast memory: the gathers that read one say so to :func:`_gathered`.
+    """
+    return _grouped(x, w, sizes)
 
 
 grouped_product.defvjp(
-    lambda x, w, sizes: (_ragged(x, w, sizes), (x, w, sizes)),
-    lambda res, g: (*_ragged_transposed(*res, g), None))
+    lambda x, w, sizes: (_grouped(x, w, sizes), (x, w, sizes)),
+    lambda res, g: (*_grouped_transposed(*res, g), None))
 
 
 #: how a router turns its logits into the probabilities it ranks
@@ -519,22 +618,43 @@ def _all_rows(x, index):
 RESIDENT_BYTES = 80 * 2 ** 20
 
 
-def _resident_rows(x) -> int:
+#: the leading part of a KERNEL's result that a gather reads it out of:
+#: the compiler holds an operand of its own making in fast memory by
+#: itself, a Pallas kernel's result lies where the kernel wrote it, and
+#: a gather of 32,768 to 65,536 rows out of the 64 MiB one of the tiled
+#: products wrote took 1.0 to 2.0 ms more a layer step than out of
+#: ``ragged_dot``'s (LFM2's ``fedml.model.moe.route`` 38.9 -> 55.7 ms a
+#: round, Keye's 83.9 -> 124.3; with the slice 40.8 and 85.4: ``PERF.md``
+#: section 6, PR 48). A slice of
+#: the leading rows is a copy the compiler makes in fast memory, as it
+#: is for a buffer over :data:`RESIDENT_BYTES`; three quarters of a
+#: buffer of twice the uniform share is one and a half times that share
+KERNEL_RESULT_PART = 3 / 4
+
+
+def _resident_rows(x, kernel_made: bool = False) -> int:
     """The leading rows of ``x`` ``[r, ...]`` that make a gather's
     operand of at most :data:`RESIDENT_BYTES`, in whole row tiles: all
-    ``r`` where ``x`` is that small."""
+    ``r`` where ``x`` is that small — but of a result that is
+    ``kernel_made`` at most :data:`KERNEL_RESULT_PART`, so that what is
+    gathered from is a copy of the compiler's own."""
     row = x.dtype.itemsize * (x.size // x.shape[0])
     rows = RESIDENT_BYTES // row // ROW_TILE * ROW_TILE
+    if kernel_made:
+        rows = min(rows, int(KERNEL_RESULT_PART * x.shape[0])
+                   // ROW_TILE * ROW_TILE)
     return min(rows, x.shape[0]) or x.shape[0]
 
 
-def _gathered(x, index, n_held, then=lambda rows: rows):
+def _gathered(x, index, n_held, then=lambda rows: rows,
+              kernel_made: bool = False):
     """``then(x[index])``, where ``index`` names one of the first
     ``n_held`` rows, and row 0 read where it does not, for ``then`` or
     the caller to make a zero of. Read out of the leading
     :func:`_resident_rows` of ``x`` alone when the held rows are fewer
     than those, so that a buffer too large for the chip's fast memory
-    is read out of it all the same: what is gathered from follows
+    (or one that is ``kernel_made``, and so not in it) is read out of
+    it all the same: what is gathered from follows
     ``n_held``, not the buffer. Else, and where ``x`` is small enough
     whole, out of all of it. (``then`` runs inside the ``cond`` that
     picks: a select and a sum applied after it the compiler parts, the
@@ -542,7 +662,7 @@ def _gathered(x, index, n_held, then=lambda rows: rows):
     at = jnp.where(index < n_held, index, 0)
     read = lambda rows: lambda: then(
         x[:rows].at[at].get(mode="promise_in_bounds"))
-    part = _resident_rows(x)
+    part = _resident_rows(x, kernel_made)
     if part == x.shape[0]:
         return read(part)()
     return jax.lax.cond(n_held < part, read(part), read(x.shape[0]))
@@ -571,16 +691,23 @@ def _read_back(x, index, n_held):
                      lambda rows: _zero_past_held(rows, index, n_held))
 
 
-def _read_summed(axis: int, x, index, n_held):
+def _read_summed(axis: int, kernel_made: bool, x, index, n_held):
     """:func:`_read_back` summed over ``axis``, a token's slots: the
     select is part of the sum's own pass."""
     return _gathered(
         x, index, n_held,
-        lambda rows: _zero_past_held(rows, index, n_held).sum(axis))
+        lambda rows: _zero_past_held(rows, index, n_held).sum(axis),
+        kernel_made)
 
 
-@once_a_client
-def _read_weighed(x, index, n_held):
+def _weighed_rows(kernel_made: bool, x, index, n_held):
+    return jax.lax.cond(
+        n_held > 0,
+        lambda: _gathered(x, index, n_held, kernel_made=kernel_made),
+        lambda: jnp.zeros(index.shape + x.shape[1:], x.dtype))
+
+
+def _read_weighed(x, index, n_held, kernel_made: bool = False):
     """:func:`_read_back` for a sum that WEIGHS its rows by
     :func:`_held_weights`: a place past the held rows reads row 0 — a
     held expert's, so finite — and its weight is the zero. No select
@@ -590,10 +717,11 @@ def _read_weighed(x, index, n_held):
     of the buffer zeroed for the purpose: reading one row of a grouped
     product's result to write it back cost a pass over the buffer on the
     chip). Where NO row is held row 0 is anything at all, and what is
-    read is zeros."""
-    return jax.lax.cond(
-        n_held > 0, lambda: _gathered(x, index, n_held),
-        lambda: jnp.zeros(index.shape + x.shape[1:], x.dtype))
+    read is zeros. ``kernel_made``: ``x`` is a Pallas kernel's result
+    (:func:`_gathered`). Unbatched under ``vmap``
+    (:func:`once_a_client`)."""
+    return once_a_client(functools.partial(_weighed_rows, kernel_made))(
+        x, index, n_held)
 
 
 def _slot_places(back, k: int):
@@ -669,7 +797,8 @@ def _held_rows_forward(r, activation, slot_w, h, w, top_w, order, back,
     with jax.named_scope(ROUTE):
         places, axes = _by_slot(back, k)
         y = jnp.einsum(axes + "d,nk->nd",
-                       _read_weighed(out, places, n_held),
+                       _read_weighed(out, places, n_held,
+                                     _tiles_of(rows, w[0]) is not None),
                        slot_w.astype(out.dtype))
     return y, (rows, into, out)
 
@@ -695,14 +824,17 @@ def _held_rows_backward(r, activation, kept, w, top_w, order, back, sizes,
     with jax.named_scope(EXPERTS):
         up, middle = jax.vjp(
             functools.partial(_middle, activation), *into)
-        d_up, d_out_w = _ragged_transposed(up, w[-1], sizes, d_out)
+        d_up, d_out_w = _grouped_transposed(up, w[-1], sizes, d_out)
         d_rows, d_w = zip(*(
-            _ragged_transposed(rows, m, sizes, d_into)
+            _grouped_transposed(rows, m, sizes, d_into)
             for m, d_into in zip(w[:-1], middle(d_up))))
     with jax.named_scope(ROUTE):
         places, axes = _by_slot(back, k)
+        # (one leading matrix: its rows' cotangent is a kernel's result
+        # as it is; of two the compiler's sum is what is read)
         d_h = once_a_client(functools.partial(
-            _read_summed, axes.index("k")))(
+            _read_summed, axes.index("k"),
+            len(d_rows) == 1 and _tiles_of(rows, w[0]) is not None))(
                 sum(d_rows[1:], d_rows[0]), places, n_held)
     return d_h, (*d_w, d_out_w), d_top_w
 
@@ -873,8 +1005,10 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     :func:`route_top_k`, ``scoring`` its kind of probability); the
     assignments whose
     expert is held are ordered by expert and go through grouped matrix
-    products (:func:`grouped_product`: on the TPU a kernel whose work
-    follows the rows present, never ``E x N``), are weighted —
+    products (:func:`grouped_product`: on the TPU kernels whose work
+    follows the rows present, never ``E x N`` — the row-tiled ones of
+    :mod:`fedml_tpu.ops.grouped` where the shape rule sends the call,
+    else the compiler's ``ragged_dot``), are weighted —
     weights normalised over all ``top_k``, held or not — and summed back
     into their tokens; the shared expert is added. That sum, and its
     transpose in the backward rule, reads a row a SLOT of a token, of
@@ -902,7 +1036,7 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     experts would add is left out: on one chip there is no exchange and
     nothing stands in for one.
 
-    -> ``(y [N, D], counters float32 [6])`` in :data:`MOE_COUNTERS`'
+    -> ``(y [N, D], counters float32 [7])`` in :data:`MOE_COUNTERS`'
     order: assignments that landed on held experts, assignments made
     (``N x top_k``), rows of the fullest held expert, assignments made
     in a call that went through the bounded buffer, rows the combine
@@ -910,7 +1044,9 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     width that the four gathers of a training step move: twice the
     buffer of the side taken (forward the tokens' rows, in the rule
     their cotangents') and twice what the combine reads (forward, and
-    its transpose in the rule)."""
+    its transpose in the rule), and the held rows of a call whose
+    products ran in the row-tiled kernels (:func:`product_tiles`; 0
+    else, and under ``vmap``)."""
     count = held[1]
     n, _ = h.shape
     apart = router_input is not None  # a router with an input of its own
@@ -936,9 +1072,14 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
             y = y + ffn(activation, h, *params["shared"])
     combined = n * min(top_k, count)
     buffered = jnp.where(bounded > 0, buffer, n * top_k)  # the side taken
+    tiled = [_tiles_of(jax.ShapeDtypeStruct((r, inside.shape[1]),
+                                            inside.dtype), params["w1"])
+             is not None for r in (buffer, n * top_k)]
     counters = jnp.stack([
         routing.n_held.astype(jnp.float32), jnp.float32(n * top_k),
         jnp.max(routing.sizes).astype(jnp.float32), bounded,
         jnp.float32(combined),
-        2 * buffered.astype(jnp.float32) + 2 * combined])
+        2 * buffered.astype(jnp.float32) + 2 * combined,
+        _rows_tiled(routing.n_held, jnp.where(bounded > 0, *tiled)).astype(
+            jnp.float32)])
     return y.astype(h.dtype), counters

@@ -64,6 +64,13 @@ def _shapes(tree, sharding):
     )
 
 
+def _grouped_products_in(text: str) -> bool:
+    """Whether a program holds the sparse layers' grouped products: the
+    compiler's ``ragged_dot`` kernels or, where the shape rule
+    (``ops/moe.py:product_tiles``) tiles the rows, ``ops/grouped.py``'s."""
+    return "ragged-dot" in text or "moe_rows_product" in text
+
+
 def _tree_bytes(tree) -> int:
     return int(sum(
         np.prod(x.shape) * np.dtype(x.dtype).itemsize
@@ -249,8 +256,16 @@ def test_rematerialised_sparse_layer_compiles_for_v5e(one_chip, monkeypatch):
     assert calls("moe_rank_top_k") == 1
     # the side that fits the bounded buffer: 3 forward, 6 backward; the
     # worst-case side: 3 forward, and 3 + 6 in its own backward rule
-    # (27 with a second forward of both sides)
-    assert calls("ragged-dot-none") == 3 + 6 + 3 + 9
+    # (27 with a second forward of both sides) — all the compiler's
+    # ``ragged_dot`` or all the row-tiled kernels (two by the rows for
+    # each one by the matrices), as the shape rule sends this shape
+    from fedml_tpu.ops.moe import product_tiles
+    products = {"ragged-dot-none": 3 + 6 + 3 + 9, "moe_rows_product": 0,
+                "moe_matrices_product": 0}
+    if product_tiles(16384, 2048, 768, 16, jnp.bfloat16) is not None:
+        products = {"ragged-dot-none": 0, "moe_rows_product": 3 + 3 + 6 + 3,
+                    "moe_matrices_product": 3 + 3}
+    assert {name: calls(name) for name in products} == products
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
@@ -325,6 +340,51 @@ def test_grouped_expert_products_compile_for_v5e(one_chip):
                 "fedml.model.moe.experts"}
 
 
+@pytest.mark.parametrize("tokens, width, inner, held, experts, top_k", [
+    (8192, 2048, 1792, 8, 32, 4),  # LFM2's share
+    (8192, 2560, 768, 16, 64, 6),  # SmallThinker's
+])
+def test_row_tiled_expert_products_compile_for_v5e(
+        one_chip, monkeypatch, tokens, width, inner, held, experts, top_k):
+    """A training step of one sparse layer's share at the widths whose
+    grouped products the shape rule sends to the row-tiled kernels
+    (``ops/grouped.py``): forward three calls of ``moe_rows_product``
+    and in the rule three more and three of ``moe_matrices_product`` on
+    the bounded side (the worst-case side runs its forward pass again),
+    no ``ragged_dot`` left, every kernel call under the experts' scope
+    by its own ``op_name``, whole matrices in fast memory within what
+    the kernels ask for, and no more scratch than the plain form's."""
+    from fedml_tpu.core.memscope import parse_scopes
+    from fedml_tpu.ops import attention as A
+    from fedml_tpu.ops.moe import moe_layer
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+    params = {"router": sds(width, experts), "w1": sds(held, width, inner),
+              "w3": sds(held, width, inner), "w2": sds(held, inner, width)}
+
+    def loss(params, h, g):
+        y, counters = moe_layer(params, h, (0, held), top_k, 1.0)
+        return jnp.sum((y * g).astype(jnp.float32)), counters
+
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)).lower(
+            params, sds(tokens, width), sds(tokens, width)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line
+               and "_product" in line]
+    count = lambda name: sum(name + "/pallas_call" in k for k in kernels)
+    assert count("moe_rows_product") == 6 + 9
+    assert count("moe_matrices_product") == 3 + 3
+    scopes = parse_scopes(text)
+    assert {scopes[k.split("=")[0].strip().removeprefix("ROOT ").lstrip("%")]
+            for k in kernels} == {"fedml.model.moe.experts"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.85e9
+
+
 def test_sparse_layer_reads_back_without_a_zero_row_for_v5e(
         one_chip, monkeypatch):
     """A training step of ONE sparse layer's share at SmallThinker's
@@ -354,7 +414,11 @@ def test_sparse_layer_reads_back_without_a_zero_row_for_v5e(
             params, sds(8192, 2560), sds(8192, 2560)).compile()
     text = compiled.as_text()
     assert "[24577,2560]" not in text and "[49153,2560]" not in text
-    assert " pad(" not in text and " while(" not in text
+    # (no array of the rows' dtype is padded: the walk of the row-tiled
+    # products pads a few dozen integers for its own gathers)
+    assert not [line for line in text.splitlines() if " pad(" in line
+                and line.split("=")[1].lstrip().startswith("bf16[")]
+    assert " while(" not in text
     # the leading 16,384 rows (80 MiB), sliced straight into the fast
     # memory space: forward and in the rule, on the bounded side
     parts = [line for line in text.splitlines() if " slice(" in line
@@ -514,7 +578,7 @@ def test_nemotron_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
         sim._round, donate_argnums=sim._donate_argnums()
     ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
     text = compiled.as_text()
-    assert "splash_mqa_fwd" in text and "ragged-dot" in text
+    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
     ma = compiled.memory_analysis()
     need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
             + ma.generated_code_size_in_bytes)
@@ -565,7 +629,7 @@ def test_smallthinker_round_and_evaluator_compile_for_v5e(
         sim._round, donate_argnums=sim._donate_argnums()
     ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
     text = compiled.as_text()
-    assert "splash_mqa_fwd" in text and "ragged-dot" in text
+    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
     ma = compiled.memory_analysis()
     need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
             + ma.generated_code_size_in_bytes)
@@ -613,7 +677,7 @@ def test_joyai_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
         sim._round, donate_argnums=sim._donate_argnums()
     ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
     text = compiled.as_text()
-    assert "splash_mqa_fwd" in text and "ragged-dot" in text
+    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
     ma = compiled.memory_analysis()
     need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
             + ma.generated_code_size_in_bytes)
@@ -661,7 +725,7 @@ def test_lfm2_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
         sim._round, donate_argnums=sim._donate_argnums()
     ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
     text = compiled.as_text()
-    assert "splash_mqa_fwd" in text and "ragged-dot" in text
+    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
     ma = compiled.memory_analysis()
     need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
             + ma.generated_code_size_in_bytes)
