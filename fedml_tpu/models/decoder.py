@@ -40,7 +40,9 @@ configuration; any other name is refused.
   ``latent_attention`` record: ``q_lora_rank``, ``kv_lora_rank``,
   ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``): ``c_q =
   RMSNorm(h W_qa)``, ``q = c_q W_qb`` as ``heads_per_layer[l]`` heads
-  of ``[nope | rope]``; ``[c_kv | k_r] = h W_kva``, ``c_kv <-
+  of ``[nope | rope]`` — or, with ``q_lora_rank`` null, ``q = h W_q``
+  straight from the normed input, with no ``q_a_proj`` / ``q_a_norm``
+  leaves; ``[c_kv | k_r] = h W_kva``, ``c_kv <-
   RMSNorm(c_kv)``, ``[k_nope | v] = c_kv W_kvb`` as heads of ``nope +
   v_head_dim``; the rotary (the kind's ``rope`` record, over
   ``qk_rope_head_dim`` dimensions) turns the LAST ``rope`` dimensions
@@ -48,10 +50,34 @@ configuration; any other name is refused.
   beside every head's own ``k_nope``: keys of ``nope + rope`` beside
   values of ``v_head_dim``, scores scaled by ``1 / sqrt(nope +
   rope)``, every head with keys of its own (the kernel runs them as
-  key-value heads of group 1); all heads are held (no
-  ``query_heads_held``) and ``head_dim`` is not read. The two
-  down-projections, their norms and the two up-projections run under
+  key-value heads of group 1), so a chip's share is ``query_heads_held``
+  alone (``W_kva`` and its norm stay whole); ``gating`` is ONE sigmoid
+  scalar a head and token here too; ``head_dim`` is not read. The
+  down-projections, their norms and the up-projections run under
   the scope ``fedml.model.attn.latent``, inside ``fedml.model.attn``;
+- ``delta_attention`` (a gated delta rule with a decay a channel of the
+  key, Kimi Delta Attention; sizes in the ``delta_attention`` record:
+  ``head_dim`` = K = V, ``conv_kernel`` taps, ``gate_lower_bound``,
+  ``chunk_size``; any other key refused): ``q~, k~, v~ = h W_q, h W_k,
+  h W_v``, each through its own causal depthwise convolution
+  (:func:`causal_depthwise_conv`, no bias) and SiLU; ``q = q' /
+  ||q'||_2 / sqrt(K)``, ``k = k' / ||k'||_2`` a head
+  (:func:`l2_normalised`); ``gamma = gate_lower_bound *
+  sigmoid(exp(A_log_head) * (h W_f + dt_bias))``, a log-decay a channel
+  in ``(gate_lower_bound, 0)``; ``beta = sigmoid(h W_b)`` a head; the
+  recurrence ``S_t = (I - beta_t k_t k_t^T) Diag(exp(gamma_t)) S_{t-1}
+  + beta_t k_t v_t^T``, ``o_t = S_t^T q_t`` in chunks
+  (:func:`fedml_tpu.ops.delta.kda_chunked`); ``o <- RMSNorm_head(o) *
+  sigmoid(h W_g)`` (one scale of ``head_dim`` for all heads, the gate a
+  channel); out ``= o W_o``; no position term. A head has its own
+  ``q``, ``k``, ``v``, decay, gate and state: a chip's share is
+  ``query_heads_held`` of ``heads_per_layer[l]``. A fresh gate
+  (:data:`DELTA_SLOPES`, :data:`DELTA_STEPS`) rests where a chunk keeps
+  a good part of a channel and loses a good part. The whole mixer runs
+  under the scope ``fedml.model.delta``, the recurrence (from the
+  decays' running sums to ``o``) under ``fedml.model.delta.scan`` and
+  the convolutions, norms and gates under ``fedml.model.delta.mix``
+  inside it;
 - ``state_space`` (Mamba-2; sizes in the ``state_space`` record:
   ``num_heads`` heads of ``head_dim``, ``n_groups`` groups of
   ``state_size``, ``conv_kernel``, ``chunk_size``, the ``time_step_*``
@@ -90,7 +116,13 @@ configuration; any other name is refused.
   score-correction bias that the CHOICE reads — the experts of largest
   probability plus bias — and the weights do not (the chosen experts'
   unbiased probabilities renormalised), so no gradient reaches it and
-  a round leaves it as it came. ``mlp_activation``
+  a round leaves it as it came. With ``router_groups = [n_group,
+  topk_group]`` the choice is limited to a token's ``topk_group`` open
+  groups of ``num_experts / n_group`` consecutive experts — a group
+  scores the sum of its two largest probabilities plus bias
+  (:func:`fedml_tpu.ops.moe.open_groups`); one group, the default, is
+  no limit — and the groups' choice, a set too, passes no gradient.
+  ``mlp_activation``
   (:data:`fedml_tpu.ops.moe.ACTIVATIONS`, the shared expert's too):
   ``silu_gated`` (the default: three matrices an expert, ``(silu(x W1)
   * (x W3)) W2``, as the dense layer is), ``relu_gated`` (three,
@@ -116,7 +148,9 @@ its ``num_heads``, whole groups only, with those groups' ``B`` / ``C``,
 convolution channels and gated-norm groups (the norm's statistics are a
 group's own); ``query_heads_held`` of ``heads_per_layer[l]`` over
 ``key_value_heads_held`` of ``num_key_value_heads`` (the held query
-heads read held key-value heads, evenly);
+heads read held key-value heads, evenly) — stated ONCE and read by the
+delta-rule and the latent layers alike, whose heads have keys of their
+own, so that a stack with either has no ``key_value_heads_held``;
 ``shared_expert_columns_held`` of ``shared_expert_intermediate_size``.
 What the absent heads, columns and experts would add to a layer's
 output is left out, and nothing stands in for their exchange.
@@ -131,8 +165,11 @@ recomputed in the backward pass (``nn.remat``) but for what
 :data:`KEPT` names. Beside a layer's input it keeps its attention
 kernel's output and row log-sum-exp; in a sparse-attention layer the
 selection; in a state-space layer the states entering each chunk and
-the scan's result; in a short-convolution layer nothing more (the
-mixer runs twice a step); and of a sparse feed-forward the routing (the
+the scan's result; in a delta-rule layer the states entering every
+other chunk and the result (:data:`fedml_tpu.ops.delta.KEPT`); in a
+short-convolution layer
+nothing more (the mixer runs twice a step); and of a sparse
+feed-forward the routing (the
 router's logits, the chosen experts and their weights, the order of
 the assignments, where a token's slots find their rows, the rows a
 held expert) and what the held experts' backward rule reads (the row
@@ -140,11 +177,15 @@ buffer's rows, those rows through each matrix that leads in, and their
 result; :data:`fedml_tpu.ops.moe.KEPT`). So these run ONCE a training
 step: the forward attention kernel, the index and its top-k; of the
 chunked scan the mix, the chunks' own states and the recurrence between
-them; the router's product, its scoring's ranking (``ops.moe.largest``), the
+them; the delta rule's recurrence between chunks; the router's product,
+the groups' scores, its scoring's ranking (``ops.moe.largest``), the
 sorts and the count, the row gather, the grouped products forward and
 the combine. And these twice: the norms, the attention's projections,
 rotary, gate and output projection, the convolution, the dense
-feed-forward, the shared expert and the latent projections. The
+feed-forward, the shared expert and the latent projections; of a
+delta-rule layer its seven projections, taps, norms and gates and,
+inside a chunk, the two decayed Gram products, the solve and the reads
+of the entering state (which the backward pass differentiates). The
 embedding, outside the layers, gathers its rows once forward and forms
 its table's gradient once backward by a rule of its own
 (:class:`DecoderLM`).
@@ -157,8 +198,9 @@ experts held)`` slots, its ways where those are the fewer (each finds
 its row through the order's ``inverse``, a second sort) and the held
 experts where THOSE are (no ``inverse`` is computed: :func:`fedml_tpu.
 ops.moe.moe_layer`), the rows a training step's four gathers into
-and out of the row buffer move, and the held rows whose grouped
-products ran in the row-tiled kernels — and, in a stack with
+and out of the row buffer move, the held rows whose grouped
+products ran in the row-tiled kernels, and the tokens for which a group
+that holds a held expert was open — and, in a stack with
 sparse-attention layers, :data:`fedml_tpu.ops.attention.
 ATTN_COUNTERS`) is summed over layers and sown into the ``counters``
 collection, which :meth:`fedml_tpu.models.base.FedModel.
@@ -185,10 +227,11 @@ from fedml_tpu.ops.attention import (
     ATTN_COUNTERS, KEPT as ATTENTION_KEPT, KEPT_SELECTION, causal_attention,
     index_scores, select_top_k,
 )
+from fedml_tpu.ops.delta import KEPT as DELTA_KEPT, kda_chunked
 from fedml_tpu.ops.embedding import embedding_lookup
 from fedml_tpu.ops.moe import (
-    ACTIVATIONS, KEPT as MOE_KEPT, MOE_COUNTERS, SCORINGS, SILU_GATED,
-    leading, moe_layer,
+    ACTIVATIONS, KEPT as MOE_KEPT, MOE_COUNTERS, ONE_GROUP, SCORINGS,
+    SILU_GATED, leading, moe_layer,
 )
 from fedml_tpu.ops.ssm import KEPT as SCAN_KEPT, ssd_chunked
 
@@ -196,8 +239,9 @@ NONE = "none"  # a layer without this half; a rope record's ``rope_type``
 FULL, SLIDING, SELECTED, LATENT = (
     "full_attention", "sliding_attention", "sparse_attention",
     "latent_attention")
-STATE_SPACE, SHORT_CONV = "state_space", "short_conv"
-MIXERS = (FULL, SLIDING, SELECTED, LATENT, STATE_SPACE, SHORT_CONV, NONE)
+STATE_SPACE, SHORT_CONV, DELTA = "state_space", "short_conv", "delta_attention"
+MIXERS = (FULL, SLIDING, SELECTED, LATENT, STATE_SPACE, SHORT_CONV, DELTA,
+          NONE)
 DENSE, SPARSE = "dense", "sparse"
 FEED_FORWARDS = (DENSE, SPARSE, NONE)
 SIGMOID = "sigmoid"  # the router's scoring where the configuration names none
@@ -212,16 +256,26 @@ ATTENTIONS = (FULL, SLIDING, SELECTED, LATENT)
 # a rope record's ``rope_pairing``: which dimensions turn together
 HALF, ADJACENT = "half", "adjacent"
 PAIRINGS = (HALF, ADJACENT)
-# the sizes a ``latent_attention`` record gives
+# the sizes a ``latent_attention`` record gives (``q_lora_rank`` an
+# integer, or null: queries straight from the normed input)
 LATENT_SIZES = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                 "qk_rope_head_dim", "v_head_dim")
 # what a ``short_conv`` record gives, and each key's type
 SHORT_CONV_KEYS = {"kernel": int, "bias": bool}
+# what a ``delta_attention`` record gives, and each key's type
+DELTA_KEYS = {"head_dim": int, "conv_kernel": int,
+              "gate_lower_bound": (int, float), "chunk_size": int}
+#: the law of a fresh delta-rule gate: ``A_log`` the log of a slope
+#: drawn uniform in this range, ``dt_bias`` the gate's value at rest as
+#: a decay a token drawn log-uniform in :data:`DELTA_STEPS` (so that a
+#: chunk of 64 tokens keeps between e^-6.4 and e^-0.064 of a channel,
+#: the median channel a half: the decay does work at a seed)
+DELTA_SLOPES, DELTA_STEPS = (0.5, 1.5), (1e-3, 1e-1)
 # the shares of attention heads a chip may hold
 HEAD_SHARES = ("query_heads_held", "key_value_heads_held")
 
 #: what a rematerialised layer keeps (``checkpoint_name``s)
-KEPT = ATTENTION_KEPT + SCAN_KEPT + MOE_KEPT
+KEPT = ATTENTION_KEPT + SCAN_KEPT + DELTA_KEPT + MOE_KEPT
 
 
 def attention_counters(layer_types) -> tuple[str, ...]:
@@ -326,13 +380,17 @@ def _counted(routed, attended):
             else routed)
 
 
+def _steps(key, shape, low: float, high: float):
+    """Steps drawn log-uniform in ``[low, high]``."""
+    return jnp.exp(jax.random.uniform(key, shape) * (
+        math.log(high) - math.log(low)) + math.log(low))
+
+
 def _inverse_softplus_steps(low: float, high: float, floor: float):
     """``dt_bias``: the inverse softplus of a step drawn log-uniform in
     ``[low, high]`` and floored."""
     def init(key, shape, dtype=jnp.float32):
-        step = jnp.exp(jax.random.uniform(key, shape) * (
-            math.log(high) - math.log(low)) + math.log(low))
-        step = jnp.maximum(step, floor)
+        step = jnp.maximum(_steps(key, shape, low, high), floor)
         return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
     return init
 
@@ -343,6 +401,22 @@ def _log_uniform(low: float, high: float):
         return jnp.log(jax.random.uniform(
             key, shape, minval=low, maxval=high)).astype(dtype)
     return init
+
+
+def _inverse_sigmoid_steps(low: float, high: float, bound: float):
+    """``dt_bias`` of a delta-rule gate ``bound * sigmoid(.)``: where the
+    gate rests at a decay a token drawn log-uniform in ``[low, high]``
+    (``bound`` negative, ``high < -bound``)."""
+    def init(key, shape, dtype=jnp.float32):
+        part = _steps(key, shape, low, high) / -bound
+        return (jnp.log(part) - jnp.log1p(-part)).astype(dtype)
+    return init
+
+
+def l2_normalised(x, eps: float = 1e-6):
+    """``x / sqrt(sum(x^2) + eps)`` over the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
 
 
 def causal_depthwise_conv(x, kernel, bias=None):
@@ -382,6 +456,8 @@ class DecoderLayer(nn.Module):
             x = x + self.state_space(x)
         elif kind == SHORT_CONV:
             x = x + self.short_conv(x)
+        elif kind == DELTA:
+            x = x + self.delta_attention(x)
         elif kind != NONE:
             x, attended, read = self.attention(x, kind, attended)
         if c["mlp_layer_types"][l] == DENSE:
@@ -435,7 +511,8 @@ class DecoderLayer(nn.Module):
                 scoring=c["router_scoring"], activation=activation,
                 router_input=read.reshape(b * t, d) if (
                     c["router_input"] == ATTENTION_INPUT) else None,
-                renorm_epsilon=c["router_renorm_epsilon"])
+                renorm_epsilon=c["router_renorm_epsilon"],
+                groups=tuple(c["router_groups"]))
             return x + y.reshape(b, t, d), _counted(counters, attended)
 
     @nn.nowrap
@@ -457,7 +534,8 @@ class DecoderLayer(nn.Module):
             h = RMSNorm(c["rms_norm_eps"], name="attn_norm")(x)
             how = {}
             if kind == LATENT:
-                heads = c["heads_per_layer"][self.index]
+                heads = _held(
+                    c, "query_heads_held", c["heads_per_layer"][self.index])
                 q, k, v, k_rope = self.latent_heads(h, heads)
                 if turns:
                     nope = k.shape[-1]
@@ -503,16 +581,20 @@ class DecoderLayer(nn.Module):
         keys' own part ``[B, T, heads, nope]``, ``v`` ``[B, T, heads,
         v_head_dim]``, the one rotary key head ``[B, T, 1, rope]``),
         nothing rotated yet: the two down-projections, their norms and
-        the two up-projections, under a scope of their own."""
+        the two up-projections (``q_lora_rank`` null: the queries' ONE
+        projection), under a scope of their own."""
         c = dict(self.cfg)
         la = dict(c["latent_attention"])
         b, t, _ = h.shape
         nope, rope, dv = (la["qk_nope_head_dim"], la["qk_rope_head_dim"],
                           la["v_head_dim"])
         with jax.named_scope("fedml.model.attn.latent"):
-            cq = RMSNorm(c["rms_norm_eps"], name="q_a_norm")(
-                self.dense(la["q_lora_rank"], "q_a_proj")(h))
-            q = self.dense(heads * (nope + rope), "q_b_proj")(cq)
+            if la["q_lora_rank"] is None:
+                q = self.dense(heads * (nope + rope), "q_proj")(h)
+            else:
+                cq = RMSNorm(c["rms_norm_eps"], name="q_a_norm")(
+                    self.dense(la["q_lora_rank"], "q_a_proj")(h))
+                q = self.dense(heads * (nope + rope), "q_b_proj")(cq)
             ckv, k_rope = jnp.split(self.dense(
                 la["kv_lora_rank"] + rope, "kv_a_proj")(h),
                 [la["kv_lora_rank"]], -1)
@@ -584,6 +666,45 @@ class DecoderLayer(nn.Module):
             with jax.named_scope("fedml.model.conv.mix"):
                 y = cm * causal_depthwise_conv(bm * u, kernel, bias)
             return self.dense(d, "out_proj")(y)
+
+    @nn.nowrap
+    def delta_attention(self, x):
+        """``mixer(norm(x))`` of a gated delta-rule layer (module
+        docstring) over the heads held here."""
+        c = dict(self.cfg)
+        s = dict(c["delta_attention"])
+        b, t, d = x.shape
+        hd, bound = s["head_dim"], float(s["gate_lower_bound"])
+        heads = _held(c, "query_heads_held", c["heads_per_layer"][self.index])
+        inner = heads * hd
+        own = lambda name, init, *shape: self.param(name, init, shape)
+        by_head = lambda v: v.reshape(b, t, heads, hd)
+        with jax.named_scope("fedml.model.delta"):
+            h = RMSNorm(c["rms_norm_eps"], name="delta_norm")(x)
+            into = [self.dense(inner, m + "_proj")(h) for m in "qkv"]
+            decay = self.dense(inner, "f_proj")(h)
+            write = self.dense(heads, "b_proj")(h)
+            gate = self.dense(inner, "g_proj")(h)
+            with jax.named_scope("fedml.model.delta.mix"):
+                q, k, v = (by_head(jax.nn.silu(causal_depthwise_conv(
+                    y, own(m + "_conv", nn.initializers.lecun_normal(),
+                           s["conv_kernel"], inner).astype(x.dtype))))
+                           for m, y in zip("qkv", into))
+                q = l2_normalised(q) * hd ** -0.5
+                k = l2_normalised(k)
+                slope = jnp.exp(own("A_log", _log_uniform(*DELTA_SLOPES),
+                                    heads).astype(jnp.float32))
+                gamma = bound * jax.nn.sigmoid(slope[:, None] * by_head(
+                    decay.astype(jnp.float32) + own(
+                        "dt_bias",
+                        _inverse_sigmoid_steps(*DELTA_STEPS, bound),
+                        inner).astype(jnp.float32)))
+                beta = jax.nn.sigmoid(write.astype(jnp.float32))
+            o = kda_chunked(q, k, v, gamma, beta, s["chunk_size"])
+            with jax.named_scope("fedml.model.delta.mix"):
+                o = RMSNorm(c["rms_norm_eps"], name="o_norm")(o)
+                o = o * by_head(jax.nn.sigmoid(gate))
+            return self.dense(d, "o_proj")(o.reshape(b, t, inner))
 
     @nn.nowrap
     def select(self, h, rope: dict):
@@ -737,24 +858,73 @@ def _check_attention_share(c: dict, attention_heads: list) -> None:
                 f"not read key_value_heads_held {[kf, kc]} of {kv} evenly")
 
 
+def _check_heads_with_keys_of_their_own(c: dict, kind: str) -> None:
+    """A latent or a delta-rule head has keys and values of its own: a
+    chip's share of such layers is ``query_heads_held`` alone."""
+    if c.get("key_value_heads_held"):
+        raise ValueError(
+            f"decoder: a {kind} head has keys of its own, so a stack with "
+            "such layers states its share as query_heads_held alone; it "
+            "has no key_value_heads_held")
+    held = c.get("query_heads_held")
+    for l, mixer in enumerate(c["layer_types"]):
+        if held and mixer == kind and not _share_in(
+                held, c["heads_per_layer"][l]):
+            raise ValueError(
+                f"decoder: query_heads_held {list(held)} does not lie in "
+                f"layer {l}'s {c['heads_per_layer'][l]} heads")
+
+
 def _check_latent_attention(c: dict) -> None:
     la = c.get("latent_attention") or {}
-    lacks = [k for k in LATENT_SIZES if not isinstance(la.get(k), int)]
+    lacks = [k for k in LATENT_SIZES if not isinstance(la.get(k), int)
+             and not (k == "q_lora_rank" and k in la and la[k] is None)]
     if lacks:
         raise ValueError(
             f"decoder: latent_attention lacks {', '.join(lacks)}")
     unknown = sorted(set(la) - set(LATENT_SIZES))
-    if unknown or min(la[k] for k in LATENT_SIZES) < 1 or (
-            la["qk_rope_head_dim"] % 2):
+    if unknown or min(la[k] for k in LATENT_SIZES if la[k] is not None
+                      ) < 1 or la["qk_rope_head_dim"] % 2:
         raise ValueError(
             "decoder: latent_attention sizes must be at least 1, the "
             f"rotary part even, and no other key given: {la}")
-    shares = [k for k in HEAD_SHARES if c.get(k)]
-    if shares or c["qk_norm"]:
+    if c["qk_norm"]:
         raise ValueError(
-            "decoder: a stack with latent_attention layers holds all its "
-            "heads and norms its latents alone; it has no "
-            f"{', '.join(shares + ['qk_norm'] * bool(c['qk_norm']))}")
+            "decoder: a stack with latent_attention layers norms its "
+            "latents alone; it has no qk_norm")
+    _check_heads_with_keys_of_their_own(c, LATENT)
+
+
+def _check_delta_attention(c: dict) -> None:
+    s = c.get("delta_attention") or {}
+    lacks = [k for k, kind in DELTA_KEYS.items()
+             if not isinstance(s.get(k), kind) or isinstance(s.get(k), bool)]
+    if lacks:
+        raise ValueError(f"decoder: delta_attention lacks {', '.join(lacks)}")
+    unknown = sorted(set(s) - set(DELTA_KEYS))
+    if unknown or s["gate_lower_bound"] >= -DELTA_STEPS[1] or min(
+            s["head_dim"], s["conv_kernel"], s["chunk_size"]) < 1:
+        raise ValueError(
+            "decoder: delta_attention sizes must be at least 1, the gate's "
+            f"lower bound under {-DELTA_STEPS[1]}, and no other key given "
+            f"than {', '.join(DELTA_KEYS)}: {s}")
+    _check_heads_with_keys_of_their_own(c, DELTA)
+
+
+def _check_router_groups(c: dict) -> None:
+    groups = c["router_groups"]
+    pair = (isinstance(groups, (list, tuple)) and len(groups) == 2
+            and all(isinstance(g, int) for g in groups)
+            and 1 <= groups[1] <= groups[0])
+    if pair and tuple(groups) == ONE_GROUP:
+        return
+    experts, ways = c["num_experts"], c["num_experts_per_tok"]
+    if not pair or experts % groups[0] or experts // groups[0] < 2 or (
+            ways > groups[1] * (experts // groups[0])):
+        raise ValueError(
+            f"decoder: router_groups {groups} is not [n_group, topk_group] "
+            f"with whole groups of at least 2 of the {experts} experts and "
+            f"room in the open ones for {ways} a token")
 
 
 def _check_short_conv(c: dict) -> None:
@@ -794,6 +964,7 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         "moe_intermediate_size": 0, "shared_expert_intermediate_size": 0,
         "num_experts": 0, "num_experts_per_tok": 0,
         "routed_scaling_factor": 1.0, "experts_held": (0, 0),
+        "router_groups": ONE_GROUP,
         **{k: v for k, v in extra.items() if k != "vocab_size"},
     }
     n = len(c["layer_types"])
@@ -844,6 +1015,7 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         raise ValueError(
             f"decoder: shared_expert_columns_held {columns} does not lie "
             f"in the {c['shared_expert_intermediate_size']} columns")
+    _check_router_groups(c)
     if c["router_scoring"] not in SCORINGS:
         raise ValueError(
             f"decoder: unknown router_scoring {c['router_scoring']!r}; "
@@ -867,6 +1039,8 @@ def decoder_from_extra(extra: dict, num_classes: int) -> DecoderLM:
         _check_state_space(c.get("state_space") or {})
     if SHORT_CONV in c["layer_types"]:
         _check_short_conv(c)
+    if DELTA in c["layer_types"]:
+        _check_delta_attention(c)
     if SELECTED in c["layer_types"]:
         sa = c.get("sparse_attention") or {}
         lacks = [k for k in ("index_heads", "index_head_dim", "topk")

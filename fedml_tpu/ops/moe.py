@@ -171,10 +171,12 @@ def make_expert_parallel_moe(mesh, axis_name: str = "ep",
 #: gathers move, over ``4 x moe_rows_held`` is the rows moved a held
 #: row; ``moe_rows_tiled``, the held rows of a call whose grouped
 #: products ran in the row-tiled kernels, 0 of one the shape rule left
-#: to ``ragged_dot``: :func:`product_tiles`)
+#: to ``ragged_dot``: :func:`product_tiles`; ``moe_tokens_group_open``,
+#: the tokens whose open groups of experts include a held expert's —
+#: every token where the router has one group: :func:`open_groups`)
 MOE_COUNTERS = ("moe_rows_held", "moe_rows_routed", "moe_rows_max_expert",
                 "moe_rows_compact", "moe_rows_combined", "moe_rows_gathered",
-                "moe_rows_tiled")
+                "moe_rows_tiled", "moe_tokens_group_open")
 
 #: the row buffer holds this many times the held experts' uniform share
 #: of the assignments, and never under this part of all of them,
@@ -451,20 +453,57 @@ def largest_kernel(b, p, k: int, tokens: int = RANK_TOKENS,
     )(*operands)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def largest(ranked, own, k: int, kernel: bool):
+#: a router's groups where the configuration names none: all experts
+#: in one, none closed (``(n_group, topk_group)``)
+ONE_GROUP = (1, 1)
+
+
+def open_groups(ranked, groups: tuple[int, int]):
+    """``ranked`` ``[E, N]`` (what the choice reads of each expert, a
+    token a column), ``groups = (n_group, topk_group)`` -> ``[n_group,
+    N]`` bool: the ``topk_group`` groups of ``E / n_group`` consecutive
+    experts that a token may choose from, those of largest score, a
+    group's score the sum of its TWO largest entries; at a tie the
+    lower group, as ``jax.lax.top_k`` orders. Maxima and a count of the
+    groups that rank before each: no sort."""
+    n_group, topk_group = groups
+    e, n = ranked.shape
+    inside = ranked.reshape(n_group, e // n_group, n)
+    place = jax.lax.broadcasted_iota(jnp.int32, inside.shape, 1)
+    first = jnp.max(inside, 1, keepdims=True)
+    at = jnp.min(jnp.where(inside == first, place, e), 1, keepdims=True)
+    score = first[:, 0] + jnp.max(
+        jnp.where(place == at, -jnp.inf, inside), 1)
+    ahead = (score[:, None] > score[None]) | (
+        (score[:, None] == score[None])
+        & (jnp.arange(n_group)[:, None, None]
+           < jnp.arange(n_group)[None, :, None]))
+    return jnp.sum(ahead, 0) < topk_group
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def largest(ranked, own, k: int, kernel: bool,
+            groups: tuple[int, int] = ONE_GROUP):
     """``ranked`` ``[N, E]`` -> ``(own [N, k], ids [N, k])`` of each
     token's ``k`` largest, as ``jax.lax.top_k(ranked, k)`` orders and
     chooses them (:func:`_largest`), the values read from ``own`` (as
     ``ranked``) where that is not None; through the TPU's ``kernel`` or
-    by the same passes over the whole array. One program a shape,
-    whichever layers call it."""
-    columns = (ranked.T, None if own is None else own.T)
-    top_e, top_p = (largest_kernel if kernel else _largest)(*columns, k)
+    by the same passes over the whole array. With more than one group
+    the experts of a token's closed groups (:func:`open_groups`) are
+    out of its ranking, marked before either road is taken. One program
+    a shape, whichever layers call it."""
+    columns = ranked.T
+    if groups[0] > 1:
+        columns = jnp.where(jnp.repeat(
+            open_groups(columns, groups), columns.shape[0] // groups[0], 0),
+            columns, -jnp.inf)
+    top_e, top_p = (largest_kernel if kernel else _largest)(
+        columns, None if own is None else own.T, k)
     return top_p.T, top_e.T
 
 
-def _ranked(scores, top_k: int, scoring: str, choice_bias=None):
+def _ranked(scores, top_k: int, scoring: str, choice_bias=None,
+            groups: tuple[int, int] = ONE_GROUP):
     """The ``top_k`` probabilities a token that rank highest and their
     experts, as ``jax.lax.top_k`` returns them TO THE BIT — descending,
     equal probabilities by the lower expert id — without its sort of
@@ -475,12 +514,14 @@ def _ranked(scores, top_k: int, scoring: str, choice_bias=None):
 
     ``ops/attention.py:select_top_k`` keeps its own selection, by
     counting passes: it marks 2,048 of 8,192 keys in place and orders
-    nothing, where this brings 6 to 22 of 64 to 512 out in order."""
+    nothing, where this brings 6 to 22 of 64 to 512 out in order.
+    ``groups``: :func:`largest`'s; the groups are scored by what the
+    ranking reads, the bias included."""
     p = SCORINGS[scoring](scores)
     kernel = attention._on_tpu() and p.shape[0] % RANK_TOKENS == 0
     if choice_bias is None:
-        return largest(p, None, top_k, kernel)
-    return largest(p + choice_bias, p, top_k, kernel)
+        return largest(p, None, top_k, kernel, groups)
+    return largest(p + choice_bias, p, top_k, kernel, groups)
 
 
 def _chosen_to_experts(top_e, d_top_p, experts: int):
@@ -492,9 +533,10 @@ def _chosen_to_experts(top_e, d_top_p, experts: int):
     return jnp.sum(jnp.where(hit, d_top_p[..., None], 0), -2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 5, 6, 7))
 def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
-                choice_bias=None, scope: str = ROUTE, epsilon: float = 0.0):
+                choice_bias=None, scope: str = ROUTE, epsilon: float = 0.0,
+                groups: tuple[int, int] = ONE_GROUP):
     """Router logits ``[N, E]`` (float32) over ALL experts -> the
     ``top_k`` a token of largest probability — ``scoring`` ``sigmoid``
     (each expert's own) or ``softmax`` (over all ``E``, in float32) —
@@ -507,7 +549,12 @@ def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
     stay the chosen experts' unbiased probabilities renormalised, so
     the bias moves the set and nothing else, and gets no gradient.
     ``epsilon`` (static) is added to the sum the weights are divided by
-    (:func:`_weights`), forward and in the rule alike.
+    (:func:`_weights`), forward and in the rule alike. ``groups =
+    (n_group, topk_group)`` (static) limits the choice to the experts of
+    a token's ``topk_group`` open groups of ``n_group``
+    (:func:`open_groups`, scored by probability plus bias); one group,
+    the default, is no limit and the program of before. Both choices
+    are sets: neither the group scores nor the bias gets a gradient.
 
     The choice is a set, so the weights' cotangent reaches the
     probabilities as ``lax.top_k``'s own rule sends it, to the ids the
@@ -517,19 +564,21 @@ def route_top_k(scores, top_k: int, scale: float, scoring: str = "sigmoid",
     chosen probabilities and the ids alone, named
     :data:`KEPT_ROUTING`, so a rematerialised layer ranks once (the
     scoring, elementwise, is run again for its own rule)."""
-    top_p, top_e = _ranked(scores, top_k, scoring, choice_bias)
+    top_p, top_e = _ranked(scores, top_k, scoring, choice_bias, groups)
     return top_e, _weights(top_p, scale, epsilon)
 
 
 def _route_top_k_fwd(scores, top_k, scale, scoring, choice_bias, scope,
-                     epsilon=0.0):
+                     epsilon=0.0, groups=ONE_GROUP):
     scores, (top_p, top_e) = checkpoint_name(
-        (scores, _ranked(scores, top_k, scoring, choice_bias)), KEPT_ROUTING)
+        (scores, _ranked(scores, top_k, scoring, choice_bias, groups)),
+        KEPT_ROUTING)
     return (top_e, _weights(top_p, scale, epsilon)), (
         scores, top_p, top_e, choice_bias)
 
 
-def _route_top_k_bwd(top_k, scale, scoring, scope, epsilon, res, cotangents):
+def _route_top_k_bwd(top_k, scale, scoring, scope, epsilon, groups, res,
+                     cotangents):
     scores, top_p, top_e, choice_bias = res
     with jax.named_scope(scope):
         d_top_p, = jax.vjp(
@@ -922,24 +971,29 @@ class Routing(NamedTuple):
     ``[N k]``, the assignments sorted by held expert, absent experts'
     last; ``back``, where a token's slots find their rows in that order
     (:func:`_by_slot`); ``sizes`` ``[count]`` rows a held expert and
-    ``n_held`` their sum."""
+    ``n_held`` their sum; ``opened``, the tokens for which a group that
+    holds a held expert was open (all ``N`` where the router has one
+    group)."""
     top_e: jax.Array
     top_w: jax.Array
     order: jax.Array
     back: Any
     sizes: jax.Array
     n_held: jax.Array
+    opened: jax.Array
 
 
 def route(router, x, held: tuple[int, int], top_k: int, scale: float,
           scoring: str = "sigmoid", scope: str = ROUTE,
-          choice_bias=None, renorm_epsilon: float = 0.0) -> Routing:
+          choice_bias=None, renorm_epsilon: float = 0.0,
+          groups: tuple[int, int] = ONE_GROUP) -> Routing:
     """From what the router reads to the routing: ``router`` ``[D, E]``
     over ALL ``E`` experts, ``x`` ``[N, D]`` — the rows the experts
     will be given, or any other tensor of the same tokens (a router
     that reads its layer's attention input) — ``held = (first, count)``
-    the experts held here; ``choice_bias`` ``[E]`` float32 or None and
-    ``renorm_epsilon``, :func:`route_top_k`'s. Logits (float32), top-k
+    the experts held here; ``choice_bias`` ``[E]`` float32 or None,
+    ``renorm_epsilon`` and ``groups``, :func:`route_top_k`'s. Logits
+    (float32), the groups a token may choose from, top-k
     and weights run under ``scope``; ordering the assignments by held expert under
     :data:`ROUTE`. A token's slots are its ways where ``top_k <= count``
     (``back``: the order's own ``argsort``) and the held experts where
@@ -950,7 +1004,15 @@ def route(router, x, held: tuple[int, int], top_k: int, scale: float,
     with jax.named_scope(scope):
         scores = jnp.dot(x, router, preferred_element_type=jnp.float32)
         top_e, top_w = route_top_k(scores, top_k, scale, scoring,
-                                   choice_bias, scope, renorm_epsilon)
+                                   choice_bias, scope, renorm_epsilon, groups)
+        opened = jnp.int32(n)
+        if groups[0] > 1:  # counted from the choice's own group scores
+            ranked = SCORINGS[scoring](jax.lax.stop_gradient(scores))
+            if choice_bias is not None:
+                ranked = ranked + choice_bias
+            size = router.shape[1] // groups[0]
+            opened = jnp.sum(jnp.any(open_groups(ranked.T, groups)[
+                first // size:(first + count - 1) // size + 1], 0))
     with jax.named_scope(ROUTE):
         local = top_e.reshape(-1) - first
         # an assignment to an absent expert sorts past every held group
@@ -965,12 +1027,13 @@ def route(router, x, held: tuple[int, int], top_k: int, scale: float,
                 local.reshape(n, top_k), count, n * top_k)
         n_held = jnp.sum(sizes)
     return Routing(top_e, *checkpoint_name(
-        (top_w, order, back, sizes, n_held), KEPT_ROUTING))
+        (top_w, order, back, sizes, n_held, opened), KEPT_ROUTING))
 
 
 def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
               scoring: str = "sigmoid", activation: str = SILU_GATED,
-              router_input=None, renorm_epsilon: float = 0.0):
+              router_input=None, renorm_epsilon: float = 0.0,
+              groups: tuple[int, int] = ONE_GROUP):
     """This chip's part of one sparse-expert layer, dropless.
 
     ``params``: ``router`` ``[D, E]`` over ALL ``E`` experts;
@@ -994,7 +1057,10 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     under :data:`ROUTER`, not :data:`ROUTE`. ``renorm_epsilon``
     (static): what the family adds to the sum of the chosen
     probabilities before it divides by it (:func:`_weights`; 0 where it
-    adds nothing). The experts' width ``W``
+    adds nothing). ``groups = (n_group, topk_group)`` (static): the
+    router's experts in ``n_group`` groups of which a token may choose
+    from its ``topk_group`` best (:func:`route_top_k`,
+    :func:`open_groups`; one group: no limit). The experts' width ``W``
     is ``D``, or ``L`` where the layer is latent: its experts then read
     ``h`` through the first latent projection and their weighted sum
     goes back through the second (no activation on either; both shared
@@ -1036,7 +1102,7 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     experts would add is left out: on one chip there is no exchange and
     nothing stands in for one.
 
-    -> ``(y [N, D], counters float32 [7])`` in :data:`MOE_COUNTERS`'
+    -> ``(y [N, D], counters float32 [8])`` in :data:`MOE_COUNTERS`'
     order: assignments that landed on held experts, assignments made
     (``N x top_k``), rows of the fullest held expert, assignments made
     in a call that went through the bounded buffer, rows the combine
@@ -1046,14 +1112,15 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
     their cotangents') and twice what the combine reads (forward, and
     its transpose in the rule), and the held rows of a call whose
     products ran in the row-tiled kernels (:func:`product_tiles`; 0
-    else, and under ``vmap``)."""
+    else, and under ``vmap``), and the tokens for which a group that
+    holds a held expert was open (``N`` under one group)."""
     count = held[1]
     n, _ = h.shape
     apart = router_input is not None  # a router with an input of its own
     routing = route(params["router"], router_input if apart else h, held,
                     top_k, scale, scoring, scope=ROUTER if apart else ROUTE,
                     choice_bias=params.get("router_bias"),
-                    renorm_epsilon=renorm_epsilon)
+                    renorm_epsilon=renorm_epsilon, groups=groups)
     inside = h
     if "latent" in params:
         with jax.named_scope(LATENT):
@@ -1081,5 +1148,6 @@ def moe_layer(params, h, held: tuple[int, int], top_k: int, scale: float,
         jnp.float32(combined),
         2 * buffered.astype(jnp.float32) + 2 * combined,
         _rows_tiled(routing.n_held, jnp.where(bounded > 0, *tiled)).astype(
-            jnp.float32)])
+            jnp.float32),
+        routing.opened.astype(jnp.float32)])
     return y.astype(h.dtype), counters

@@ -544,6 +544,53 @@ def test_mesh_evaluator_compiles_for_four_v5e_chips(mesh_sim):
     assert f"f32[{per_chip}]" in text and f"f32[{whole}]" not in text
 
 
+def _round_and_evaluator_compile(cell_name, parameters, one_chip,
+                                 monkeypatch):
+    """One decoder cell as it runs — ``FedAvgSim``'s bulk round at a
+    block of one and the evaluator, from the configuration's and the
+    traffic's own files at the published widths — for a described v5e:
+    the blockwise kernel and the grouped products are in the round
+    program, and each program's scratch with its arguments and its code
+    stays under the chip's memory (the round holds the global copy, one
+    client's copy, its gradient, the running sum and a bfloat16 cast).
+    -> the round's compiled text and the compiler's count of its bytes."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks"))
+    import run
+    from fedml_tpu.ops import attention as A
+    from lib import traffic as TR
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    cell = run.load_cell(cell_name)
+    config, traffic = cell["config"], cell["traffic"]
+    sim = run.build_sim(
+        run.experiment_config(config, traffic), traffic,
+        TR.make_population(config["dataset"], traffic, 1),
+        cell["reference"].TASK)
+    state = _shapes(jax.eval_shape(sim.init), one_chip)
+    assert _tree_bytes(state.variables) == 4 * parameters
+    compiled = jax.jit(
+        sim._round, donate_argnums=sim._donate_argnums()
+    ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
+    text = compiled.as_text()
+    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
+    ma = compiled.memory_analysis()
+    need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.generated_code_size_in_bytes)
+    # under the 16.9 GB the chip's allocator reports
+    assert 10e9 < need < 16.9e9, ma
+    evaluator, operands = sim._global_eval()
+    ev = evaluator.lower(
+        state.variables, *_shapes(operands, one_chip)
+    ).compile().memory_analysis()
+    # beside the state a window holds (the global copy)
+    assert ev.temp_size_in_bytes + ev.argument_size_in_bytes < (
+        V5E_HBM_BYTES - _tree_bytes(state.variables)), ev
+    return text, ma
+
+
 # slow: 160 s on many threads; beside five other workers of the fast
 # tier it starved timing-bound tests (CHANGES.md, PR 33)
 @pytest.mark.slow
@@ -557,43 +604,12 @@ def test_nemotron_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
     of each program with its arguments and its code stays under the
     chip's memory (the round holds the global copy, one client's copy,
     its gradient, the running sum and a bfloat16 cast)."""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
-    import run
-    from fedml_tpu.ops import attention as A
-    from lib import traffic as TR
-
-    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
-    cell = run.load_cell("nemotron3s-c2of32-b1x8192")
-    config, traffic = cell["config"], cell["traffic"]
-    sim = run.build_sim(
-        run.experiment_config(config, traffic), traffic,
-        TR.make_population(config["dataset"], traffic, 1),
-        cell["reference"].TASK)
-    state = _shapes(jax.eval_shape(sim.init), one_chip)
-    assert _tree_bytes(state.variables) == 4 * 508_187_120
-    compiled = jax.jit(
-        sim._round, donate_argnums=sim._donate_argnums()
-    ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
-    text = compiled.as_text()
-    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
-    ma = compiled.memory_analysis()
-    need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
-            + ma.generated_code_size_in_bytes)
     # 15.16 GB by the compiler's count (12.56 of it scratch; 15.95 and
     # 13.34 before PR 35's combine read a row a held expert), of the
     # 16.9 GB the chip's allocator reports; the chip itself read 14.1
     # GB (PR 33's tree 14.2) where this count read 15.5 (PERF.md)
-    assert 10e9 < need < 16.9e9, ma
-    evaluator, operands = sim._global_eval()
-    ma = evaluator.lower(
-        state.variables, *_shapes(operands, one_chip)
-    ).compile().memory_analysis()
-    # beside the state a window holds (the global copy, 2.03 GB)
-    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < (
-        V5E_HBM_BYTES - _tree_bytes(state.variables)), ma
+    _round_and_evaluator_compile(
+        "nemotron3s-c2of32-b1x8192", 508_187_120, one_chip, monkeypatch)
 
 
 # slow for the same reason: 100 s of compiling on many threads
@@ -608,41 +624,10 @@ def test_smallthinker_round_and_evaluator_compile_for_v5e(
     described v5e: the blockwise kernel and the grouped products are in
     the round program, and each program's scratch with its arguments
     and its code stays under the chip's memory."""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
-    import run
-    from fedml_tpu.ops import attention as A
-    from lib import traffic as TR
-
-    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
-    cell = run.load_cell("smallthinker-c2of32-b1x8192")
-    config, traffic = cell["config"], cell["traffic"]
-    sim = run.build_sim(
-        run.experiment_config(config, traffic), traffic,
-        TR.make_population(config["dataset"], traffic, 1),
-        cell["reference"].TASK)
-    state = _shapes(jax.eval_shape(sim.init), one_chip)
-    assert _tree_bytes(state.variables) == 4 * 593_615_360
-    compiled = jax.jit(
-        sim._round, donate_argnums=sim._donate_argnums()
-    ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
-    text = compiled.as_text()
-    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
-    ma = compiled.memory_analysis()
-    need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
-            + ma.generated_code_size_in_bytes)
     # 14.93 GB by the compiler's count (12.26 of it scratch); the chip
     # itself read 13.75 GB (PERF.md, PR 39)
-    assert 10e9 < need < 16.9e9, ma
-    evaluator, operands = sim._global_eval()
-    ma = evaluator.lower(
-        state.variables, *_shapes(operands, one_chip)
-    ).compile().memory_analysis()
-    # beside the state a window holds (the global copy, 2.37 GB)
-    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < (
-        V5E_HBM_BYTES - _tree_bytes(state.variables)), ma
+    _round_and_evaluator_compile(
+        "smallthinker-c2of32-b1x8192", 593_615_360, one_chip, monkeypatch)
 
 
 # slow for the same reason: 100 s of compiling on many threads
@@ -656,40 +641,9 @@ def test_joyai_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
     kernel and the grouped products are in the round program, and each
     program's scratch with its arguments and its code stays under the
     chip's memory."""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
-    import run
-    from fedml_tpu.ops import attention as A
-    from lib import traffic as TR
-
-    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
-    cell = run.load_cell("joyai-flash-c2of32-b1x8192")
-    config, traffic = cell["config"], cell["traffic"]
-    sim = run.build_sim(
-        run.experiment_config(config, traffic), traffic,
-        TR.make_population(config["dataset"], traffic, 1),
-        cell["reference"].TASK)
-    state = _shapes(jax.eval_shape(sim.init), one_chip)
-    assert _tree_bytes(state.variables) == 4 * 564_954_112
-    compiled = jax.jit(
-        sim._round, donate_argnums=sim._donate_argnums()
-    ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
-    text = compiled.as_text()
-    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
-    ma = compiled.memory_analysis()
-    need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
-            + ma.generated_code_size_in_bytes)
     # 14.66 GB by the compiler's count (11.99 of it scratch)
-    assert 10e9 < need < 16.9e9, ma
-    evaluator, operands = sim._global_eval()
-    ma = evaluator.lower(
-        state.variables, *_shapes(operands, one_chip)
-    ).compile().memory_analysis()
-    # beside the state a window holds (the global copy, 2.26 GB)
-    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < (
-        V5E_HBM_BYTES - _tree_bytes(state.variables)), ma
+    _round_and_evaluator_compile(
+        "joyai-flash-c2of32-b1x8192", 564_954_112, one_chip, monkeypatch)
 
 
 # slow for the same reason: 90 s of compiling on many threads
@@ -704,37 +658,26 @@ def test_lfm2_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
     blockwise kernel and the grouped products are in the round program,
     and each program's scratch with its arguments and its code stays
     under the chip's memory."""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
-    import run
-    from fedml_tpu.ops import attention as A
-    from lib import traffic as TR
-
-    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
-    cell = run.load_cell("lfm2-8b-c2of32-b1x8192")
-    config, traffic = cell["config"], cell["traffic"]
-    sim = run.build_sim(
-        run.experiment_config(config, traffic), traffic,
-        TR.make_population(config["dataset"], traffic, 1),
-        cell["reference"].TASK)
-    state = _shapes(jax.eval_shape(sim.init), one_chip)
-    assert _tree_bytes(state.variables) == 4 * 568_647_936
-    compiled = jax.jit(
-        sim._round, donate_argnums=sim._donate_argnums()
-    ).lower(state, _shapes(sim.arrays, one_chip), None, None).compile()
-    text = compiled.as_text()
-    assert "splash_mqa_fwd" in text and _grouped_products_in(text)
-    ma = compiled.memory_analysis()
-    need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
-            + ma.generated_code_size_in_bytes)
     # 15.76 GB by the compiler's count (13.23 of it scratch)
-    assert 10e9 < need < 16.9e9, ma
-    evaluator, operands = sim._global_eval()
-    ma = evaluator.lower(
-        state.variables, *_shapes(operands, one_chip)
-    ).compile().memory_analysis()
-    # beside the state a window holds (the global copy, 2.27 GB)
-    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < (
-        V5E_HBM_BYTES - _tree_bytes(state.variables)), ma
+    _round_and_evaluator_compile(
+        "lfm2-8b-c2of32-b1x8192", 568_647_936, one_chip, monkeypatch)
+
+
+# slow for the same reason: 180 s of compiling on many threads
+@pytest.mark.slow
+def test_ling_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
+    """``ling3-flash-share64`` as its cell runs it (586,929,872
+    parameters, one sequence of 8,192 tokens a step through five gated
+    delta-rule mixers of 16 held heads — 128 chunks of 64 a layer, a
+    triangular solve a chunk and head — and one latent-attention layer):
+    the router's ranking kernel is in the round program beside the
+    blockwise kernel and the grouped products, and the recurrence's
+    solve."""
+    # 16.62 GB by the compiler's count (13.40 of it scratch, 0.86 code;
+    # 16.92 and 13.73 with every chunk's state kept); one delta-rule
+    # layer's forward and backward alone hold 2.4 GB
+    text, ma = _round_and_evaluator_compile(
+        "ling3-flash-c2of32-b1x8192", 586_929_872, one_chip, monkeypatch)
+    assert "moe_rank_top_k" in text and "triangular" in text.lower()
+    print("ling3-flash round, compiler's bytes:", ma)
+

@@ -314,7 +314,8 @@ def test_bounded_buffer_against_the_written_out_layer(case):
         "moe_rows_compact": 0.0 if case == "one_over" else 256.0,
         "moe_rows_combined": 256.0,
         "moe_rows_gathered": 2 * buffered + 2 * 256.0,
-        "moe_rows_tiled": 0.0}  # off the chip the products are ragged_dot's
+        "moe_rows_tiled": 0.0,  # off the chip the products are ragged_dot's
+        "moe_tokens_group_open": 64.0}  # one group: open for every token
 
 
 @pytest.mark.parametrize("cases", [("one_over", "well_under"),

@@ -348,7 +348,11 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
      "unknown rope_pairing 'interleaved'"),
     ({"mlp_layer_types": ["dense"] * 5}, "router_score_bias needs a sparse"),
     ({"query_heads_held": [0, 2], "key_value_heads_held": [0, 2]},
-     "holds all its heads"),
+     "states its share as query_heads_held alone"),
+    ({"query_heads_held": [2, 3]}, "does not lie in layer 0's 4 heads"),
+    ({"qk_norm": True}, "norms its latents alone"),
+    ({"router_groups": [3, 2]}, "router_groups"),
+    ({"router_groups": [8, 9]}, "router_groups"),
     ({"layer_types": ["latent"] * 5}, "known layer_types"),
 ])
 def test_a_configuration_that_cannot_be_built_is_refused(change, message):

@@ -436,4 +436,4 @@ def test_rows_gathered_counts_the_four_gathers_of_a_training_step(shape):
         assert float(counters[at("moe_rows_gathered")]) == (
             2 * (c if bounded else n * top_k) + 2 * n * slots)
         assert float(counters[at("moe_rows_combined")]) == n * slots
-    assert len(MOE.MOE_COUNTERS) == counters.shape[0] == 7
+    assert len(MOE.MOE_COUNTERS) == counters.shape[0] == 8

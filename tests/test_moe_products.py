@@ -242,11 +242,11 @@ def _counted(counters):
     return dict(zip(MOE.MOE_COUNTERS, np.asarray(counters).T))
 
 
-def test_rows_tiled_is_the_last_counter_and_follows_the_rule(monkeypatch):
+def test_rows_tiled_is_a_counter_and_follows_the_rule(monkeypatch):
     """``moe_rows_tiled`` is ``moe_rows_held`` where the layer's products
     ran in the tiled kernels and 0 where the rule left them to
     ``ragged_dot``: off the chip, for float32 operands, under ``vmap``."""
-    assert MOE.MOE_COUNTERS[-1] == "moe_rows_tiled"
+    assert MOE.MOE_COUNTERS[-2] == "moe_rows_tiled"
     layer = lambda params, h: MOE.moe_layer(params, h, (1, 2), 2, 1.0)[1]
     params, h = _layer(jnp.bfloat16)
     off = _counted(layer(params, h))

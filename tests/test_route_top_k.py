@@ -131,7 +131,7 @@ def _rules(cell, bias):
     rule and the parent's, over what the forward pass kept."""
     _, k, scoring, _ = CELLS[cell]
     return (lambda kept, g: MOE._route_top_k_bwd(
-                k, 2.5, scoring, MOE.ROUTE, 0.0, kept, (None, g)),
+                k, 2.5, scoring, MOE.ROUTE, 0.0, MOE.ONE_GROUP, kept, (None, g)),
             lambda kept, g: _scattered_bwd(k, 2.5, scoring, kept, (None, g)))
 
 
