@@ -151,7 +151,7 @@ def build_span(init):
 
 #: round-record keys that are counts of the model's own
 #: (``FedModel.counters``), carried on ``fedml.log`` as ``slot_steps`` is
-COUNTER_PREFIXES = ("moe_rows_", "attn_keys_")
+COUNTER_PREFIXES = ("moe_rows_", "attn_keys_", "delta_chunks")
 
 
 def log_span(record: dict) -> span:
@@ -159,8 +159,8 @@ def log_span(record: dict) -> span:
     ``sink.log`` site). A counter the round program reported rides it
     as an attr, so a profiler capture holds it beside the device's
     timeline: ``slot_steps`` (the sharded cohort round) and the model's
-    own counters (``FedModel.counters``: the decoder's ``moe_rows_*``
-    and ``attn_keys_*``;
+    own counters (``FedModel.counters``: the decoder's ``moe_rows_*``,
+    ``attn_keys_*`` and ``delta_chunks*``;
     a per-client list rides as its text, ``[a, b]``)."""
     attrs = {"round": record["round"]}
     for name, value in record.items():

@@ -165,8 +165,10 @@ recomputed in the backward pass (``nn.remat``) but for what
 :data:`KEPT` names. Beside a layer's input it keeps its attention
 kernel's output and row log-sum-exp; in a sparse-attention layer the
 selection; in a state-space layer the states entering each chunk and
-the scan's result; in a delta-rule layer the states entering every
-other chunk and the result (:data:`fedml_tpu.ops.delta.KEPT`); in a
+the scan's result; in a delta-rule layer the states entering the
+chunks (every chunk's on the TPU, where the chunk kernels run; every
+other one's in the plain form) and the result
+(:data:`fedml_tpu.ops.delta.KEPT`); in a
 short-convolution layer
 nothing more (the mixer runs twice a step); and of a sparse
 feed-forward the routing (the
@@ -177,7 +179,8 @@ buffer's rows, those rows through each matrix that leads in, and their
 result; :data:`fedml_tpu.ops.moe.KEPT`). So these run ONCE a training
 step: the forward attention kernel, the index and its top-k; of the
 chunked scan the mix, the chunks' own states and the recurrence between
-them; the delta rule's recurrence between chunks; the router's product,
+them; the delta rule's recurrence between chunks (on the TPU its whole
+forward kernel); the router's product,
 the groups' scores, its scoring's ranking (``ops.moe.largest``), the
 sorts and the count, the row gather, the grouped products forward and
 the combine. And these twice: the norms, the attention's projections,
@@ -185,7 +188,8 @@ rotary, gate and output projection, the convolution, the dense
 feed-forward, the shared expert and the latent projections; of a
 delta-rule layer its seven projections, taps, norms and gates and,
 inside a chunk, the two decayed Gram products, the solve and the reads
-of the entering state (which the backward pass differentiates). The
+of the entering state (which the backward pass differentiates; on the
+TPU the backward kernel makes them again in fast memory). The
 embedding, outside the layers, gathers its rows once forward and forms
 its table's gradient once backward by a rule of its own
 (:class:`DecoderLM`).
@@ -202,8 +206,10 @@ and out of the row buffer move, the held rows whose grouped
 products ran in the row-tiled kernels, and the tokens for which a group
 that holds a held expert was open — and, in a stack with
 sparse-attention layers, :data:`fedml_tpu.ops.attention.
-ATTN_COUNTERS`) is summed over layers and sown into the ``counters``
-collection, which :meth:`fedml_tpu.models.base.FedModel.
+ATTN_COUNTERS`; in one with delta-rule layers, :data:`fedml_tpu.ops.
+delta.DELTA_COUNTERS`: a chunk of a head through such a layer, and those
+of them the TPU's chunk kernels ran) is summed over layers and sown into
+the ``counters`` collection, which :meth:`fedml_tpu.models.base.FedModel.
 apply_train_counted` hands to the local update.
 
 Projections are built through the ``dense_cls`` hook of
@@ -227,7 +233,9 @@ from fedml_tpu.ops.attention import (
     ATTN_COUNTERS, KEPT as ATTENTION_KEPT, KEPT_SELECTION, causal_attention,
     index_scores, select_top_k,
 )
-from fedml_tpu.ops.delta import KEPT as DELTA_KEPT, kda_chunked
+from fedml_tpu.ops.delta import (
+    DELTA_COUNTERS, KEPT as DELTA_KEPT, chunk_counts, kda_chunked,
+)
 from fedml_tpu.ops.embedding import embedding_lookup
 from fedml_tpu.ops.moe import (
     ACTIVATIONS, KEPT as MOE_KEPT, MOE_COUNTERS, ONE_GROUP, SCORINGS,
@@ -280,8 +288,10 @@ KEPT = ATTENTION_KEPT + SCAN_KEPT + DELTA_KEPT + MOE_KEPT
 
 def attention_counters(layer_types) -> tuple[str, ...]:
     """What a stack of these attention kinds counts beside its sparse
-    layers' :data:`MOE_COUNTERS` (every layer of it, of any kind)."""
-    return ATTN_COUNTERS if SELECTED in layer_types else ()
+    layers' :data:`MOE_COUNTERS` (every layer of it, of any kind): the
+    sparse-attention layers' two, then the delta-rule layers' two."""
+    return (ATTN_COUNTERS if SELECTED in layer_types else ()) + (
+        DELTA_COUNTERS if DELTA in layer_types else ())
 
 
 def counter_names(layer_types) -> tuple[str, ...]:
@@ -448,7 +458,8 @@ class DecoderLayer(nn.Module):
         l = self.index
         b, t, d = x.shape
         kind = c["layer_types"][l]
-        # a stack with sparse-attention layers counts in every layer
+        # a stack with sparse-attention or delta-rule layers counts in
+        # every layer
         attended = (jnp.float32(0),) * len(
             attention_counters(c["layer_types"]))
         read = None  # what the attention read: its normed input
@@ -457,7 +468,8 @@ class DecoderLayer(nn.Module):
         elif kind == SHORT_CONV:
             x = x + self.short_conv(x)
         elif kind == DELTA:
-            x = x + self.delta_attention(x)
+            mixed, chunks = self.delta_attention(x)
+            x, attended = x + mixed, attended[:-len(chunks)] + chunks
         elif kind != NONE:
             x, attended, read = self.attention(x, kind, attended)
         if c["mlp_layer_types"][l] == DENSE:
@@ -559,7 +571,8 @@ class DecoderLayer(nn.Module):
                     q = RMSNorm(c["rms_norm_eps"], name="q_norm")(q)
                     k = RMSNorm(c["rms_norm_eps"], name="k_norm")(k)
                 if kind == SELECTED:
-                    how["selection"], attended = self.select(h, rope)
+                    how["selection"], keys = self.select(h, rope)
+                    attended = keys + attended[len(keys):]
                 if turns:
                     cos, sin = rope_tables(rope, hd, t)
                     q = apply_rope(q, cos, sin, pairing)
@@ -670,7 +683,8 @@ class DecoderLayer(nn.Module):
     @nn.nowrap
     def delta_attention(self, x):
         """``mixer(norm(x))`` of a gated delta-rule layer (module
-        docstring) over the heads held here."""
+        docstring) over the heads held here -> (it,
+        :data:`DELTA_COUNTERS`' two counts of this call)."""
         c = dict(self.cfg)
         s = dict(c["delta_attention"])
         b, t, d = x.shape
@@ -701,10 +715,11 @@ class DecoderLayer(nn.Module):
                         inner).astype(jnp.float32)))
                 beta = jax.nn.sigmoid(write.astype(jnp.float32))
             o = kda_chunked(q, k, v, gamma, beta, s["chunk_size"])
+            counts = chunk_counts(q, k, v, s["chunk_size"])
             with jax.named_scope("fedml.model.delta.mix"):
                 o = RMSNorm(c["rms_norm_eps"], name="o_norm")(o)
                 o = o * by_head(jax.nn.sigmoid(gate))
-            return self.dense(d, "o_proj")(o.reshape(b, t, inner))
+            return self.dense(d, "o_proj")(o.reshape(b, t, inner)), counts
 
     @nn.nowrap
     def select(self, h, rope: dict):

@@ -45,33 +45,81 @@ is at most 1.
 
 Decays, their running sums, the solve and the states are float32
 whatever the products' inputs are (``v``'s dtype, the step's compute
-dtype, accumulated in float32; the recurrence between chunks, whose
-operand is the float32 state itself, at precision "highest": two
-products of ``Q x K x V`` a chunk and head that wait on each other).
-The backward pass is JAX's own of these products and of the solve, and
-the reversed recurrence for the one between chunks. **What a
-rematerialised layer keeps:** the states entering every OTHER chunk
-carry the ``checkpoint_name`` :data:`KEPT_STATES` (``[B, T / 2 Q, H, K,
-V]`` float32: 67 MB for 16 heads of 128 x 128 over 8,192 tokens at
-chunks of 64; the others are one step on from them, all at once:
-:func:`_all_from_every_other`) and the result ``o`` :data:`KEPT_OUTPUT`
-(33.5 MB in bfloat16 there), so under ``save_only_these_names(*KEPT)``
-the recurrence between chunks runs once a training step; ``A``, ``P``,
-the solve and the reads of the entering state, which the backward pass
-differentiates, are made again. (By the compiler's count of a round of
-five such layers at those sizes for a v5e: every chunk's state kept,
-13.73 GB of scratch; every other one's, 13.40; and WITHOUT ``o`` kept
-14.54 — keeping ``o`` costs 168 MB through a step and saves five times
-that.)
+dtype, accumulated in float32; the products whose operand is the float32
+state or the solve's, at precision "highest").
+
+**On the TPU** (:func:`kernel_heads`; ``fedml_tpu/ops/delta_chunk.py``)
+a chunk's whole work runs inside ONE Pallas kernel a pass, a few heads a
+grid step, the chunks the grid's last, sequential axis:
+
+- ``delta_chunk_fwd`` holds in fast memory the chunk's five inputs (read
+  once from HBM: ``q``, ``k`` as the layer's normalisation leaves them,
+  float32; ``v`` in the step's dtype; ``gamma``, ``beta`` float32), every
+  intermediate of the list above, and the float32 state, which stays in
+  scratch from one chunk to the next (TRANSPOSED, ``[V, K]``: a
+  channel's decay then runs along its rows and every product with it
+  sums over both operands' last axis). It writes ``o`` and the state
+  ENTERING the chunk, every chunk's: no ``[B, T / Q, H, Q, ...]`` array
+  exists. ``rest = U - W S`` is made once, from the float32 state.
+- the solve is an explicit inverse in float32, without the compiler's
+  ``triangular_solve`` (Mosaic has none): ``I + A``'s four 16 x 16
+  diagonal blocks by row substitution (15 steps, all blocks and heads of
+  the step at once), the blocks below them by block products — ``[[L1,
+  0], [B, L2]]^-1 = [[L1^-1, 0], [-L2^-1 B L1^-1, L2^-1]]``, once at
+  16 and once at 32 — so that ``[W | U] = M Diag(beta) [k e^G | v]`` is
+  one product, and the backward pass's ``dA = -M^T dM M^T`` two.
+- ``delta_chunk_bwd`` walks the chunks from the last to the first with
+  the state's cotangent in scratch: a step reads the five inputs, the
+  chunk's entering state and ``o``'s cotangent, makes ``A``, ``P``,
+  ``M``, ``W``, ``U`` again in fast memory (``jax.vjp`` of the chunk's
+  forward inside the kernel body) and writes the five cotangents.
+- what stays float32: decays, running sums (a product with a triangle
+  of ones at "highest": exact products, float32 sums), the inverse,
+  ``W``, ``U``, ``rest``, the state and its cotangent, and every product
+  with the state or the inverse but ``(q e^G) S``, at "highest". In
+  ``v``'s dtype with float32 sums, as in the plain form: the two Gram
+  products, ``(q e^G) S`` and ``P rest``.
+- which calls take the plain form below: off the TPU all; a chunk other
+  than 64; keys or values not whole lanes of 128; a sequence that is not
+  whole chunks (one shorter than a chunk among them); values neither
+  bfloat16 nor float32, or queries and keys neither float32 nor the
+  values' dtype. Decided on what the call's operands show, and counted:
+  :data:`DELTA_COUNTERS`.
+
+**What a rematerialised layer keeps** (:data:`KEPT`): the result ``o``
+(:data:`KEPT_OUTPUT`, 33.5 MB in bfloat16 for 16 heads of 128 x 128 over
+8,192 tokens) and states entering chunks (:data:`KEPT_STATES`, float32).
+On the TPU every chunk's (134 MB there): with ``o`` that is ALL the
+forward kernel writes, so under ``save_only_these_names(*KEPT)`` a
+training step runs ``delta_chunk_fwd`` once and ``delta_chunk_bwd`` once
+a layer, and the backward kernel starts each chunk from its own state
+(with every other one kept it would first step the even chunk's state
+on, one more forward a pair of chunks for 67 MB a layer; the 2.4 GB of
+whole-sequence scratch a layer's plain form held is gone either way). In
+the
+plain form the states entering every OTHER chunk (67 MB; the others are
+one step on from them, all at once: :func:`_all_from_every_other`): the
+recurrence between chunks (:func:`entering_states`, its backward pass
+the reversed recurrence) runs once a training step; ``A``, ``P``, the
+solve and the reads of the entering state, which JAX's own backward pass
+of those products differentiates, are made again.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from fedml_tpu.ops import attention, delta_chunk
+
 SCAN = "fedml.model.delta.scan"
+
+#: what a ``delta_attention`` layer counts a step: a chunk of a head
+#: through the layer, and those of them the TPU kernels ran
+DELTA_COUNTERS = ("delta_chunks", "delta_chunks_fused")
 
 #: ``checkpoint_name``s (module docstring): the states entering each
 #: chunk, and the recurrence's result
@@ -79,7 +127,7 @@ KEPT_STATES, KEPT_OUTPUT = "fedml_delta_states", "fedml_delta_output"
 KEPT = (KEPT_STATES, KEPT_OUTPUT)
 
 #: rows of ``A`` and ``P`` formed about one origin (module docstring)
-SUB = 16
+SUB = delta_chunk.SUB
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -190,11 +238,62 @@ def _entering_bwd(res, g):
 entering_states.defvjp(_entering_fwd, _entering_bwd)
 
 
+def kernel_heads(q, k, v, chunk: int) -> int | None:
+    """How this call runs HERE: the heads a grid step of the TPU's chunk
+    kernels (:func:`fedml_tpu.ops.delta_chunk.heads_a_step`, the shape
+    rule), or None for the plain form — off the TPU always."""
+    if not attention._on_tpu():
+        return None
+    return delta_chunk.heads_a_step(q, k, v, chunk)
+
+
+def chunk_counts(q, k, v, chunk: int):
+    """:data:`DELTA_COUNTERS`' two counts of one :func:`kda_chunked`
+    call: its chunks times its heads, and those the kernels run."""
+    bsz, t, h, _ = k.shape
+    chunks = bsz * h * -(-t // chunk)
+    fused = kernel_heads(q, k, v, chunk) is not None
+    return jnp.float32(chunks), jnp.float32(chunks * fused)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kda_kernels(q, k, v, gamma, beta, chunk, heads):
+    return _kernels_fwd(q, k, v, gamma, beta, chunk, heads)[0]
+
+
+def _kernels_fwd(q, k, v, gamma, beta, chunk, heads):
+    o, entering = delta_chunk.chunks_forward(
+        q, k, v, gamma, beta, chunk=chunk, heads=heads)
+    return o, (q, k, v, gamma, beta, checkpoint_name(entering, KEPT_STATES))
+
+
+def _kernels_bwd(chunk, heads, kept, g):
+    # (the rule's ops carry no scope of the call's: booked here)
+    with jax.named_scope(SCAN):
+        return delta_chunk.chunks_backward(
+            *kept, g, chunk=chunk, heads=heads)
+
+
+_kda_kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
 def kda_chunked(q, k, v, gamma, beta, chunk: int):
     """:func:`kda_sequential`'s ``o`` by chunks of ``chunk`` tokens
     (module docstring). A sequence shorter than a chunk is one chunk; a
     longer one is whole chunks, and a chunk whole sub-blocks of
-    :data:`SUB` (or one shorter than that), or refused."""
+    :data:`SUB` (or one shorter than that), or refused. Through the TPU
+    kernels where :func:`kernel_heads` sends the call."""
+    heads = kernel_heads(q, k, v, chunk)
+    if heads is None:
+        return _kda_plain(q, k, v, gamma, beta, chunk)
+    with jax.named_scope(SCAN):
+        return checkpoint_name(_kda_kernels(
+            q, k, v, gamma, beta, chunk, heads), KEPT_OUTPUT)
+
+
+def _kda_plain(q, k, v, gamma, beta, chunk: int):
+    """:func:`kda_chunked` in plain JAX: whole-sequence arrays a chunk
+    side by side, the compiler's solve, :func:`entering_states`."""
     bsz, t, h, dk = k.shape
     c = min(chunk, t)
     sub = min(SUB, c)

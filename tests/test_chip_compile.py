@@ -544,6 +544,61 @@ def test_mesh_evaluator_compiles_for_four_v5e_chips(mesh_sim):
     assert f"f32[{per_chip}]" in text and f"f32[{whole}]" not in text
 
 
+def _kernel_calls(text: str, name: str) -> list[str]:
+    """The lines of a compiled program's text that call the Pallas
+    kernel ``name``."""
+    return [line for line in text.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line
+            and f"%{name}" in line.split("=")[0]]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_rematerialised_delta_layer_compiles_for_v5e(
+        one_chip, monkeypatch, dtype):
+    """A training step of ONE gated delta-rule layer at Ling-3.0-flash's
+    widths (16 held heads of 128 x 128, 8,192 tokens in 128 chunks of
+    64; queries and keys float32 from their normalisation, values in
+    the step's dtype) under ``DecoderLM``'s remat: Mosaic takes the two
+    chunk kernels at the published shapes in both dtypes; the layer
+    keeps ``o`` and the states entering the chunks, all the forward
+    kernel makes, so the program holds it ONCE beside the one backward
+    kernel; both are booked under ``fedml.model.delta.scan``; and no
+    solve of the compiler's is left."""
+    from fedml_tpu.models.decoder import decoder_from_extra
+    from fedml_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)  # the chip's branch
+    model = decoder_from_extra({
+        "hidden_size": 2560, "head_dim": 128, "num_key_value_heads": 32,
+        "heads_per_layer": [32], "query_heads_held": [0, 16],
+        "layer_types": ["delta_attention"], "mlp_layer_types": ["none"],
+        "delta_attention": {"head_dim": 128, "conv_kernel": 4,
+                            "gate_lower_bound": -5, "chunk_size": 64},
+        "rope": {}, "intermediate_size": 6144}, 1024)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.key(0),
+                       jnp.zeros((1, 8192), jnp.int32))["params"])
+
+    def loss(params, tokens):
+        logits, _ = model.apply({"params": params}, tokens,
+                                mutable=["counters"])
+        return jnp.mean(logits.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, tokens).compile()
+    text = compiled.as_text()
+    forward = _kernel_calls(text, "delta_chunk_fwd")
+    backward = _kernel_calls(text, "delta_chunk_bwd")
+    assert len(forward) == 1 and len(backward) == 1
+    assert all("fedml.model.delta.scan" in line
+               for line in forward + backward)
+    assert "triangular" not in text.lower()
+    print("one delta-rule layer, compiler's bytes:",
+          compiled.memory_analysis())
+
+
 def _round_and_evaluator_compile(cell_name, parameters, one_chip,
                                  monkeypatch):
     """One decoder cell as it runs — ``FedAvgSim``'s bulk round at a
@@ -668,16 +723,26 @@ def test_lfm2_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
 def test_ling_round_and_evaluator_compile_for_v5e(one_chip, monkeypatch):
     """``ling3-flash-share64`` as its cell runs it (586,929,872
     parameters, one sequence of 8,192 tokens a step through five gated
-    delta-rule mixers of 16 held heads — 128 chunks of 64 a layer, a
-    triangular solve a chunk and head — and one latent-attention layer):
-    the router's ranking kernel is in the round program beside the
-    blockwise kernel and the grouped products, and the recurrence's
-    solve."""
-    # 16.62 GB by the compiler's count (13.40 of it scratch, 0.86 code;
-    # 16.92 and 13.73 with every chunk's state kept); one delta-rule
-    # layer's forward and backward alone hold 2.4 GB
+    delta-rule mixers of 16 held heads — 128 chunks of 64 a layer — and
+    one latent-attention layer): the router's ranking kernel is in the
+    round program beside the blockwise kernel and the grouped products,
+    and the delta rule's two chunk kernels ONCE a layer and step each (a
+    rematerialised layer keeps all the forward kernel makes), every call
+    under ``fedml.model.delta.scan``, with no solve of the compiler's
+    left."""
+    # PR 49's plain form: 16.62 GB by the compiler's count (13.40 of it
+    # scratch, 0.86 code); one delta-rule layer's forward and backward
+    # alone held 2.4 GB
     text, ma = _round_and_evaluator_compile(
         "ling3-flash-c2of32-b1x8192", 586_929_872, one_chip, monkeypatch)
-    assert "moe_rank_top_k" in text and "triangular" in text.lower()
+    assert "moe_rank_top_k" in text and "triangular" not in text.lower()
+    forward = _kernel_calls(text, "delta_chunk_fwd")
+    backward = _kernel_calls(text, "delta_chunk_bwd")
+    # one a layer in each copy of the step the round program holds (its
+    # ONE latent-attention layer's forward kernel counts the copies)
+    steps = len(_kernel_calls(text, "splash_mqa_fwd"))
+    assert len(forward) == len(backward) == 5 * steps > 0
+    assert all("fedml.model.delta.scan" in line
+               for line in forward + backward)
     print("ling3-flash round, compiler's bytes:", ma)
 

@@ -7,6 +7,7 @@ ratio kept; the grouped choice against a sort-based one; the head
 shares and the expert shares tied to the uncut layer; the fresh gate's
 law; what ``decoder_from_extra`` refuses; the published share's size."""
 
+import json
 import os
 import sys
 
@@ -24,11 +25,13 @@ for _p in (os.path.join(ROOT, "benchmarks"),
 import tiny_ling as TL  # noqa: E402
 from test_decoder import _assert_trees_close as _close  # noqa: E402
 from test_decoder import _loss, _model_config, _sim  # noqa: E402
+from test_delta import _interpreted as interpreted_kernels  # noqa: E402
 from test_smallthinker import _layer_params  # noqa: E402
 
 from fedml_tpu.config import ModelConfig  # noqa: E402
 from fedml_tpu.models import create_model  # noqa: E402
 from fedml_tpu.models import decoder as D  # noqa: E402
+from fedml_tpu.ops import attention as A  # noqa: E402
 from fedml_tpu.ops import delta as DL  # noqa: E402
 from fedml_tpu.ops import moe as MOE  # noqa: E402
 
@@ -83,7 +86,9 @@ def test_program_against_reference_logits_and_gradients(pattern, tmp_path):
     _assert_trees_close(g_ours, g_ref, 2e-3, but=("router_bias",))
     sparse = sum(layer[1] == "S" for layer in pattern)
     if sparse:
-        assert set(counted) == set(MOE.MOE_COUNTERS)
+        delta = any(layer[0] == "k" for layer in pattern)
+        assert set(counted) == set(
+            MOE.MOE_COUNTERS + DL.DELTA_COUNTERS * delta)
         assert float(counted["moe_rows_routed"]) == x.size * 4 * sparse
         assert 0 < float(counted["moe_rows_held"]) < x.size * 4 * sparse
         # one group of eight held, four open a token: about half
@@ -448,6 +453,100 @@ def test_the_state_entering_a_chunk_reaches_the_layers_output(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# what the delta-rule layers count
+# ---------------------------------------------------------------------------
+
+MOE_ONLY, SELECTED = MOE.MOE_COUNTERS, MOE.MOE_COUNTERS + A.ATTN_COUNTERS
+
+
+@pytest.mark.parametrize("config, counters", [
+    ("laguna-xs2-share8", MOE_ONLY),
+    ("keye-vl2-a3b-share8", SELECTED),
+    ("nemotron3-super-share64", MOE_ONLY),
+    ("smallthinker-21b-share4", MOE_ONLY),
+    ("joyai-llm-flash-share16", MOE_ONLY),
+    ("lfm2-8b-a1b-share4", MOE_ONLY),
+    ("ling3-flash-share64", MOE_ONLY + DL.DELTA_COUNTERS),
+])
+def test_only_a_stack_with_delta_layers_counts_chunks(config, counters):
+    """The counters a benchmark configuration's stack is built with:
+    the tuple of before for the six without ``delta_attention`` layers
+    (their round programs sow and sum what they did), the delta pair
+    last for the one with."""
+    with open(os.path.join(
+            ROOT, "benchmarks", "configs", config + ".json")) as f:
+        kinds = json.load(f)["model"]["extra"]["layer_types"]
+    assert D.counter_names(kinds) == counters
+    assert D.attention_counters(kinds) == counters[len(MOE_ONLY):]
+
+
+def test_log_span_carries_the_delta_counters():
+    from fedml_tpu.core.tracing import log_span
+
+    attrs = log_span({"round": 3, "train_loss": 1.0, "delta_chunks": 40960.0,
+                      "delta_chunks_fused": 40960.0}).attrs
+    assert attrs == {"round": 3, "delta_chunks": 40960,
+                     "delta_chunks_fused": 40960}
+
+
+def test_both_pairs_keep_their_places_in_one_stack():
+    kinds = ("sparse_attention", "delta_attention", "full_attention")
+    assert D.attention_counters(kinds) == (
+        A.ATTN_COUNTERS + DL.DELTA_COUNTERS)
+    assert D.attention_counters(kinds[::2]) == A.ATTN_COUNTERS
+    assert D.attention_counters(kinds[2:]) == ()
+
+
+def test_a_delta_layer_counts_its_chunks_and_the_model_sows_them():
+    """2 sequences x 2 held heads x 4 chunks of 16 a delta-rule layer
+    and none a latent one; off the chip none of them fused. The model
+    sows the pair under ``DELTA_COUNTERS``' names, summed over layers."""
+    x = _stream()
+    extra = TL.tiny_config(pattern=("kN", "lN"))["model"]["extra"]
+    cfg = D.decoder_from_extra(extra, TL.VOCAB).cfg
+    for index, chunks in ((0, 16.0), (1, 0.0)):
+        layer = D.DecoderLayer(cfg, index)
+        counted = layer.apply(
+            {"params": layer.init(jax.random.key(5), x)["params"]}, x)[1]
+        assert counted.shape == (len(MOE_ONLY) + 2,)
+        assert [float(c) for c in counted[-2:]] == [chunks, 0.0]
+        assert float(jnp.abs(counted[:-2]).max()) == 0.0
+    model = create_model(_model_config(TL.tiny_config()))
+    assert model.counters == MOE_ONLY + DL.DELTA_COUNTERS
+    variables = model.init(jax.random.key(0))
+    tokens = jnp.zeros((2, TL.SEQ), jnp.int32)
+    _, sown = model.module.apply(
+        {"params": variables["params"]}, tokens, train=True,
+        mutable=["counters"])
+    delta_layers = TL.tiny_config()["model"]["extra"]["layer_types"].count(
+        "delta_attention")
+    assert float(sown["counters"]["delta_chunks"]) == 16.0 * delta_layers
+    assert float(sown["counters"]["delta_chunks_fused"]) == 0.0
+
+
+def test_a_delta_layer_through_the_kernels_is_the_layer(monkeypatch):
+    """A delta-rule layer at heads of 128 and chunks of 64 — a shape
+    the kernels take — through the kernels (the Pallas interpreter)
+    against the same layer through the plain form: output, gradients,
+    and every chunk counted fused."""
+    layer = _one_layer("k", delta_attention={
+        **TL.DELTA, "head_dim": 128, "chunk_size": 64})
+    x = jax.random.normal(jax.random.key(8), (1, 128, HIDDEN))
+    params = layer.init(jax.random.key(5), x)["params"]
+    run = lambda: jax.value_and_grad(lambda p: jnp.sum(
+        layer.apply({"params": p}, x)[0] ** 2))(params)
+    counts = lambda: [float(c) for c in layer.apply(
+        {"params": params}, x)[1][-2:]]
+    plain = run()
+    assert counts() == [4.0, 0.0]
+    interpreted_kernels(monkeypatch)
+    fused = run()
+    assert counts() == [4.0, 4.0]
+    np.testing.assert_allclose(fused[0], plain[0], rtol=1e-5)
+    _close(fused[1], plain[1], 1e-4)
+
+
+# ---------------------------------------------------------------------------
 # what cannot be built is refused
 # ---------------------------------------------------------------------------
 
@@ -502,7 +601,7 @@ def test_published_share_parameter_count(held, total):
     config = TL.real_config()
     config["model"]["extra"]["query_heads_held"] = [0, held]
     model = create_model(_model_config(config))
-    assert model.counters == MOE.MOE_COUNTERS
+    assert model.counters == MOE.MOE_COUNTERS + DL.DELTA_COUNTERS
     shapes = jax.eval_shape(model.init, jax.random.key(0))["params"]
     assert _count(shapes) == total
     if held != 16:
